@@ -9,6 +9,8 @@ segment store when the toolchain is unavailable.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,20 +20,49 @@ from typing import Optional
 import numpy as _np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_HERE, "build", "libshmstore.so")
+_BUILD = os.path.join(_HERE, "build")
 _lib = None
 _lib_lock = threading.Lock()
 
 
-def _stale(artifact: str, *sources: str) -> bool:
-    """True if the artifact is missing or older than any of its sources."""
-    if not os.path.exists(artifact):
-        return True
-    mtime = os.path.getmtime(artifact)
-    return any(
-        os.path.exists(src) and os.path.getmtime(src) > mtime
-        for src in sources
-    )
+def ensure_built(target: str, source: str) -> str:
+    """Path of ``build/<target>``, built now unless it was made from
+    exactly these bytes of ``source`` and the Makefile. The test is the
+    sources' content, never a modification time: ``build/`` is not in
+    git, so a copy of the tree or another session can leave a binary
+    there that looks newer than sources it was not built from. Raises
+    ``OSError`` / ``subprocess.SubprocessError`` when it cannot build."""
+    artifact = os.path.join(_BUILD, target)
+    stamp = artifact + ".sha256"
+    h = hashlib.sha256()
+    for name in (source, "Makefile"):
+        with open(os.path.join(_HERE, name), "rb") as f:
+            h.update(f.read())
+    digest = h.hexdigest()
+
+    def fresh() -> bool:
+        try:
+            with open(stamp) as f:
+                return f.read() == digest and os.path.exists(artifact)
+        except OSError:
+            return False
+
+    if fresh():
+        return artifact
+    os.makedirs(_BUILD, exist_ok=True)
+    with open(os.path.join(_BUILD, ".lock"), "w") as lock:
+        # One builder per tree; the others wait here and find it fresh.
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not fresh():
+            # -B: make itself goes by modification times.
+            subprocess.run(
+                ["make", "-C", _HERE, "-B", f"build/{target}"],
+                check=True, capture_output=True, timeout=180)
+            tmp = f"{stamp}.{os.getpid()}"
+            with open(tmp, "w") as f:
+                f.write(digest)
+            os.replace(tmp, stamp)
+    return artifact
 
 
 def _load_lib():
@@ -39,15 +70,11 @@ def _load_lib():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if _stale(_LIB_PATH, os.path.join(_HERE, "shm_store.cc")):
-            try:
-                subprocess.run(
-                    ["make", "-C", _HERE], check=True,
-                    capture_output=True, timeout=120,
-                )
-            except Exception as e:
-                raise RuntimeError(f"native store build failed: {e}") from e
-        lib = ctypes.CDLL(_LIB_PATH)
+        try:
+            path = ensure_built("libshmstore.so", "shm_store.cc")
+        except (OSError, subprocess.SubprocessError) as e:
+            raise RuntimeError(f"native store build failed: {e}") from e
+        lib = ctypes.CDLL(path)
         lib.rt_store_create.restype = ctypes.c_void_p
         lib.rt_store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
         lib.rt_store_attach.restype = ctypes.c_void_p

@@ -359,7 +359,6 @@ class HeadKiller:
             "RT_NATIVE_CONTROL_STORE": "1",
             "RT_CONTROL_STORE_PERSIST_PATH": self.persist_path,
             "JAX_PLATFORMS": "cpu",
-            "RT_JAX_PLATFORM": "cpu",
             # Small arena: SIGKILLed heads leak their /dev/shm files
             # until reboot; keep the per-cycle footprint tiny.
             "RT_OBJECT_STORE_MEMORY": str(64 * 1024 * 1024),

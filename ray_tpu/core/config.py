@@ -13,7 +13,7 @@ import json
 import os
 import threading
 from dataclasses import dataclass
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 
 def _parse_bool(v: str) -> bool:
@@ -166,17 +166,38 @@ _define("flight_recorder_enabled", bool, True,
         "and aggregate per-function per-stage latency on the head "
         "(reference: gcs_task_manager task events -> `ray summary "
         "tasks`). No effect when telemetry_enabled is off.")
-_define("hbm_bandwidth_gbps", float, 900.0,
-        "Peak per-chip HBM bandwidth in GB/s used as the roofline "
-        "denominator for rt_llm_roofline_frac (v5e ~819, v5p ~2765, "
-        "v4 ~1228; default ~v4-ish). Set per deployment for honest "
-        "fractions.")
 _define("event_log_max_bytes", int, 64 * 1024**2, "Structured event log cap.")
 _define("debug_dump_period_ms", int, 10_000,
         "Period for debug-state dumps (reference: "
         "debug_dump_period_milliseconds).")
 
 _ENV_PREFIX = "RT_"
+
+
+def jax_pinned_to_cpu() -> bool:
+    """True where ``JAX_PLATFORMS=cpu`` holds JAX — in this process and,
+    by inheritance, in every process it starts — to the CPU."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def export_compile_cache_dir() -> Optional[str]:
+    """Place JAX's persistent compile cache for this process and every
+    process it starts: where ``JAX_COMPILATION_CACHE_DIR`` already says,
+    else ``.jax_cache`` at the root of the checkout — one fixed path,
+    because the path is part of the cache key and a directory that moves
+    never hits. Only the environment variable is set (JAX reads it when
+    imported, children inherit it), so the caller never imports JAX and
+    no code sets another directory.
+
+    No default where JAX is pinned to the CPU: the cache is for the
+    chip's minutes-long compiles, and XLA's CPU loader logs a
+    machine-feature error for every entry it reads back."""
+    if jax_pinned_to_cpu():
+        return os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                                 os.path.join(root, ".jax_cache"))
 
 
 class Config:

@@ -508,17 +508,14 @@ class _Subscriber:
 
 
 def build_native() -> bool:
-    """Build the daemon binary if missing or stale; True when available."""
-    from .._native import _stale
+    """Build the daemon binary unless it is current; True when available."""
+    from .._native import ensure_built
 
-    if not _stale(_BINARY, os.path.join(_NATIVE_DIR, "control_store.cc")):
-        return True
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True, timeout=180)
-    except Exception:
+        ensure_built("control_store", "control_store.cc")
+    except (OSError, subprocess.SubprocessError):
         return False
-    return os.path.exists(_BINARY)
+    return True
 
 
 class ControlStoreProcess:

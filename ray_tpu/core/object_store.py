@@ -99,6 +99,14 @@ class SharedMemoryStore:
             except Exception:
                 self._arena = None
 
+    @property
+    def backend(self) -> str:
+        """Which store new objects land in: ``"arena"`` (the native
+        store) or ``"segment"`` (the Python one it falls back to when
+        the native build is unavailable) — ``ObjectMeta.backend``'s
+        vocabulary, so a failed native build can be seen."""
+        return "arena" if self._arena is not None else "segment"
+
     # -- create/seal ---------------------------------------------------------
     def put_serialized(self, object_id: ObjectID, obj: SerializedObject) -> ObjectMeta:
         """Zero-copy put: write the frame (header + inband + out-of-band

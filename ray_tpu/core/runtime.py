@@ -18,6 +18,11 @@ worker the module-level API routes to the worker's own runtime adapter.
 
 from __future__ import annotations
 
+import importlib.util
+import json
+import os
+import subprocess
+import sys
 import threading
 import time
 from concurrent.futures import Future
@@ -26,7 +31,12 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from . import serialization
-from .config import Config, config
+from .config import (
+    Config,
+    config,
+    export_compile_cache_dir,
+    jax_pinned_to_cpu,
+)
 from .exceptions import (
     ActorDiedError,
     ActorError,
@@ -140,6 +150,10 @@ class Runtime:
         self._blocked_workers: Dict[bytes, NodeManager] = {}
         self._put_counter = 0
         self._env = dict(env or {})
+        # Before any worker starts, so that each inherits it from its
+        # first instruction (a spawned child imports the driver's main
+        # module, and with it possibly JAX, before worker_entry runs).
+        export_compile_cache_dir()
         self._stopped = threading.Event()
         self._submit_buf: List[_TaskRecord] = []
         self._submit_cv = threading.Condition()
@@ -211,8 +225,8 @@ class Runtime:
         ncpu = num_cpus if num_cpus is not None else multiprocessing.cpu_count()
         node_resources = {"CPU": float(ncpu)}
         node_resources.update(resources or {})
-        # TPU resources discovered from the local JAX client, if any.
-        node_resources.setdefault("TPU", float(_local_chip_count()))
+        if "TPU" not in node_resources:
+            node_resources["TPU"] = float(_local_chip_count())
         for i in range(num_nodes):
             self.add_node(node_resources, object_store_memory=object_store_memory)
         self.scheduler.start()
@@ -2384,13 +2398,34 @@ class Runtime:
             pool.shutdown(wait=False)
 
 
-def _local_chip_count() -> int:
-    try:
-        import jax
+_DEVICE_PROBE = (
+    "import json, jax; d = jax.devices(); "
+    "print(json.dumps({'platform': d[0].platform, "
+    "'kind': d[0].device_kind, 'count': len(d)}))")
 
-        return len([d for d in jax.devices() if d.platform != "cpu"])
-    except Exception:
-        return 0
+
+def probe_devices(timeout: float = 120.0) -> Dict[str, Any]:
+    """What JAX finds on this host — ``{"platform", "kind", "count"}`` —
+    asked of a short-lived child, never of this process. A chip belongs
+    to one process at a time: a driver that initialised a backend would
+    hold the chip its own workers need. The child has exited, and let
+    the chip go, before this returns."""
+    proc = subprocess.run([sys.executable, "-c", _DEVICE_PROBE],
+                          capture_output=True, text=True, timeout=timeout)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"device probe failed (rc={proc.returncode}): "
+            f"{proc.stderr.strip()[-800:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def _local_chip_count() -> int:
+    """Accelerator chips on this host, for the node's ``TPU`` resource
+    when ``resources`` does not give one."""
+    if jax_pinned_to_cpu() or importlib.util.find_spec("jax") is None:
+        return 0  # nothing to count, and no child to pay for
+    found = probe_devices()
+    return 0 if found["platform"] == "cpu" else int(found["count"])
 
 
 # ---------------------------------------------------------------------------

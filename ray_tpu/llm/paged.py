@@ -275,8 +275,9 @@ def llm_metrics() -> Optional[Dict[str, Any]]:
                                 0.5, 1.0]),
                 "roofline_frac": get_or_create(
                     Gauge, "rt_llm_roofline_frac",
-                    "Achieved decode HBM bytes/s over the configured "
-                    "peak bandwidth (hbm_bandwidth_gbps x mesh size)"),
+                    "Achieved decode HBM bytes/s over the device's "
+                    "published peak bandwidth x mesh size (unset on a "
+                    "device with no published peak)"),
                 "decode_steps": get_or_create(
                     Gauge, "rt_llm_decode_steps_per_s",
                     "Steady-state decode steps/s over the current "

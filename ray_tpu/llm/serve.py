@@ -21,6 +21,7 @@ accelerator-resident serving loop at all.
 from __future__ import annotations
 
 import asyncio
+import time
 from typing import Optional
 
 import jax
@@ -61,7 +62,9 @@ class LLMServer:
                  max_pending: Optional[int] = 256,
                  queue_timeout_s: Optional[float] = 30.0,
                  decode_block: int = 1, tp: int = 1):
+        t0 = time.monotonic()
         params, cfg = _build_params(model, seed, checkpoint_path)
+        t_params = time.monotonic()
         self.default_max_tokens = default_max_tokens
         # tp > 1: tensor-shard this replica over the first tp local
         # devices — params by their logical axes, KV pages on the
@@ -88,7 +91,14 @@ class LLMServer:
                                  max_pending=max_pending,
                                  queue_timeout_s=queue_timeout_s,
                                  decode_block=decode_block, mesh=mesh)
+        t_engine = time.monotonic()
         self.engine.warmup()  # compile before the replica is routable
+        # Set-up seconds, apart from any request's: building the weights,
+        # the engine (placement + page pool), and the warm-up request
+        # that compiles both programs.
+        self._startup_s = {"params": round(t_params - t0, 3),
+                           "engine": round(t_engine - t_params, 3),
+                           "warmup": round(time.monotonic() - t_engine, 3)}
         self.engine.start()
         self._recoveries: list = []  # crash-path restore latencies (ms)
 
@@ -194,7 +204,13 @@ class LLMServer:
         return info
 
     def stats(self) -> dict:
+        from ..parallel.mesh import device_triple
+
         return {
+            # What this replica runs on, as JAX reports it HERE: the
+            # replica holds the chip, so a driver asks it, not JAX.
+            "device": device_triple(),
+            "startup_s": dict(self._startup_s),
             "tokens_generated": self.engine.tokens_generated,
             "requests_completed": self.engine.requests_completed,
             "requests_shed": self.engine.requests_shed,

@@ -167,11 +167,15 @@ def init_params(key, cfg: GPT2Config) -> Tuple[Dict, Dict]:
 
 
 def _attend(q, k, v, cfg: GPT2Config, rules):
+    from ..parallel.sharding import current_mesh, smap, spec_for
+
     impl = cfg.attention_impl
     if impl in ("auto", "flash", "reference"):
         from jax.ad_checkpoint import checkpoint_name
 
-        o = attention_op(q, k, v, causal=True, impl=impl)
+        o = attention_op(
+            q, k, v, causal=True, impl=impl, mesh=current_mesh(),
+            spec=spec_for(("batch", "heads", None, None), rules))
         # Named for the "dots_attn" remat policy: saving attention outputs
         # skips re-running the flash kernel in the backward pass (the
         # single biggest recompute in the block at ~400MB saved for 355M).
@@ -179,8 +183,6 @@ def _attend(q, k, v, cfg: GPT2Config, rules):
     # Sequence-parallel impls: nest a shard_map over the ambient mesh so the
     # GSPMD program hands locally-sharded blocks to the ring/a2a body.
     from functools import partial as _partial
-
-    from ..parallel.sharding import current_mesh, smap, spec_for
 
     mesh = current_mesh()
     if mesh is None:

@@ -138,7 +138,11 @@ def _block(x, p, cfg: LlamaConfig, rules, positions):
     k = rope(k, positions, cfg.rope_theta)
     k = _repeat_kv(k, h // hkv)
     v = _repeat_kv(v, h // hkv)
-    o = attention_op(q, k, v, causal=True)
+    from ..parallel.sharding import current_mesh, spec_for
+
+    o = attention_op(
+        q, k, v, causal=True, mesh=current_mesh(),
+        spec=spec_for(("batch", "heads", None, None), rules))
     o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
     o = o @ p["wo"].astype(o.dtype)
     x = x + constrain(o, ("batch", "seq", None), rules)

@@ -16,9 +16,10 @@ algorithm, tiled for the MXU:
   - bf16 matmul operands, fp32 accumulation (``preferred_element_type``)
 
 ``flash_attention`` is differentiable end-to-end in Pallas: forward kernel
-plus dq and dk/dv backward kernels (blockwise recompute from the saved LSE
-— no S×S materialization anywhere). An XLA blockwise fallback covers
-shapes the kernels can't tile.
+plus a fused dq/dk/dv backward kernel (blockwise recompute from the saved
+LSE — no S×S materialization anywhere). An explicit request for the
+kernel runs the kernel or raises: a shape it cannot tile is an error, and
+on TPU it is compiled, never interpreted.
 """
 
 from __future__ import annotations
@@ -34,10 +35,7 @@ _NEG_INF = -1e30
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -413,56 +411,13 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
-    """Backward: pallas kernels (dq + dk/dv) when shapes tile; XLA
-    blockwise recompute otherwise. Both recompute P per block from the
-    saved LSE (no S×S materialization across blocks) with bf16 matmul
-    operands and fp32 accumulation.
-    """
+    """Backward: the fused pallas kernel, recomputing P per block from
+    the saved LSE (no S×S materialization across blocks) with bf16 matmul
+    operands and fp32 accumulation. ``flash_attention`` admits only
+    shapes the blocks divide, so there is no other path."""
     q, k, v, o, lse = res
-    sq, sk = q.shape[2], k.shape[2]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    if sq % bq == 0 and sk % bk == 0:
-        return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
-                                 bq, bk, interpret=not _on_tpu())
-
-    # delta = rowsum(dO * O), fp32 elementwise (cheap, bandwidth-bound)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)  # [B,H,Sq]
-
-    n_blocks = max(1, sk // block_k)
-
-    def body(kb, carry):
-        dq, dk, dv = carry
-        ks = jax.lax.dynamic_slice_in_dim(k, kb * block_k, block_k, axis=2)
-        vs = jax.lax.dynamic_slice_in_dim(v, kb * block_k, block_k, axis=2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, ks,
-                       preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = jnp.arange(sq)[:, None]
-            k_pos = jnp.arange(block_k)[None, :] + kb * block_k
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse[..., None])  # [B,H,Sq,block_k] fp32
-        p_lo = p.astype(q.dtype)
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p_lo, do,
-                            preferred_element_type=jnp.float32)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", do, vs,
-                        preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[..., None]) * scale).astype(q.dtype)
-        dq_blk = jnp.einsum("bhqk,bhkd->bhqd", ds, ks,
-                            preferred_element_type=jnp.float32)
-        dk_blk = jnp.einsum("bhqk,bhqd->bhkd", ds, q,
-                            preferred_element_type=jnp.float32)
-        dk = jax.lax.dynamic_update_slice_in_dim(
-            dk, dk_blk, kb * block_k, axis=2)
-        dv = jax.lax.dynamic_update_slice_in_dim(
-            dv, dv_blk, kb * block_k, axis=2)
-        return dq + dq_blk, dk, dv
-
-    shape_f32 = lambda t: jnp.zeros(t.shape, jnp.float32)
-    dq, dk, dv = jax.lax.fori_loop(
-        0, n_blocks, body, (shape_f32(q), shape_f32(k), shape_f32(v)))
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
+                             block_q, block_k, interpret=not _on_tpu())
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -479,44 +434,76 @@ def _tileable(q, k, causal: bool, block_q: int, block_k: int):
     return bq, bk, ok
 
 
+def _blocks_or_raise(q, k, causal: bool, block_q: int, block_k: int):
+    bq, bk, ok = _tileable(q, k, causal, block_q, block_k)
+    if not ok:
+        raise ValueError(
+            f"flash attention cannot tile q seq {q.shape[2]} / k seq "
+            f"{k.shape[2]} with blocks {bq}/{bk} (causal={causal}); use "
+            "impl='reference' or 'auto' for such a shape")
+    return bq, bk
+
+
+def _use_reference(impl: str, q, k, causal: bool,
+                   block_q: int, block_k: int) -> bool:
+    """'reference' always; 'auto' wherever the kernel would not run
+    compiled (off TPU, or a shape it cannot tile); 'flash' never."""
+    if impl == "auto":
+        return not (_on_tpu()
+                    and _tileable(q, k, causal, block_q, block_k)[2])
+    return impl == "reference"
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512):
     """Flash attention. q/k/v: [batch, heads, seq, head_dim].
 
-    Pallas kernel on TPU; interpreter mode (same code path) on CPU tests.
-    Falls back to :func:`mha_reference` for shapes the kernel can't tile.
+    The Pallas kernel: compiled on TPU, interpreted (same code path) in
+    CPU tests. Raises ``ValueError`` for a shape the kernel cannot tile.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    bq, bk, ok = _tileable(q, k, causal, block_q, block_k)
-    if not ok:
-        return mha_reference(q, k, v, causal=causal, scale=scale)
+    bq, bk = _blocks_or_raise(q, k, causal, block_q, block_k)
     return _flash(q, k, v, causal, scale, bq, bk)
 
 
 def attention(q, k, v, causal: bool = True, impl: str = "auto",
-              scale: Optional[float] = None):
-    """Dispatch: 'flash' | 'reference' | 'auto' (flash on TPU)."""
-    if impl == "reference" or (impl == "auto" and not _on_tpu()):
+              scale: Optional[float] = None, mesh=None, spec=None):
+    """Dispatch: 'flash' | 'reference' | 'auto' (flash on TPU for shapes
+    it tiles, the reference elsewhere).
+
+    ``mesh`` / ``spec``: GSPMD cannot partition a Mosaic kernel, so in a
+    program over several devices the kernel runs per shard inside a
+    ``shard_map`` over the whole ``mesh``, q/k/v and the output split as
+    the PartitionSpec ``spec`` says. Attention is independent across
+    batch and heads, so ``spec`` may shard those two dims and no other.
+    The reference is plain XLA and needs neither.
+    """
+    if _use_reference(impl, q, k, causal, 512, 512):
         return mha_reference(q, k, v, causal=causal, scale=scale)
-    return flash_attention(q, k, v, causal=causal, scale=scale)
+    fn = functools.partial(flash_attention, causal=causal, scale=scale)
+    # Not where the caller is itself a shard_map body (the pp pipeline's
+    # stages): there the mesh axes are manual already.
+    if (mesh is not None and mesh.size > 1
+            and not jax.sharding.get_abstract_mesh().manual_axes):
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
+    return fn(q, k, v)
 
 
 def attention_with_lse(q, k, v, causal: bool = True,
                        scale: Optional[float] = None, impl: str = "auto",
                        block_q: int = 512, block_k: int = 512):
-    """Attention returning (o, lse) — pallas flash forward on TPU,
-    reference path elsewhere. Forward-only contract (no custom vjp):
-    the ring TRAINING path uses the autodiff-able einsum body; this is
-    the serving/inference block used by ``ring_flash_attention_local``.
+    """Attention returning (o, lse); ``impl`` as in :func:`attention`.
+    Forward-only contract (no custom vjp): the ring TRAINING path uses
+    the autodiff-able einsum body; this is the serving/inference block
+    used by ``ring_flash_attention_local``.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if impl == "reference" or (impl == "auto" and not _on_tpu()):
+    if _use_reference(impl, q, k, causal, block_q, block_k):
         return mha_reference_with_lse(q, k, v, causal=causal, scale=scale)
-    bq, bk, ok = _tileable(q, k, causal, block_q, block_k)
-    if not ok:
-        return mha_reference_with_lse(q, k, v, causal=causal, scale=scale)
+    bq, bk = _blocks_or_raise(q, k, causal, block_q, block_k)
     return _flash_fwd_pallas(q, k, v, causal, scale, bq, bk,
                              interpret=not _on_tpu())

@@ -152,16 +152,8 @@ class Bootstrap:
         # jax.default_backend(): the latter would initialize backends
         # before jax.distributed, which is exactly the ordering bug
         # this guard exists to avoid.
-        try:
-            platforms = jax.config.jax_platforms or ""
-        except AttributeError:
-            platforms = ""
-        if "cpu" in platforms.split(","):
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except (AttributeError, ValueError):
-                pass  # option absent (very old jax) or gloo unavailable
+        if "cpu" in (jax.config.jax_platforms or "").split(","):
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator,
             num_processes=self.world_size,

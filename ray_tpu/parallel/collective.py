@@ -475,17 +475,6 @@ class HostGroup:
 # aliases so library code imports one module for both styles.
 # --------------------------------------------------------------------------
 
-def axis_size(axis_name: str):
-    """Version-compat ``jax.lax.axis_size``: the symbol only exists on
-    jax >= 0.6; older jax computes it as a psum of ones over the axis
-    (constant-folded at trace time). Every in-graph collective in
-    ``parallel/`` must use THIS, not jax.lax directly."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 class ops:
     """In-graph collective ops (compiled into the surrounding program)."""
 
@@ -502,6 +491,6 @@ class ops:
     @staticmethod
     def ring_permute(x, axis_name: str, shift: int = 1):
         """Rotate shards around the ring defined by a mesh axis."""
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         perm = [(i, (i + shift) % n) for i in range(n)]
         return jax.lax.ppermute(x, axis_name, perm)

@@ -31,6 +31,25 @@ import numpy as np
 
 AXIS_ORDER = ("dp", "fsdp", "pp", "sp", "tp", "ep")
 
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" system architecture (197 TFLOP/s
+# bf16, 16 GB HBM2e at 819 GB/s per chip). A device that is not here has
+# no roofline: callers report nothing for it rather than assume a peak.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+}
+
+
+def device_triple() -> Dict[str, object]:
+    """``{"platform", "kind", "count"}`` of this process's devices as JAX
+    reports them. Initialises the backend — for the process that holds
+    the chip (a replica, a train worker), never a driver."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
 
 @dataclass(frozen=True)
 class MeshSpec:

@@ -19,7 +19,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .collective import axis_size
 
 
 def router_topk(logits, k: int):
@@ -77,7 +76,7 @@ def moe_ffn_local(x, router_w, w_in, w_out, *, num_experts: int,
     Returns (y [tokens_local, model], aux_loss scalar).
     """
     tokens, model = x.shape
-    ep = axis_size(axis_name) if axis_name else 1
+    ep = jax.lax.axis_size(axis_name) if axis_name else 1
     e_local = num_experts // ep
 
     logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)

@@ -19,7 +19,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .collective import axis_size
 
 
 def pipeline_apply_local(stage_fn: Callable, stage_params: Any, microbatches,
@@ -37,7 +36,7 @@ def pipeline_apply_local(stage_fn: Callable, stage_params: Any, microbatches,
     Returns [M, micro_batch, ...] outputs, valid on the LAST rank and
     broadcast to all ranks (so the caller's out_spec can be replicated).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     m = microbatches.shape[0]
     total_ticks = m + n - 1

@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .collective import axis_size
 
 _NEG_INF = -1e30
 
@@ -36,7 +35,7 @@ def ring_attention_local(q, k, v, axis_name: str = "sp",
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, h, s_local, d = q.shape
 
@@ -104,7 +103,7 @@ def ring_flash_attention_local(q, k, v, axis_name: str = "sp",
 
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, h, s_local, d = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]

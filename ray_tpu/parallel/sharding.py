@@ -86,14 +86,8 @@ def constrain(x, logical_axes: Sequence[Optional[str]],
     spec = spec_for(logical_axes, rules)
     if not len(spec):
         return x
-    mesh = current_mesh()
-    if mesh is None:
-        try:
-            ambient = jax.sharding.get_abstract_mesh()
-            if ambient is None or ambient.empty:
-                return x
-        except Exception:
-            return x
+    if current_mesh() is None and jax.sharding.get_abstract_mesh().empty:
+        return x
     return jax.lax.with_sharding_constraint(x, spec)
 
 
@@ -136,18 +130,6 @@ def current_mesh() -> Optional[Mesh]:
     return _CURRENT_MESH[0]
 
 
-def use_mesh(mesh: Mesh):
-    """Version-compat ``jax.set_mesh`` context: the symbol only exists
-    on newer jax; older jax enters the mesh context directly (``with
-    mesh:``), which makes bare PartitionSpecs resolve the same way.
-    ALWAYS use this (not jax.set_mesh) around pjit calls that rely on
-    bare specs."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def under_mesh(mesh: Mesh, fn):
     """Wrap ``fn`` so every call runs with ``mesh`` as BOTH the repo's
     current mesh (so :func:`constrain` resolves) and the ambient jax
@@ -160,7 +142,7 @@ def under_mesh(mesh: Mesh, fn):
         prev = current_mesh()
         set_current_mesh(mesh)
         try:
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return target(*args, **kwargs)
         finally:
             set_current_mesh(prev)
@@ -175,13 +157,7 @@ def under_mesh(mesh: Mesh, fn):
 
 
 def smap(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with version compat (jax>=0.8 moved it to jax.shard_map
-    and renamed check_rep->check_vma)."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check (the ring
+    and pipeline bodies mix replicated and per-shard values freely)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -19,13 +19,12 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import attention as _attention
-from .collective import axis_size
 
 
 def ulysses_attention_local(q, k, v, axis_name: str = "sp",
                             causal: bool = True, impl: str = "auto"):
     """Per-shard body (inside shard_map). q/k/v: [B, H, S_local, D]."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def seq_to_heads(x):
         # [B, H, S/n, D] -> [B, H/n, S, D]: split heads dim, concat seq dim.

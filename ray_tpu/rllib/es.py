@@ -62,10 +62,8 @@ class ESEvalWorker:
                  noise_size: int = 2_000_000, noise_seed: int = 42):
         import jax
 
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+        # CPU rollouts; the chip stays with the learner (rollout_worker.py).
+        jax.config.update("jax_platforms", "cpu")
         from jax.flatten_util import ravel_pytree
 
         cfg = policy_config or {}

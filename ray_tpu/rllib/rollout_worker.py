@@ -37,11 +37,10 @@ class RolloutWorker:
         import jax
 
         # Rollout workers always run CPU inference — the learner owns the
-        # accelerator (reference: rollout workers are CPU actors).
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+        # accelerator, and a chip belongs to one process (reference:
+        # rollout workers are CPU actors). Takes effect because a fresh
+        # worker process has not initialised a backend yet.
+        jax.config.update("jax_platforms", "cpu")
         self.env = make_env(env_spec, num_envs, seed + worker_index * 1000)
         cfg = policy_config or {}
         # Connector pipelines sit between env and policy (reference:

@@ -331,7 +331,6 @@ def test_daemon_rejoins_replacement_head(tmp_path):
         "RT_CLUSTER_LISTENER_PORT": str(port),
         "RT_OBJECT_STORE_MEMORY": str(64 * 1024 * 1024),
         "JAX_PLATFORMS": "cpu",
-        "RT_JAX_PLATFORM": "cpu",
         "PYTHONUNBUFFERED": "1",
         "PYTHONPATH": repo + os.pathsep + env.get("PYTHONPATH", ""),
     })

@@ -170,8 +170,6 @@ def test_moe_local_no_ep():
 def test_moe_expert_parallel():
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
-
     mesh = MeshSpec(ep=4).build(jax.devices()[:4])
     tokens, model, hidden, E = 32, 8, 16, 4
     key = jax.random.PRNGKey(7)
@@ -180,13 +178,13 @@ def test_moe_expert_parallel():
     w_in = jax.random.normal(jax.random.fold_in(key, 2), (E, model, hidden)) * 0.1
     w_out = jax.random.normal(jax.random.fold_in(key, 3), (E, hidden, model)) * 0.1
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(moe_ffn_local, num_experts=E, top_k=1, axis_name="ep",
                 capacity_factor=4.0),
         mesh=mesh,
         in_specs=(P("ep"), P(), P("ep"), P("ep")),
         out_specs=(P("ep"), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(x, router_w, w_in, w_out)
     assert y.shape == x.shape
@@ -200,8 +198,6 @@ def test_moe_expert_parallel_matches_local():
     removes the split axis and inserts the device axis at concat)."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
-
     mesh = MeshSpec(ep=4).build(jax.devices()[:4])
     tokens, model, hidden, E = 32, 8, 16, 8  # e_local = 2
     key = jax.random.PRNGKey(7)
@@ -212,13 +208,13 @@ def test_moe_expert_parallel_matches_local():
     w_out = jax.random.normal(
         jax.random.fold_in(key, 3), (E, hidden, model)) * 0.1
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(moe_ffn_local, num_experts=E, top_k=1, axis_name="ep",
                 capacity_factor=8.0),
         mesh=mesh,
         in_specs=(P("ep"), P(), P("ep"), P("ep")),
         out_specs=(P("ep"), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, _ = fn(x, router_w, w_in, w_out)
     ref = jnp.concatenate([

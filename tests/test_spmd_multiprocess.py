@@ -26,17 +26,7 @@ def _spmd_train_fn(config):
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        # Older jax has no such config option; the XLA flag is the
-        # equivalent (must land before the backend initializes, which
-        # holds here — this worker process only just imported jax).
-        import os
-
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=2").strip()
+    jax.config.update("jax_num_cpu_devices", 2)
 
     from ray_tpu.core.gcs_socket import ControlStoreClient
     from ray_tpu.parallel.bootstrap import Bootstrap

@@ -1,0 +1,138 @@
+"""The Pallas kernels of the main path compile for the real chip.
+
+Interpret mode (every other test) cannot see what the TPU's compiler
+refuses: a slice off the tiling, more VMEM than a kernel may take, a
+kernel GSPMD cannot partition. libtpu compiles for a chip that is
+described and not attached (``/opt/skills/guides/on-chip-measurement``
+§2.3), so these ask it — shapes, not arrays; nothing runs, and a pass is
+not a chip run. Skipped where the topology cannot be described.
+
+Code that asks JAX for its backend still sees the CPU here, so the
+kernels' own ``_on_tpu`` is steered from the test.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops import attention as A
+from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
+
+# [batch, heads, seq, head_dim] of the training cells (bench.py): gpt2-774m
+# and gpt2-1.5b at batch 8 x seq 1024, and the 355M long-context run at 16k.
+TRAIN_SHAPES = {
+    "gpt2-774m": (8, 20, 1024, 64),
+    "gpt2-1.5b": (8, 25, 1024, 64),
+    "seq-16k": (1, 16, 16384, 64),
+}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def compiled_for_tpu(monkeypatch):
+    """Kernels take their compiled (not interpreted) branch, and the
+    persistent cache stays out of it: a program compiled for a described
+    chip is written there but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernels(fn, *specs) -> int:
+    """Compile ``fn`` for the specs' (described) devices; how many
+    Mosaic kernels the compiled program holds."""
+    return jax.jit(fn).lower(*specs).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def test_described_chip_is_the_one_in_the_peaks_table(v5e):
+    assert v5e[0].platform == "tpu" and len(v5e) == 4
+    assert v5e[0].device_kind in DEVICE_PEAKS
+
+
+@pytest.mark.parametrize("name", list(TRAIN_SHAPES))
+def test_flash_forward_and_backward_compile(v5e, name):
+    x = jax.ShapeDtypeStruct(TRAIN_SHAPES[name], jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    assert _kernels(lambda q, k, v: A.flash_attention(q, k, v), x, x, x) == 1
+
+    def loss(q, k, v):
+        return A.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    # the forward that saves (o, lse) and the fused dq/dk/dv backward
+    assert _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 2
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_ring_flash_block_compiles(v5e, causal):
+    """The (o, lse) block ``ring_flash_attention_local`` calls per ring
+    step — diagonal steps causal, earlier shards not — at the local
+    shape of 16k tokens over sp=4."""
+    x = jax.ShapeDtypeStruct((1, 16, 4096, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    assert _kernels(lambda q, k, v: A.attention_with_lse(
+        q, k, v, causal=causal, impl="flash"), x, x, x) == 1
+
+
+def test_ring_flash_attention_compiles_over_four_chips(v5e):
+    from ray_tpu.parallel.ring import ring_attention
+
+    mesh = MeshSpec(sp=4).build(v5e)
+    x = jax.ShapeDtypeStruct(
+        (1, 16, 16384, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, None, "sp", None)))
+    text = jax.jit(lambda q, k, v: ring_attention(
+        q, k, v, mesh, causal=True, batch_axes=(), heads_axis=None,
+        impl="flash")).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 2  # causal + full block
+    assert "collective-permute" in text  # the K/V rotation
+
+
+def test_flash_kernel_is_sharded_not_partitioned(v5e):
+    """GSPMD refuses to partition a Mosaic kernel, so under a mesh
+    ``attention`` runs it per (batch, heads) shard in a shard_map — and
+    without that wrapper the compiler's refusal is an error, not a
+    quiet reference run."""
+    mesh = MeshSpec(fsdp=2, tp=2).build(v5e)
+    spec = P("fsdp", "tp", None, None)
+    x = jax.ShapeDtypeStruct(TRAIN_SHAPES["gpt2-774m"], jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+    assert _kernels(lambda q, k, v: A.attention(
+        q, k, v, impl="flash", mesh=mesh, spec=spec), x, x, x) == 1
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _kernels(lambda q, k, v: A.attention(q, k, v, impl="flash"),
+                 x, x, x)
+
+
+def test_explicit_flash_raises_on_a_shape_it_cannot_tile():
+    """seq 768 is not a multiple of the 512 block: under 'flash' that is
+    an error; under 'auto' it is the reference."""
+    x = jnp.zeros((1, 2, 768, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="cannot tile"):
+        A.attention(x, x, x, impl="flash")
+    with pytest.raises(ValueError, match="cannot tile"):
+        A.attention_with_lse(x, x, x, impl="flash")
+    assert A.attention(x, x, x, impl="auto").shape == x.shape
