@@ -1366,15 +1366,12 @@ def bench_llm(on_tpu: bool) -> dict:
 
 
 def bench_llm_longgen(on_tpu: bool, smoke: bool = False) -> dict:
-    """Long-generation decode throughput vs the HBM roof (ISSUE 17 —
-    this PR's headline number). All slots prefill up front, then the
-    engine sits in the pure ``decode_only_fn`` loop for the whole
-    generation: the profiler window is RESET after the last prefill so
-    ``roofline_frac`` measures steady-state decode alone, not pipeline
-    fill. Commits tok/s, the decode block size, the roofline fraction,
-    and the bytes-per-step attribution (params vs KV pages) — the
-    decode-step profile the acceptance criterion asks for when the
-    fraction lands under 0.5. A tp2 parity sub-stage reruns a short
+    """Long-generation decode throughput. All slots prefill up front,
+    then the engine sits in the pure ``decode_only_fn`` loop for the
+    whole generation. Commits tok/s, the decode block size and what the
+    engine's own step counters say the steps were made of (how far a
+    step is from the device's limits is a device-trace question:
+    ``benchmark/``). A tp2 parity sub-stage reruns a short
     greedy generation on a 2-device tp mesh and asserts bit-for-bit
     token parity vs tp1; skipped cleanly when the host only has one
     device."""
@@ -1415,9 +1412,9 @@ def bench_llm_longgen(on_tpu: bool, smoke: bool = False) -> dict:
         engine.step()
         guard += 1
         assert guard < 100_000, "longgen prefill phase did not converge"
-    # Phase 2: pure long-gen decode, measured on a fresh roofline
-    # window (satellite: the window-reset API exists exactly for this).
-    engine.reset_decode_profile()
+    # Phase 2: pure long-gen decode, counted from here.
+    steps0 = engine.steps_block + engine.steps_decode_only
+    active0 = engine.slot_steps_active
     produced0 = sum(len(h._tokens) for h in handles)
     t0 = time.perf_counter()
     while engine.step():
@@ -1426,28 +1423,20 @@ def bench_llm_longgen(on_tpu: bool, smoke: bool = False) -> dict:
     assert all(h.result(timeout=0).finish_reason == "length"
                for h in handles)
     produced = sum(len(h.result(timeout=0).tokens) for h in handles)
-    prof = engine.decode_profile()
-    kv_bytes = (engine._pool.used_count * engine._kv_page_bytes)
+    steps = engine.steps_block + engine.steps_decode_only - steps0
     out = {
         "model": model,
         "tokens_per_s_longgen": round((produced - produced0) / dt, 1),
         "decode_block": block,
         "long_new_tokens": max_new,
         "concurrent_slots": slots,
-        "decode_steps": prof["steps"],
-        "steps_per_s": prof["steps_per_s"],
-        "avg_step_ms": prof["avg_step_ms"],
-        # None off the peaks table (parallel.mesh.DEVICE_PEAKS).
-        "roofline_frac": prof["roofline_frac"],
-        "achieved_gbps": prof["achieved_gbps"],
-        "hbm_gbps": prof["hbm_gbps"],
-        "devices": prof["devices"],
-        # Decode-step byte attribution: where a step's HBM traffic goes.
-        # At 1B scale the params stream dominates until the pool fills;
-        # the KV share grows linearly over a long generation.
-        "bytes_per_step": prof["bytes_per_step"],
-        "param_bytes": engine._param_bytes,
-        "kv_resident_bytes_end": kv_bytes,
+        "decode_steps": steps * block,
+        "steps_per_s": round(steps * block / dt, 2),
+        "avg_step_ms": round(dt / max(1, steps * block) * 1e3, 4),
+        # slot-steps that decoded a token, and tokens the device computed
+        # past a request's end (lag-1 dispatch: one block a request)
+        "slot_steps_active": engine.slot_steps_active - active0,
+        "overshoot_tokens": engine.overshoot_tokens,
     }
     del engine
     gc.collect()
@@ -1602,10 +1591,8 @@ def bench_flight(on_tpu: bool, smoke: bool = False) -> dict:
     (queue/sched/exec/transfer) p50/p99 plus the stage-sum/total
     fraction, which is ~1.0 by construction and asserted by the smoke
     test. LLM half — drive a paged engine, report per-request stage
-    p50s from the response ``timing`` metadata, and commit the decode
-    roofline fraction (achieved HBM bytes/step over the device's
-    published peak; None for a device with none) so regressions in
-    decode-step bandwidth show up between rounds."""
+    p50s from the response ``timing`` metadata and the engine's step
+    counters."""
     import gc
 
     import ray_tpu as rt
@@ -1654,7 +1641,7 @@ def bench_flight(on_tpu: bool, smoke: bool = False) -> dict:
         out["task_stage_sum_frac_mean"] = round(
             sum(fracs) / len(fracs), 4)
 
-    # -- LLM half: per-request stage timing + decode roofline. Engine
+    # -- LLM half: per-request stage timing + step counters. Engine
     # lives in THIS process, so its rt_llm_* series land in the local
     # registry the scrape stage reads.
     import jax
@@ -1682,7 +1669,6 @@ def bench_flight(on_tpu: bool, smoke: bool = False) -> dict:
             rng.integers(1, cfg.vocab_size, size=prompt_len).tolist(),
             max_new=max_new) for _ in range(n_reqs)]
         timings = [h.result(timeout=300).timing for h in handles]
-        prof = engine.decode_profile()
     finally:
         engine.stop()
     timings = [t for t in timings if t]
@@ -1691,10 +1677,9 @@ def bench_flight(on_tpu: bool, smoke: bool = False) -> dict:
                 "decode_s", "decode_per_token_s", "total_s"):
         pct = percentiles([t[key] * 1e3 for t in timings])
         out[f"llm_{key[:-2]}_ms_p50"] = pct["p50"]
-    out["llm_decode_steps"] = prof["steps"]
-    out["llm_decode_bytes_per_step"] = prof["bytes_per_step"]
-    out["llm_achieved_gbps"] = prof["achieved_gbps"]
-    out["rt_llm_roofline_frac"] = prof["roofline_frac"]
+    out["llm_decode_steps"] = (engine.steps_block
+                               + engine.steps_decode_only) * block
+    out["llm_slot_steps_active"] = engine.slot_steps_active
     del engine, params
     gc.collect()
     return out
@@ -1882,8 +1867,6 @@ def scrape_telemetry(port: int = 18269) -> dict:
         "rt_task_stage_seconds_count": total(
             "rt_task_stage_seconds_count"),
         "rt_llm_stage_seconds_count": total("rt_llm_stage_seconds_count"),
-        "rt_llm_roofline_frac": total("rt_llm_roofline_frac"),
-        "rt_llm_decode_steps_per_s": total("rt_llm_decode_steps_per_s"),
     }
 
 
@@ -2069,15 +2052,14 @@ def smoke() -> dict:
         result["llm_drain"] = bench_llm_drain(smoke=True)
     except Exception as e:  # noqa: BLE001
         result["llm_drain_error"] = repr(e)[:300]
-    # Long-gen decode + roofline stage (ISSUE 17), incl. the tp2 parity
+    # Long-gen decode stage (ISSUE 17), incl. the tp2 parity
     # sub-stage when the host exposes >= 2 (possibly virtual) devices.
     try:
         result["llm_longgen"] = bench_llm_longgen(False, smoke=True)
     except Exception as e:  # noqa: BLE001
         result["llm_longgen_error"] = repr(e)[:300]
-    # Flight-recorder stage BEFORE the scrape: it sets the roofline
-    # gauge and observes the stage histograms this process's /metrics
-    # must then contain.
+    # Flight-recorder stage BEFORE the scrape: it observes the stage
+    # histograms this process's /metrics must then contain.
     try:
         result["bench_flight"] = bench_flight(False, smoke=True)
     except Exception as e:  # noqa: BLE001

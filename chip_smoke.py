@@ -227,8 +227,11 @@ def serve_phase(found: dict, seed: int) -> dict:
                 and stats["sessions_resident"] == 1, f"stats: {stats}")
         require(stats["device"] == base["device"], "device changed")
         log(f"serve: stats agree — {sent} requests, {tokens} tokens, "
-            f"{hits} prefix hits, 0 shed; decode profile "
-            f"{stats['decode_profile']}")
+            f"{hits} prefix hits, 0 shed; {stats['steps_block']} fused and "
+            f"{stats['steps_decode_only']} decode-only steps, "
+            f"{stats['slot_steps_active']} of {stats['slot_steps']} "
+            f"slot-steps decoded, {stats['overshoot_tokens']} tokens "
+            "overshot")
         return stats["device"]
     finally:
         try:

@@ -50,6 +50,7 @@ import numpy as np
 
 from ..core.exceptions import EngineStoppedError
 from ..models import llama
+from ..observability import tracing
 from .paged import OverloadedError, PagePool, RadixIndex, llm_metrics
 
 # Interned tag keys for the per-stage histogram (request finish path).
@@ -64,14 +65,15 @@ def _sample(logits, temps, seeds, qpos):
     always draws from ``fold_in(PRNGKey(s), qpos)`` — independent of
     batching, decode blocking, or how much prefill a prefix hit
     skipped. [B,V] -> [B]."""
-    greedy = jnp.argmax(logits, axis=-1)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1)
 
-    def one(lg, t, s, q):
-        key = jax.random.fold_in(jax.random.PRNGKey(s), q)
-        return jax.random.categorical(key, lg / jnp.maximum(t, 1e-6))
+        def one(lg, t, s, q):
+            key = jax.random.fold_in(jax.random.PRNGKey(s), q)
+            return jax.random.categorical(key, lg / jnp.maximum(t, 1e-6))
 
-    sampled = jax.vmap(one)(logits, temps, seeds, qpos)
-    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        sampled = jax.vmap(one)(logits, temps, seeds, qpos)
+        return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
 
 @dataclass
@@ -192,6 +194,11 @@ class SlotEngine:
     # (training) table leaves replicated — tp-sharded serving maps it to
     # tp so the KV pages (the decode bandwidth bill) split across chips.
     SERVE_RULES = {"kv": "tp"}
+    TIMINGS_KEPT = 1024  # finished requests request_timings() remembers
+    # cumulative per-step accounting, returned by LLMServer.stats()
+    STEP_COUNTERS = ("steps_block", "steps_decode_only", "slot_steps",
+                     "slot_steps_active", "slot_steps_prefill_wait",
+                     "prefill_tokens", "overshoot_tokens")
 
     def __init__(self, params, cfg: llama.LlamaConfig, num_slots: int = 8,
                  chunk: int = 64, seed: int = 0, decode_block: int = 1,
@@ -360,21 +367,6 @@ class SlotEngine:
         # warmup() would race a running engine thread's dispatches.
         zero = jnp.zeros((1,), jnp.int32)
         self._cache = self._copy_pages(self._cache, zero, zero)
-        # Decode-step roofline profiler (flight recorder, LLM path): a
-        # decode step is memory-bound — it must stream the params plus
-        # every resident KV page through HBM once. Model footprint is
-        # measured from the actual pytrees; achieved bytes/s over the
-        # device's published peak bandwidth is rt_llm_roofline_frac.
-        self._param_bytes = sum(
-            x.size * x.dtype.itemsize
-            for x in jax.tree_util.tree_leaves(self._params))
-        cache_bytes = sum(x.size * x.dtype.itemsize
-                          for x in jax.tree_util.tree_leaves(self._cache))
-        self._kv_page_bytes = cache_bytes // max(1, self._num_pages)
-        self._prof_steps = 0
-        self._prof_wall = 0.0
-        self._prof_bytes = 0.0
-        self._prof_t0: Optional[float] = None
         # lag-1 decode pipeline state
         self._inflight = None  # (snapshot, pre_info, toks_k, pre_tok)
         self._last_dev = jnp.zeros((num_slots,), jnp.int32)
@@ -403,6 +395,23 @@ class SlotEngine:
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_tokens_saved = 0
+        # Per-step accounting, cumulative; the rt.llm.step span carries
+        # the same counts for one step. A slot-step is one slot's place
+        # in one decode step of a dispatched program: slot_steps counts
+        # all of them, _active those that decoded a token, _prefill_wait
+        # those of a slot whose prompt was not through yet. Once nothing
+        # is in flight, tokens_generated + overshoot_tokens ==
+        # slot_steps_active + one first token per finished prefill.
+        self.steps_block = 0
+        self.steps_decode_only = 0
+        self.slot_steps = 0
+        self.slot_steps_active = 0
+        self.slot_steps_prefill_wait = 0
+        self.prefill_tokens = 0
+        self.overshoot_tokens = 0
+        # The last finished requests' timing: a streamed response
+        # carries tokens only, so this is where its stages are read.
+        self._timings: deque = deque(maxlen=self.TIMINGS_KEPT)
 
     # -- public API --------------------------------------------------------
 
@@ -430,8 +439,6 @@ class SlotEngine:
         if trace_ctx is None:
             # Direct submits (no serve hop) still join a caller's trace
             # when one is open on this thread / task.
-            from ..observability import tracing
-
             trace_ctx = tracing.inject_context()
         handle = RequestHandle(len(prompt))
         slot = _Slot(handle=handle, prompt=prompt, max_new=max_new,
@@ -746,7 +753,8 @@ class SlotEngine:
         while True:
             with self._work:
                 while not self._stop and not self._has_work_locked():
-                    self._work.wait()
+                    with tracing.step_span("rt.llm.wait_work"):
+                        self._work.wait()
                 if self._stop:
                     self._drain_control_locked()
                     self._fail_all_locked(
@@ -905,8 +913,16 @@ class SlotEngine:
         decode+prefill block, then fetch the PREVIOUS block's tokens
         (ready by now — lag-1 pipelining). Returns True if any work
         ran."""
-        ran_control = False
         with self._lock:
+            if not self._has_work_locked():
+                return False
+        with tracing.step_span("rt.llm.step", slots=self.num_slots,
+                               block=self.decode_block) as sp:
+            return self._step(sp)
+
+    def _step(self, sp) -> bool:
+        ran_control = False
+        with tracing.step_span("rt.llm.schedule") as sched, self._lock:
             # Session export/import and friends run HERE, between
             # decode steps: the previous block's cache assignment is
             # complete and the next dispatch hasn't consumed it.
@@ -914,44 +930,62 @@ class SlotEngine:
                 self._control.popleft()()
                 ran_control = True
             self._shed_expired_locked()
+            admitted = 0
             for i in range(self.num_slots):
                 if self._slots[i] is None and self._pending:
                     if not self._admit_locked(i, self._pending[0]):
                         break  # pool exhausted; FIFO order preserved
                     self._pending.popleft()
+                    admitted += 1
             prefill_idx = next(
                 (i for i, s in enumerate(self._slots)
                  if s is not None and not s.prefill_done), None)
             active = [(i, s) for i, s in enumerate(self._slots)
                       if s is not None and s.prefill_done
                       and not s.first_tok_pending]
+            live = [s for s in self._slots if s is not None]
+            pending = len(self._pending)
+            sched.set(admitted=admitted)
         ran = ran_control
         had_fetch = self._inflight is not None
-        new_block = (self._dispatch_block(active, prefill_idx)
-                     if (active or prefill_idx is not None) else None)
+        program, pre_tokens, waiting = "none", 0, len(live) - len(active)
+        if prefill_idx is not None:
+            program = "block"
+            s = self._slots[prefill_idx]
+            pre_tokens = min(self.chunk, len(s.prompt) - s.prefill_offset)
+            self.steps_block += 1
+        elif active:
+            program = "decode_only"
+            self.steps_decode_only += 1
+        if program != "none":
+            k = self.decode_block
+            self.slot_steps += self.num_slots * k
+            self.slot_steps_active += len(active) * k
+            self.slot_steps_prefill_wait += waiting * k
+            self.prefill_tokens += pre_tokens
+        if sp.recording:
+            ps = self.page_size
+            sp.set(program=program, active=len(active),
+                   prefill_tokens=pre_tokens, prefill_waiting=waiting,
+                   pending=pending, pages_allocated=self._pool.used_count,
+                   # written so far: a prompt in the lane has pos 0 and
+                   # prefill_offset tokens in its pages; pos runs past
+                   # the reservation by the overshoot, which lands in
+                   # the scratch page
+                   pages_written=sum(
+                       min(len(s.pages),
+                           -(-max(s.pos, s.prefill_offset) // ps))
+                       for s in live))
+        new_block = None
+        if program != "none":
+            with tracing.step_span("rt.llm.dispatch"):
+                new_block = self._dispatch_block(active, prefill_idx)
         if had_fetch:
             self._process_fetch()
             ran = True
         if new_block is not None:
             self._inflight = new_block
             ran = True
-        # Roofline accounting: only steady pipeline intervals count —
-        # a step that both dispatched a block with active decode slots
-        # AND fetched the previous one spans exactly decode_block
-        # device steps; anything else (admission-only, pipeline fill or
-        # drain, idle) would pollute the bytes/s estimate.
-        if new_block is not None and had_fetch and active:
-            now = time.monotonic()
-            if self._prof_t0 is not None:
-                steps = self.decode_block
-                self._prof_wall += now - self._prof_t0
-                self._prof_steps += steps
-                self._prof_bytes += steps * (
-                    self._param_bytes
-                    + self._pool.used_count * self._kv_page_bytes)
-            self._prof_t0 = now
-        else:
-            self._prof_t0 = None
         return ran
 
     def _dispatch_block(self, active, prefill_idx):
@@ -1022,14 +1056,35 @@ class SlotEngine:
     def _process_fetch(self) -> None:
         snapshot, pre_info, toks_k, pre_tok = self._inflight
         self._inflight = None
-        arr = np.asarray(toks_k)  # [K, rows]; ready -> fast fetch
+        with tracing.step_span("rt.llm.fetch"):
+            # the lag-1 wait for the device: the block dispatched one
+            # step ago is usually ready, so this is a fast fetch
+            arr = np.asarray(toks_k)  # [K, rows]
+        with tracing.step_span("rt.llm.deliver") as sp:
+            tokens0, done0 = self.tokens_generated, self.requests_completed
+            overshoot = self._deliver_block(snapshot, pre_info, arr,
+                                            pre_tok)
+            self.overshoot_tokens += overshoot
+            sp.set(delivered=self.tokens_generated - tokens0,
+                   finished=self.requests_completed - done0,
+                   overshoot=overshoot)
+
+    def _deliver_block(self, snapshot, pre_info, arr, pre_tok) -> int:
+        """Hand a fetched block's tokens to their requests. Returns the
+        tokens the device computed and nobody gets: the rest of a block
+        after EOS / length, and the whole block of a slot that finished
+        while it was in flight."""
+        k_block = arr.shape[0]
+        overshoot = 0
         for idx, s in snapshot:
             if self._slots[idx] is not s:
-                continue  # finished in an earlier block; rows are garbage
-            for k in range(arr.shape[0]):
+                overshoot += k_block  # finished in an earlier block
+                continue
+            for k in range(k_block):
                 self._deliver(idx, s, int(arr[k, idx]))
                 if self._slots[idx] is not s:
-                    break  # eos / length hit mid-block; drop overshoot
+                    overshoot += k_block - 1 - k  # eos / length mid-block
+                    break
         if pre_info is not None:
             idx, s, final = pre_info
             if final and self._slots[idx] is s:
@@ -1049,6 +1104,7 @@ class SlotEngine:
                 s.pos = len(s.prompt)
                 s.on_device_chain = False
                 self._deliver(idx, s, int(pre_tok))
+        return overshoot
 
     def _request_timing(self, s: _Slot) -> dict:
         """Stage decomposition of one finished request. admission =
@@ -1093,9 +1149,14 @@ class SlotEngine:
         prefill/decode children laid out from the SAME durations the
         timing dict reports (so span tree and `timing` metadata agree by
         construction). Stamps are monotonic; the wall offset lines them
-        up with proxy/replica spans within clock-sampling noise."""
-        from ..observability import tracing
+        up with proxy/replica spans within clock-sampling noise.
 
+        What these are: host wall-clock STAGES of one request, laid end
+        to end from its stamps, for ``rt trace``. They time no device
+        work: a decode stage is the wall time the request spent in the
+        batch, shared with every other slot's. The device's time is in
+        the profiler trace, under the programs' scope names, and what
+        each engine step was made of is in the ``rt.llm.*`` step spans."""
         if not tracing.get_tracer().enabled:
             return
         off = time.time() - time.monotonic()
@@ -1124,64 +1185,14 @@ class SlotEngine:
                                 parent_id=root.span_id, start_s=match_t0,
                                 end_s=match_t0 + timing["prefix_match_s"])
 
-    def reset_decode_profile(self) -> None:
-        """Zero the roofline window. Successive bench stages call this
-        between phases so each measures its OWN steady-state interval —
-        without it, a long-gen stage inherits the warmup/prefill
-        stage's lag-1 state and pollutes its bytes/s estimate."""
-        self._prof_steps = 0
-        self._prof_wall = 0.0
-        self._prof_bytes = 0.0
-        self._prof_t0 = None
-
-    def decode_profile(self) -> dict:
-        """Achieved-vs-peak HBM accounting for the decode loop
-        (ROADMAP item 2's ``roofline_frac``). Publishes the
-        ``rt_llm_roofline_frac`` / ``rt_llm_decode_steps_per_s``
-        gauges as a side effect. The peak is the device's own
-        (``parallel.mesh.DEVICE_PEAKS``, by ``device_kind``) and scales
-        with the mesh size: a tp-sharded pool streams 1/n of the bytes
-        per chip, so the aggregate peak is n chips' bandwidth. A device
-        with no published peak has no roofline: ``hbm_gbps`` and
-        ``roofline_frac`` are None and the gauge is left alone."""
-        from ..parallel.mesh import DEVICE_PEAKS, device_triple
-
-        steps, wall = self._prof_steps, self._prof_wall
-        peak = DEVICE_PEAKS.get(device_triple()["kind"])
-        hbm_gbps = None if peak is None else peak["hbm_gbps"]
-        devices = 1 if self._mesh is None else int(self._mesh.devices.size)
-        if steps == 0 or wall <= 0.0:
-            prof = {"steps": 0, "wall_s": 0.0, "avg_step_ms": 0.0,
-                    "steps_per_s": 0.0, "bytes_per_step": 0,
-                    "achieved_gbps": 0.0, "hbm_gbps": hbm_gbps,
-                    "devices": devices,
-                    "roofline_frac": None if peak is None else 0.0}
-        else:
-            achieved_gbps = self._prof_bytes / wall / 1e9
-            prof = {
-                "steps": steps,
-                "wall_s": round(wall, 6),
-                "avg_step_ms": round(wall / steps * 1e3, 4),
-                "steps_per_s": round(steps / wall, 2),
-                "bytes_per_step": int(self._prof_bytes / steps),
-                "achieved_gbps": round(achieved_gbps, 4),
-                "hbm_gbps": hbm_gbps,
-                "devices": devices,
-                "roofline_frac": (None if peak is None else
-                                  achieved_gbps / (hbm_gbps * devices)),
-            }
-        # Publish only MEASURED windows: an idle engine's stats() call
-        # (zero steps since the last reset) would ship a 0.0 gauge that
-        # overwrites another process's live roofline on the head —
-        # last-writer-wins gauge merge — so the scrape-time value raced
-        # with whichever engine happened to flush last. The gauges read
-        # as "last measured decode window" cluster-wide.
-        m = llm_metrics()
-        if m is not None and steps > 0:
-            if peak is not None:
-                m["roofline_frac"].set(prof["roofline_frac"])
-            m["decode_steps"].set(prof["steps_per_s"])
-        return prof
+    def request_timings(self, since_unix_s: float = 0.0) -> List[dict]:
+        """The ``timing`` of the last ``TIMINGS_KEPT`` finished requests
+        submitted at or after ``since_unix_s``, oldest first, each with
+        its ``seed``, ``submit_unix_s`` and, where the request came with
+        a trace context, its ``request_id`` (the x-request-id)."""
+        with self._lock:
+            kept = list(self._timings)
+        return [t for t in kept if t["submit_unix_s"] >= since_unix_s]
 
     def _deliver(self, idx: int, s: _Slot, tok: int) -> None:
         s.last_token = tok
@@ -1201,8 +1212,13 @@ class SlotEngine:
         out_of_room = (len(s.prompt) + s.produced) >= self.cfg.max_seq
         if hit_eos or s.produced >= s.max_new or out_of_room:
             s.handle.timing = self._request_timing(s)
+            kept = dict(s.handle.timing, seed=s.seed, submit_unix_s=(
+                s.submit_t + time.time() - time.monotonic()))
             if s.trace_ctx is not None:
+                kept["request_id"] = s.trace_ctx[0]
                 self._emit_trace_spans(s, s.handle.timing)
+            with self._lock:
+                self._timings.append(kept)
             s.handle._finish("stop" if hit_eos else "length")
             if s.on_token:
                 s.on_token(None)

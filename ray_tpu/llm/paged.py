@@ -273,15 +273,6 @@ def llm_metrics() -> Optional[Dict[str, Any]]:
                     "Mean inter-token decode latency per request",
                     boundaries=[0.0005, 0.001, 0.005, 0.01, 0.05, 0.1,
                                 0.5, 1.0]),
-                "roofline_frac": get_or_create(
-                    Gauge, "rt_llm_roofline_frac",
-                    "Achieved decode HBM bytes/s over the device's "
-                    "published peak bandwidth x mesh size (unset on a "
-                    "device with no published peak)"),
-                "decode_steps": get_or_create(
-                    Gauge, "rt_llm_decode_steps_per_s",
-                    "Steady-state decode steps/s over the current "
-                    "roofline window"),
                 # Monotone token production: the rate source behind the
                 # history ring's tok/s series (`rt top`); a gauge of
                 # engine.tokens_generated would reset on replica

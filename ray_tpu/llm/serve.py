@@ -222,8 +222,17 @@ class LLMServer:
             "pages_free": self.engine.pages_free,
             "sessions_resident": self.engine.session_count,
             "session_recovery_ms": list(self._recoveries),
-            "decode_profile": self.engine.decode_profile(),
+            # what the engine's steps were made of, cumulative (the
+            # rt.llm.step span carries the same counts per step)
+            **{k: getattr(self.engine, k)
+               for k in SlotEngine.STEP_COUNTERS},
         }
+
+    def request_timings(self, since_unix_s: float = 0.0) -> list:
+        """Stage timing of recently finished requests, streamed ones
+        included (a stream carries tokens only): see
+        ``SlotEngine.request_timings``."""
+        return self.engine.request_timings(since_unix_s)
 
 
 def build_llm_app(model: str = "llama-tiny", num_slots: int = 8,

@@ -260,33 +260,35 @@ def _block(x, p, cfg: GPT2Config, rules):
 
     from jax.ad_checkpoint import checkpoint_name
 
-    y = layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-    qkv = (y @ p["qkv_w"].astype(y.dtype)) + p["qkv_b"].astype(y.dtype)
-    qkv = constrain(qkv, ("batch", "seq", "qkv"), rules)
-    qkv = checkpoint_name(qkv, "qkv")
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    with jax.named_scope("attn"):
+        y = layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+        qkv = (y @ p["qkv_w"].astype(y.dtype)) + p["qkv_b"].astype(y.dtype)
+        qkv = constrain(qkv, ("batch", "seq", "qkv"), rules)
+        qkv = checkpoint_name(qkv, "qkv")
+        q, k, v = jnp.split(qkv, 3, axis=-1)
 
-    def heads(t):  # [B,S,D] -> [B,H,S,hd]
-        return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        def heads(t):  # [B,S,D] -> [B,H,S,hd]
+            return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
 
-    o = _attend(heads(q), heads(k), heads(v), cfg, rules)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-    o = (o @ p["proj_w"].astype(o.dtype)) + p["proj_b"].astype(o.dtype)
-    x = x + constrain(o, ("batch", "seq", None), rules)
+        o = _attend(heads(q), heads(k), heads(v), cfg, rules)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+        o = (o @ p["proj_w"].astype(o.dtype)) + p["proj_b"].astype(o.dtype)
+        x = x + constrain(o, ("batch", "seq", None), rules)
 
-    y = layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-    if cfg.num_experts > 0:
-        out, aux = _moe_ffn(y, p, cfg, rules)
-        return x + constrain(out, ("batch", "seq", None), rules), aux
+    with jax.named_scope("mlp"):
+        y = layer_norm(x, p["ln2_scale"], p["ln2_bias"])
+        if cfg.num_experts > 0:
+            out, aux = _moe_ffn(y, p, cfg, rules)
+            return x + constrain(out, ("batch", "seq", None), rules), aux
 
-    hdn = (y @ p["mlp_in_w"].astype(y.dtype)) + p["mlp_in_b"].astype(y.dtype)
-    hdn = constrain(hdn, ("batch", "seq", "mlp"), rules)
-    hdn = checkpoint_name(hdn, "mlp_in")
-    hdn = jax.nn.gelu(hdn, approximate=True)
-    out = (hdn @ p["mlp_out_w"].astype(hdn.dtype)) + p["mlp_out_b"].astype(
-        hdn.dtype
-    )
-    x = x + constrain(out, ("batch", "seq", None), rules)
+        hdn = (y @ p["mlp_in_w"].astype(y.dtype)) \
+            + p["mlp_in_b"].astype(y.dtype)
+        hdn = constrain(hdn, ("batch", "seq", "mlp"), rules)
+        hdn = checkpoint_name(hdn, "mlp_in")
+        hdn = jax.nn.gelu(hdn, approximate=True)
+        out = (hdn @ p["mlp_out_w"].astype(hdn.dtype)) \
+            + p["mlp_out_b"].astype(hdn.dtype)
+        x = x + constrain(out, ("batch", "seq", None), rules)
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -521,9 +523,11 @@ def loss_fn(params, batch, cfg: GPT2Config, rules=None,
         nll_sum, denom = carry
         return (nll_sum + nll, denom + count), None
 
-    (nll_sum, denom), _ = jax.lax.scan(
-        chunk_loss, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xc, tc))
+    with jax.named_scope("ce"):
+        (nll_sum, denom), _ = jax.lax.scan(
+            chunk_loss,
+            (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+            (xc, tc))
     loss = nll_sum / jnp.maximum(denom, 1.0)
     if cfg.num_experts > 0:
         loss = loss + cfg.moe_aux_weight * aux / cfg.num_layers

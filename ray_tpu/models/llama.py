@@ -254,9 +254,10 @@ def _cache_layer_step(x, p, cfg: LlamaConfig, positions, kv_mask,
 
 def _lm_head(x, params, cfg: LlamaConfig):
     """[N, D] hidden states -> [N, vocab] fp32 logits."""
-    x = rms_norm(x, params["final_norm"])
-    return jnp.einsum("bd,vd->bv", x, params["wte"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"])
+        return jnp.einsum("bd,vd->bv", x, params["wte"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def decode_step(params, cache, tokens, pos, cfg: LlamaConfig):
@@ -495,6 +496,18 @@ def prefill_chunk(params, cache, tokens, slot, p0, cfg: LlamaConfig,
 # q/k/v head axes of every intermediate — shard across chips while the
 # page/seq axes stay replicated; with no mesh the constraints no-op and
 # the kernels are byte-identical to the single-device path.
+#
+# Scope names: the paged programs carry ``jax.named_scope`` names — metadata
+# on the HLO (``op_name``), no operation added, moved or changed — so a
+# device trace can say what a step was made of under names the program
+# chose, not XLA's ``copy.68``. ``layers`` is round each scan over the
+# layers; inside it every operation sits in ``qkv`` (norm, projections,
+# rope), ``kv_write`` (the new K/V into the pool), ``kv_gather`` (the slots'
+# pages out of it), ``attn`` (attention and its output projection) or
+# ``mlp``. What a trace shows under ``layers`` and none of those is the
+# scan's own work: slicing one layer out of the pool and stacking it back.
+# Outside: ``embed``, ``lm_head``; ``prefill_lane`` is round the prompt
+# chunk's half of the fused step. benchmark/trace/program.py reads them.
 # ---------------------------------------------------------------------------
 
 def init_paged_kv_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
@@ -577,33 +590,39 @@ def _paged_layer_step(x, p, cfg: LlamaConfig, positions, kv_mask,
     x: [B, T, D]. Returns (x, kv_l)."""
     b, t, _ = x.shape
     h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    y = rms_norm(x, p["attn_norm"])
-    q = (y @ p["wq"].astype(y.dtype)).reshape(b, t, h, hd).transpose(
-        0, 2, 1, 3)
-    k_new = (y @ p["wk"].astype(y.dtype)).reshape(
-        b, t, hkv, hd).transpose(0, 2, 1, 3)
-    v_new = (y @ p["wv"].astype(y.dtype)).reshape(
-        b, t, hkv, hd).transpose(0, 2, 1, 3)
-    q = rope(q, positions, cfg.rope_theta)
-    k_new = rope(k_new, positions, cfg.rope_theta)
-    q = constrain(q, (None, "heads", None, None), rules)
-    k_new = constrain(k_new, (None, "kv", None, None), rules)
-    v_new = constrain(v_new, (None, "kv", None, None), rules)
-    kv_l = write_kv(k_new, v_new)
-    # Pin the written pool AND the gathered view to the kv-heads
-    # sharding: the scatter/gather must never trigger a resharding of
-    # the (multi-GB) page pool, and the scan-stacked output must match
-    # the donated input's sharding so in-place donation survives.
-    kv_l = constrain(kv_l, PAGED_KV_AXES[1:], rules)
-    kv_att = constrain(attend_view(kv_l), (None, None, None, "kv", None),
-                       rules)
-    o = _gqa_paged_attention(q, kv_att, kv_mask, cfg)
-    x = x + o @ p["wo"].astype(o.dtype)
-    y = rms_norm(x, p["ffn_norm"])
-    gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
-    up = y @ p["w_up"].astype(y.dtype)
-    hidden = constrain(gate * up, (None, None, "mlp"), rules)
-    x = x + hidden @ p["w_down"].astype(y.dtype)
+    with jax.named_scope("qkv"):
+        y = rms_norm(x, p["attn_norm"])
+        q = (y @ p["wq"].astype(y.dtype)).reshape(b, t, h, hd).transpose(
+            0, 2, 1, 3)
+        k_new = (y @ p["wk"].astype(y.dtype)).reshape(
+            b, t, hkv, hd).transpose(0, 2, 1, 3)
+        v_new = (y @ p["wv"].astype(y.dtype)).reshape(
+            b, t, hkv, hd).transpose(0, 2, 1, 3)
+        q = rope(q, positions, cfg.rope_theta)
+        k_new = rope(k_new, positions, cfg.rope_theta)
+        q = constrain(q, (None, "heads", None, None), rules)
+        k_new = constrain(k_new, (None, "kv", None, None), rules)
+        v_new = constrain(v_new, (None, "kv", None, None), rules)
+    with jax.named_scope("kv_write"):
+        kv_l = write_kv(k_new, v_new)
+        # Pin the written pool AND the gathered view to the kv-heads
+        # sharding: the scatter/gather must never trigger a resharding
+        # of the (multi-GB) page pool, and the scan-stacked output must
+        # match the donated input's sharding so in-place donation
+        # survives.
+        kv_l = constrain(kv_l, PAGED_KV_AXES[1:], rules)
+    with jax.named_scope("kv_gather"):
+        kv_att = constrain(attend_view(kv_l),
+                           (None, None, None, "kv", None), rules)
+    with jax.named_scope("attn"):
+        o = _gqa_paged_attention(q, kv_att, kv_mask, cfg)
+        x = x + o @ p["wo"].astype(o.dtype)
+    with jax.named_scope("mlp"):
+        y = rms_norm(x, p["ffn_norm"])
+        gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
+        up = y @ p["w_up"].astype(y.dtype)
+        hidden = constrain(gate * up, (None, None, "mlp"), rules)
+        x = x + hidden @ p["w_down"].astype(y.dtype)
     return x, kv_l
 
 
@@ -618,7 +637,8 @@ def decode_slots_paged(params, cache, tables, tokens, pos,
     or any slot whose table row is all-scratch) write garbage only into
     the scratch page."""
     b = tokens.shape[0]
-    x = params["wte"][tokens].astype(cfg.dtype)[:, None, :]  # [B,1,D]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(cfg.dtype)[:, None, :]  # [B,1,D]
     positions = pos[:, None]
     kv_mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
                <= pos[:, None, None, None, None])
@@ -639,8 +659,9 @@ def decode_slots_paged(params, cache, tables, tokens, pos,
                                    write, view, rules)
         return x, kv2
 
-    x, new_kv = jax.lax.scan(
-        layer_step, x, (params["blocks"], cache["kv"]))
+    with jax.named_scope("layers"):
+        x, new_kv = jax.lax.scan(
+            layer_step, x, (params["blocks"], cache["kv"]))
     return _lm_head(x[:, 0], params, cfg), {"kv": new_kv}
 
 
@@ -658,7 +679,8 @@ def prefill_chunk_paged(params, cache, tables, tokens, slot, p0, n_valid,
     n_valid - 1, new_cache)."""
     c = tokens.shape[0]
     p = tables.shape[1]
-    x = params["wte"][tokens].astype(cfg.dtype)[None]  # [1,C,D]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(cfg.dtype)[None]  # [1,C,D]
     idx = jnp.arange(c)
     abs_pos = p0 + idx
     positions = abs_pos[None, :]
@@ -686,8 +708,9 @@ def prefill_chunk_paged(params, cache, tables, tokens, slot, p0, n_valid,
                                    write, view, rules)
         return x, kv2
 
-    x, new_kv = jax.lax.scan(
-        layer_step, x, (params["blocks"], cache["kv"]))
+    with jax.named_scope("layers"):
+        x, new_kv = jax.lax.scan(
+            layer_step, x, (params["blocks"], cache["kv"]))
     row = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                        keepdims=False)
     return _lm_head(row[None], params, cfg)[0], {"kv": new_kv}
@@ -714,7 +737,8 @@ def decode_slots_with_prefill_paged(params, cache, tables, tokens, pos,
     s_max = cfg.max_seq
     p = tables.shape[1]
     packed = jnp.concatenate([tokens, pre_tokens])
-    x = params["wte"][packed].astype(cfg.dtype)[None]  # [1, B+C, D]
+    with jax.named_scope("embed"):
+        x = params["wte"][packed].astype(cfg.dtype)[None]  # [1, B+C, D]
     pre_positions = pre_p0 + jnp.arange(c)
     positions = jnp.concatenate([pos, pre_positions])[None]
     dec_mask = (jnp.arange(s_max)[None, None, None, None, :]
@@ -731,50 +755,60 @@ def decode_slots_with_prefill_paged(params, cache, tables, tokens, pos,
 
     def layer_step(x, inputs):
         pr, kv_l = inputs
-        y = rms_norm(x, pr["attn_norm"])
         t = b + c
-        q = (y @ pr["wq"].astype(y.dtype)).reshape(1, t, h, hd).transpose(
-            0, 2, 1, 3)
-        k_new = (y @ pr["wk"].astype(y.dtype)).reshape(
-            1, t, hkv, hd).transpose(0, 2, 1, 3)
-        v_new = (y @ pr["wv"].astype(y.dtype)).reshape(
-            1, t, hkv, hd).transpose(0, 2, 1, 3)
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
-        q = constrain(q, (None, "heads", None, None), rules)
-        k_new = constrain(k_new, (None, "kv", None, None), rules)
-        v_new = constrain(v_new, (None, "kv", None, None), rules)
-        qd = q[0, :, :b].transpose(1, 0, 2)[:, :, None, :]  # [B,h,1,hd]
-        kd = k_new[0, :, :b].transpose(1, 0, 2)             # [B,Hkv,hd]
-        vd = v_new[0, :, :b].transpose(1, 0, 2)
-        qp = q[:, :, b:]                                    # [1,h,C,hd]
-        kp = k_new[0, :, b:].transpose(1, 0, 2)             # [C,Hkv,hd]
-        vp = v_new[0, :, b:].transpose(1, 0, 2)
+        with jax.named_scope("qkv"):
+            y = rms_norm(x, pr["attn_norm"])
+            q = (y @ pr["wq"].astype(y.dtype)).reshape(
+                1, t, h, hd).transpose(0, 2, 1, 3)
+            k_new = (y @ pr["wk"].astype(y.dtype)).reshape(
+                1, t, hkv, hd).transpose(0, 2, 1, 3)
+            v_new = (y @ pr["wv"].astype(y.dtype)).reshape(
+                1, t, hkv, hd).transpose(0, 2, 1, 3)
+            q = rope(q, positions, cfg.rope_theta)
+            k_new = rope(k_new, positions, cfg.rope_theta)
+            q = constrain(q, (None, "heads", None, None), rules)
+            k_new = constrain(k_new, (None, "kv", None, None), rules)
+            v_new = constrain(v_new, (None, "kv", None, None), rules)
+            qd = q[0, :, :b].transpose(1, 0, 2)[:, :, None, :]  # [B,h,1,hd]
+            kd = k_new[0, :, :b].transpose(1, 0, 2)             # [B,Hkv,hd]
+            vd = v_new[0, :, :b].transpose(1, 0, 2)
+            with jax.named_scope("prefill_lane"):
+                qp = q[:, :, b:]                                # [1,h,C,hd]
+                kp = k_new[0, :, b:].transpose(1, 0, 2)         # [C,Hkv,hd]
+                vp = v_new[0, :, b:].transpose(1, 0, 2)
         # Writes first, decode rows then the chunk (disjoint pages by
         # the caller's pre_slot guarantee), so in-chunk causality holds.
-        kv_l = _scatter_token_kv(kv_l, kd, vd, tables, rows, pos,
-                                 page_size, s_max)
-        kv_l = kv_l.at[:, phys_c, off_c].set(jnp.stack([kp, vp]))
-        kv_l = constrain(kv_l, PAGED_KV_AXES[1:], rules)
+        with jax.named_scope("kv_write"):
+            kv_l = _scatter_token_kv(kv_l, kd, vd, tables, rows, pos,
+                                     page_size, s_max)
+            with jax.named_scope("prefill_lane"):
+                kv_l = kv_l.at[:, phys_c, off_c].set(jnp.stack([kp, vp]))
+            kv_l = constrain(kv_l, PAGED_KV_AXES[1:], rules)
         kv_axes = (None, None, None, "kv", None)
-        od = _gqa_paged_attention(
-            qd, constrain(_gather_pages(kv_l, tables), kv_axes, rules),
-            dec_mask, cfg)
-        op = _gqa_paged_attention(
-            qp, constrain(_gather_pages(kv_l, slot_table), kv_axes,
-                          rules),
-            pre_mask, cfg)
-        o = jnp.concatenate([od[:, 0][None], op], axis=1)  # [1,B+C,D]
-        x = x + o @ pr["wo"].astype(o.dtype)
-        y = rms_norm(x, pr["ffn_norm"])
-        gate = jax.nn.silu(y @ pr["w_gate"].astype(y.dtype))
-        up = y @ pr["w_up"].astype(y.dtype)
-        hidden = constrain(gate * up, (None, None, "mlp"), rules)
-        x = x + hidden @ pr["w_down"].astype(y.dtype)
+        with jax.named_scope("kv_gather"):
+            kv_d = constrain(_gather_pages(kv_l, tables), kv_axes, rules)
+        with jax.named_scope("attn"):
+            od = _gqa_paged_attention(qd, kv_d, dec_mask, cfg)
+        with jax.named_scope("prefill_lane"):
+            with jax.named_scope("kv_gather"):
+                kv_p = constrain(_gather_pages(kv_l, slot_table), kv_axes,
+                                 rules)
+            with jax.named_scope("attn"):
+                op = _gqa_paged_attention(qp, kv_p, pre_mask, cfg)
+        with jax.named_scope("attn"):
+            o = jnp.concatenate([od[:, 0][None], op], axis=1)  # [1,B+C,D]
+            x = x + o @ pr["wo"].astype(o.dtype)
+        with jax.named_scope("mlp"):
+            y = rms_norm(x, pr["ffn_norm"])
+            gate = jax.nn.silu(y @ pr["w_gate"].astype(y.dtype))
+            up = y @ pr["w_up"].astype(y.dtype)
+            hidden = constrain(gate * up, (None, None, "mlp"), rules)
+            x = x + hidden @ pr["w_down"].astype(y.dtype)
         return x, kv_l
 
-    x, new_kv = jax.lax.scan(
-        layer_step, x, (params["blocks"], cache["kv"]))
+    with jax.named_scope("layers"):
+        x, new_kv = jax.lax.scan(
+            layer_step, x, (params["blocks"], cache["kv"]))
     heads_in = jnp.concatenate(
         [x[0, :b], x[0, b + pre_n_valid - 1][None]], axis=0)  # [B+1, D]
     logits = _lm_head(heads_in, params, cfg)

@@ -9,6 +9,15 @@ in-process records exported as chrome://tracing events
 
 Enable with ``tracing.enable()`` (or config flag ``tracing_enabled``);
 ``@trace_span("name")`` / ``with span("name"):`` for app code.
+
+Two clocks, one module. ``span`` / ``record_span`` are per-request stage
+attribution on the HOST's wall clock (``rt trace <id>``). ``step_span``
+is for the hot paths that drive the device (an engine step, a stream
+pull): it always opens a ``jax.profiler.TraceAnnotation``, which lands in
+a profiler trace's ``/host:CPU`` plane on the clock the device events
+carry, and records a ring span too only when the tracer is on. The
+device's own time is never in a span: it is in the profiler trace, under
+the ``jax.named_scope`` names the programs carry.
 """
 
 from __future__ import annotations
@@ -16,6 +25,7 @@ from __future__ import annotations
 import contextvars
 import functools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -241,6 +251,100 @@ def span(name: str, **attributes):
     if not _tracer.enabled:
         return _NULL_SPAN
     return _SpanCtx(name, attributes)
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded here
+
+
+class _BoundsSpanCtx:
+    """Ring side of a span that crosses awaits on a shared event-loop
+    thread: the thread's span stack cannot hold it, so it is recorded at
+    its end from its bounds, on the task's request trace."""
+
+    __slots__ = ("_name", "_attributes", "_start_s")
+
+    def __init__(self, name: str, attributes: Dict[str, Any]):
+        self._name = name
+        self._attributes = attributes
+
+    def __enter__(self):
+        self._start_s = time.time()
+
+    def __exit__(self, *exc):
+        trace_id, parent_id = (_request_ctx.get()
+                               or (os.urandom(16).hex(), None))
+        record_span(self._name, trace_id, parent_id,
+                    start_s=self._start_s, **self._attributes)
+        return False
+
+
+class _StepSpan:
+    """What :func:`step_span` returns: the profiler annotation and, when
+    the tracer is on, a ring span, entered and left together."""
+
+    __slots__ = ("_ann", "_ctx", "_attributes")
+
+    def __init__(self, ann, ctx, attributes):
+        self._ann = ann
+        self._ctx = ctx  # ring side: _SpanCtx, _BoundsSpanCtx or None
+        self._attributes = attributes  # the ring span's dict too
+
+    def __enter__(self) -> "_StepSpan":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._ctx is not None:
+            self._ctx.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ctx is not None:
+            self._ctx.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+    @property
+    def recording(self) -> bool:
+        """Somebody will read this span: a profiler trace is being
+        recorded or the tracer is on. Callers compute an attribute that
+        costs more than a few additions only when this is true."""
+        return self._ctx is not None or (
+            self._ann is not None and self._ann.is_enabled())
+
+    def set(self, **attributes) -> None:
+        """Attributes known only inside the span (counts at its end)."""
+        if self._ctx is not None:
+            self._attributes.update(attributes)
+        if self._ann is not None:
+            self._ann.set_metadata(**attributes)
+
+
+def step_span(name: str, interleaved: bool = False,
+              **attributes) -> _StepSpan:
+    """A span round one step of a hot path (names start with ``rt.``).
+
+    Always a ``jax.profiler.TraceAnnotation``: while a profiler trace is
+    recorded it is an event of this thread in the trace's host plane,
+    with ``attributes`` as its stats, on the device events' clock; while
+    none is, constructing it checks one flag. A process that has not
+    imported JAX (the head, the proxy) gets none — JAX is never imported
+    from here. With the tracer on it is also a ring :class:`Span` under
+    the thread's current span, so ``rt trace`` and the chrome export show
+    steps beside requests. ``interleaved`` is for a span that crosses
+    awaits on a shared event-loop thread: the annotation keeps its own
+    start and end there; the ring span is a :class:`_BoundsSpanCtx`."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            _annotation = jax.profiler.TraceAnnotation
+    ctx = None
+    if _tracer.enabled:
+        ctx = (_BoundsSpanCtx if interleaved else _SpanCtx)(name,
+                                                            attributes)
+    return _StepSpan(
+        None if _annotation is None else _annotation(name, **attributes),
+        ctx, attributes)
 
 
 def trace_span(name: Optional[str] = None, **attributes):

@@ -344,7 +344,7 @@ class _Replica:
         the replica's event loop — _streams is loop-confined)."""
         self._sweep_streams()
         self._stream_counter += 1
-        self._streams[self._stream_counter] = (gen, time.monotonic())
+        self._streams[self._stream_counter] = (gen, time.monotonic(), 0)
         return ("__rt_stream__", self._stream_counter)
 
     def _limit(self, timeout_s: Optional[float]) -> Optional[float]:
@@ -395,9 +395,9 @@ class _Replica:
         dropped StreamingResponse) so generators don't leak for the
         replica's lifetime. Lazy sweep on registration — no timers."""
         now = time.monotonic()
-        for sid in [s for s, (_, t) in self._streams.items()
+        for sid in [s for s, (_, t, _n) in self._streams.items()
                     if now - t > idle_s]:
-            gen, _ = self._streams.pop(sid)
+            gen = self._streams.pop(sid)[0]
             try:
                 close = getattr(gen, "close", None) or getattr(
                     gen, "aclose", None)
@@ -600,24 +600,33 @@ class _Replica:
         entry = self._streams.get(stream_id)
         if entry is None:
             return True, []
-        gen = entry[0]
-        self._streams[stream_id] = (gen, time.monotonic())
+        gen, _, pulls = entry
+        self._streams[stream_id] = (gen, time.monotonic(), pulls + 1)
         items = []
-        try:
-            if inspect.isasyncgen(gen):
-                async for item in gen:
-                    items.append(item)
-                    if len(items) >= max_n:
-                        return False, items
-            else:
-                for item in gen:
-                    items.append(item)
-                    if len(items) >= max_n:
-                        return False, items
-        finally:
-            if len(items) < max_n:
-                self._streams.pop(stream_id, None)
-        return True, items
+        # The replica's side of the proxy's chunked pull. Pulls of many
+        # streams interleave on this one thread; each annotation keeps
+        # its own start and end (checked in a recorded trace).
+        with tracing.step_span("rt.serve.next_chunks", interleaved=True,
+                               first=int(pulls == 0)) as sp:
+            done = True
+            try:
+                if inspect.isasyncgen(gen):
+                    async for item in gen:
+                        items.append(item)
+                        if len(items) >= max_n:
+                            done = False
+                            break
+                else:
+                    for item in gen:
+                        items.append(item)
+                        if len(items) >= max_n:
+                            done = False
+                            break
+            finally:
+                if len(items) < max_n:
+                    self._streams.pop(stream_id, None)
+                sp.set(items=len(items), done=int(done))
+        return done, items
 
     def metrics(self):
         # "streams" lets the controller's drain verb wait for handed-off

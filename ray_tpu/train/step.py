@@ -138,21 +138,29 @@ def build_sharded_train(
             ) if hasattr(x, "ndim") and x.ndim >= 2 else x,
             batch,
         )
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        if master_fp32:
-            master, inner = opt_state["master"], opt_state["inner"]
-            grads32 = jax.tree.map(
-                lambda g: g.astype(jnp.float32)
-                if jnp.issubdtype(g.dtype, jnp.floating) else g, grads)
-            updates, inner = optimizer.update(grads32, inner, master)
-            master = optax.apply_updates(master, updates)
-            params = jax.tree.map(
-                lambda m, p: m.astype(p.dtype), master, params)
-            opt_state = {"master": master, "inner": inner}
-        else:
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
+        # Scope names (metadata on the HLO, nothing else): JAX marks the
+        # transposed half of fwd_bwd ``transpose(jvp(...))`` and the
+        # recomputation ``rematted_computation`` by itself, so a device
+        # trace splits forward, recompute, backward and optimizer.
+        with jax.named_scope("fwd_bwd"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope("optimizer"):
+            if master_fp32:
+                master, inner = opt_state["master"], opt_state["inner"]
+                grads32 = jax.tree.map(
+                    lambda g: g.astype(jnp.float32)
+                    if jnp.issubdtype(g.dtype, jnp.floating) else g, grads)
+                updates, inner = optimizer.update(grads32, inner, master)
+                master = optax.apply_updates(master, updates)
+                params = jax.tree.map(
+                    lambda m, p: m.astype(p.dtype), master, params)
+                opt_state = {"master": master, "inner": inner}
+            else:
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
+        with jax.named_scope("grad_norm"):
+            gnorm = optax.global_norm(grads)
         return params, opt_state, step + 1, {"loss": loss, "grad_norm": gnorm}
 
     # constrain() uses bare PartitionSpecs, which need an ambient mesh

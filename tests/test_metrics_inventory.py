@@ -60,12 +60,12 @@ def test_every_emitted_metric_is_documented(rt_init):
     a = InvActor.remote()
     assert rt.get(a.ping.remote()) == 1
     # LLM family: a series only appears in the exposition once touched
-    # — commit a zero roofline sample the way an idle engine would.
+    # — publish the page gauges the way an idle engine would.
     from ray_tpu.llm.paged import llm_metrics
 
     m = llm_metrics()
     assert m is not None
-    m["roofline_frac"].set(0.0)
+    m["pages_used"].set(0.0)
     # One telemetry flush so worker-side series reach the head.
     from ray_tpu.core.config import config
 
@@ -86,7 +86,7 @@ def test_every_emitted_metric_is_documented(rt_init):
     assert documented, "COMPONENTS.md metrics inventory table missing"
     # The workload above must actually exercise the planes under test.
     for required in ("rt_tasks_submitted", "rt_task_latency_seconds",
-                     "rt_task_stage_seconds", "rt_llm_roofline_frac"):
+                     "rt_task_stage_seconds", "rt_llm_pages_used"):
         assert required in emitted, sorted(emitted)
     undocumented = emitted - documented
     assert not undocumented, (

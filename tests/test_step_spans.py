@@ -1,0 +1,314 @@
+"""Step-level spans, counters and scope names inside the program.
+
+What the benchmark's trace reduction reads comes from here: the
+``rt.llm.*`` spans with their counts, the engine's cumulative step
+counters, ``request_timings()``, and the ``jax.named_scope`` names on the
+compiled programs. Nothing starts a cluster; every wait has a bound.
+"""
+
+import glob
+import os
+import re
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm.engine import SlotEngine
+from ray_tpu.models import gpt2, llama
+from ray_tpu.observability import tracing
+
+CFG = llama.CONFIGS["llama-tiny"]
+STEP_CHILDREN = {"rt.llm.schedule", "rt.llm.dispatch", "rt.llm.fetch",
+                 "rt.llm.deliver"}
+COUNTERS = SlotEngine.STEP_COUNTERS
+# The parent commit's greedy tokens for PROMPT on the CPU (scopes are
+# metadata: the same program, the same tokens).
+PROMPT = list(range(1, 40))
+PARENT_GREEDY = [38, 38, 38, 38, 38, 38, 38, 38, 38]
+PARENT_SAMPLED = [289, 355, 304, 199, 227, 496, 219, 464, 59]
+
+
+@pytest.fixture(scope="module")
+def params():
+    p, _ = llama.init_params(jax.random.PRNGKey(0), CFG)
+    return p
+
+
+@pytest.fixture
+def tracer():
+    t = tracing.get_tracer()
+    t.clear()
+    tracing.enable()
+    yield t
+    tracing.disable()
+    t.clear()
+
+
+def _drain(eng, limit=4000):
+    for _ in range(limit):
+        if not eng.step():
+            return
+    raise AssertionError("engine did not drain")
+
+
+@pytest.mark.parametrize("eos", [None, "third"], ids=["length", "eos"])
+def test_step_spans_children_counts_and_token_invariant(params, tracer,
+                                                        eos):
+    """Every ``rt.llm.step`` holds its children inside its bounds (all
+    four once the lag-1 pipeline is full); the spans' counts add up to
+    the engine's counters; and tokens delivered + overshot == slot-steps
+    that decoded + one first token per finished prefill, with and
+    without an EOS that cuts requests short mid-block."""
+    block = 2
+    # no prefix cache: every prompt token is prefilled and no page is
+    # shared, so the spans' token and page counts are plain sums
+    eng = SlotEngine(params, CFG, num_slots=4, chunk=16,
+                     decode_block=block, prefix_cache=False)
+    eos_id = None
+    if eos:
+        probe = eng.submit(PROMPT, max_new=9, temperature=0.7, seed=5)
+        _drain(eng)
+        eos_id = probe.result(timeout=0).tokens[2]
+        tracer.clear()
+    before = {k: getattr(eng, k) for k in COUNTERS + ("tokens_generated",)}
+    handles = [eng.submit(list(range(1, 30 + 5 * i)), max_new=9,
+                          eos_id=eos_id) for i in range(6)]
+    handles.append(eng.submit(PROMPT, max_new=9, eos_id=eos_id,
+                              temperature=0.7, seed=5))
+    _drain(eng)
+    results = [h.result(timeout=0) for h in handles]
+    if eos:
+        assert results[-1].finish_reason == "stop"
+        assert len(results[-1].tokens) == 3
+    delta = {k: getattr(eng, k) - v for k, v in before.items()}
+
+    spans = tracer.spans("rt.llm.")
+    steps = [s for s in spans if s.name == "rt.llm.step"]
+    assert steps and all(s.end_s is not None for s in spans)
+    full = 0
+    for st in steps:
+        kids = [s for s in spans if s.parent_id == st.span_id]
+        assert {k.name for k in kids} <= STEP_CHILDREN
+        assert len(kids) == len({k.name for k in kids})
+        assert all(st.start_s <= k.start_s and k.end_s <= st.end_s
+                   for k in kids)
+        a = st.attributes
+        assert a["slots"] == 4 and a["block"] == block
+        assert a["active"] + a["prefill_waiting"] <= a["slots"]
+        assert a["pages_written"] <= a["pages_allocated"]
+        names = {k.name for k in kids}
+        assert ("rt.llm.dispatch" in names) == (a["program"] != "none")
+        full += names == STEP_CHILDREN
+    assert full >= len(steps) // 2  # the steady state has all four
+    ran = [s.attributes for s in steps if s.attributes["program"] != "none"]
+    assert sum(a["active"] for a in ran) * block \
+        == delta["slot_steps_active"]
+    assert sum(a["prefill_waiting"] for a in ran) * block \
+        == delta["slot_steps_prefill_wait"]
+    assert len(ran) * 4 * block == delta["slot_steps"]
+    assert sum(a["prefill_tokens"] for a in ran) == delta["prefill_tokens"]
+    assert sum(a["program"] == "block" for a in ran) == delta["steps_block"]
+    assert sum(a["program"] == "decode_only" for a in ran) \
+        == delta["steps_decode_only"]
+    assert delta["prefill_tokens"] == sum(
+        r.prompt_len for r in results)
+    deliver = [s.attributes for s in spans if s.name == "rt.llm.deliver"]
+    assert sum(a["delivered"] for a in deliver) == delta["tokens_generated"]
+    assert sum(a["finished"] for a in deliver) == len(handles)
+    assert sum(a["overshoot"] for a in deliver) == delta["overshoot_tokens"]
+    # nothing in flight: the token invariant
+    assert delta["tokens_generated"] == sum(len(r.tokens) for r in results)
+    assert delta["tokens_generated"] + delta["overshoot_tokens"] \
+        == delta["slot_steps_active"] + len(handles)
+    # lag-1 dispatch computes one block for nobody per request, at least
+    assert delta["overshoot_tokens"] >= len(handles)
+
+
+def test_request_timings_keep_streamed_requests_and_forget(params,
+                                                           monkeypatch):
+    """A streamed request (tokens through ``on_token``, as the replica's
+    stream takes them) leaves its timing in ``request_timings()``; the
+    deque forgets beyond ``TIMINGS_KEPT``."""
+    monkeypatch.setattr(SlotEngine, "TIMINGS_KEPT", 3)
+    eng = SlotEngine(params, CFG, num_slots=2, chunk=16)
+    t0 = time.time()
+    streamed = []
+    h = eng.submit(PROMPT, max_new=4, seed=77, on_token=streamed.append,
+                   trace_ctx=("req-1", "span-1"))
+    _drain(eng)
+    assert streamed[-1] is None and len(streamed) == 5
+    (kept,) = eng.request_timings()
+    assert kept["seed"] == 77 and kept["request_id"] == "req-1"
+    assert kept["produced_tokens"] == 4
+    assert t0 - 1 <= kept["submit_unix_s"] <= time.time()
+    assert {k: v for k, v in kept.items() if k in h.timing} == h.timing
+    assert eng.request_timings(since_unix_s=time.time() + 1) == []
+    for i in range(4):
+        eng.submit([1, 2, 3], max_new=2, seed=100 + i)
+    _drain(eng)
+    assert [t["seed"] for t in eng.request_timings()] == [101, 102, 103]
+
+
+def _engine_lowered(eng):
+    rows = eng.num_slots
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    common = (eng._params, eng._cache, i32(rows, eng._pages_per_seq),
+              i32(rows), jax.ShapeDtypeStruct((rows,), jnp.bool_),
+              i32(rows), i32(rows),
+              jax.ShapeDtypeStruct((rows,), jnp.float32), i32(rows))
+    fused = common + (i32(eng.chunk), i32(), i32(), i32(),
+                      jax.ShapeDtypeStruct((), jnp.float32), i32())
+    return eng._block.lower(*fused), eng._decode_only.lower(*common)
+
+
+def _train_step():
+    import optax
+
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.train.step import build_sharded_train
+
+    gcfg = gpt2.GPT2Config(vocab_size=256, max_seq=32, num_layers=2,
+                           num_heads=2, d_model=32, remat=True,
+                           remat_policy="mem2")
+    mesh = MeshSpec(fsdp=2).build(jax.devices()[:2])
+    sinit, sstep, _ = build_sharded_train(
+        lambda k: gpt2.init_params(k, gcfg),
+        lambda p, b: gpt2.loss_fn(p, b, gcfg), mesh,
+        optimizer=optax.adamw(1e-3), master_fp32=True)
+    state = sinit(jax.random.PRNGKey(1))
+    batch = {"tokens": jnp.asarray(np.random.default_rng(0).integers(
+        0, 256, (4, 33)), jnp.int32)}
+    return sstep, state, batch
+
+
+@pytest.mark.parametrize("program", ["block", "decode_only", "train"])
+def test_programs_keep_their_names_and_carry_the_scopes(params, program):
+    """The trace reduction finds the engine's programs by
+    ``jit_block_fn`` / ``jit_decode_only_fn`` and splits device time by
+    scope name: a refactor that renames either fails here instead of
+    blanking a metric."""
+    if program == "train":
+        sstep, state, batch = _train_step()
+        lowered = sstep.lower(*state, batch)
+        name, scopes = "jit_sharded_step", (
+            "fwd_bwd", "optimizer", "grad_norm", "ce", "attn", "mlp")
+    else:
+        eng = SlotEngine(params, CFG, num_slots=2, chunk=8, page_size=8)
+        fused, decode_only = _engine_lowered(eng)
+        lowered = fused if program == "block" else decode_only
+        name = f"jit_{program}_fn"
+        scopes = ("layers", "qkv", "kv_write", "kv_gather", "attn", "mlp",
+                  "embed", "lm_head", "sample") + (
+                      ("prefill_lane",) if program == "block" else ())
+    text = lowered.as_text(debug_info=True)
+    assert re.search(r"module @(\S+)", text).group(1) == name
+    for scope in scopes:  # a word of some location's name stack
+        assert re.search(rf'loc\("[^"]*\b{scope}\b[^"]*"', text), scope
+
+
+def test_scopes_change_no_token_and_no_loss(params, monkeypatch):
+    """Scopes are metadata: the tokens of a fixed greedy and a fixed
+    seeded request are the parent commit's, and tokens and three steps'
+    losses are bit-identical with every ``jax.named_scope`` turned into
+    nothing."""
+    import contextlib
+
+    def run():
+        eng = SlotEngine(params, CFG, num_slots=2, chunk=16,
+                         decode_block=2)
+        greedy = eng.submit(PROMPT[:38], max_new=9)
+        sampled = eng.submit(PROMPT, max_new=9, temperature=0.7, seed=5)
+        _drain(eng)
+        sstep, state, batch = _train_step()
+        losses = []
+        for _ in range(3):
+            *state, metrics = sstep(*state, batch)
+            losses.append((float(metrics["loss"]).hex(),
+                           float(metrics["grad_norm"]).hex()))
+        return (greedy.result(timeout=0).tokens,
+                sampled.result(timeout=0).tokens, losses)
+
+    scoped = run()
+    assert scoped[0] == PARENT_GREEDY and scoped[1] == PARENT_SAMPLED
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert run() == scoped
+
+
+def test_step_spans_reach_a_profiler_trace(params, tmp_path):
+    """The only guard that the spans reach a trace at all: a short
+    ``jax.profiler`` trace round a few engine steps has ``rt.llm.step``
+    with its attributes in the host plane, on the profiler's clock."""
+    from jax.profiler import ProfileData
+
+    eng = SlotEngine(params, CFG, num_slots=2, chunk=16)
+    eng.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.submit(PROMPT, max_new=4)
+        _drain(eng)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("rt.llm."):
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    assert STEP_CHILDREN | {"rt.llm.step"} <= set(found)
+    steps = found["rt.llm.step"]
+    assert all(d > 0 for _, d, _ in steps)
+    stats = steps[0][2]
+    assert stats["slots"] == 2 and stats["block"] == 1
+    assert stats["program"] == "block" and stats["prefill_tokens"] == 16
+    assert sum(s["prefill_tokens"] for _, _, s in steps) == len(PROMPT)
+    # a child lies inside its step on the trace's own clock
+    s0, d0, _ = steps[1]
+    assert any(s0 <= s and s + d <= s0 + d0
+               for s, d, _ in found["rt.llm.fetch"])
+
+
+def test_step_span_without_jax_and_tracer_off_allocates_no_span():
+    """The head and the proxy never import JAX: there ``step_span`` is a
+    no-op that makes no annotation and no ``Span``, and imports nothing."""
+    code = (
+        "import sys\n"
+        "from ray_tpu.observability import tracing\n"
+        "made = []\n"
+        "init = tracing.Span.__init__\n"
+        "def counting(self, *a, **k):\n"
+        "    made.append(1); init(self, *a, **k)\n"
+        "tracing.Span.__init__ = counting\n"
+        "with tracing.step_span('rt.x', a=1) as sp:\n"
+        "    sp.set(b=2)\n"
+        "    assert not sp.recording\n"
+        "with tracing.step_span('rt.y', interleaved=True):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "assert not made and not tracing.get_tracer().spans()\n"
+        "tracing.enable()\n"
+        "with tracing.step_span('rt.x', a=1) as sp:\n"
+        "    sp.set(b=2)\n"
+        "with tracing.step_span('rt.y', interleaved=True, c=3):\n"
+        "    pass\n"
+        "got = {s.name: s.attributes for s in tracing.get_tracer().spans()}\n"
+        "assert got == {'rt.x': {'a': 1, 'b': 2}, 'rt.y': {'c': 3}}, got\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
