@@ -231,7 +231,7 @@ def serve_phase(found: dict, seed: int) -> dict:
             f"{stats['steps_decode_only']} decode-only steps, "
             f"{stats['slot_steps_active']} of {stats['slot_steps']} "
             f"slot-steps decoded, {stats['overshoot_tokens']} tokens "
-            "overshot")
+            f"overshot; {stats['kv_pages_read']} KV pages read a layer")
         return stats["device"]
     finally:
         try:

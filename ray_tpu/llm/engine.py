@@ -11,9 +11,10 @@ Design (TPU-first, static shapes throughout):
   admission. Freed pages return to an LRU free-list; full prompt pages
   are filed in a radix index keyed on page-size token chunks so the
   NEXT turn of a session (or another session sharing the system prompt)
-  hits them. PagedAttention (vLLM) + RadixAttention (SGLang),
-  re-expressed as plain gather/scatter in the engine's
-  two-XLA-program style.
+  hits them. PagedAttention (vLLM) + RadixAttention (SGLang) in the
+  engine's two-XLA-program style: on the TPU a Pallas kernel reads and
+  writes the pages in place (``ops/paged_attention.py``), elsewhere a
+  plain scatter and gather.
 - ``decode_slots_paged`` advances EVERY slot one token per call with
   per-slot positions; idle slots are parked past ``max_seq`` where
   their garbage writes are routed to the reserved scratch page.
@@ -186,6 +187,71 @@ class _Slot:
         return self.prefill_offset >= len(self.prompt)
 
 
+def build_step_programs(cfg: llama.LlamaConfig, page_size: int,
+                        decode_block: int, rules=None):
+    """The engine's two step programs, un-jitted: ``(block_fn,
+    decode_only_fn)``. ``SlotEngine`` jits them with the cache donated;
+    tests/test_tpu_compile.py compiles the same two for a described chip
+    from shapes alone."""
+
+    def block_fn(params, cache, tables, override_vals, override_mask,
+                 prev_last, pos, temps, seeds,
+                 pre_tokens, pre_slot, pre_p0, pre_n_valid,
+                 pre_temp, pre_seed):
+        """K-token decode block with the prefill lane fused into the
+        FIRST step (decode_slots_with_prefill_paged): a prompt chunk
+        rides the same params read as the decode batch, so prefill
+        no longer costs a separate full-model pass."""
+        tokens0 = jnp.where(override_mask, override_vals, prev_last)
+        dec_logits, pre_logits, cache = \
+            llama.decode_slots_with_prefill_paged(
+                params, cache, tables, tokens0, pos, pre_tokens,
+                pre_slot, pre_p0, pre_n_valid, cfg, page_size,
+                rules=rules)
+        tok1 = _sample(dec_logits, temps, seeds, pos + 1)
+        pre_tok = _sample(pre_logits[None], pre_temp[None],
+                          pre_seed[None],
+                          (pre_p0 + pre_n_valid)[None])[0]
+
+        if decode_block == 1:  # nothing to scan: trace no second program
+            return tok1[None], tok1, pre_tok, cache
+
+        def body(carry, _):
+            toks, cache, p = carry
+            logits, cache = llama.decode_slots_paged(
+                params, cache, tables, toks, p, cfg, page_size,
+                rules=rules)
+            nxt = _sample(logits, temps, seeds, p + 1)
+            return (nxt, cache, p + 1), nxt
+
+        (last, cache, _), toks_rest = jax.lax.scan(
+            body, (tok1, cache, pos + 1), None,
+            length=decode_block - 1)
+        toks_k = jnp.concatenate([tok1[None], toks_rest], axis=0)
+        return toks_k, last, pre_tok, cache
+
+    def decode_only_fn(params, cache, tables, override_vals,
+                       override_mask, prev_last, pos, temps, seeds):
+        """Pure K-step decode block — dispatched whenever no prompt
+        chunk is pending, so idle steps never pay the fused
+        program's C-token prefill lane."""
+        tokens0 = jnp.where(override_mask, override_vals, prev_last)
+
+        def body(carry, _):
+            toks, cache, p = carry
+            logits, cache = llama.decode_slots_paged(
+                params, cache, tables, toks, p, cfg, page_size,
+                rules=rules)
+            nxt = _sample(logits, temps, seeds, p + 1)
+            return (nxt, cache, p + 1), nxt
+
+        (last, cache, _), toks_k = jax.lax.scan(
+            body, (tokens0, cache, pos), None, length=decode_block)
+        return toks_k, last, cache
+
+    return block_fn, decode_only_fn
+
+
 class SlotEngine:
     """Continuous-batching generation over a paged KV-cache pool."""
 
@@ -198,7 +264,7 @@ class SlotEngine:
     # cumulative per-step accounting, returned by LLMServer.stats()
     STEP_COUNTERS = ("steps_block", "steps_decode_only", "slot_steps",
                      "slot_steps_active", "slot_steps_prefill_wait",
-                     "prefill_tokens", "overshoot_tokens")
+                     "prefill_tokens", "overshoot_tokens", "kv_pages_read")
 
     def __init__(self, params, cfg: llama.LlamaConfig, num_slots: int = 8,
                  chunk: int = 64, seed: int = 0, decode_block: int = 1,
@@ -283,65 +349,18 @@ class SlotEngine:
                 lambda x: jax.device_put(x, kv_sharding), self._cache)
         self._base_seed = seed
         self._req_counter = 0
-        ps = page_size
-        rules_ = self._rules
+        block_fn, decode_only_fn = build_step_programs(
+            cfg, page_size, decode_block, self._rules)
 
-        def block_fn(params, cache, tables, override_vals, override_mask,
-                     prev_last, pos, temps, seeds,
-                     pre_tokens, pre_slot, pre_p0, pre_n_valid,
-                     pre_temp, pre_seed):
-            """K-token decode block with the prefill lane fused into the
-            FIRST step (decode_slots_with_prefill_paged): a prompt chunk
-            rides the same params read as the decode batch, so prefill
-            no longer costs a separate full-model pass."""
-            tokens0 = jnp.where(override_mask, override_vals, prev_last)
-            dec_logits, pre_logits, cache = \
-                llama.decode_slots_with_prefill_paged(
-                    params, cache, tables, tokens0, pos, pre_tokens,
-                    pre_slot, pre_p0, pre_n_valid, cfg, ps,
-                    rules=rules_)
-            tok1 = _sample(dec_logits, temps, seeds, pos + 1)
-            pre_tok = _sample(pre_logits[None], pre_temp[None],
-                              pre_seed[None],
-                              (pre_p0 + pre_n_valid)[None])[0]
-
-            def body(carry, _):
-                toks, cache, p = carry
-                logits, cache = llama.decode_slots_paged(
-                    params, cache, tables, toks, p, cfg, ps,
-                    rules=rules_)
-                nxt = _sample(logits, temps, seeds, p + 1)
-                return (nxt, cache, p + 1), nxt
-
-            (last, cache, _), toks_rest = jax.lax.scan(
-                body, (tok1, cache, pos + 1), None,
-                length=decode_block - 1)
-            toks_k = jnp.concatenate([tok1[None], toks_rest], axis=0)
-            return toks_k, last, pre_tok, cache
-
-        def decode_only_fn(params, cache, tables, override_vals,
-                           override_mask, prev_last, pos, temps, seeds):
-            """Pure K-step decode block — dispatched whenever no prompt
-            chunk is pending, so idle steps never pay the fused
-            program's C-token prefill lane."""
-            tokens0 = jnp.where(override_mask, override_vals, prev_last)
-
-            def body(carry, _):
-                toks, cache, p = carry
-                logits, cache = llama.decode_slots_paged(
-                    params, cache, tables, toks, p, cfg, ps,
-                    rules=rules_)
-                nxt = _sample(logits, temps, seeds, p + 1)
-                return (nxt, cache, p + 1), nxt
-
-            (last, cache, _), toks_k = jax.lax.scan(
-                body, (tokens0, cache, pos), None, length=decode_block)
-            return toks_k, last, cache
-
-        # The cache is donated: XLA updates it in place, so a decode
-        # step never copies the (potentially multi-GB) KV pages. Under
-        # a mesh, every compiled-program call is wrapped so constrain()
-        # resolves (ambient mesh + current-mesh global); the in-kernel
+        # The cache is donated, and as compiled for the TPU a step
+        # touches it only in place: the layer loop carries the pool whole
+        # and the paged-attention kernel, to which the pool is aliased,
+        # writes the new rows into their pages and reads the pages in
+        # use (tests/test_tpu_compile.py holds both programs to that: no
+        # pool-shaped copy, temporaries a small fraction of the pool).
+        # Off the TPU the reference path scatters and gathers instead.
+        # Under a mesh, every compiled-program call is wrapped so
+        # constrain() resolves (ambient mesh + current-mesh global); the
         # constraints pin the output cache to the input's sharding, so
         # donation stays an in-place aliasing across steps.
         def _maybe_mesh(fn):
@@ -409,6 +428,13 @@ class SlotEngine:
         self.slot_steps_prefill_wait = 0
         self.prefill_tokens = 0
         self.overshoot_tokens = 0
+        # KV pages the dispatched rows attend over, a layer: the sum of
+        # ceil(length / page_size) over every decode row of every decode
+        # step of a block and the prefill lane's slot. On the TPU it is
+        # what the paged-attention kernel reads; against slot_steps x
+        # pages_per_seq it is the live share of what gathering every
+        # table entry reads.
+        self.kv_pages_read = 0
         # The last finished requests' timing: a streamed response
         # carries tokens only, so this is where its stages are read.
         self._timings: deque = deque(maxlen=self.TIMINGS_KEPT)
@@ -963,11 +989,14 @@ class SlotEngine:
             self.slot_steps_active += len(active) * k
             self.slot_steps_prefill_wait += waiting * k
             self.prefill_tokens += pre_tokens
+            pages_read = self._pages_read(active, prefill_idx)
+            self.kv_pages_read += pages_read
         if sp.recording:
             ps = self.page_size
             sp.set(program=program, active=len(active),
                    prefill_tokens=pre_tokens, prefill_waiting=waiting,
                    pending=pending, pages_allocated=self._pool.used_count,
+                   pages_read=pages_read if program != "none" else 0,
                    # written so far: a prompt in the lane has pos 0 and
                    # prefill_offset tokens in its pages; pos runs past
                    # the reservation by the overshoot, which lands in
@@ -987,6 +1016,21 @@ class SlotEngine:
             self._inflight = new_block
             ran = True
         return ran
+
+    def _pages_read(self, active, prefill_idx) -> int:
+        """Pages the rows about to be dispatched attend over, a layer:
+        a decode row at position p reads its p + 1 live positions, in
+        each of the block's steps; the lane's slot reads its prompt up
+        to the end of this chunk."""
+        ps, cap = self.page_size, self.cfg.max_seq
+        pages = sum(-(-(s.pos + k + 1) // ps)
+                    for _, s in active for k in range(self.decode_block)
+                    if s.pos + k < cap)
+        if prefill_idx is not None:
+            s = self._slots[prefill_idx]
+            pages += -(-min(len(s.prompt),
+                            s.prefill_offset + self.chunk) // ps)
+        return pages
 
     def _dispatch_block(self, active, prefill_idx):
         """Dispatch one K-step block: every active slot decodes K

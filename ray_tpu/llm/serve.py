@@ -254,6 +254,14 @@ def build_llm_app(model: str = "llama-tiny", num_slots: int = 8,
     # for in-replica admission).
     deploy_opts.setdefault("max_pending", max_pending)
     deploy_opts.setdefault("queue_timeout_s", queue_timeout_s)
+    # A replica that holds an accelerator can be deaf for tens of seconds
+    # and healthy: a device-runtime call that keeps the interpreter lock
+    # (stopping a profiler trace costs ~40 us a device event, 20-40 s for
+    # five seconds of a fast decode loop: PERF.md, PR 25) answers no
+    # probe. Serve's default, three probes of 5 s, kills it there, loses
+    # the warm engine, and the replacement cannot take the chip while
+    # the old process still has it. Three probes of 30 s.
+    deploy_opts.setdefault("health_check_timeout_s", 30.0)
     dep = deployment(LLMServer, name=name, **deploy_opts)
     return dep.bind(model=model, num_slots=num_slots, chunk=chunk,
                     seed=seed, checkpoint_path=checkpoint_path,

@@ -17,7 +17,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..ops import paged_attention as paged_attention_op
 from ..ops.attention import attention as attention_op, mha_reference
 from ..parallel.sharding import constrain
 from .common import rms_norm, truncated_normal
@@ -117,6 +119,39 @@ def rope(x, positions, theta: float):
     out2 = xf2 * cos + xf1 * sin
     out = jnp.stack([out1, out2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
+
+
+def rope_pair_tables(positions, d: int, theta: float):
+    """The rotary tables of ``positions`` [B, T] for :func:`rope_pairs`:
+    (cos, signed sin), each [B, T, 1, d], pair i's angle at lanes 2i and
+    2i + 1 and the sine negative at 2i. They depend on the positions
+    alone: a loop over layers computes them once, outside."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = positions[:, :, None, None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)        # [B, T, 1, d/2]
+    shape = angles.shape[:-1] + (d,)
+    return (jnp.stack([cos, cos], axis=-1).reshape(shape),
+            jnp.stack([-sin, sin], axis=-1).reshape(shape))
+
+
+def rope_pairs(x, tables):
+    """:func:`rope` of token-major x [B, T, heads, D] without cutting the
+    pairs apart: ``x * cos + swap(x) * signed_sin``, where swap exchanges
+    the two lanes of every pair — the same products and the same sum, bit
+    for bit, as rope's ``x1 * cos - x2 * sin`` and ``x2 * cos + x1 *
+    sin``. The strided halves and their re-interleaving are gathers and
+    copies on the TPU, a dozen operations a layer; the exchange is one
+    exact product with a 0/1 matrix."""
+    cos, sin = tables
+    d = x.shape[-1]
+    swap = np.zeros((d, d), np.float32)
+    swap[np.arange(d) ^ 1, np.arange(d)] = 1.0
+    swapped = jnp.einsum(
+        "bthd,de->bthe", x, swap.astype(x.dtype),
+        precision=(jax.lax.Precision.HIGHEST
+                   if x.dtype == jnp.float32 else None),
+        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
 
 
 def _repeat_kv(x, n_rep: int):
@@ -469,168 +504,167 @@ def prefill_chunk(params, cache, tokens, slot, p0, cfg: LlamaConfig,
 # indirection, so prompt-prefix pages can be SHARED between slots
 # (radix/prefix cache, refcounted by the engine) and freed pages return
 # to a pool instead of dying with a slot. PagedAttention (vLLM) /
-# RadixAttention (SGLang) re-expressed in this repo's two-XLA-program
-# style: plain gather/scatter by physical page id, no custom kernel.
+# RadixAttention (SGLang) in this repo's two-XLA-program style.
 #
 # Layout: cache["kv"] is ONE fused array [L, 2, num_pages, page_size,
-# Hkv, hd] (index 0 = K, 1 = V) in HEADS-MINOR page order: a physical
-# page's row is a contiguous [page_size, Hkv, hd] block, so gathering a
-# slot's pages by table row is a contiguous per-page copy and the
-# gathered view reshapes to seq-major [S, Hkv, hd] for FREE — the old
-# heads-major layout ([.., Hkv, page_size, hd]) needed a transpose that
-# materialized the whole gathered cache every decode step. Fusing K and
-# V into one array halves the number of gather ops per layer (page-
-# gather fusion): one indexed read serves both attention operands.
-# A page table row [P] (P = max_seq // page_size) maps a slot's logical
-# page l to a physical page id. Physical page 0 is the RESERVED SCRATCH
-# page: every invalid write (parked slots, chunk tail padding, position
-# overshoot) is routed there explicitly, so garbage can never land in a
-# real — possibly shared — page. Unallocated page-table entries are 0
-# for the same reason. Positions in unallocated logical pages are
-# always > the slot's current pos, so attention masks them before they
-# are ever read.
+# Hkv * hd] (index 0 = K, 1 = V): a token's KV heads lie side by side in
+# the minor axis, so a physical page is a contiguous [page_size, Hkv * hd]
+# block whose rows fill whole 128-lane rows (hd = 64 alone is half of one:
+# with [.., Hkv, hd] minor axes the TPU either pads hd to 128 or, as it
+# did, makes the PAGE index the lane axis and scatters a page over the
+# whole pool). A page table row [P] (P = max_seq // page_size) maps a
+# slot's logical page l to a physical page id. Physical page 0 is the
+# RESERVED SCRATCH page: every invalid write (parked slots, chunk tail
+# padding, position overshoot) is routed there explicitly, so garbage can
+# never land in a real — possibly shared — page. Unallocated page-table
+# entries are 0 for the same reason. Positions in unallocated logical
+# pages are always > the slot's current pos, so attention masks them
+# before they are ever read.
 #
-# Sharding: every paged kernel takes an optional ``rules`` table
+# The pool inside a step program is touched only IN PLACE. The loop over
+# the layers carries the whole pool and scans over the layers' weights
+# and a layer index: a layer is never sliced out of the pool nor stacked
+# back (as the loop's xs/ys it was, and XLA then copied the pool into the
+# loop's layout and back every step). On the TPU the pool's only reader
+# and writer is ``ops/paged_attention.py``: a Pallas kernel that takes the
+# whole pool, aliased to its output, and the layer index, puts the rows'
+# new K/V into their pages and DMAs only the pages a row has (an XLA
+# scatter beside it asked for another layout of the whole pool, and XLA
+# copied the pool there and back every layer). Off the TPU it is the
+# reference below: a scatter at [layer, :, page, offset] on the carried
+# pool, a gather of every table entry, and the masked einsum.
+#
+# Sharding: every paged program takes an optional ``rules`` table
 # (logical axis -> mesh axis). Under a tp mesh the serving engine maps
-# the "kv" logical axis to tp, so the page pool's Hkv axis — and the
-# q/k/v head axes of every intermediate — shard across chips while the
-# page/seq axes stay replicated; with no mesh the constraints no-op and
-# the kernels are byte-identical to the single-device path.
+# the "kv" logical axis to tp, so the pool's Hkv * hd axis — whole heads a
+# shard — and the q/k/v head axes of every intermediate shard across
+# chips while the page/seq axes stay replicated; the kernel runs per
+# shard in a shard_map. With no mesh the constraints no-op.
 #
 # Scope names: the paged programs carry ``jax.named_scope`` names — metadata
 # on the HLO (``op_name``), no operation added, moved or changed — so a
 # device trace can say what a step was made of under names the program
-# chose, not XLA's ``copy.68``. ``layers`` is round each scan over the
-# layers; inside it every operation sits in ``qkv`` (norm, projections,
-# rope), ``kv_write`` (the new K/V into the pool), ``kv_gather`` (the slots'
-# pages out of it), ``attn`` (attention and its output projection) or
-# ``mlp``. What a trace shows under ``layers`` and none of those is the
-# scan's own work: slicing one layer out of the pool and stacking it back.
-# Outside: ``embed``, ``lm_head``; ``prefill_lane`` is round the prompt
-# chunk's half of the fused step. benchmark/trace/program.py reads them.
+# chose. ``layers`` is round each loop over the layers; inside it every
+# operation sits in ``qkv`` (norm, projections, rope), ``kv_write`` and
+# ``kv_gather`` (the reference's scatter and gather; nothing on the TPU,
+# where the kernel under ``attn`` does both), ``attn`` (attention and its
+# output projection) or ``mlp``.
+# What a trace shows under ``layers`` and none of those is the loop's own
+# work, which should be nothing. Outside: ``embed``, ``lm_head``;
+# ``prefill_lane`` is round the prompt chunk's half of the fused step.
+# benchmark/trace/program.py reads them.
 # ---------------------------------------------------------------------------
 
 def init_paged_kv_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
     if cfg.max_seq % page_size != 0:
         raise ValueError(
             f"page_size ({page_size}) must divide max_seq ({cfg.max_seq})")
-    shape = (cfg.num_layers, 2, num_pages, page_size, cfg.num_kv_heads,
-             cfg.head_dim)
+    shape = (cfg.num_layers, 2, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
     return {"kv": jnp.zeros(shape, cfg.dtype)}
 
 
-# Logical axes of cache["kv"] — the heads axis shards under the "kv"
-# rule (the serving engine maps it to tp).
-PAGED_KV_AXES = (None, None, None, None, "kv", None)
+# Logical axes of cache["kv"] — the heads-and-head_dim axis shards under
+# the "kv" rule (the serving engine maps it to tp), whole heads a shard.
+PAGED_KV_AXES = (None, None, None, None, "kv")
 
 
-def _gather_pages(kv_l, tables):
-    """ONE fused gather: [2, NP, ps, Hkv, hd] by tables [B, P] ->
-    seq-major [2, B, P*ps, Hkv, hd] (0 = K, 1 = V).
+def _write_and_attend(q, kn, vn, kv, layer, rows, cfg: LlamaConfig,
+                      page_size: int, rules):
+    """The rows' new K/V into the carried pool, then attention of q over
+    each row's pages of ``layer`` -> (o [R, T, D], pool).
 
-    The gathered view puts logical page l's slot (offset o) at sequence
-    position l * ps + o, so positions/masks are identical to the dense
-    layout — the paths differ only in where bytes physically live.
-    Heads-minor pages make the reshape to seq-major free (each page row
-    is already a contiguous [ps, Hkv, hd] block)."""
-    b, p = tables.shape
-    g = kv_l[:, tables]  # [2, B, P, ps, Hkv, hd] — contiguous per page
-    return g.reshape(2, b, p * g.shape[3], g.shape[4], g.shape[5])
+    q [R, T, H, hd]; kn / vn [R, T, Hkv * hd]; rows: ``row_meta`` of the
+    page tables [R, P], q_start [R] and lengths [R]; token t of row r is
+    position q_start[r] + t, written if it is under lengths[r]
+    (a parked row has length 0, a chunk's tail lies past it) and reading
+    positions <= its own. On the TPU one Pallas kernel does both, in
+    place, reading only the row's live pages. Off the TPU: a scatter with
+    the invalid tokens routed to the scratch page, a gather of every
+    table entry, and the masked einsum."""
+    r, t, h, hd = q.shape
+    if paged_attention_op.use_kernel():
+        from ..parallel.sharding import current_mesh, spec_for
 
-
-def _scatter_token_kv(kv_l, kn, vn, tables, rows, pos,
-                      page_size: int, max_seq: int):
-    """Scatter one token per row into the fused cache: row r's K/V
-    lands in physical page tables[rows[r], pos[r] // ps] at offset
-    pos[r] % ps. Writes at pos >= max_seq (parked rows / overshoot) are
-    routed to the scratch page so they can never corrupt a live page.
-    kn/vn: [B, Hkv, hd]; one scatter covers both K and V."""
-    p = tables.shape[1]
-    valid = pos < max_seq
-    lpage = jnp.minimum(pos // page_size, p - 1)
-    phys = jnp.where(valid, tables[rows, lpage], 0)
-    off = jnp.where(valid, pos % page_size, 0)
-    return kv_l.at[:, phys, off].set(jnp.stack([kn, vn]))
-
-
-def _gqa_paged_attention(q, kv, mask, cfg: LlamaConfig):
-    """Grouped-query attention of q against a fused SEQ-MAJOR cache
-    view, without materializing the repeated KV heads.
-
-    q: [B, H, C, hd]; kv: [2, B, S, Hkv, hd] (heads-minor, as
-    :func:`_gather_pages` returns it — no transpose needed); mask
-    broadcastable to [B, Hkv, G, C, S]. Returns [B, C, D]."""
-    b, h, c, hd = q.shape
-    hkv = cfg.num_kv_heads
-    g = h // hkv
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(b, hkv, g, c, hd)
-    # bf16 operands + fp32 accumulation: an explicit .astype(f32) here
-    # would materialize an fp32 copy of the whole KV cache every step —
-    # at decode time the cache read IS the bandwidth bill.
-    scores = jnp.einsum("bkgcd,bskd->bkgcs", qg, kv[0],
-                        preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bkgcs,bskd->bkgcd", probs.astype(kv.dtype), kv[1])
-    return o.reshape(b, h, c, hd).transpose(0, 2, 1, 3).reshape(
-        b, c, cfg.d_model)
+        kv_spec = spec_for(("kv",), rules)
+        with jax.named_scope("attn"):
+            o, kv = paged_attention_op.paged_attention(
+                q, kn, vn, kv, layer, rows, mesh=current_mesh(),
+                heads_axis=kv_spec[0] if len(kv_spec) else None)
+        return o.reshape(r, t, h * hd), kv
+    tables, q_start, lengths = rows[:, :-2], rows[:, -2], rows[:, -1]
+    pos = q_start[:, None] + jnp.arange(t)[None, :]              # [R, T]
+    with jax.named_scope("kv_write"):
+        phys, off = paged_attention_op.page_slots(
+            tables, jnp.arange(r)[:, None], pos, pos < lengths[:, None],
+            page_size)
+        # Pin the written pool to the kv sharding: the scatter must never
+        # trigger a resharding of the (multi-GB) pool, and the loop's
+        # carry must match the donated input's sharding so donation stays
+        # in place.
+        kv = constrain(paged_attention_op.write_token_kv(
+            kv, layer, kn.reshape(r * t, -1), vn.reshape(r * t, -1),
+            phys.reshape(-1), off.reshape(-1)), PAGED_KV_AXES, rules)
+    with jax.named_scope("kv_gather"):
+        kv_l = jax.lax.dynamic_index_in_dim(kv, layer, 0, keepdims=False)
+        kv_att = constrain(
+            paged_attention_op.gather_pages(kv_l, tables, cfg.num_kv_heads),
+            (None, None, None, "kv", None), rules)
+    with jax.named_scope("attn"):
+        mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
+                <= pos[:, None, None, :, None])
+        return paged_attention_op.gqa_attention(
+            q.transpose(0, 2, 1, 3), kv_att, mask, cfg.num_kv_heads), kv
 
 
-def _paged_layer_step(x, p, cfg: LlamaConfig, positions, kv_mask,
-                      write_kv, attend_view, rules=None):
-    """Shared per-layer block for the PAGED cache paths — the paged
-    twin of :func:`_cache_layer_step`, differing in the fused
-    heads-minor cache (``write_kv`` lands new K/V by physical page id,
-    ``attend_view`` gathers a seq-major [2, B, S, Hkv, hd] view) and in
-    carrying logical-axis sharding constraints: under a tp mesh q/k/v
-    shard on their head axes and the page pool on Hkv; with no mesh
-    every constraint is a no-op.
+def _chunk_length(p0, n_valid, c: int, max_seq: int):
+    """Live positions of a slot once the chunk's valid tokens are in:
+    tokens at chunk index >= n_valid or position >= max_seq are not."""
+    return p0 + jnp.clip(jnp.minimum(n_valid, max_seq - p0), 0, c)
 
-    x: [B, T, D]. Returns (x, kv_l)."""
+
+def _scan_layers(layer_step, x, cache, params, cfg: LlamaConfig):
+    """The loop over the layers with the pool as its CARRY: scanned are
+    the layers' weights and a layer index, never the pool."""
+    with jax.named_scope("layers"):
+        (x, kv), _ = jax.lax.scan(
+            lambda carry, inp: (layer_step(*carry, *inp), None),
+            (x, cache["kv"]),
+            (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    return x, {"kv": kv}
+
+
+def _qkv(x, p, cfg: LlamaConfig, angles, rules):
+    """x [B, T, D] -> token-major q [B, T, H, hd], k_new / v_new
+    [B, T, Hkv, hd], q and k_new rotated by ``angles``
+    (:func:`rope_pair_tables` of the tokens' positions)."""
     b, t, _ = x.shape
     h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    with jax.named_scope("qkv"):
-        y = rms_norm(x, p["attn_norm"])
-        q = (y @ p["wq"].astype(y.dtype)).reshape(b, t, h, hd).transpose(
-            0, 2, 1, 3)
-        k_new = (y @ p["wk"].astype(y.dtype)).reshape(
-            b, t, hkv, hd).transpose(0, 2, 1, 3)
-        v_new = (y @ p["wv"].astype(y.dtype)).reshape(
-            b, t, hkv, hd).transpose(0, 2, 1, 3)
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
-        q = constrain(q, (None, "heads", None, None), rules)
-        k_new = constrain(k_new, (None, "kv", None, None), rules)
-        v_new = constrain(v_new, (None, "kv", None, None), rules)
-    with jax.named_scope("kv_write"):
-        kv_l = write_kv(k_new, v_new)
-        # Pin the written pool AND the gathered view to the kv-heads
-        # sharding: the scatter/gather must never trigger a resharding
-        # of the (multi-GB) page pool, and the scan-stacked output must
-        # match the donated input's sharding so in-place donation
-        # survives.
-        kv_l = constrain(kv_l, PAGED_KV_AXES[1:], rules)
-    with jax.named_scope("kv_gather"):
-        kv_att = constrain(attend_view(kv_l),
-                           (None, None, None, "kv", None), rules)
-    with jax.named_scope("attn"):
-        o = _gqa_paged_attention(q, kv_att, kv_mask, cfg)
-        x = x + o @ p["wo"].astype(o.dtype)
+    y = rms_norm(x, p["attn_norm"])
+    q = (y @ p["wq"].astype(y.dtype)).reshape(b, t, h, hd)
+    k_new = (y @ p["wk"].astype(y.dtype)).reshape(b, t, hkv, hd)
+    v_new = (y @ p["wv"].astype(y.dtype)).reshape(b, t, hkv, hd)
+    q = constrain(rope_pairs(q, angles), (None, None, "heads", None), rules)
+    k_new = constrain(rope_pairs(k_new, angles), (None, None, "kv", None),
+                      rules)
+    v_new = constrain(v_new, (None, None, "kv", None), rules)
+    return q, k_new, v_new
+
+
+def _mlp(x, p, rules):
     with jax.named_scope("mlp"):
         y = rms_norm(x, p["ffn_norm"])
         gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
         up = y @ p["w_up"].astype(y.dtype)
         hidden = constrain(gate * up, (None, None, "mlp"), rules)
-        x = x + hidden @ p["w_down"].astype(y.dtype)
-    return x, kv_l
+        return x + hidden @ p["w_down"].astype(y.dtype)
 
 
 def decode_slots_paged(params, cache, tables, tokens, pos,
                        cfg: LlamaConfig, page_size: int, rules=None):
     """``decode_slots`` over a paged cache: one decode step with
-    per-slot positions, gathering each slot's pages through its page
-    table row and scattering the new K/V by physical page id.
+    per-slot positions, the new K/V scattered by physical page id and
+    each slot attending through its page table row.
 
     tables [B, P] int32, tokens [B] int32, pos [B] int32. Returns
     (logits [B, vocab] fp32, new_cache). Parked slots (pos >= max_seq,
@@ -639,30 +673,22 @@ def decode_slots_paged(params, cache, tables, tokens, pos,
     b = tokens.shape[0]
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(cfg.dtype)[:, None, :]  # [B,1,D]
-    positions = pos[:, None]
-    kv_mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
-               <= pos[:, None, None, None, None])
-    rows = jnp.arange(b)
+    angles = rope_pair_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    rows = paged_attention_op.row_meta(
+        tables, pos, jnp.where(pos < cfg.max_seq, pos + 1, 0))
 
-    def layer_step(x, inputs):
-        p, kv_l = inputs
+    def layer_step(x, kv, p, layer):
+        with jax.named_scope("qkv"):
+            q, k_new, v_new = _qkv(x, p, cfg, angles, rules)
+        o, kv = _write_and_attend(
+            q, k_new.reshape(b, 1, -1), v_new.reshape(b, 1, -1), kv, layer,
+            rows, cfg, page_size, rules)
+        with jax.named_scope("attn"):
+            x = x + o @ p["wo"].astype(o.dtype)
+        return _mlp(x, p, rules), kv
 
-        def write(kn, vn):
-            return _scatter_token_kv(
-                kv_l, kn[:, :, 0, :], vn[:, :, 0, :],
-                tables, rows, pos, page_size, cfg.max_seq)
-
-        def view(kv):
-            return _gather_pages(kv, tables)
-
-        x, kv2 = _paged_layer_step(x, p, cfg, positions, kv_mask,
-                                   write, view, rules)
-        return x, kv2
-
-    with jax.named_scope("layers"):
-        x, new_kv = jax.lax.scan(
-            layer_step, x, (params["blocks"], cache["kv"]))
-    return _lm_head(x[:, 0], params, cfg), {"kv": new_kv}
+    x, cache = _scan_layers(layer_step, x, cache, params, cfg)
+    return _lm_head(x[:, 0], params, cfg), cache
 
 
 def prefill_chunk_paged(params, cache, tables, tokens, slot, p0, n_valid,
@@ -678,42 +704,31 @@ def prefill_chunk_paged(params, cache, tables, tokens, slot, p0, n_valid,
     chunk aligns to pages. Returns ([vocab] logits of chunk index
     n_valid - 1, new_cache)."""
     c = tokens.shape[0]
-    p = tables.shape[1]
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(cfg.dtype)[None]  # [1,C,D]
-    idx = jnp.arange(c)
-    abs_pos = p0 + idx
-    positions = abs_pos[None, :]
-    kv_mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
-               <= abs_pos[None, None, None, :, None])
-    cvalid = (idx < n_valid) & (abs_pos < cfg.max_seq)
-    lpage = jnp.minimum(abs_pos // page_size, p - 1)
-    phys = jnp.where(cvalid, tables[slot, lpage], 0)
-    off = jnp.where(cvalid, abs_pos % page_size, 0)
-    slot_table = jax.lax.dynamic_slice(tables, (slot, 0), (1, p))
+    abs_pos = p0 + jnp.arange(c)
+    angles = rope_pair_tables(abs_pos[None, :], cfg.head_dim,
+                              cfg.rope_theta)
+    slot_table = jax.lax.dynamic_slice(tables, (slot, 0),
+                                       (1, tables.shape[1]))
+    rows = paged_attention_op.row_meta(
+        slot_table, jnp.reshape(p0, (1,)),
+        jnp.reshape(_chunk_length(p0, n_valid, c, cfg.max_seq), (1,)))
 
-    def layer_step(x, inputs):
-        pr, kv_l = inputs
+    def layer_step(x, kv, p, layer):
+        with jax.named_scope("qkv"):
+            q, k_new, v_new = _qkv(x, p, cfg, angles, rules)
+        o, kv = _write_and_attend(
+            q, k_new.reshape(1, c, -1), v_new.reshape(1, c, -1), kv, layer,
+            rows, cfg, page_size, rules)
+        with jax.named_scope("attn"):
+            x = x + o @ p["wo"].astype(o.dtype)
+        return _mlp(x, p, rules), kv
 
-        def write(kn, vn):
-            # kn/vn: [1, Hkv, C, hd] -> per-token scatter [C, Hkv, hd]
-            return kv_l.at[:, phys, off].set(
-                jnp.stack([kn[0].transpose(1, 0, 2),
-                           vn[0].transpose(1, 0, 2)]))
-
-        def view(kv):
-            return _gather_pages(kv, slot_table)
-
-        x, kv2 = _paged_layer_step(x, pr, cfg, positions, kv_mask,
-                                   write, view, rules)
-        return x, kv2
-
-    with jax.named_scope("layers"):
-        x, new_kv = jax.lax.scan(
-            layer_step, x, (params["blocks"], cache["kv"]))
+    x, cache = _scan_layers(layer_step, x, cache, params, cfg)
     row = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                        keepdims=False)
-    return _lm_head(row[None], params, cfg)[0], {"kv": new_kv}
+    return _lm_head(row[None], params, cfg)[0], cache
 
 
 def decode_slots_with_prefill_paged(params, cache, tables, tokens, pos,
@@ -733,86 +748,49 @@ def decode_slots_with_prefill_paged(params, cache, tables, tokens, pos,
     (dec_logits [B, vocab], pre_logits [vocab], new_cache)."""
     b = tokens.shape[0]
     c = pre_tokens.shape[0]
-    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
     s_max = cfg.max_seq
-    p = tables.shape[1]
     packed = jnp.concatenate([tokens, pre_tokens])
     with jax.named_scope("embed"):
         x = params["wte"][packed].astype(cfg.dtype)[None]  # [1, B+C, D]
     pre_positions = pre_p0 + jnp.arange(c)
     positions = jnp.concatenate([pos, pre_positions])[None]
-    dec_mask = (jnp.arange(s_max)[None, None, None, None, :]
-                <= pos[:, None, None, None, None])
-    pre_mask = (jnp.arange(s_max)[None, None, None, None, :]
-                <= pre_positions[None, None, None, :, None])
-    rows = jnp.arange(b)
-    idx = jnp.arange(c)
-    cvalid = (idx < pre_n_valid) & (pre_positions < s_max)
-    lpage_c = jnp.minimum(pre_positions // page_size, p - 1)
-    phys_c = jnp.where(cvalid, tables[pre_slot, lpage_c], 0)
-    off_c = jnp.where(cvalid, pre_positions % page_size, 0)
-    slot_table = jax.lax.dynamic_slice(tables, (pre_slot, 0), (1, p))
+    angles = rope_pair_tables(positions, cfg.head_dim, cfg.rope_theta)
+    slot_table = jax.lax.dynamic_slice(tables, (pre_slot, 0),
+                                       (1, tables.shape[1]))
+    rows_d = paged_attention_op.row_meta(
+        tables, pos, jnp.where(pos < s_max, pos + 1, 0))
+    rows_c = paged_attention_op.row_meta(
+        slot_table, jnp.reshape(pre_p0, (1,)),
+        jnp.reshape(_chunk_length(pre_p0, pre_n_valid, c, s_max), (1,)))
 
-    def layer_step(x, inputs):
-        pr, kv_l = inputs
-        t = b + c
+    def layer_step(x, kv, pr, layer):
         with jax.named_scope("qkv"):
-            y = rms_norm(x, pr["attn_norm"])
-            q = (y @ pr["wq"].astype(y.dtype)).reshape(
-                1, t, h, hd).transpose(0, 2, 1, 3)
-            k_new = (y @ pr["wk"].astype(y.dtype)).reshape(
-                1, t, hkv, hd).transpose(0, 2, 1, 3)
-            v_new = (y @ pr["wv"].astype(y.dtype)).reshape(
-                1, t, hkv, hd).transpose(0, 2, 1, 3)
-            q = rope(q, positions, cfg.rope_theta)
-            k_new = rope(k_new, positions, cfg.rope_theta)
-            q = constrain(q, (None, "heads", None, None), rules)
-            k_new = constrain(k_new, (None, "kv", None, None), rules)
-            v_new = constrain(v_new, (None, "kv", None, None), rules)
-            qd = q[0, :, :b].transpose(1, 0, 2)[:, :, None, :]  # [B,h,1,hd]
-            kd = k_new[0, :, :b].transpose(1, 0, 2)             # [B,Hkv,hd]
-            vd = v_new[0, :, :b].transpose(1, 0, 2)
+            q, k_new, v_new = _qkv(x, pr, cfg, angles, rules)
+            qd = q[0, :b][:, None]                           # [B,1,h,hd]
+            kd = k_new[0, :b].reshape(b, 1, -1)              # [B,1,Hkv*hd]
+            vd = v_new[0, :b].reshape(b, 1, -1)
             with jax.named_scope("prefill_lane"):
-                qp = q[:, :, b:]                                # [1,h,C,hd]
-                kp = k_new[0, :, b:].transpose(1, 0, 2)         # [C,Hkv,hd]
-                vp = v_new[0, :, b:].transpose(1, 0, 2)
-        # Writes first, decode rows then the chunk (disjoint pages by
-        # the caller's pre_slot guarantee), so in-chunk causality holds.
-        with jax.named_scope("kv_write"):
-            kv_l = _scatter_token_kv(kv_l, kd, vd, tables, rows, pos,
-                                     page_size, s_max)
-            with jax.named_scope("prefill_lane"):
-                kv_l = kv_l.at[:, phys_c, off_c].set(jnp.stack([kp, vp]))
-            kv_l = constrain(kv_l, PAGED_KV_AXES[1:], rules)
-        kv_axes = (None, None, None, "kv", None)
-        with jax.named_scope("kv_gather"):
-            kv_d = constrain(_gather_pages(kv_l, tables), kv_axes, rules)
-        with jax.named_scope("attn"):
-            od = _gqa_paged_attention(qd, kv_d, dec_mask, cfg)
+                qp = q[:, b:]                                # [1,C,h,hd]
+                kp = k_new[:, b:].reshape(1, c, -1)          # [1,C,Hkv*hd]
+                vp = v_new[:, b:].reshape(1, c, -1)
+        # Decode rows, then the chunk: each writes its own tokens before
+        # it attends, so in-chunk causality holds, and the two touch
+        # disjoint pages by the caller's pre_slot guarantee.
+        od, kv = _write_and_attend(qd, kd, vd, kv, layer, rows_d, cfg,
+                                   page_size, rules)
         with jax.named_scope("prefill_lane"):
-            with jax.named_scope("kv_gather"):
-                kv_p = constrain(_gather_pages(kv_l, slot_table), kv_axes,
-                                 rules)
-            with jax.named_scope("attn"):
-                op = _gqa_paged_attention(qp, kv_p, pre_mask, cfg)
+            op, kv = _write_and_attend(qp, kp, vp, kv, layer, rows_c, cfg,
+                                       page_size, rules)
         with jax.named_scope("attn"):
             o = jnp.concatenate([od[:, 0][None], op], axis=1)  # [1,B+C,D]
             x = x + o @ pr["wo"].astype(o.dtype)
-        with jax.named_scope("mlp"):
-            y = rms_norm(x, pr["ffn_norm"])
-            gate = jax.nn.silu(y @ pr["w_gate"].astype(y.dtype))
-            up = y @ pr["w_up"].astype(y.dtype)
-            hidden = constrain(gate * up, (None, None, "mlp"), rules)
-            x = x + hidden @ pr["w_down"].astype(y.dtype)
-        return x, kv_l
+        return _mlp(x, pr, rules), kv
 
-    with jax.named_scope("layers"):
-        x, new_kv = jax.lax.scan(
-            layer_step, x, (params["blocks"], cache["kv"]))
+    x, cache = _scan_layers(layer_step, x, cache, params, cfg)
     heads_in = jnp.concatenate(
         [x[0, :b], x[0, b + pre_n_valid - 1][None]], axis=0)  # [B+1, D]
     logits = _lm_head(heads_in, params, cfg)
-    return logits[:b], logits[b], {"kv": new_kv}
+    return logits[:b], logits[b], cache
 
 
 def copy_pages(cache, src, dst):

@@ -95,18 +95,20 @@ def test_radix_match_insert_evict():
 # -- kernel parity: paged vs dense programs -----------------------------------
 
 def test_paged_cache_layout_heads_minor():
-    """The page pool is ONE fused array [L, 2, pages, page_size, Hkv,
-    hd] — K and V stacked so the decode gather is a single HBM sweep,
-    heads-minor so a gathered page reshapes to the seq-major attention
-    view without a materializing transpose, and axis 4 carries the
-    'kv' logical axis for tp sharding."""
+    """The page pool is ONE fused array [L, 2, pages, page_size,
+    Hkv * hd] — K and V stacked so one indexed read serves both, a
+    token's heads side by side in the minor axis so a page's rows fill
+    whole lane rows on the TPU (PERF.md, PR 25) and a gathered page
+    reshapes to the seq-major attention view without a materializing
+    transpose, and axis 4 carries the 'kv' logical axis for tp sharding
+    (whole heads a shard)."""
     cache = llama.init_paged_kv_cache(CFG, 7, PS)
     assert set(cache) == {"kv"}
     assert cache["kv"].shape == (CFG.num_layers, 2, 7, PS,
-                                 CFG.num_kv_heads, CFG.head_dim)
+                                 CFG.num_kv_heads * CFG.head_dim)
     # The logical-axis annotation must line up with that shape: exactly
     # one 'kv' entry, on the heads axis.
-    assert llama.PAGED_KV_AXES == (None, None, None, None, "kv", None)
+    assert llama.PAGED_KV_AXES == (None, None, None, None, "kv")
 
 
 def test_paged_sampled_parity_vs_dense_reference(engine, params):

@@ -28,6 +28,22 @@ def _reference(prompt, max_new):
     return [int(t) for t in np.asarray(out)[0, len(prompt):]]
 
 
+@pytest.mark.parametrize("given,want", [({}, 30.0),
+                                        ({"health_check_timeout_s": 2.0},
+                                         2.0)])
+def test_llm_app_tolerates_a_deaf_replica_longer_than_serve_does(given,
+                                                                 want):
+    """An engine replica can hold the interpreter lock for tens of
+    seconds in a device-runtime call and be healthy (PERF.md, PR 25), so
+    the LLM app asks for probes of 30 s unless the caller says otherwise;
+    every other deployment keeps Serve's 5 s."""
+    from ray_tpu.llm import build_llm_app
+
+    app = build_llm_app(**given)
+    assert app.deployment._opts["health_check_timeout_s"] == want
+    assert app.deployment._opts["health_check_failure_threshold"] == 3
+
+
 def test_llm_app_http_and_stream(serve_instance):
     from ray_tpu.llm import build_llm_app
 

@@ -154,6 +154,42 @@ def test_request_timings_keep_streamed_requests_and_forget(params,
     assert [t["seed"] for t in eng.request_timings()] == [101, 102, 103]
 
 
+def test_pages_read_is_the_dispatched_rows_live_pages(params, tracer):
+    """``pages_read`` on ``rt.llm.step`` is the sum of ceil(length /
+    page_size) over what the device is handed — every decode row not
+    parked, in each of the block's steps, and the prefill lane's slot —
+    and ``kv_pages_read`` (``LLMServer.stats()`` returns every name in
+    ``STEP_COUNTERS``) is its running sum."""
+    ps, block = 8, 2
+    eng = SlotEngine(params, CFG, num_slots=3, chunk=16, page_size=ps,
+                     decode_block=block, prefix_cache=False)
+    assert "kv_pages_read" in SlotEngine.STEP_COUNTERS
+    handed = []
+
+    def watching(fn, lane):
+        def call(*args):
+            pos = np.asarray(args[6])
+            pages = sum(-(-(int(p) + k + 1) // ps) for p in pos
+                        for k in range(block) if p + k < CFG.max_seq)
+            if lane:
+                pages += -(-(int(args[11]) + int(args[12])) // ps)
+            handed.append(pages)
+            return fn(*args)
+        return call
+
+    eng._decode_only = watching(eng._decode_only, lane=False)
+    eng._block = watching(eng._block, lane=True)
+    for i in range(5):
+        eng.submit(list(range(1, 12 + 9 * i)), max_new=7)
+    _drain(eng)
+    ran = [s.attributes for s in tracer.spans("rt.llm.step")
+           if s.attributes["program"] != "none"]
+    assert [a["pages_read"] for a in ran] == handed and len(handed) > 10
+    assert eng.kv_pages_read == sum(handed)
+    # against gathering every table entry of every row, every step
+    assert 0 < eng.kv_pages_read < eng.slot_steps * eng._pages_per_seq
+
+
 def _engine_lowered(eng):
     rows = eng.num_slots
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731

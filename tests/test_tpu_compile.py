@@ -11,7 +11,9 @@ Code that asks JAX for its backend still sees the CPU here, so the
 kernels' own ``_on_tpu`` is steered from the test.
 """
 
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -21,7 +23,10 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu.llm.engine import build_step_programs
+from ray_tpu.models import llama
 from ray_tpu.ops import attention as A
+from ray_tpu.ops import paged_attention as PA
 from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
 
 # [batch, heads, seq, head_dim] of the training cells (bench.py): gpt2-774m
@@ -53,6 +58,7 @@ def compiled_for_tpu(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(PA, "_on_tpu", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -136,3 +142,93 @@ def test_explicit_flash_raises_on_a_shape_it_cannot_tile():
     with pytest.raises(ValueError, match="cannot tile"):
         A.attention_with_lse(x, x, x, impl="flash")
     assert A.attention(x, x, x, impl="auto").shape == x.shape
+
+
+# -- the serving engine's two step programs -----------------------------------
+
+# The published smollm2-1.7b widths (benchmark/configs/smollm2-1.7b.json)
+# at two layers, with the deployment's 8 slots and its whole pool of
+# 8 x 2048 / 16 + 1 pages: what a layer does to the pool does not depend
+# on how many layers there are.
+SMOLLM2_2L = llama.LlamaConfig(
+    vocab_size=49152, max_seq=2048, num_layers=2, num_heads=32,
+    num_kv_heads=32, d_model=2048, d_mlp=8192, rope_theta=130000.0,
+    dtype=jnp.bfloat16, remat=False)
+SLOTS, PAGE, CHUNK = 8, 16, 64
+
+
+def _engine_program_specs(cfg, sharding):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pages = SLOTS * cfg.max_seq // PAGE + 1
+    params = jax.tree.map(
+        lambda x: sds(x.shape, cfg.dtype),
+        jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0),
+                                                 cfg)[0]))
+    pool = sds((cfg.num_layers, 2, pages, PAGE,
+                cfg.num_kv_heads * cfg.head_dim), cfg.dtype)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    common = (params, {"kv": pool}, i32(SLOTS, cfg.max_seq // PAGE),
+              i32(SLOTS), sds((SLOTS,), jnp.bool_), i32(SLOTS), i32(SLOTS),
+              sds((SLOTS,), jnp.float32), i32(SLOTS))
+    fused = common + (i32(CHUNK), i32(), i32(), i32(),
+                      sds((), jnp.float32), i32())
+    return {"block": fused, "decode_only": common}, pool
+
+
+@pytest.mark.parametrize("program", ["block", "decode_only"])
+def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
+    """As compiled for the chip, a step holds the Mosaic kernel and no
+    copy or fusion whose result is the pool or one layer's slice of it,
+    and its temporaries are a small fraction of the pool's bytes: the
+    pool is the layer loop's carry, aliased through the kernel, and never
+    laid out again. (Before the kernel each program copied the whole pool
+    several times a step and held a temporary the size of it.)"""
+    cfg = SMOLLM2_2L
+    specs, pool = _engine_program_specs(cfg, SingleDeviceSharding(v5e[0]))
+    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
+    fn = block_fn if program == "block" else decode_only_fn
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        *specs[program]).compile()
+    text = compiled.as_text()
+    # decode rows, and in the fused program the prompt chunk's lane
+    assert text.count("tpu_custom_call") == (2 if program == "block" else 1)
+    shapes = {",".join(map(str, pool.shape)),          # the pool
+              ",".join(map(str, (1,) + pool.shape[1:])),  # a layer of it
+              ",".join(map(str, pool.shape[1:]))}
+    moved = [line.strip()[:120] for line in text.splitlines()
+             for m in [re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* "
+                                r"(copy|fusion|scatter|gather|"
+                                r"dynamic-slice|dynamic-update-slice)\(",
+                                line)]
+             if m and m.group(1) in shapes]
+    assert not moved, moved
+    pool_bytes = pool.dtype.itemsize * math.prod(pool.shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
+def test_paged_kernel_is_sharded_not_partitioned(v5e):
+    """The twin of the flash test for the serving kernel: under a tp
+    mesh it runs per KV-heads shard in a shard_map, pool and new K/V
+    split on their lane axis and q on its heads; handed sharded operands
+    without the mesh, the compiler's refusal is an error."""
+    mesh = MeshSpec(tp=2).build(v5e[:2])
+    cfg = SMOLLM2_2L
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    f = cfg.num_kv_heads * cfg.head_dim
+    args = (sds((SLOTS, 1, cfg.num_heads, cfg.head_dim), cfg.dtype,
+                None, None, "tp"),
+            sds((SLOTS, 1, f), cfg.dtype, None, None, "tp"),
+            sds((SLOTS, 1, f), cfg.dtype, None, None, "tp"),
+            sds((2, 2, 257, PAGE, f), cfg.dtype, None, None, None, None,
+                "tp"),
+            sds((), jnp.int32), sds((SLOTS, 128 + 2), jnp.int32))
+    assert _kernels(lambda *a: PA.paged_attention(
+        *a, mesh=mesh, heads_axis="tp"), *args) == 1
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _kernels(lambda *a: PA.paged_attention(*a), *args)
