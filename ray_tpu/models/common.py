@@ -56,14 +56,35 @@ def rms_norm(x, scale, eps: float = 1e-6):
     )
 
 
-def cross_entropy_sums(logits, targets, ignore_id: int = -1):
+def cross_entropy_sums(logits, targets, ignore_id: int = -1,
+                       vocab_axis=None):
     """Masked token CE in fp32 as (nll_sum, token_count) — the composable
-    form, summable across sequence/loss chunks."""
+    form, summable across sequence/loss chunks.
+
+    Inside a ``shard_map`` whose ``vocab_axis`` mesh axis (or axes) shards
+    the vocab, ``logits`` is this device's slice of it: the log-sum-exp's
+    max and sum and the gold logit cross that axis as ``[tokens]``
+    vectors, the ``[tokens, vocab]`` matrix never does. The sums are of
+    this device's tokens either way.
+    """
     logits = logits.astype(jnp.float32)
     mask = (targets != ignore_id).astype(jnp.float32)
     targets = jnp.maximum(targets, 0)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    if not vocab_axis:
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+    else:
+        v = logits.shape[-1]
+        top = jax.lax.pmax(
+            jax.lax.stop_gradient(logits.max(axis=-1)), vocab_axis)
+        logz = top + jnp.log(jax.lax.psum(
+            jnp.exp(logits - top[..., None]).sum(axis=-1), vocab_axis))
+        local = targets - jax.lax.axis_index(vocab_axis) * v
+        here = jnp.take_along_axis(
+            logits, jnp.clip(local, 0, v - 1)[..., None], axis=-1)[..., 0]
+        gold = jax.lax.psum(
+            jnp.where((local >= 0) & (local < v), here, 0.0), vocab_axis)
     return jnp.sum((logz - gold) * mask), mask.sum()
 
 

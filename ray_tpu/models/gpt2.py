@@ -383,9 +383,13 @@ def forward_features(params, tokens, cfg: GPT2Config, rules=None):
 def forward(params, tokens, cfg: GPT2Config, rules=None):
     """tokens [B, S] -> logits [B, S, vocab]."""
     x, _ = forward_features(params, tokens, cfg, rules)
-    # Tied LM head (fp32 logits for a stable loss).
+    # Tied LM head (fp32 logits for a stable loss), laid out as
+    # ``_head_ce_sums`` lays it out: wte gathered over its embed (fsdp)
+    # axis, so each device contracts the whole of ``d`` for its own
+    # tokens and no partial logits are summed across chips.
     logits = jnp.einsum(
-        "bsd,vd->bsv", x, params["wte"].astype(cfg.dtype),
+        "bsd,vd->bsv", constrain(x, ("batch", "seq", None), rules),
+        constrain(params["wte"].astype(cfg.dtype), ("vocab", None), rules),
         preferred_element_type=jnp.float32,
     )
     return constrain(logits, ("batch", "seq", "vocab"), rules)
@@ -477,26 +481,23 @@ def _pp_forward_features(params, tokens, cfg: GPT2Config, rules):
     return x, jnp.zeros((), jnp.float32)
 
 
-def loss_fn(params, batch, cfg: GPT2Config, rules=None,
-            loss_chunk: int = 4096):
-    """batch: {"tokens": [B, S+1]} → next-token CE loss.
+def _ce_sums_local(x, targets, wte, loss_chunk, token_axes, vocab_axes,
+                   embed_axes):
+    """(nll_sum, count) over ALL tokens from one device's share of them.
 
-    The LM head + CE run in token chunks under ``jax.checkpoint``: fp32
-    logits for the full batch are B*S*vocab*4 bytes (1.65GB at 774M batch
-    8) and the CE backward doubles that — chunking caps the live logits
-    footprint at chunk*vocab*4*2 and recomputes the chunk's head matmul
-    in backward (~2.5% extra FLOPs), which is what lets the large-batch
-    configs fit one chip.
+    x [b, s, d] and targets [b, s] are the tokens this device holds, wte
+    its shard of the stored table. The table is gathered over
+    ``embed_axes`` here, outside the chunk loop (it keeps its slice of
+    the vocab under ``vocab_axes``): once a step, and the transpose is
+    one reduce-scatter of ``d wte``, which accumulates over the chunks on
+    the device (the TPU compiler makes it an all-reduce and a slice where
+    the shard is off the 128-lane tiling, as gpt2-xl's 400 is). The head
+    matmul + CE run in token chunks under ``jax.checkpoint``, so the
+    float32 logits live a chunk at a time.
     """
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    if _pp_axis_size(rules) > 1:
-        x, aux = _pp_forward_features(params, inputs, cfg, rules)
-    else:
-        x, aux = forward_features(params, inputs, cfg, rules)
+    if embed_axes:
+        wte = jax.lax.all_gather(wte, embed_axes, axis=1, tiled=True)
     d = x.shape[-1]
-    wte = params["wte"].astype(cfg.dtype)
-
     xf = x.reshape(-1, d)
     tf = targets.reshape(-1)
     n = xf.shape[0]
@@ -519,15 +520,90 @@ def loss_fn(params, batch, cfg: GPT2Config, rules=None,
         logits = jax.lax.dot_general(
             xi, wte, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        nll, count = cross_entropy_sums(logits, ti)
+        nll, count = cross_entropy_sums(logits, ti, vocab_axis=vocab_axes)
         nll_sum, denom = carry
         return (nll_sum + nll, denom + count), None
 
+    sums, _ = jax.lax.scan(
+        chunk_loss,
+        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+        (xc, tc))
+    if token_axes:  # the two scalars are all that crosses chips
+        sums = jax.lax.psum(sums, token_axes)
+    return sums
+
+
+@jax.custom_vjp
+def _leave_together(x, wte):
+    """Identity whose two cotangents leave the backward pass together (an
+    optimization barrier): ``d wte`` is reduced to its shard before the
+    blocks' backward pass starts on ``dx``. Left to itself XLA's
+    scheduler parks the reduction after that pass, beside the lookup's,
+    and holds a whole unreduced table through it."""
+    return x, wte
+
+
+_leave_together.defvjp(
+    lambda x, wte: ((x, wte), None),
+    lambda _, cts: jax.lax.optimization_barrier(cts))
+
+
+def _head_ce_sums(x, targets, wte, rules, loss_chunk):
+    """Tied LM head + CE, decomposed over TOKENS: every device scores the
+    tokens it already holds (batch / seq sharded, as the blocks leave
+    them) against a wte whole in ``d``, so the contraction is one on-chip
+    float32 accumulation and no ``[tokens, vocab]`` partial logits are
+    all-reduced. Under GSPMD alone ``d wte`` would cross the chunk loop
+    reduced, a collective a chunk; a shard_map pins the decomposition (as
+    ``_embed_lookup`` does for the same table): the loop is local, the
+    table's gather and its gradient's reduction happen once a step, and
+    the reduced gradient is a shard, not a whole table held through the
+    backward pass until the lookup's joins it. ``loss_chunk`` thereby
+    caps the logits one DEVICE holds."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.sharding import (current_mesh, mesh_axes_for, smap,
+                                     spec_for)
+
+    mesh = current_mesh()
+    if mesh is None:
+        return _ce_sums_local(x, targets, wte, loss_chunk, (), (), ())
+    embed_axes = mesh_axes_for("embed", rules)
+    body = partial(
+        _ce_sums_local, loss_chunk=loss_chunk,
+        token_axes=mesh_axes_for("batch", rules) + mesh_axes_for("seq", rules),
+        vocab_axes=mesh_axes_for("vocab", rules), embed_axes=embed_axes)
+    fn = smap(body, mesh,
+              in_specs=(spec_for(("batch", "seq", None), rules),
+                        spec_for(("batch", "seq"), rules),
+                        spec_for(("vocab", "embed"), rules)),
+              out_specs=(P(), P()))
+    if embed_axes:  # there is a reduction to place
+        x, wte = _leave_together(x, wte)
+    return fn(x, targets, wte)
+
+
+def loss_fn(params, batch, cfg: GPT2Config, rules=None,
+            loss_chunk: int = 4096):
+    """batch: {"tokens": [B, S+1]} → next-token CE loss.
+
+    The LM head + CE run in token chunks under ``jax.checkpoint``: fp32
+    logits for the full batch are B*S*vocab*4 bytes (1.65GB at 774M batch
+    8) and the CE backward doubles that — chunking caps the live logits
+    footprint at chunk*vocab*4*2 A DEVICE (chunks are cut from a device's
+    own tokens, ``_head_ce_sums``) and recomputes the chunk's head matmul
+    in backward (~2.5% extra FLOPs), which is what lets the large-batch
+    configs fit one chip.
+    """
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    if _pp_axis_size(rules) > 1:
+        x, aux = _pp_forward_features(params, inputs, cfg, rules)
+    else:
+        x, aux = forward_features(params, inputs, cfg, rules)
     with jax.named_scope("ce"):
-        (nll_sum, denom), _ = jax.lax.scan(
-            chunk_loss,
-            (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (xc, tc))
+        nll_sum, denom = _head_ce_sums(
+            x, targets, params["wte"].astype(cfg.dtype), rules, loss_chunk)
     loss = nll_sum / jnp.maximum(denom, 1.0)
     if cfg.num_experts > 0:
         loss = loss + cfg.moe_aux_weight * aux / cfg.num_layers

@@ -54,6 +54,16 @@ def spec_for(logical_axes: Sequence[Optional[str]],
     return P(*out)
 
 
+def mesh_axes_for(logical: str,
+                  rules: Optional[Rules] = None) -> Tuple[str, ...]:
+    """The mesh axes the rules shard one logical axis over (() = none):
+    what a ``shard_map`` body names in its collectives."""
+    axes = dict(DEFAULT_RULES, **(rules or {})).get(logical)
+    if axes is None:
+        return ()
+    return (axes,) if isinstance(axes, str) else tuple(axes)
+
+
 def tree_spec(logical_tree: Any, rules: Optional[Rules] = None) -> Any:
     """Map a pytree of logical-axis tuples to a pytree of PartitionSpecs."""
     return jax.tree.map(

@@ -285,3 +285,66 @@ def test_gpt_pp_pipeline_train_step_decreases_loss():
         losses.append(float(m["loss"]))
     assert np.isfinite(losses[-1]) and losses[-1] < losses[0]
     assert float(m["grad_norm"]) > 0
+
+
+# -- the tied LM head, decomposed over tokens ---------------------------------
+
+HEAD_MESHES = {
+    "fsdp4": (MeshSpec(fsdp=4), "reference"),
+    "fsdp2_tp2": (MeshSpec(fsdp=2, tp=2), "reference"),
+    "dp2_fsdp2": (MeshSpec(dp=2, fsdp=2), "reference"),
+    "sp2_ring": (MeshSpec(sp=2), "ring"),
+    "one_device": (MeshSpec(), "reference"),
+}
+
+
+@pytest.mark.parametrize("name", list(HEAD_MESHES))
+def test_gpt2_head_on_a_mesh_equals_one_device(name):
+    """Loss and every gradient leaf of ``gpt2.loss_fn`` with the head cut
+    by tokens (and by vocab under tp) equal the plain float32 values:
+    whole logits from ``forward()`` on one device, one un-chunked CE. A
+    device's tokens do not fill its chunks (the pad / ignore_id path) and
+    some targets are -1."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.models.common import cross_entropy_sums
+    from ray_tpu.parallel.sharding import (prune_rules_for_mesh,
+                                           shardings_for, under_mesh)
+
+    spec, attention = HEAD_MESHES[name]
+    base = dict(vocab_size=256, max_seq=150, num_layers=2, num_heads=2,
+                d_model=32, dtype=jnp.float32, remat=False)
+    cfg = gpt2.GPT2Config(attention_impl=attention, **base)
+    plain = gpt2.GPT2Config(attention_impl="reference", **base)
+    params, axes = gpt2.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = np.array(jax.random.randint(
+        jax.random.PRNGKey(1), (8, 151), 0, 256))
+    tokens[[0, 3, 5], -1] = -1  # the last column is a target only
+    tokens[6, -40:] = -1  # an input too: wte[-1] on both sides
+    batch = {"tokens": jnp.asarray(tokens)}
+
+    def plain_loss(p):
+        logits = gpt2.forward(p, batch["tokens"][:, :-1], plain)
+        nll, count = cross_entropy_sums(logits, batch["tokens"][:, 1:])
+        return nll / count
+
+    want, want_grads = jax.value_and_grad(plain_loss)(params)
+
+    mesh = spec.build(jax.devices()[:spec.num_devices])
+    rules = prune_rules_for_mesh(mesh)
+    # 1200 tokens in chunks of at most 200: one device pads 1200 to five
+    # chunks of 256, a quarter of them (300) to two.
+    step = under_mesh(mesh, jax.jit(
+        jax.value_and_grad(lambda p, b: gpt2.loss_fn(
+            p, b, cfg, rules, loss_chunk=200)),
+        in_shardings=(shardings_for(mesh, axes, rules), None)))
+    got, got_grads = step(params, batch)
+
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
+    flat_want = jax.tree_util.tree_leaves_with_path(want_grads)
+    flat_got = jax.tree.leaves(got_grads)
+    assert len(flat_want) == len(flat_got)
+    for (path, w), g in zip(flat_want, flat_got):
+        w = np.asarray(w)  # leaves' scales differ 1000-fold: 2e-5 of each
+        np.testing.assert_allclose(
+            np.asarray(g), w, atol=2e-5 * np.abs(w).max(), rtol=2e-5,
+            err_msg=jax.tree_util.keystr(path))

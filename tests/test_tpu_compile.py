@@ -232,3 +232,104 @@ def test_paged_kernel_is_sharded_not_partitioned(v5e):
         *a, mesh=mesh, heads_axis="tp"), *args) == 1
     with pytest.raises(NotImplementedError, match="shard_map"):
         _kernels(lambda *a: PA.paged_attention(*a), *args)
+
+
+# -- the train step's tied LM head on a mesh ----------------------------------
+
+# The published widths (XL under fsdp=4; Large under fsdp=2 x tp=2, whose
+# 20 heads tp=2 divides where XL's 25 do not) at two layers and the
+# cells' batches. ``parent_temp``: temporaries a device of the same step
+# before the head was cut by tokens (PR 28's parent, this compiler).
+HEAD_CASES = {
+    "fsdp4": dict(mesh=dict(fsdp=4), heads=25, d=1600, batch=24,
+                  parent_temp=1_576_602_624),
+    "fsdp2_tp2": dict(mesh=dict(fsdp=2, tp=2), heads=20, d=1280, batch=8,
+                      parent_temp=1_079_698_432),
+}
+VOCAB = 50304
+_COLLECTIVE = re.compile(
+    r"= (.*?) (all-reduce|all-gather|reduce-scatter|all-to-all)"
+    r"(?:-start)?\(")
+_CALLEE = re.compile(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)")
+
+
+def _vocab_collectives(text, vocab_dims):
+    """(kind, shapes, inside a loop?) of every collective of the compiled
+    program that moves arrays with a vocab-sized dimension: those arrays'
+    shapes, operands and results alike."""
+    calls, loops, found, name = {}, set(), [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            calls[name] = set()
+            continue
+        calls.setdefault(name, set()).update(_CALLEE.findall(line))
+        if " while(" in line:
+            loops.update(re.findall(r"(?:body|condition)=%([\w.\-]+)", line))
+        m = _COLLECTIVE.search(line)
+        if m:
+            shapes = {tuple(int(x) for x in dims.split(","))
+                      for dims in re.findall(r"\w+\[([\d,]+)\]", line)}
+            shapes = {s for s in shapes if vocab_dims & set(s)}
+            if shapes:
+                found.append((m.group(2), shapes, name))
+    grew = True
+    while grew:  # whatever a loop's body calls is in the loop
+        inner = {c for f in loops for c in calls.get(f, ())} - loops
+        grew = bool(inner)
+        loops |= inner
+    return [(kind, shapes, where in loops) for kind, shapes, where in found]
+
+
+@pytest.mark.parametrize("case", list(HEAD_CASES))
+def test_lm_head_moves_no_logits_between_chips(v5e, case):
+    """The gpt2 train step as compiled for four chips: no collective has
+    an operand or result with a vocab-sized dimension beside a token
+    dimension (the parent all-reduced f32[4096, vocab] partial logits,
+    forward and in the recompute, every chunk). What crosses chips with a
+    vocab-sized dimension is wte: its gather(s) and its gradient's
+    reductions, outside the chunk loop, once a step."""
+    import optax
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.sharding import prune_rules_for_mesh
+    from ray_tpu.train.step import build_sharded_train
+
+    c = HEAD_CASES[case]
+    mesh = MeshSpec(**c["mesh"]).build(v5e)
+    cfg = gpt2.GPT2Config(
+        vocab_size=VOCAB, max_seq=1024, num_layers=2, num_heads=c["heads"],
+        d_model=c["d"], dtype=jnp.bfloat16, attention_impl="flash",
+        remat=True, remat_policy="mem2")
+    rules = prune_rules_for_mesh(mesh)
+    sinit, sstep, _ = build_sharded_train(
+        lambda k: gpt2.init_params(k, cfg),
+        lambda p, b: gpt2.loss_fn(p, b, cfg, rules), mesh,
+        optimizer=optax.chain(optax.clip_by_global_norm(1.0),
+                              optax.adamw(1e-5)), master_fp32=True)
+    whole = NamedSharding(mesh, P())
+    init = sinit.lower(jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                            sharding=whole))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        init.out_info, init.compile().output_shardings)
+    tokens = jax.ShapeDtypeStruct((c["batch"], 1025), jnp.int32,
+                                  sharding=whole)
+    compiled = sstep.lower(*state, {"tokens": tokens}).compile()
+
+    tp, fsdp = c["mesh"].get("tp", 1), c["mesh"]["fsdp"]
+    table = {(v, d) for v in (VOCAB, VOCAB // tp)
+             for d in (c["d"], c["d"] // fsdp)}
+    moved = _vocab_collectives(compiled.as_text(), {VOCAB, VOCAB // tp})
+    assert not [m for m in moved if m[1] - table], moved  # no logits
+    assert not [m for m in moved if m[2]], moved  # none a chunk
+    kinds = [kind for kind, _, _ in moved]
+    assert kinds.count("all-gather") >= 1
+    # d wte is reduced twice a step: the lookup's all-reduce, which the
+    # parent had too, and the head's own (under fsdp=4 the compiler makes
+    # it an all-reduce and a slice: a 400-lane shard is off the tiling)
+    assert len(kinds) - kinds.count("all-gather") == 2, moved
+    # (under tp the step reads 0.16 MB over the parent's)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= c["parent_temp"] + 2**20)
