@@ -3,8 +3,9 @@ programs, with a radix prefix cache that skips redundant prefill.
 
 Design (TPU-first, static shapes throughout):
 
-- The KV cache is a pool of fixed-size PAGES (``llama.init_paged_kv_cache``)
-  reached through a per-slot page table, not dense per-slot rows: a
+- The KV cache is a pool of fixed-size PAGES (the model family's, behind
+  ``models/serving.py``: the engine names no model and never looks into
+  a page) reached through a per-slot page table, not dense per-slot rows: a
   request whose prompt prefix is already resident borrows those pages
   read-only (refcounted) and starts prefill at the matched length; a
   prefix dying mid-page is copied on write into a fresh page at
@@ -15,7 +16,7 @@ Design (TPU-first, static shapes throughout):
   engine's two-XLA-program style: on the TPU a Pallas kernel reads and
   writes the pages in place (``ops/paged_attention.py``), elsewhere a
   plain scatter and gather.
-- ``decode_slots_paged`` advances EVERY slot one token per call with
+- The family's one step advances EVERY slot one token per call with
   per-slot positions; idle slots are parked past ``max_seq`` where
   their garbage writes are routed to the reserved scratch page.
 - The fused program additionally runs one fixed-size prompt chunk in
@@ -50,8 +51,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.exceptions import EngineStoppedError
-from ..models import llama
+from ..models import serving
 from ..observability import tracing
+from ..parallel import sharding as shd
 from .paged import OverloadedError, PagePool, RadixIndex, llm_metrics
 
 # Interned tag keys for the per-stage histogram (request finish path).
@@ -187,27 +189,27 @@ class _Slot:
         return self.prefill_offset >= len(self.prompt)
 
 
-def build_step_programs(cfg: llama.LlamaConfig, page_size: int,
-                        decode_block: int, rules=None):
+def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
     """The engine's two step programs, un-jitted: ``(block_fn,
-    decode_only_fn)``. ``SlotEngine`` jits them with the cache donated;
-    tests/test_tpu_compile.py compiles the same two for a described chip
-    from shapes alone."""
+    decode_only_fn)``, round the one step of ``cfg``'s family
+    (``models/serving.py``). ``SlotEngine`` jits them with the cache
+    donated; tests/test_tpu_compile.py compiles the same two for a
+    described chip from shapes alone."""
+    step = serving.model_for(cfg).step
 
     def block_fn(params, cache, tables, override_vals, override_mask,
                  prev_last, pos, temps, seeds,
                  pre_tokens, pre_slot, pre_p0, pre_n_valid,
                  pre_temp, pre_seed):
         """K-token decode block with the prefill lane fused into the
-        FIRST step (decode_slots_with_prefill_paged): a prompt chunk
-        rides the same params read as the decode batch, so prefill
-        no longer costs a separate full-model pass."""
+        FIRST step: a prompt chunk rides the same params read as the
+        decode batch, so prefill no longer costs a separate full-model
+        pass."""
         tokens0 = jnp.where(override_mask, override_vals, prev_last)
-        dec_logits, pre_logits, cache = \
-            llama.decode_slots_with_prefill_paged(
-                params, cache, tables, tokens0, pos, pre_tokens,
-                pre_slot, pre_p0, pre_n_valid, cfg, page_size,
-                rules=rules)
+        dec_logits, pre_logits, cache = step(
+            params, cache, tables, tokens0, pos,
+            (pre_tokens, pre_slot, pre_p0, pre_n_valid), cfg, page_size,
+            rules)
         tok1 = _sample(dec_logits, temps, seeds, pos + 1)
         pre_tok = _sample(pre_logits[None], pre_temp[None],
                           pre_seed[None],
@@ -218,9 +220,8 @@ def build_step_programs(cfg: llama.LlamaConfig, page_size: int,
 
         def body(carry, _):
             toks, cache, p = carry
-            logits, cache = llama.decode_slots_paged(
-                params, cache, tables, toks, p, cfg, page_size,
-                rules=rules)
+            logits, _, cache = step(params, cache, tables, toks, p, None,
+                                    cfg, page_size, rules)
             nxt = _sample(logits, temps, seeds, p + 1)
             return (nxt, cache, p + 1), nxt
 
@@ -239,9 +240,8 @@ def build_step_programs(cfg: llama.LlamaConfig, page_size: int,
 
         def body(carry, _):
             toks, cache, p = carry
-            logits, cache = llama.decode_slots_paged(
-                params, cache, tables, toks, p, cfg, page_size,
-                rules=rules)
+            logits, _, cache = step(params, cache, tables, toks, p, None,
+                                    cfg, page_size, rules)
             nxt = _sample(logits, temps, seeds, p + 1)
             return (nxt, cache, p + 1), nxt
 
@@ -266,7 +266,7 @@ class SlotEngine:
                      "slot_steps_active", "slot_steps_prefill_wait",
                      "prefill_tokens", "overshoot_tokens", "kv_pages_read")
 
-    def __init__(self, params, cfg: llama.LlamaConfig, num_slots: int = 8,
+    def __init__(self, params, cfg, num_slots: int = 8,
                  chunk: int = 64, seed: int = 0, decode_block: int = 1,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  prefix_cache: bool = True,
@@ -283,6 +283,7 @@ class SlotEngine:
                 f"page_size ({page_size}) must divide max_seq "
                 f"({cfg.max_seq})")
         self.cfg = cfg
+        self._model = model = serving.model_for(cfg)
         self.num_slots = num_slots
         self.chunk = chunk
         self.page_size = page_size
@@ -305,19 +306,11 @@ class SlotEngine:
         # every constraint no-ops and placement is plain device_put.
         self._mesh = mesh
         if mesh is not None:
-            from ..parallel import sharding as shd
-
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            tp = sizes.get("tp", 1)
-            if tp > 1 and (cfg.num_kv_heads % tp or cfg.num_heads % tp
-                           or cfg.d_mlp % tp or cfg.vocab_size % tp):
-                raise ValueError(
-                    f"tp={tp} must divide num_kv_heads "
-                    f"({cfg.num_kv_heads}), num_heads ({cfg.num_heads}), "
-                    f"d_mlp ({cfg.d_mlp}) and vocab ({cfg.vocab_size})")
+            model.check_shardable(cfg, sizes.get("tp", 1))
             self._rules = shd.prune_rules_for_mesh(
                 mesh, dict(self.SERVE_RULES, **(rules or {})))
-            self._params = shd.place(mesh, params, llama.param_axes(),
+            self._params = shd.place(mesh, params, model.param_axes(),
                                      self._rules)
         else:
             self._rules = None
@@ -336,17 +329,10 @@ class SlotEngine:
             RadixIndex(self._pool, page_size) if prefix_cache else None)
         self._tables = np.zeros((num_slots, self._pages_per_seq),
                                 dtype=np.int32)
-        self._cache = llama.init_paged_kv_cache(cfg, self._num_pages,
-                                                page_size)
+        self._cache = model.init_cache(cfg, self._num_pages, page_size)
         if mesh is not None:
-            from jax.sharding import NamedSharding
-
-            from ..parallel import sharding as shd
-
-            kv_sharding = NamedSharding(
-                mesh, shd.spec_for(llama.PAGED_KV_AXES, self._rules))
-            self._cache = jax.tree.map(
-                lambda x: jax.device_put(x, kv_sharding), self._cache)
+            self._cache = shd.place(mesh, self._cache, model.cache_axes,
+                                    self._rules)
         self._base_seed = seed
         self._req_counter = 0
         block_fn, decode_only_fn = build_step_programs(
@@ -364,22 +350,18 @@ class SlotEngine:
         # constraints pin the output cache to the input's sharding, so
         # donation stays an in-place aliasing across steps.
         def _maybe_mesh(fn):
-            if mesh is None:
-                return fn
-            from ..parallel.sharding import under_mesh
-
-            return under_mesh(mesh, fn)
+            return fn if mesh is None else shd.under_mesh(mesh, fn)
 
         self._block = _maybe_mesh(jax.jit(block_fn, donate_argnums=(1,)))
         self._decode_only = _maybe_mesh(
             jax.jit(decode_only_fn, donate_argnums=(1,)))
         self._copy_pages = _maybe_mesh(
-            jax.jit(llama.copy_pages, donate_argnums=(0,)))
+            jax.jit(model.copy_pages, donate_argnums=(0,)))
         # Session import (page migration): compiled lazily on first use
         # from the engine thread's control-op slot, where no concurrent
         # dispatch can be touching the donated cache.
         self._write_pages = _maybe_mesh(
-            jax.jit(llama.write_pages, donate_argnums=(0,)))
+            jax.jit(model.write_pages, donate_argnums=(0,)))
         # Pre-compile the COW page-copy program NOW, while no engine
         # thread can be touching the (donated) cache: the first partial
         # prefix hit must not stall on a compile, and compiling from
@@ -637,11 +619,10 @@ class SlotEngine:
             pages, _ = self._radix.match(transcript)
         frames = None
         if pages:
-            idx = np.asarray(pages, dtype=np.int32)
             # Device gather -> host; pages stay index-owned (we hold
             # the lock, so no concurrent eviction can free them).
-            frames = np.ascontiguousarray(
-                np.asarray(self._cache["kv"][:, :, idx]))
+            frames = self._model.read_pages(
+                self._cache, np.asarray(pages, dtype=np.int32))
         m = llm_metrics()
         if m is not None:
             m["session_migrations"].inc(tags={"result": "export"})
@@ -680,12 +661,7 @@ class SlotEngine:
             fresh: List[int] = []
             if (self._radix is not None and n_chunks > 0
                     and frames is not None):
-                kv_shape = self._cache["kv"].shape
-                if (tuple(frames.shape[:2]) != tuple(kv_shape[:2])
-                        or tuple(frames.shape[3:]) != tuple(kv_shape[3:])):
-                    raise ValueError(
-                        f"KV frame shape {frames.shape} does not match "
-                        f"cache {kv_shape}")
+                self._model.check_frames(self._cache, frames)
                 matched, _ = self._radix.match(transcript[:n_chunks * ps])
                 need = n_chunks - len(matched)
                 if need > 0 and self._pool.free_count < need:

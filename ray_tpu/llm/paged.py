@@ -4,11 +4,12 @@ token chunks so multi-turn sessions sharing a prompt prefix skip the
 redundant prefill (RadixAttention, SGLang — re-expressed over this
 repo's page-table indirection instead of a custom attention kernel).
 
-Division of labor with :mod:`ray_tpu.models.llama`:
+Division of labor with the model family behind
+:mod:`ray_tpu.models.serving`:
 
-- device side: ``init_paged_kv_cache`` / ``*_paged`` programs read and
-  write physical pages through a ``[rows, P]`` page table; physical
-  page 0 is the reserved scratch page every invalid write is routed to.
+- device side: the family's cache and its one step read and write
+  physical pages through a ``[rows, P]`` page table; physical page 0 is
+  the reserved scratch page every invalid write is routed to.
 - host side (this module): who owns which page. ``PagePool`` refcounts
   pages; ``RadixIndex`` keys full pages on their page-size token chunk
   so a later prompt sharing the prefix maps the SAME physical pages

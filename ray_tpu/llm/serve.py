@@ -26,20 +26,20 @@ from typing import Optional
 
 import jax
 
-from ..models import llama
+from ..models import serving
 from .engine import SlotEngine
 from .paged import OverloadedError
 
 
 def _build_params(model: str, seed: int,
                   checkpoint_path: Optional[str] = None):
-    cfg = llama.CONFIGS[model]
+    cfg, family = serving.named(model)
     if checkpoint_path:
         from ..train.checkpoint import restore_arrays
 
         params = restore_arrays(checkpoint_path)
     else:
-        params, _ = llama.init_params(jax.random.PRNGKey(seed), cfg)
+        params, _ = family.init_params(jax.random.PRNGKey(seed), cfg)
     if cfg.dtype is not None:
         params = jax.tree.map(lambda x: x.astype(cfg.dtype), params)
     return params, cfg

@@ -1,11 +1,8 @@
-"""Llama-architecture LM: RMSNorm, RoPE, GQA, SwiGLU — with KV-cache
-decoding for the Serve inference path.
-
-Baseline config: "Ray Serve Llama-2-7B inference replica (pjit)"
-(``BASELINE.md`` tracked configs). Same pure-pytree + logical-axes design
-as ``gpt2.py``; decode step is a separate jit-compiled function over a
-static-shape KV cache (no dynamic shapes — TPU-friendly continuous
-batching slots into fixed cache pages).
+"""Llama-architecture LM: RMSNorm, RoPE, GQA, SwiGLU. Same pure-pytree +
+logical-axes design as ``gpt2.py``. Three parts: the training forward,
+the plain dense-cache reference of decoding, and what the serving engine
+runs — one step over a paged KV cache, offered through
+``models/serving.py`` (static shapes throughout).
 """
 
 from __future__ import annotations
@@ -13,16 +10,17 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..ops import paged_attention as paged_attention_op
-from ..ops.attention import attention as attention_op, mha_reference
-from ..parallel.sharding import constrain
-from .common import rms_norm, truncated_normal
+from ..ops.attention import attention as attention_op
+from ..parallel.sharding import constrain, current_mesh, spec_for
+from . import serving
+from .common import cross_entropy_loss, rms_norm, truncated_normal
 
 
 @dataclass(frozen=True)
@@ -157,7 +155,6 @@ def rope_pairs(x, tables):
 def _repeat_kv(x, n_rep: int):
     if n_rep == 1:
         return x
-    b, h, s, d = x.shape
     return jnp.repeat(x, n_rep, axis=1)
 
 
@@ -173,8 +170,6 @@ def _block(x, p, cfg: LlamaConfig, rules, positions):
     k = rope(k, positions, cfg.rope_theta)
     k = _repeat_kv(k, h // hkv)
     v = _repeat_kv(v, h // hkv)
-    from ..parallel.sharding import current_mesh, spec_for
-
     o = attention_op(
         q, k, v, causal=True, mesh=current_mesh(),
         spec=spec_for(("batch", "heads", None, None), rules))
@@ -209,8 +204,6 @@ def forward(params, tokens, cfg: LlamaConfig, rules=None):
 
 
 def loss_fn(params, batch, cfg: LlamaConfig, rules=None):
-    from .common import cross_entropy_loss
-
     tokens = batch["tokens"]
     logits = forward(params, tokens[:, :-1], cfg, rules)
     loss, _ = cross_entropy_loss(logits, tokens[:, 1:])
@@ -218,7 +211,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, rules=None):
 
 
 # ---------------------------------------------------------------------------
-# KV-cache decoding (serve path): static cache [L, B, Hkv, max_seq, hd].
+# The plain reference of decoding: a dense cache [L, B, Hkv, max_seq, hd],
+# one position a step (``decode_step``, ``generate``). The serving step
+# below and the benchmark's float32 reference are held to it.
 # ---------------------------------------------------------------------------
 
 def init_kv_cache(cfg: LlamaConfig, batch: int):
@@ -256,11 +251,9 @@ def _gqa_cache_attention(q, k_cache, v_cache, mask, cfg: LlamaConfig):
 
 
 def _cache_layer_step(x, p, cfg: LlamaConfig, positions, kv_mask,
-                      write_kv, attend_view=None):
-    """Shared per-layer transformer block for every KV-cache path
-    (single-position decode, per-slot decode, chunked prefill) — the
-    paths differ ONLY in how new K/V lands in the cache (``write_kv``)
-    and which cache view attention reads (``attend_view``).
+                      write_kv):
+    """One transformer block of the dense-cache reference; ``write_kv``
+    lands the new K/V in the layer's cache.
 
     x: [B, T, D]. Returns (x, k_cache, v_cache).
     """
@@ -276,9 +269,7 @@ def _cache_layer_step(x, p, cfg: LlamaConfig, positions, kv_mask,
     q = rope(q, positions, cfg.rope_theta)
     k_new = rope(k_new, positions, cfg.rope_theta)
     k_cache, v_cache = write_kv(k_new, v_new)
-    k_att, v_att = ((k_cache, v_cache) if attend_view is None
-                    else attend_view(k_cache, v_cache))
-    o = _gqa_cache_attention(q, k_att, v_att, kv_mask, cfg)
+    o = _gqa_cache_attention(q, k_cache, v_cache, kv_mask, cfg)
     x = x + o @ p["wo"].astype(o.dtype)
     y = rms_norm(x, p["ffn_norm"])
     gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
@@ -321,238 +312,82 @@ def decode_step(params, cache, tokens, pos, cfg: LlamaConfig):
     return _lm_head(x[:, 0], params, cfg), {"k": new_k, "v": new_v}
 
 
-def decode_slots(params, cache, tokens, pos, cfg: LlamaConfig):
-    """One decode step with PER-SLOT positions — the continuous-batching
-    inner loop (reference intent: serve/_private/replica.py request plane
-    + serve/batching.py, re-designed as a static-shape TPU program).
-
-    Each cache slot b holds an independent sequence at its own position
-    ``pos[b]``; requests join/leave slots between steps without touching
-    the compiled program. tokens [B] int32, pos [B] int32 (the position
-    the new token is written at). Returns (logits [B, vocab] fp32,
-    new_cache). Idle slots should be parked at pos = max_seq - 1: the
-    garbage K/V they write is always overwritten by a later occupant
-    before that position is attended.
-    """
-    x = params["wte"][tokens].astype(cfg.dtype)[:, None, :]  # [B,1,D]
-    positions = pos[:, None]  # [B,1] — per-slot rotary phase
-    kv_mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
-               <= pos[:, None, None, None, None])
-
-    def layer_step(x, inputs):
-        p, k_cache, v_cache = inputs
-        # Per-slot scatter: slot b writes its token's K/V at pos[b].
-        upd = jax.vmap(
-            lambda c, n, p_: jax.lax.dynamic_update_slice_in_dim(
-                c, n, p_, 1))
-
-        def write(kn, vn):
-            return upd(k_cache, kn, pos), upd(v_cache, vn, pos)
-
-        x, k2, v2 = _cache_layer_step(x, p, cfg, positions, kv_mask, write)
-        return x, (k2, v2)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["blocks"], cache["k"], cache["v"]))
-    return _lm_head(x[:, 0], params, cfg), {"k": new_k, "v": new_v}
-
-
-def decode_slots_with_prefill(params, cache, tokens, pos, pre_tokens,
-                              pre_slot, pre_p0, pre_last_idx,
-                              cfg: LlamaConfig):
-    """Fused continuous-batching step: B decode tokens (one per slot)
-    AND one C-token prefill chunk for ``pre_slot``, sharing every
-    weight matmul — ONE params read per step instead of two. At 1B-bf16
-    scale the params read IS the decode bandwidth bill, so a separate
-    prefill program costs a whole extra step per chunk (measured ~50%
-    of serving throughput on short generations).
-
-    All B+C tokens ride the matmuls as one packed [1, B+C, D] sequence;
-    only attention splits: decode rows attend their own slot's cache
-    (per-slot positions, as ``decode_slots``), prefill rows attend
-    ``pre_slot``'s cache (causal over p0..p0+i, as ``prefill_chunk``).
-    K/V writes land before attention, so in-chunk causality holds.
-
-    The caller guarantees ``pre_slot`` is not an active decode slot
-    this step (true by construction: a slot prefills before it ever
-    decodes; idle/no-prefill steps point pre_slot at a scratch slot).
-
-    tokens [B] int32 (parked slots at max_seq-1), pos [B] int32,
-    pre_tokens [C] int32 (tail padding allowed), pre_p0 / pre_last_idx
-    scalar int32. Requires max_seq % C == 0 so a padded tail chunk
-    never clamps past the cache end. Returns
-    (dec_logits [B, vocab], pre_logits [vocab], new_cache).
-    """
-    b = tokens.shape[0]
-    c = pre_tokens.shape[0]
-    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    s_max = cfg.max_seq
-    packed = jnp.concatenate([tokens, pre_tokens])
-    x = params["wte"][packed].astype(cfg.dtype)[None]  # [1, B+C, D]
-    pre_positions = pre_p0 + jnp.arange(c)
-    positions = jnp.concatenate([pos, pre_positions])[None]  # [1, B+C]
-    dec_mask = (jnp.arange(s_max)[None, None, None, None, :]
-                <= pos[:, None, None, None, None])
-    pre_mask = (jnp.arange(s_max)[None, None, None, None, :]
-                <= pre_positions[None, None, None, :, None])
-
-    def layer_step(x, inputs):
-        p, k_cache, v_cache = inputs
-        y = rms_norm(x, p["attn_norm"])
-        t = b + c
-        q = (y @ p["wq"].astype(y.dtype)).reshape(1, t, h, hd).transpose(
-            0, 2, 1, 3)
-        k_new = (y @ p["wk"].astype(y.dtype)).reshape(
-            1, t, hkv, hd).transpose(0, 2, 1, 3)
-        v_new = (y @ p["wv"].astype(y.dtype)).reshape(
-            1, t, hkv, hd).transpose(0, 2, 1, 3)
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
-        # Split back into the two attention groups.
-        qd = q[0, :, :b].transpose(1, 0, 2)[:, :, None, :]  # [B,h,1,hd]
-        kd = k_new[0, :, :b].transpose(1, 0, 2)[:, :, None, :]
-        vd = v_new[0, :, :b].transpose(1, 0, 2)[:, :, None, :]
-        qp = q[:, :, b:]                                    # [1,h,C,hd]
-        kp = k_new[:, :, b:]
-        vp = v_new[:, :, b:]
-        # Writes first (decode per-slot scatter, then the chunk block);
-        # disjoint by the caller's pre_slot guarantee.
-        upd = jax.vmap(
-            lambda cch, n, p_: jax.lax.dynamic_update_slice_in_dim(
-                cch, n, p_, 1))
-        k_cache = upd(k_cache, kd, pos)
-        v_cache = upd(v_cache, vd, pos)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, kp, (pre_slot, 0, pre_p0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, vp, (pre_slot, 0, pre_p0, 0))
-        od = _gqa_cache_attention(qd, k_cache, v_cache, dec_mask, cfg)
-        k_slice = jax.lax.dynamic_slice(
-            k_cache, (pre_slot, 0, 0, 0), (1, hkv, s_max, hd))
-        v_slice = jax.lax.dynamic_slice(
-            v_cache, (pre_slot, 0, 0, 0), (1, hkv, s_max, hd))
-        op = _gqa_cache_attention(qp, k_slice, v_slice, pre_mask, cfg)
-        o = jnp.concatenate([od[:, 0][None], op], axis=1)  # [1,B+C,D]
-        x = x + o @ p["wo"].astype(o.dtype)
-        y = rms_norm(x, p["ffn_norm"])
-        gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
-        up = y @ p["w_up"].astype(y.dtype)
-        x = x + (gate * up) @ p["w_down"].astype(y.dtype)
-        return x, (k_cache, v_cache)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["blocks"], cache["k"], cache["v"]))
-    heads_in = jnp.concatenate(
-        [x[0, :b], x[0, b + pre_last_idx][None]], axis=0)  # [B+1, D]
-    logits = _lm_head(heads_in, params, cfg)
-    return logits[:b], logits[b], {"k": new_k, "v": new_v}
-
-
-def prefill_chunk(params, cache, tokens, slot, p0, cfg: LlamaConfig,
-                  last_idx=None):
-    """Write one prompt chunk into ``slot``'s KV pages and return the
-    chunk logits — chunked prefill that interleaves with ``decode_slots``
-    so a long prompt never stalls in-flight decodes.
-
-    tokens [C] int32 (tail padding allowed — padded positions write
-    garbage K/V beyond the prompt which later writes always overwrite
-    before it is attended), slot/p0 scalar int32. Returns
-    (logits, new_cache): logits is [vocab] for the single row
-    ``last_idx`` when given (the serving path — only the final prompt
-    position's logits are ever sampled, and a [C, vocab] lm_head per
-    chunk would be ~C x wasted FLOPs), else [C, vocab].
-    """
-    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    c = tokens.shape[0]
-    x = params["wte"][tokens].astype(cfg.dtype)[None]  # [1,C,D]
-    positions = (p0 + jnp.arange(c))[None, :]  # [1,C]
-    # Query at chunk offset i (global p0+i) sees cache keys <= p0+i.
-    kv_mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
-               <= positions[0][None, None, None, :, None])
-
-    def layer_step(x, inputs):
-        p, k_cache, v_cache = inputs
-
-        def write(kn, vn):
-            return (jax.lax.dynamic_update_slice(k_cache, kn,
-                                                 (slot, 0, p0, 0)),
-                    jax.lax.dynamic_update_slice(v_cache, vn,
-                                                 (slot, 0, p0, 0)))
-
-        def view(kc, vc):
-            return (jax.lax.dynamic_slice(
-                        kc, (slot, 0, 0, 0), (1, hkv, cfg.max_seq, hd)),
-                    jax.lax.dynamic_slice(
-                        vc, (slot, 0, 0, 0), (1, hkv, cfg.max_seq, hd)))
-
-        x, k2, v2 = _cache_layer_step(x, p, cfg, positions, kv_mask,
-                                      write, view)
-        return x, (k2, v2)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["blocks"], cache["k"], cache["v"]))
-    cache = {"k": new_k, "v": new_v}
-    if last_idx is not None:
-        row = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0,
-                                           keepdims=False)
-        return _lm_head(row[None], params, cfg)[0], cache
-    return _lm_head(x[0], params, cfg), cache
+def generate(params, prompt_tokens, cfg: LlamaConfig, max_new: int = 32,
+             temperature: float = 0.0, key=None):
+    """Greedy/sampled generation, one ``decode_step`` a token, the
+    prompt's too: the reference the engine's tokens are held to."""
+    if temperature > 0 and key is None:
+        raise ValueError("temperature > 0 requires a PRNG key")
+    b, s = prompt_tokens.shape
+    cache = init_kv_cache(cfg, b)
+    step = jax.jit(partial(decode_step, cfg=cfg))
+    tokens = prompt_tokens
+    logits = None
+    for i in range(s):
+        logits, cache = step(params, cache, tokens[:, i], jnp.asarray(i))
+    out = [tokens]
+    cur = None
+    for j in range(max_new):
+        if temperature > 0:
+            key, sub = jax.random.split(key)
+            cur = jax.random.categorical(sub, logits / temperature, axis=-1)
+        else:
+            cur = jnp.argmax(logits, axis=-1)
+        out.append(cur[:, None])
+        logits, cache = step(params, cache, cur, jnp.asarray(s + j))
+    return jnp.concatenate(out, axis=1)
 
 
 # ---------------------------------------------------------------------------
-# Paged KV cache (serve path v2): fixed-size pages + slot->page-table
-# indirection, so prompt-prefix pages can be SHARED between slots
-# (radix/prefix cache, refcounted by the engine) and freed pages return
-# to a pool instead of dying with a slot. PagedAttention (vLLM) /
-# RadixAttention (SGLang) in this repo's two-XLA-program style.
+# Paged KV cache — what this family offers the serving engine through
+# ``models/serving.py``: fixed-size pages + slot->page-table indirection,
+# so prompt-prefix pages can be SHARED between slots (radix/prefix cache,
+# refcounted by the engine) and freed pages return to a pool instead of
+# dying with a slot. PagedAttention (vLLM) / RadixAttention (SGLang).
 #
 # Layout: cache["kv"] is ONE fused array [L, 2, num_pages, page_size,
 # Hkv * hd] (index 0 = K, 1 = V): a token's KV heads lie side by side in
 # the minor axis, so a physical page is a contiguous [page_size, Hkv * hd]
 # block whose rows fill whole 128-lane rows (hd = 64 alone is half of one:
-# with [.., Hkv, hd] minor axes the TPU either pads hd to 128 or, as it
-# did, makes the PAGE index the lane axis and scatters a page over the
-# whole pool). A page table row [P] (P = max_seq // page_size) maps a
-# slot's logical page l to a physical page id. Physical page 0 is the
-# RESERVED SCRATCH page: every invalid write (parked slots, chunk tail
-# padding, position overshoot) is routed there explicitly, so garbage can
-# never land in a real — possibly shared — page. Unallocated page-table
-# entries are 0 for the same reason. Positions in unallocated logical
-# pages are always > the slot's current pos, so attention masks them
-# before they are ever read.
+# with [.., Hkv, hd] minor axes the TPU pads hd to 128 or makes the PAGE
+# index the lane axis and scatters a page over the whole pool). A page
+# table row [P] (P = max_seq // page_size) maps a slot's logical page l to
+# a physical page id. Physical page 0 is the RESERVED SCRATCH page: every
+# invalid write (parked slots, chunk tail padding, position overshoot) is
+# routed there explicitly, so garbage can never land in a real — possibly
+# shared — page. Unallocated page-table entries are 0 for the same reason.
+# Positions in unallocated logical pages are always > the slot's current
+# pos, so attention masks them before they are ever read.
 #
-# The pool inside a step program is touched only IN PLACE. The loop over
-# the layers carries the whole pool and scans over the layers' weights
-# and a layer index: a layer is never sliced out of the pool nor stacked
-# back (as the loop's xs/ys it was, and XLA then copied the pool into the
-# loop's layout and back every step). On the TPU the pool's only reader
-# and writer is ``ops/paged_attention.py``: a Pallas kernel that takes the
-# whole pool, aliased to its output, and the layer index, puts the rows'
-# new K/V into their pages and DMAs only the pages a row has (an XLA
-# scatter beside it asked for another layout of the whole pool, and XLA
-# copied the pool there and back every layer). Off the TPU it is the
+# The pool inside a step is touched only IN PLACE. The loop over the
+# layers carries the whole pool and scans over the layers' weights and a
+# layer index: a layer sliced out of the pool or stacked back as the
+# loop's xs/ys makes XLA copy the pool into the loop's layout and back
+# every step. On the TPU the pool's only reader and writer is
+# ``ops/paged_attention.py``: a Pallas kernel that takes the whole pool,
+# aliased to its output, and the layer index, puts the rows' new K/V into
+# their pages and DMAs only the pages a row has. Off the TPU it is the
 # reference below: a scatter at [layer, :, page, offset] on the carried
 # pool, a gather of every table entry, and the masked einsum.
 #
-# Sharding: every paged program takes an optional ``rules`` table
-# (logical axis -> mesh axis). Under a tp mesh the serving engine maps
-# the "kv" logical axis to tp, so the pool's Hkv * hd axis — whole heads a
-# shard — and the q/k/v head axes of every intermediate shard across
-# chips while the page/seq axes stay replicated; the kernel runs per
-# shard in a shard_map. With no mesh the constraints no-op.
+# Sharding: ``rules`` is a table logical axis -> mesh axis. Under a tp
+# mesh the serving engine maps the "kv" logical axis to tp, so the pool's
+# Hkv * hd axis — whole heads a shard — and the q/k/v head axes of every
+# intermediate shard across chips while the page/seq axes stay
+# replicated; the kernel runs per shard in a shard_map. With no mesh the
+# constraints no-op.
 #
-# Scope names: the paged programs carry ``jax.named_scope`` names — metadata
-# on the HLO (``op_name``), no operation added, moved or changed — so a
+# Scope names: the step carries ``jax.named_scope`` names — metadata on
+# the HLO (``op_name``), no operation added, moved or changed — so a
 # device trace can say what a step was made of under names the program
-# chose. ``layers`` is round each loop over the layers; inside it every
+# chose. ``layers`` is round the loop over the layers; inside it every
 # operation sits in ``qkv`` (norm, projections, rope), ``kv_write`` and
 # ``kv_gather`` (the reference's scatter and gather; nothing on the TPU,
 # where the kernel under ``attn`` does both), ``attn`` (attention and its
-# output projection) or ``mlp``.
-# What a trace shows under ``layers`` and none of those is the loop's own
-# work, which should be nothing. Outside: ``embed``, ``lm_head``;
-# ``prefill_lane`` is round the prompt chunk's half of the fused step.
-# benchmark/trace/program.py reads them.
+# output projection) or ``mlp``. What a trace shows under ``layers`` and
+# none of those is the loop's own work, which should be nothing. Outside:
+# ``embed``, ``lm_head``; ``prefill_lane`` is round the prompt chunk's
+# half of the step. benchmark/trace/program.py reads them.
 # ---------------------------------------------------------------------------
 
 def init_paged_kv_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
@@ -584,8 +419,6 @@ def _write_and_attend(q, kn, vn, kv, layer, rows, cfg: LlamaConfig,
     table entry, and the masked einsum."""
     r, t, h, hd = q.shape
     if paged_attention_op.use_kernel():
-        from ..parallel.sharding import current_mesh, spec_for
-
         kv_spec = spec_for(("kv",), rules)
         with jax.named_scope("attn"):
             o, kv = paged_attention_op.paged_attention(
@@ -617,180 +450,98 @@ def _write_and_attend(q, kn, vn, kv, layer, rows, cfg: LlamaConfig,
             q.transpose(0, 2, 1, 3), kv_att, mask, cfg.num_kv_heads), kv
 
 
-def _chunk_length(p0, n_valid, c: int, max_seq: int):
-    """Live positions of a slot once the chunk's valid tokens are in:
-    tokens at chunk index >= n_valid or position >= max_seq are not."""
-    return p0 + jnp.clip(jnp.minimum(n_valid, max_seq - p0), 0, c)
+def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
+               page_size: int, rules=None):
+    """One continuous-batching step over the paged cache: every slot's
+    row decodes one token at its own position and, when ``chunk`` is
+    given, one C-token prompt chunk rides the same weight matmuls; only
+    attention and the K/V landing sites split.
 
+    tables [B, P] int32, tokens [B] int32, pos [B] int32 (the position
+    the new token is written at; a row at pos >= max_seq is parked and
+    writes nothing). chunk: None, or (pre_tokens [C], pre_slot, pre_p0,
+    pre_n_valid): the chunk goes into ``pre_slot``'s pages from position
+    pre_p0, straddling page boundaries freely, and its tokens at index >=
+    pre_n_valid are tail padding that lands nowhere. The caller
+    guarantees pre_slot is not a live decode row this step, so the two
+    groups of rows touch disjoint pages. A chunk alone is a chunk with
+    every decode row parked.
 
-def _scan_layers(layer_step, x, cache, params, cfg: LlamaConfig):
-    """The loop over the layers with the pool as its CARRY: scanned are
-    the layers' weights and a layer index, never the pool."""
-    with jax.named_scope("layers"):
+    Returns (logits [B, vocab] fp32, the logits [vocab] of chunk index
+    pre_n_valid - 1 or None, new cache)."""
+    b, s_max = tokens.shape[0], cfg.max_seq
+    if chunk is None:
+        packed, lay = tokens, lambda a: a[:, None]     # rows [B, 1, ..]
+    else:
+        pre_tokens, pre_slot, pre_p0, pre_n_valid = chunk
+        c = pre_tokens.shape[0]
+        packed = jnp.concatenate([tokens, pre_tokens])
+        lay = lambda a: a[None]              # one sequence [1, B + C, ..]
+    with jax.named_scope("embed"):
+        x = lay(params["wte"][packed].astype(cfg.dtype))
+    positions = (pos if chunk is None
+                 else jnp.concatenate([pos, pre_p0 + jnp.arange(c)]))
+    angles = rope_pair_tables(lay(positions), cfg.head_dim, cfg.rope_theta)
+    if chunk is not None:
+        slot_table = jax.lax.dynamic_slice(tables, (pre_slot, 0),
+                                           (1, tables.shape[1]))
+    rows_d = paged_attention_op.row_meta(
+        tables, pos, jnp.where(pos < s_max, pos + 1, 0))
+    if chunk is not None:
+        rows_c = paged_attention_op.row_meta(
+            slot_table, jnp.reshape(pre_p0, (1,)),
+            jnp.reshape(pre_p0 + jnp.clip(
+                jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c), (1,)))
+
+    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
+    q_axes, kv_axes = (None, None, "heads", None), (None, None, "kv", None)
+
+    def layer_step(x, kv, p, layer):
+        with jax.named_scope("qkv"):   # token-major [.., T, heads, hd]
+            y = rms_norm(x, p["attn_norm"])
+            bt = y.shape[:2]
+            q = (y @ p["wq"].astype(y.dtype)).reshape(*bt, h, hd)
+            k_new = (y @ p["wk"].astype(y.dtype)).reshape(*bt, hkv, hd)
+            v_new = (y @ p["wv"].astype(y.dtype)).reshape(*bt, hkv, hd)
+            q = constrain(rope_pairs(q, angles), q_axes, rules)
+            k_new = constrain(rope_pairs(k_new, angles), kv_axes, rules)
+            v_new = constrain(v_new, kv_axes, rules)
+            if chunk is not None:
+                with jax.named_scope("prefill_lane"):
+                    qp = q[:, b:]                            # [1,C,h,hd]
+                    kp = k_new[:, b:].reshape(1, c, -1)      # [1,C,Hkv*hd]
+                    vp = v_new[:, b:].reshape(1, c, -1)
+                q, k_new, v_new = q[0, :b][:, None], k_new[0, :b], v_new[0, :b]
+            k_new, v_new = k_new.reshape(b, 1, -1), v_new.reshape(b, 1, -1)
+        # Decode rows, then the chunk: each writes its own tokens before
+        # it attends, so in-chunk causality holds.
+        o, kv = _write_and_attend(q, k_new, v_new, kv, layer, rows_d, cfg,
+                                  page_size, rules)
+        if chunk is not None:
+            with jax.named_scope("prefill_lane"):
+                op, kv = _write_and_attend(qp, kp, vp, kv, layer, rows_c,
+                                           cfg, page_size, rules)
+        with jax.named_scope("attn"):
+            if chunk is not None:
+                o = jnp.concatenate([o[:, 0][None], op], axis=1)
+            x = x + o @ p["wo"].astype(o.dtype)
+        with jax.named_scope("mlp"):
+            y = rms_norm(x, p["ffn_norm"])
+            gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
+            up = y @ p["w_up"].astype(y.dtype)
+            hidden = constrain(gate * up, (None, None, "mlp"), rules)
+            return x + hidden @ p["w_down"].astype(y.dtype), kv
+
+    with jax.named_scope("layers"):  # the pool is the carry, never scanned
         (x, kv), _ = jax.lax.scan(
             lambda carry, inp: (layer_step(*carry, *inp), None),
             (x, cache["kv"]),
             (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
-    return x, {"kv": kv}
-
-
-def _qkv(x, p, cfg: LlamaConfig, angles, rules):
-    """x [B, T, D] -> token-major q [B, T, H, hd], k_new / v_new
-    [B, T, Hkv, hd], q and k_new rotated by ``angles``
-    (:func:`rope_pair_tables` of the tokens' positions)."""
-    b, t, _ = x.shape
-    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    y = rms_norm(x, p["attn_norm"])
-    q = (y @ p["wq"].astype(y.dtype)).reshape(b, t, h, hd)
-    k_new = (y @ p["wk"].astype(y.dtype)).reshape(b, t, hkv, hd)
-    v_new = (y @ p["wv"].astype(y.dtype)).reshape(b, t, hkv, hd)
-    q = constrain(rope_pairs(q, angles), (None, None, "heads", None), rules)
-    k_new = constrain(rope_pairs(k_new, angles), (None, None, "kv", None),
-                      rules)
-    v_new = constrain(v_new, (None, None, "kv", None), rules)
-    return q, k_new, v_new
-
-
-def _mlp(x, p, rules):
-    with jax.named_scope("mlp"):
-        y = rms_norm(x, p["ffn_norm"])
-        gate = jax.nn.silu(y @ p["w_gate"].astype(y.dtype))
-        up = y @ p["w_up"].astype(y.dtype)
-        hidden = constrain(gate * up, (None, None, "mlp"), rules)
-        return x + hidden @ p["w_down"].astype(y.dtype)
-
-
-def decode_slots_paged(params, cache, tables, tokens, pos,
-                       cfg: LlamaConfig, page_size: int, rules=None):
-    """``decode_slots`` over a paged cache: one decode step with
-    per-slot positions, the new K/V scattered by physical page id and
-    each slot attending through its page table row.
-
-    tables [B, P] int32, tokens [B] int32, pos [B] int32. Returns
-    (logits [B, vocab] fp32, new_cache). Parked slots (pos >= max_seq,
-    or any slot whose table row is all-scratch) write garbage only into
-    the scratch page."""
-    b = tokens.shape[0]
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens].astype(cfg.dtype)[:, None, :]  # [B,1,D]
-    angles = rope_pair_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
-    rows = paged_attention_op.row_meta(
-        tables, pos, jnp.where(pos < cfg.max_seq, pos + 1, 0))
-
-    def layer_step(x, kv, p, layer):
-        with jax.named_scope("qkv"):
-            q, k_new, v_new = _qkv(x, p, cfg, angles, rules)
-        o, kv = _write_and_attend(
-            q, k_new.reshape(b, 1, -1), v_new.reshape(b, 1, -1), kv, layer,
-            rows, cfg, page_size, rules)
-        with jax.named_scope("attn"):
-            x = x + o @ p["wo"].astype(o.dtype)
-        return _mlp(x, p, rules), kv
-
-    x, cache = _scan_layers(layer_step, x, cache, params, cfg)
-    return _lm_head(x[:, 0], params, cfg), cache
-
-
-def prefill_chunk_paged(params, cache, tables, tokens, slot, p0, n_valid,
-                        cfg: LlamaConfig, page_size: int, rules=None):
-    """``prefill_chunk`` over a paged cache: write one C-token prompt
-    chunk into ``slot``'s pages (chunk may straddle page boundaries —
-    each token's physical destination is computed independently) and
-    return the final valid position's logits.
-
-    tokens [C] int32 (tail padding allowed), slot / p0 / n_valid scalar
-    int32. Tokens at index >= n_valid are routed to the scratch page, so
-    chunk-tail garbage never lands in a real page regardless of how the
-    chunk aligns to pages. Returns ([vocab] logits of chunk index
-    n_valid - 1, new_cache)."""
-    c = tokens.shape[0]
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens].astype(cfg.dtype)[None]  # [1,C,D]
-    abs_pos = p0 + jnp.arange(c)
-    angles = rope_pair_tables(abs_pos[None, :], cfg.head_dim,
-                              cfg.rope_theta)
-    slot_table = jax.lax.dynamic_slice(tables, (slot, 0),
-                                       (1, tables.shape[1]))
-    rows = paged_attention_op.row_meta(
-        slot_table, jnp.reshape(p0, (1,)),
-        jnp.reshape(_chunk_length(p0, n_valid, c, cfg.max_seq), (1,)))
-
-    def layer_step(x, kv, p, layer):
-        with jax.named_scope("qkv"):
-            q, k_new, v_new = _qkv(x, p, cfg, angles, rules)
-        o, kv = _write_and_attend(
-            q, k_new.reshape(1, c, -1), v_new.reshape(1, c, -1), kv, layer,
-            rows, cfg, page_size, rules)
-        with jax.named_scope("attn"):
-            x = x + o @ p["wo"].astype(o.dtype)
-        return _mlp(x, p, rules), kv
-
-    x, cache = _scan_layers(layer_step, x, cache, params, cfg)
-    row = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                       keepdims=False)
-    return _lm_head(row[None], params, cfg)[0], cache
-
-
-def decode_slots_with_prefill_paged(params, cache, tables, tokens, pos,
-                                    pre_tokens, pre_slot, pre_p0,
-                                    pre_n_valid, cfg: LlamaConfig,
-                                    page_size: int, rules=None):
-    """Fused continuous-batching step over the PAGED cache — the paged
-    twin of ``decode_slots_with_prefill``: B decode tokens and one
-    C-token prefill chunk share every weight matmul; only attention and
-    the K/V landing sites split. Decode rows scatter one token each by
-    page id; the chunk scatters per token into ``pre_slot``'s pages
-    (straddling page boundaries freely); invalid writes (parked rows,
-    chunk tail at index >= pre_n_valid) go to the scratch page.
-
-    The caller guarantees pre_slot is not an active decode row this
-    step, so the two scatter groups touch disjoint pages. Returns
-    (dec_logits [B, vocab], pre_logits [vocab], new_cache)."""
-    b = tokens.shape[0]
-    c = pre_tokens.shape[0]
-    s_max = cfg.max_seq
-    packed = jnp.concatenate([tokens, pre_tokens])
-    with jax.named_scope("embed"):
-        x = params["wte"][packed].astype(cfg.dtype)[None]  # [1, B+C, D]
-    pre_positions = pre_p0 + jnp.arange(c)
-    positions = jnp.concatenate([pos, pre_positions])[None]
-    angles = rope_pair_tables(positions, cfg.head_dim, cfg.rope_theta)
-    slot_table = jax.lax.dynamic_slice(tables, (pre_slot, 0),
-                                       (1, tables.shape[1]))
-    rows_d = paged_attention_op.row_meta(
-        tables, pos, jnp.where(pos < s_max, pos + 1, 0))
-    rows_c = paged_attention_op.row_meta(
-        slot_table, jnp.reshape(pre_p0, (1,)),
-        jnp.reshape(_chunk_length(pre_p0, pre_n_valid, c, s_max), (1,)))
-
-    def layer_step(x, kv, pr, layer):
-        with jax.named_scope("qkv"):
-            q, k_new, v_new = _qkv(x, pr, cfg, angles, rules)
-            qd = q[0, :b][:, None]                           # [B,1,h,hd]
-            kd = k_new[0, :b].reshape(b, 1, -1)              # [B,1,Hkv*hd]
-            vd = v_new[0, :b].reshape(b, 1, -1)
-            with jax.named_scope("prefill_lane"):
-                qp = q[:, b:]                                # [1,C,h,hd]
-                kp = k_new[:, b:].reshape(1, c, -1)          # [1,C,Hkv*hd]
-                vp = v_new[:, b:].reshape(1, c, -1)
-        # Decode rows, then the chunk: each writes its own tokens before
-        # it attends, so in-chunk causality holds, and the two touch
-        # disjoint pages by the caller's pre_slot guarantee.
-        od, kv = _write_and_attend(qd, kd, vd, kv, layer, rows_d, cfg,
-                                   page_size, rules)
-        with jax.named_scope("prefill_lane"):
-            op, kv = _write_and_attend(qp, kp, vp, kv, layer, rows_c, cfg,
-                                       page_size, rules)
-        with jax.named_scope("attn"):
-            o = jnp.concatenate([od[:, 0][None], op], axis=1)  # [1,B+C,D]
-            x = x + o @ pr["wo"].astype(o.dtype)
-        return _mlp(x, pr, rules), kv
-
-    x, cache = _scan_layers(layer_step, x, cache, params, cfg)
-    heads_in = jnp.concatenate(
-        [x[0, :b], x[0, b + pre_n_valid - 1][None]], axis=0)  # [B+1, D]
-    logits = _lm_head(heads_in, params, cfg)
-    return logits[:b], logits[b], cache
+    if chunk is None:
+        return _lm_head(x[:, 0], params, cfg), None, {"kv": kv}
+    logits = _lm_head(jnp.concatenate(
+        [x[0, :b], x[0, b + pre_n_valid - 1][None]], axis=0), params, cfg)
+    return logits[:b], logits[b], {"kv": kv}
 
 
 def copy_pages(cache, src, dst):
@@ -804,38 +555,43 @@ def copy_pages(cache, src, dst):
 def write_pages(cache, dst, values):
     """Host->device page import (session migration): physical pages
     ``dst[i]`` <- ``values[:, :, i]`` across every layer in one program.
-    dst [N] int32; values [L, 2, N, page_size, Hkv, hd] host frames
-    from a peer engine's export. Jit with the cache donated so the
-    import is an in-place scatter; callers pad N to a few fixed bucket
-    sizes (padding rows aimed at the reserved scratch page 0, which
-    absorbs them) so repeated imports never recompile."""
+    dst [N] int32; values [L, 2, N, page_size, Hkv * hd] host frames of
+    a peer engine's :func:`read_pages`. Jit with the cache donated so the
+    import is an in-place scatter; callers pad N to a few bucket sizes
+    (padding rows aimed at scratch page 0) so imports rarely recompile."""
     kv = cache["kv"]
     return {"kv": kv.at[:, :, dst].set(values.astype(kv.dtype))}
 
 
-def generate(params, prompt_tokens, cfg: LlamaConfig, max_new: int = 32,
-             temperature: float = 0.0, key=None):
-    """Greedy/sampled generation (the serve replica's inner loop)."""
-    if temperature > 0 and key is None:
-        raise ValueError("temperature > 0 requires a PRNG key")
-    b, s = prompt_tokens.shape
-    cache = init_kv_cache(cfg, b)
-    # Prefill one token at a time keeps this reference implementation
-    # simple; the serve bench uses jit(decode_step) so the per-step cost
-    # is one compiled program either way.
-    step = jax.jit(partial(decode_step, cfg=cfg))
-    tokens = prompt_tokens
-    logits = None
-    for i in range(s):
-        logits, cache = step(params, cache, tokens[:, i], jnp.asarray(i))
-    out = [tokens]
-    cur = None
-    for j in range(max_new):
-        if temperature > 0:
-            key, sub = jax.random.split(key)
-            cur = jax.random.categorical(sub, logits / temperature, axis=-1)
-        else:
-            cur = jnp.argmax(logits, axis=-1)
-        out.append(cur[:, None])
-        logits, cache = step(params, cache, cur, jnp.asarray(s + j))
-    return jnp.concatenate(out, axis=1)
+def read_pages(cache, idx):
+    """Device->host page export: physical pages ``idx`` [N] of every
+    layer as one contiguous host frame [L, 2, N, page_size, Hkv * hd]."""
+    return np.ascontiguousarray(np.asarray(cache["kv"][:, :, idx]))
+
+
+def check_frames(cache, frames) -> None:
+    """ValueError unless ``frames`` are pages of a pool like this one."""
+    kv_shape = cache["kv"].shape
+    if (tuple(frames.shape[:2]) != tuple(kv_shape[:2])
+            or tuple(frames.shape[3:]) != tuple(kv_shape[3:])):
+        raise ValueError(
+            f"KV frame shape {frames.shape} does not match "
+            f"cache {kv_shape}")
+
+
+def check_shardable(cfg: LlamaConfig, tp: int) -> None:
+    """ValueError unless tp divides every axis the "tp" rules shard."""
+    if tp > 1 and (cfg.num_kv_heads % tp or cfg.num_heads % tp
+                   or cfg.d_mlp % tp or cfg.vocab_size % tp):
+        raise ValueError(
+            f"tp={tp} must divide num_kv_heads "
+            f"({cfg.num_kv_heads}), num_heads ({cfg.num_heads}), "
+            f"d_mlp ({cfg.d_mlp}) and vocab ({cfg.vocab_size})")
+
+
+serving.register(serving.ServingModel(
+    config_type=LlamaConfig, configs=CONFIGS, init_params=init_params,
+    param_axes=param_axes, check_shardable=check_shardable,
+    init_cache=init_paged_kv_cache, cache_axes={"kv": PAGED_KV_AXES},
+    step=paged_step, copy_pages=copy_pages, write_pages=write_pages,
+    read_pages=read_pages, check_frames=check_frames))

@@ -1,5 +1,5 @@
 """Paged KV cache + radix prefix reuse (ISSUE 15): token parity of the
-paged engine vs the dense reference paths, bit-for-bit prefix-hit
+paged engine vs the dense reference, bit-for-bit prefix-hit
 outputs (greedy AND seeded sampling), COW fork isolation, page
 accounting (no leaks, reserved scratch page), LRU eviction under pool
 pressure, and bounded-admission shedding."""
@@ -140,54 +140,80 @@ def test_paged_sampled_parity_vs_dense_reference(engine, params):
     assert got == ref
 
 
-def test_paged_kernels_match_dense(params):
-    """prefill_chunk_paged + decode_slots_paged must produce the same
-    logits as the dense prefill_chunk + decode_slots for the same
-    tokens — pages only move the bytes, never the math."""
+@pytest.mark.parametrize("case", ["chunk_only", "decode_only", "both"])
+def test_paged_kernels_match_dense(params, case):
+    """The one paged step against the dense ``decode_step``, token by
+    token — pages only move the bytes, never the math. A prompt goes into
+    slot 1's scattered pages through the chunk lane (decode rows parked:
+    what the engine sends when nothing decodes) or a token a step through
+    its decode row; "both" has slot 0 decoding another sequence beside
+    the chunks. Then a few greedy decode steps on top."""
     rng = np.random.default_rng(23)
     prompt = rng.integers(1, CFG.vocab_size, size=13).astype(np.int32)
-    nrows, pps = 2, CFG.max_seq // PS
-    dense = llama.init_kv_cache(CFG, nrows)
-    paged = llama.init_paged_kv_cache(CFG, nrows * pps + 1, PS)
-    # Slot 1 of the dense cache <-> an arbitrary scattered page set.
+    other = rng.integers(1, CFG.vocab_size, size=6).astype(np.int32)
+    nrows, pps, c = 2, CFG.max_seq // PS, 8
     tables = np.zeros((nrows, pps), dtype=np.int32)
-    tables[1] = np.arange(1, pps + 1)[::-1]
+    tables[0] = np.arange(pps + 1, 2 * pps + 1)
+    tables[1] = np.arange(1, pps + 1)[::-1]  # an arbitrary scattered set
     tables = jnp.asarray(tables)
-    slot = jnp.asarray(1, jnp.int32)
-    # Whole-prompt prefill in one chunk (tail-padded).
-    buf = np.zeros((16,), dtype=np.int32)
-    buf[:len(prompt)] = prompt
-    lg_d, dense = llama.prefill_chunk(
-        params, dense, jnp.asarray(buf), slot, jnp.asarray(0, jnp.int32),
-        CFG, last_idx=jnp.asarray(len(prompt) - 1, jnp.int32))
-    lg_p, paged = llama.prefill_chunk_paged(
-        params, paged, tables, jnp.asarray(buf), slot,
-        jnp.asarray(0, jnp.int32),
-        jnp.asarray(len(prompt), jnp.int32), CFG, PS)
-    np.testing.assert_allclose(np.asarray(lg_d), np.asarray(lg_p),
-                               rtol=1e-5, atol=1e-5)
-    # A few decode steps on top, greedy-chained.
-    tok_d, tok_p = (jnp.argmax(lg_d, -1).astype(jnp.int32),
-                    jnp.argmax(lg_p, -1).astype(jnp.int32))
-    for step in range(4):
-        pos = np.full((nrows,), CFG.max_seq, dtype=np.int32)
-        pos[1] = len(prompt) + step
-        toks_d = jnp.zeros((nrows,), jnp.int32).at[1].set(tok_d)
-        toks_p = jnp.zeros((nrows,), jnp.int32).at[1].set(tok_p)
-        # Dense parks idle rows at max_seq - 1; paged routes >= max_seq
-        # to the scratch page.
-        pos_d = np.minimum(pos, CFG.max_seq - 1)
-        lg_d, dense = llama.decode_slots(params, dense, toks_d,
-                                         jnp.asarray(pos_d), CFG)
-        lg_p, paged = llama.decode_slots_paged(params, paged, tables,
-                                               toks_p, jnp.asarray(pos),
-                                               CFG, PS)
-        np.testing.assert_allclose(np.asarray(lg_d[1]),
-                                   np.asarray(lg_p[1]),
-                                   rtol=1e-5, atol=1e-5)
-        tok_d, tok_p = (jnp.argmax(lg_d[1], -1).astype(jnp.int32),
-                        jnp.argmax(lg_p[1], -1).astype(jnp.int32))
-        assert int(tok_d) == int(tok_p)
+    step = jax.jit(lambda cache, toks, pos, chunk: llama.paged_step(
+        params, cache, tables, toks, pos, chunk, CFG, PS))
+    dense_step = jax.jit(lambda cache, tok, i: llama.decode_step(
+        params, cache, tok[None], i, CFG))
+
+    def dense(tokens):
+        """Row 0's logits after each token, and the cache."""
+        cache, out = llama.init_kv_cache(CFG, 1), []
+        for i, t in enumerate(tokens):
+            lg, cache = dense_step(cache, jnp.asarray(t, jnp.int32),
+                                   jnp.asarray(i, jnp.int32))
+            out.append(np.asarray(lg[0]))
+        return out, cache
+
+    def close(got, want):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)
+
+    want_prompt, dense_cache = dense(prompt)
+    want_other, _ = dense(other)
+    paged = llama.init_paged_kv_cache(CFG, nrows * pps + 1, PS)
+    toks = np.zeros((nrows,), np.int32)
+    pos = np.full((nrows,), CFG.max_seq, np.int32)  # every row parked
+    if case == "decode_only":
+        for i, t in enumerate(prompt):
+            toks[1], pos[1] = t, i
+            lg, none, paged = step(paged, jnp.asarray(toks),
+                                   jnp.asarray(pos), None)
+            assert none is None
+            close(lg[1], want_prompt[i])
+        last = lg[1]
+    else:
+        for n, p0 in enumerate(range(0, len(prompt), c)):
+            piece = prompt[p0:p0 + c]
+            buf = np.zeros((c,), np.int32)  # tail-padded
+            buf[:len(piece)] = piece
+            if case == "both":
+                toks[0], pos[0] = other[n], n
+            lg, last, paged = step(
+                paged, jnp.asarray(toks), jnp.asarray(pos),
+                (jnp.asarray(buf), jnp.asarray(1, jnp.int32),
+                 jnp.asarray(p0, jnp.int32),
+                 jnp.asarray(len(piece), jnp.int32)))
+            close(last, want_prompt[p0 + len(piece) - 1])
+            if case == "both":
+                close(lg[0], want_other[n])
+    toks[0], pos[0] = 0, CFG.max_seq
+    tok = int(jnp.argmax(last))
+    for j in range(4):
+        toks[1], pos[1] = tok, len(prompt) + j
+        want, dense_cache = dense_step(
+            dense_cache, jnp.asarray(tok, jnp.int32),
+            jnp.asarray(len(prompt) + j, jnp.int32))
+        lg, _, paged = step(paged, jnp.asarray(toks), jnp.asarray(pos),
+                            None)
+        close(lg[1], np.asarray(want[0]))
+        assert int(jnp.argmax(lg[1])) == int(jnp.argmax(want[0]))
+        tok = int(jnp.argmax(lg[1]))
 
 
 # -- engine: prefix hit parity ------------------------------------------------

@@ -155,16 +155,15 @@ PROGRAM_CONFIGS = {"llama-tiny": (llama.CONFIGS["llama-tiny"], 8, 1e-4),
 
 
 def _greedy_run(cfg, ps, params, prompt, chunk, steps):
-    """One slot of three prefilled through the fused program a chunk at a
-    time while another decodes beside it, then decode-only steps; every
-    step's logits, greedy-chained."""
+    """One slot of three prefilled through the step's chunk lane a chunk
+    at a time while another decodes beside it, then steps with no chunk;
+    every step's logits, greedy-chained."""
     b, pps = 3, cfg.max_seq // ps
     cache = llama.init_paged_kv_cache(cfg, b * pps + 1, ps)
     tables = jnp.asarray(
         np.arange(1, b * pps + 1)[::-1].reshape(b, pps).astype(np.int32))
-    fused = jax.jit(lambda *a: llama.decode_slots_with_prefill_paged(
-        *a, cfg, ps))
-    decode = jax.jit(lambda *a: llama.decode_slots_paged(*a, cfg, ps))
+    step = jax.jit(lambda cache, toks, pos, chunk: llama.paged_step(
+        params, cache, tables, toks, pos, chunk, cfg, ps))
     out = []
     pos = np.full((b,), cfg.max_seq, np.int32)   # all parked
     toks = np.zeros((b,), np.int32)
@@ -173,17 +172,17 @@ def _greedy_run(cfg, ps, params, prompt, chunk, steps):
         piece = prompt[p0:p0 + chunk]
         buf = np.zeros((chunk,), np.int32)
         buf[:len(piece)] = piece
-        dec, pre, cache = fused(
-            params, cache, tables, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(buf), jnp.asarray(2, jnp.int32),
-            jnp.asarray(p0, jnp.int32), jnp.asarray(len(piece), jnp.int32))
+        dec, pre, cache = step(
+            cache, jnp.asarray(toks), jnp.asarray(pos),
+            (jnp.asarray(buf), jnp.asarray(2, jnp.int32),
+             jnp.asarray(p0, jnp.int32), jnp.asarray(len(piece), jnp.int32)))
         out += [np.asarray(dec[0]), np.asarray(pre)]
         toks[0] = int(jnp.argmax(dec[0]))
         pos[0] += 1
     pos[2], toks[2] = len(prompt), int(jnp.argmax(pre))
     for _ in range(steps):
-        logits, cache = decode(params, cache, tables, jnp.asarray(toks),
-                               jnp.asarray(pos))
+        logits, _, cache = step(cache, jnp.asarray(toks), jnp.asarray(pos),
+                                None)
         live = np.asarray(logits)[[0, 2]]
         out.append(live)
         toks[[0, 2]] = live.argmax(-1)
@@ -194,9 +193,9 @@ def _greedy_run(cfg, ps, params, prompt, chunk, steps):
 @pytest.mark.parametrize("name", list(PROGRAM_CONFIGS))
 def test_programs_with_the_kernel_match_the_reference_path(name,
                                                            monkeypatch):
-    """``decode_slots_with_prefill_paged`` and ``decode_slots_paged`` with
-    the kernel in them give the reference path's logits, step after step,
-    and therefore the same greedy tokens."""
+    """``paged_step``, with a chunk and without, with the kernel in it
+    gives the reference path's logits, step after step, and therefore the
+    same greedy tokens."""
     cfg, ps, tol = PROGRAM_CONFIGS[name]
     params, _ = llama.init_params(jax.random.PRNGKey(0), cfg)
     params = jax.tree.map(lambda x: x.astype(cfg.dtype), params)

@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm.engine import build_step_programs
-from ray_tpu.models import llama
+from ray_tpu.models import llama, serving
 from ray_tpu.ops import attention as A
 from ray_tpu.ops import paged_attention as PA
 from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
@@ -161,15 +161,19 @@ def _engine_program_specs(cfg, sharding):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    # params and cache as the engine gets them: from the family's record
+    model = serving.model_for(cfg)
     pages = SLOTS * cfg.max_seq // PAGE + 1
     params = jax.tree.map(
         lambda x: sds(x.shape, cfg.dtype),
-        jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0),
+        jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0),
                                                  cfg)[0]))
-    pool = sds((cfg.num_layers, 2, pages, PAGE,
-                cfg.num_kv_heads * cfg.head_dim), cfg.dtype)
+    cache = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init_cache(cfg, pages, PAGE)))
+    (pool,) = jax.tree.leaves(cache)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
-    common = (params, {"kv": pool}, i32(SLOTS, cfg.max_seq // PAGE),
+    common = (params, cache, i32(SLOTS, cfg.max_seq // PAGE),
               i32(SLOTS), sds((SLOTS,), jnp.bool_), i32(SLOTS), i32(SLOTS),
               sds((SLOTS,), jnp.float32), i32(SLOTS))
     fused = common + (i32(CHUNK), i32(), i32(), i32(),

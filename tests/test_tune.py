@@ -56,15 +56,39 @@ def test_tune_run_multiple_reports(rt_shared):
         assert t.last_result["training_iteration"] == 4
 
 
-def test_asha_stops_bad_trials(rt_shared):
+def test_asha_stops_bad_trials(rt_shared, tmp_path):
     from ray_tpu.tune import AsyncHyperBandScheduler, Tuner, TuneConfig, grid_search, report
+
+    gate = str(tmp_path)
 
     def objective(config):
         # Trial quality is determined by "quality"; bad trials plateau high.
+        # Paced by events, never by the clock, because ASHA judges a trial
+        # against what EARLIER arrivals recorded at a rung: a trial reports
+        # again only once the tuner has taken its last report (a stop
+        # decided at a rung lands there, not a drained batch later), and a
+        # bad trial comes to the first rung only after both good ones are
+        # recorded there.
+        import os
+
+        from ray_tpu.train.session import get_session
+
+        def wait_for(cond):
+            deadline = time.monotonic() + 120
+            while not cond():
+                if time.monotonic() > deadline:
+                    raise TimeoutError("trial waited 120 s on the tuner")
+                time.sleep(0.002)
+
+        good = config["quality"] == 0.0
         for i in range(20):
+            if not good and i == 1:
+                wait_for(lambda: len(os.listdir(gate)) >= 2)
             loss = config["quality"] + 10.0 / (i + 1)
             report({"loss": loss})
-            time.sleep(0.01)
+            wait_for(lambda: not get_session().results)
+            if good and i == 1:
+                open(os.path.join(gate, str(os.getpid())), "w").close()
 
     scheduler = AsyncHyperBandScheduler(
         metric="loss", mode="min", grace_period=2, reduction_factor=2,
