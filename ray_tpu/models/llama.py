@@ -15,10 +15,12 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..ops import paged_attention as paged_attention_op
 from ..ops.attention import attention as attention_op
-from ..parallel.sharding import constrain, current_mesh, spec_for
+from ..parallel.sharding import (constrain, current_mesh, mesh_axes_for,
+                                 spec_for)
 from . import serving
 from .common import cross_entropy_loss, rms_norm, truncated_normal
 
@@ -119,37 +121,66 @@ def rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
-def rope_pair_tables(positions, d: int, theta: float):
-    """The rotary tables of ``positions`` [B, T] for :func:`rope_pairs`:
-    (cos, signed sin), each [B, T, 1, d], pair i's angle at lanes 2i and
-    2i + 1 and the sine negative at 2i. They depend on the positions
-    alone: a loop over layers computes them once, outside."""
+def rope_lane_tables(positions, heads: int, d: int, theta: float):
+    """The rotary tables of ``positions`` [B, T] for :func:`rope_lanes`:
+    (cos, signed sin), each [B, T, heads * d] — a token's heads side by
+    side, every head the same d lanes: pair i's angle at lanes 2i and
+    2i + 1 of a head and the sine negative at 2i. They depend on the
+    positions alone: a loop over layers computes them once, outside."""
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[:, :, None, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(angles), jnp.sin(angles)        # [B, T, 1, d/2]
+    angles = positions[:, :, None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)           # [B, T, d/2]
     shape = angles.shape[:-1] + (d,)
-    return (jnp.stack([cos, cos], axis=-1).reshape(shape),
-            jnp.stack([-sin, sin], axis=-1).reshape(shape))
+    return tuple(
+        jnp.tile(jnp.stack(pair, axis=-1).reshape(shape), (1, 1, heads))
+        for pair in ((cos, cos), (-sin, sin)))
 
 
-def rope_pairs(x, tables):
-    """:func:`rope` of token-major x [B, T, heads, D] without cutting the
-    pairs apart: ``x * cos + swap(x) * signed_sin``, where swap exchanges
-    the two lanes of every pair — the same products and the same sum, bit
-    for bit, as rope's ``x1 * cos - x2 * sin`` and ``x2 * cos + x1 *
-    sin``. The strided halves and their re-interleaving are gathers and
-    copies on the TPU, a dozen operations a layer; the exchange is one
-    exact product with a 0/1 matrix."""
-    cos, sin = tables
-    d = x.shape[-1]
-    swap = np.zeros((d, d), np.float32)
-    swap[np.arange(d) ^ 1, np.arange(d)] = 1.0
-    swapped = jnp.einsum(
-        "bthd,de->bthe", x, swap.astype(x.dtype),
-        precision=(jax.lax.Precision.HIGHEST
-                   if x.dtype == jnp.float32 else None),
-        preferred_element_type=jnp.float32)
-    return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
+def _swap_pairs(x):
+    """Lanes 2i and 2i + 1 of the last axis exchanged: each lane takes
+    its left or its right neighbour, by its parity (two shifts and a
+    select, no lane leaves its pair, so the zeros shifted in are never
+    taken)."""
+    edge = [(0, 0, 0)] * (x.ndim - 1)
+    zero = jnp.zeros((), x.dtype)
+    return jnp.where(jnp.arange(x.shape[-1]) % 2 == 0,
+                     jax.lax.pad(x, zero, edge + [(-1, 1, 0)]),
+                     jax.lax.pad(x, zero, edge + [(1, -1, 0)]))
+
+
+def rope_lanes(x, tables, mesh=None, lanes_axis=None):
+    """:func:`rope` of a projection's output AS THE MATMUL LEAVES IT, x
+    [B, T, heads * D] with a token's heads side by side: ``x * cos +
+    swap(x) * signed_sin``, where swap exchanges the two lanes of every
+    pair — the same products and the same sum, bit for bit, as rope's
+    ``x1 * cos - x2 * sin`` and ``x2 * cos + x1 * sin``.
+
+    Why on the flat lanes and not on [.., heads, D]: a reshape to heads
+    between the projection and the rotary step is folded by the TPU
+    compiler INTO the projection, which becomes a product batched over
+    the heads and wants its weight as [heads, D, d_in], the transpose of
+    what is stored — so every layer of every step it slices the layer's
+    [d_in, heads * D] weight out of the stacked array and writes it again
+    transposed before the matmul reads it (40 MiB of traffic for an 8 MiB
+    weight; tests/test_tpu_compile.py holds both engine programs to no
+    such copy). Kept flat, the matmul reads the layer where it lies. The
+    strided halves of :func:`rope` and their re-interleaving are gathers
+    and copies on the TPU, a dozen operations a layer; here q and k share
+    one elementwise fusion.
+
+    The shifts cross the lanes axis: where that axis is sharded (whole
+    heads a shard, so no pair is cut) they run per shard in a shard_map,
+    or GSPMD would exchange a halo between chips for lanes never taken.
+    """
+    def rotate(x, cos, sin):
+        return (x.astype(jnp.float32) * cos
+                + _swap_pairs(x).astype(jnp.float32) * sin).astype(x.dtype)
+
+    if mesh is None or lanes_axis is None:
+        return rotate(x, *tables)
+    spec = P(None, None, lanes_axis)
+    return jax.shard_map(rotate, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(x, *tables)
 
 
 def _repeat_kv(x, n_rep: int):
@@ -372,10 +403,10 @@ def generate(params, prompt_tokens, cfg: LlamaConfig, max_new: int = 32,
 #
 # Sharding: ``rules`` is a table logical axis -> mesh axis. Under a tp
 # mesh the serving engine maps the "kv" logical axis to tp, so the pool's
-# Hkv * hd axis — whole heads a shard — and the q/k/v head axes of every
+# Hkv * hd axis — whole heads a shard — and the q/k/v lanes of every
 # intermediate shard across chips while the page/seq axes stay
-# replicated; the kernel runs per shard in a shard_map. With no mesh the
-# constraints no-op.
+# replicated; the kernel and the rotary step's lane shifts run per shard
+# in a shard_map. With no mesh the constraints no-op.
 #
 # Scope names: the step carries ``jax.named_scope`` names — metadata on
 # the HLO (``op_name``), no operation added, moved or changed — so a
@@ -467,6 +498,14 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
     groups of rows touch disjoint pages. A chunk alone is a chunk with
     every decode row parked.
 
+    The q / k / v projections read a layer of the stacked weights where
+    it lies, as ``wo`` and the MLP's do: their outputs stay [.., T,
+    heads * hd], the rotary step runs on those flat lanes
+    (:func:`rope_lanes`, which says what a reshape to [.., heads, hd]
+    before it costs on the TPU: a transposed copy of the layer's weight,
+    every layer of every step), K and V go to the kernel as they are and
+    q is cut into heads only at the attention call.
+
     Returns (logits [B, vocab] fp32, the logits [vocab] of chunk index
     pre_n_valid - 1 or None, new cache)."""
     b, s_max = tokens.shape[0], cfg.max_seq
@@ -481,7 +520,10 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
         x = lay(params["wte"][packed].astype(cfg.dtype))
     positions = (pos if chunk is None
                  else jnp.concatenate([pos, pre_p0 + jnp.arange(c)]))
-    angles = rope_pair_tables(lay(positions), cfg.head_dim, cfg.rope_theta)
+    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
+    angles_q, angles_k = (
+        rope_lane_tables(lay(positions), n, hd, cfg.rope_theta)
+        for n in (h, hkv))
     if chunk is not None:
         slot_table = jax.lax.dynamic_slice(tables, (pre_slot, 0),
                                            (1, tables.shape[1]))
@@ -493,26 +535,26 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
             jnp.reshape(pre_p0 + jnp.clip(
                 jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c), (1,)))
 
-    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    q_axes, kv_axes = (None, None, "heads", None), (None, None, "kv", None)
+    q_axes, kv_axes = (None, None, "qkv"), (None, None, "kv")
+    mesh = current_mesh()
+    q_shard, kv_shard = (mesh_axes_for(axes[2], rules) or None
+                         for axes in (q_axes, kv_axes))
 
     def layer_step(x, kv, p, layer):
-        with jax.named_scope("qkv"):   # token-major [.., T, heads, hd]
+        with jax.named_scope("qkv"):   # token-major, heads side by side
             y = rms_norm(x, p["attn_norm"])
-            bt = y.shape[:2]
-            q = (y @ p["wq"].astype(y.dtype)).reshape(*bt, h, hd)
-            k_new = (y @ p["wk"].astype(y.dtype)).reshape(*bt, hkv, hd)
-            v_new = (y @ p["wv"].astype(y.dtype)).reshape(*bt, hkv, hd)
-            q = constrain(rope_pairs(q, angles), q_axes, rules)
-            k_new = constrain(rope_pairs(k_new, angles), kv_axes, rules)
-            v_new = constrain(v_new, kv_axes, rules)
+            q = constrain(y @ p["wq"].astype(y.dtype), q_axes, rules)
+            k_new = constrain(y @ p["wk"].astype(y.dtype), kv_axes, rules)
+            v_new = constrain(y @ p["wv"].astype(y.dtype), kv_axes, rules)
+            q = rope_lanes(q, angles_q, mesh, q_shard)
+            k_new = rope_lanes(k_new, angles_k, mesh, kv_shard)
             if chunk is not None:
                 with jax.named_scope("prefill_lane"):
-                    qp = q[:, b:]                            # [1,C,h,hd]
-                    kp = k_new[:, b:].reshape(1, c, -1)      # [1,C,Hkv*hd]
-                    vp = v_new[:, b:].reshape(1, c, -1)
-                q, k_new, v_new = q[0, :b][:, None], k_new[0, :b], v_new[0, :b]
-            k_new, v_new = k_new.reshape(b, 1, -1), v_new.reshape(b, 1, -1)
+                    qp = q[:, b:].reshape(1, c, h, hd)
+                    kp, vp = k_new[:, b:], v_new[:, b:]      # [1,C,Hkv*hd]
+                q, k_new, v_new = (a[0, :b][:, None]
+                                   for a in (q, k_new, v_new))
+            q = q.reshape(b, 1, h, hd)
         # Decode rows, then the chunk: each writes its own tokens before
         # it attends, so in-chunk causality holds.
         o, kv = _write_and_attend(q, k_new, v_new, kv, layer, rows_d, cfg,

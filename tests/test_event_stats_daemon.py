@@ -10,6 +10,8 @@ def test_daemon_event_stats_reach_head():
     from ray_tpu.cluster_utils import Cluster
     from ray_tpu.observability import event_loop_stats
 
+    if rt.is_initialized():  # left by whichever file this worker ran last
+        rt.shutdown()
     cluster = Cluster(head_node_args={"num_cpus": 1})
     try:
         nid = cluster.add_node(num_cpus=2, resources={"zone_d": 1.0},

@@ -4,6 +4,8 @@ outputs (greedy AND seeded sampling), COW fork isolation, page
 accounting (no leaks, reserved scratch page), LRU eviction under pool
 pressure, and bounded-admission shedding."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -140,14 +142,24 @@ def test_paged_sampled_parity_vs_dense_reference(engine, params):
     assert got == ref
 
 
+@pytest.mark.parametrize("heads", ["gqa", "mha"])
 @pytest.mark.parametrize("case", ["chunk_only", "decode_only", "both"])
-def test_paged_kernels_match_dense(params, case):
+def test_paged_kernels_match_dense(params, case, heads):
     """The one paged step against the dense ``decode_step``, token by
-    token — pages only move the bytes, never the math. A prompt goes into
-    slot 1's scattered pages through the chunk lane (decode rows parked:
-    what the engine sends when nothing decodes) or a token a step through
-    its decode row; "both" has slot 0 decoding another sequence beside
-    the chunks. Then a few greedy decode steps on top."""
+    token — pages only move the bytes, never the math — for llama-tiny's
+    grouped KV heads (4 query heads on 2) and for as many KV heads as
+    query heads, the published SmolLM2 shape: the step keeps q / k / v on
+    the flat lanes its matmuls leave and rotates them there
+    (``rope_lanes``), the reference cuts heads out first (``rope``). A
+    prompt goes into slot 1's scattered pages through the chunk lane
+    (decode rows parked: what the engine sends when nothing decodes) or a
+    token a step through its decode row; "both" has slot 0 decoding
+    another sequence beside the chunks. Then a few greedy decode steps on
+    top."""
+    cfg = CFG
+    if heads == "mha":
+        cfg = dataclasses.replace(CFG, num_kv_heads=CFG.num_heads)
+        params, _ = llama.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(23)
     prompt = rng.integers(1, CFG.vocab_size, size=13).astype(np.int32)
     other = rng.integers(1, CFG.vocab_size, size=6).astype(np.int32)
@@ -157,13 +169,13 @@ def test_paged_kernels_match_dense(params, case):
     tables[1] = np.arange(1, pps + 1)[::-1]  # an arbitrary scattered set
     tables = jnp.asarray(tables)
     step = jax.jit(lambda cache, toks, pos, chunk: llama.paged_step(
-        params, cache, tables, toks, pos, chunk, CFG, PS))
+        params, cache, tables, toks, pos, chunk, cfg, PS))
     dense_step = jax.jit(lambda cache, tok, i: llama.decode_step(
-        params, cache, tok[None], i, CFG))
+        params, cache, tok[None], i, cfg))
 
     def dense(tokens):
         """Row 0's logits after each token, and the cache."""
-        cache, out = llama.init_kv_cache(CFG, 1), []
+        cache, out = llama.init_kv_cache(cfg, 1), []
         for i, t in enumerate(tokens):
             lg, cache = dense_step(cache, jnp.asarray(t, jnp.int32),
                                    jnp.asarray(i, jnp.int32))
@@ -176,7 +188,7 @@ def test_paged_kernels_match_dense(params, case):
 
     want_prompt, dense_cache = dense(prompt)
     want_other, _ = dense(other)
-    paged = llama.init_paged_kv_cache(CFG, nrows * pps + 1, PS)
+    paged = llama.init_paged_kv_cache(cfg, nrows * pps + 1, PS)
     toks = np.zeros((nrows,), np.int32)
     pos = np.full((nrows,), CFG.max_seq, np.int32)  # every row parked
     if case == "decode_only":

@@ -116,3 +116,43 @@ def test_llama_generate():
                                 cfg.vocab_size)
     out = llama.generate(params, prompt, cfg, max_new=5)
     assert out.shape == (2, 9)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads,t", [(32, 1), (32, 64), (4, 1), (4, 72)])
+def test_llama_rope_lanes_is_rope_bit_for_bit(heads, t, dtype):
+    """The serving step's rotary step on the flat lanes of a projection's
+    output, [B, T, heads * hd], against the strided reference ``rope`` on
+    [B, heads, T, hd]: the same bits, for the widths of
+    q and of grouped K (32 and 4 heads of 64), one decode token a row and
+    a prompt chunk, rows at their own positions."""
+    from ray_tpu.models import llama
+
+    b, hd, theta = 3, 64, 130000.0
+    x = (jax.random.normal(jax.random.PRNGKey(heads + t), (b, t, heads, hd))
+         * 3).astype(dtype)
+    positions = (jnp.asarray([0, 5, 1900])[:, None] + jnp.arange(t))
+
+    def strided(x, positions):
+        return llama.rope(x.transpose(0, 2, 1, 3), positions,
+                          theta).transpose(0, 2, 1, 3)
+
+    def lanes(x, positions):
+        tables = llama.rope_lane_tables(positions, heads, hd, theta)
+        return llama.rope_lanes(x.reshape(b, t, heads * hd),
+                                tables).reshape(x.shape)
+
+    # Bit for bit op by op. Jitted, XLA's CPU backend contracts either
+    # function's products and sum into fused multiply-adds as its fusions
+    # fall, and compiles its own cos / sin: the last place moves, for
+    # both alike.
+    got, want = lanes(x, positions), strided(x, positions)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    ulp = 2.0 ** (-7 if dtype == jnp.bfloat16 else -20)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(lanes)(x, positions).astype(jnp.float32)),
+        np.asarray(jax.jit(strided)(x, positions).astype(jnp.float32)),
+        rtol=ulp, atol=ulp)

@@ -23,11 +23,13 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.llm.engine import build_step_programs
+from ray_tpu.llm.engine import SlotEngine, build_step_programs
 from ray_tpu.models import llama, serving
 from ray_tpu.ops import attention as A
 from ray_tpu.ops import paged_attention as PA
 from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
+from ray_tpu.parallel.sharding import (prune_rules_for_mesh, shardings_for,
+                                       under_mesh)
 
 # [batch, heads, seq, head_dim] of the training cells (bench.py): gpt2-774m
 # and gpt2-1.5b at batch 8 x seq 1024, and the 355M long-context run at 16k.
@@ -154,23 +156,42 @@ SMOLLM2_2L = llama.LlamaConfig(
     vocab_size=49152, max_seq=2048, num_layers=2, num_heads=32,
     num_kv_heads=32, d_model=2048, d_mlp=8192, rope_theta=130000.0,
     dtype=jnp.bfloat16, remat=False)
+# llama-1b's widths (``llama.CONFIGS``): grouped KV heads, so wk and wv
+# are [d, 256] beside wq's [d, d].
+LLAMA1B_2L = llama.LlamaConfig(
+    vocab_size=32000, max_seq=2048, num_layers=2, num_heads=32,
+    num_kv_heads=4, d_model=2048, d_mlp=5632, dtype=jnp.bfloat16,
+    remat=False)
+ENGINE_CONFIGS = {"smollm2": SMOLLM2_2L, "llama-1b": LLAMA1B_2L}
 SLOTS, PAGE, CHUNK = 8, 16, 64
+_COLLECTIVE = re.compile(
+    r"= (.*?) (all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start)?\(")
 
 
-def _engine_program_specs(cfg, sharding):
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+def _engine_program_specs(cfg, sharding, mesh_rules=None):
+    """Shapes of both programs' arguments, every one on ``sharding``;
+    with ``mesh_rules`` = (mesh, rules) the params and the cache are laid
+    over the mesh as ``SlotEngine`` places them."""
+    def sds(shape, dtype, where=sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
 
     # params and cache as the engine gets them: from the family's record
     model = serving.model_for(cfg)
     pages = SLOTS * cfg.max_seq // PAGE + 1
-    params = jax.tree.map(
-        lambda x: sds(x.shape, cfg.dtype),
-        jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0),
-                                                 cfg)[0]))
-    cache = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda: model.init_cache(cfg, pages, PAGE)))
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), cfg)[0])
+    cache = jax.eval_shape(lambda: model.init_cache(cfg, pages, PAGE))
+    if mesh_rules is None:
+        placed = [jax.tree.map(lambda x: sharding, t)
+                  for t in (params, cache)]
+    else:
+        placed = [shardings_for(mesh_rules[0], axes, mesh_rules[1])
+                  for axes in (model.param_axes(), model.cache_axes)]
+    params = jax.tree.map(lambda x, w: sds(x.shape, cfg.dtype, w),
+                          params, placed[0])
+    cache = jax.tree.map(lambda x, w: sds(x.shape, x.dtype, w),
+                         cache, placed[1])
     (pool,) = jax.tree.leaves(cache)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     common = (params, cache, i32(SLOTS, cfg.max_seq // PAGE),
@@ -181,6 +202,23 @@ def _engine_program_specs(cfg, sharding):
     return {"block": fused, "decode_only": common}, pool
 
 
+_COMPILED_STEPS = {}
+
+
+def _compiled_step(v5e, config, program):
+    """``(compiled, pool's shape)`` of one engine program on one chip at
+    a configuration's widths; compiled once for the tests that read it."""
+    if (config, program) not in _COMPILED_STEPS:
+        cfg = ENGINE_CONFIGS[config]
+        specs, pool = _engine_program_specs(
+            cfg, SingleDeviceSharding(v5e[0]))
+        block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
+        fn = block_fn if program == "block" else decode_only_fn
+        _COMPILED_STEPS[config, program] = jax.jit(
+            fn, donate_argnums=(1,)).lower(*specs[program]).compile(), pool
+    return _COMPILED_STEPS[config, program]
+
+
 @pytest.mark.parametrize("program", ["block", "decode_only"])
 def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
     """As compiled for the chip, a step holds the Mosaic kernel and no
@@ -189,12 +227,7 @@ def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
     pool is the layer loop's carry, aliased through the kernel, and never
     laid out again. (Before the kernel each program copied the whole pool
     several times a step and held a temporary the size of it.)"""
-    cfg = SMOLLM2_2L
-    specs, pool = _engine_program_specs(cfg, SingleDeviceSharding(v5e[0]))
-    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
-    fn = block_fn if program == "block" else decode_only_fn
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        *specs[program]).compile()
+    compiled, pool = _compiled_step(v5e, "smollm2", program)
     text = compiled.as_text()
     # decode rows, and in the fused program the prompt chunk's lane
     assert text.count("tpu_custom_call") == (2 if program == "block" else 1)
@@ -210,6 +243,102 @@ def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
     assert not moved, moved
     pool_bytes = pool.dtype.itemsize * math.prod(pool.shape)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
+def _unfused_lines(text):
+    """The lines of a compiled program's text that are instructions of
+    its own computations (the entry, loop bodies and conditions), not of
+    a fusion's: what a fused computation holds is not materialised."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    name, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+        elif name not in fused:
+            out.append(line)
+    return out
+
+
+def _moved_weights(text, cfg, tp=1):
+    """Instructions of the compiled step that copy, slice out or
+    otherwise materialise an array the shape of one layer of a stacked
+    block weight (a device's shard of it under ``tp``), with or without
+    the leading 1, as stored or transposed."""
+    d, m = cfg.d_model, cfg.d_mlp
+    kv = cfg.num_kv_heads * cfg.head_dim
+    layer_shapes = set()
+    for a, b in ((d, d // tp), (d // tp, d), (d, kv // tp), (d, m // tp),
+                 (m // tp, d)):
+        for dims in ((a, b), (b, a)):
+            layer_shapes |= {"%d,%d" % dims, "1,%d,%d" % dims}
+    return [line.strip()[:140] for line in _unfused_lines(text)
+            for mo in [re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* "
+                                r"(copy|transpose|fusion|slice|"
+                                r"dynamic-slice)\(", line)]
+            if mo and mo.group(1) in layer_shapes]
+
+
+@pytest.mark.parametrize("config", list(ENGINE_CONFIGS))
+@pytest.mark.parametrize("program", ["block", "decode_only"])
+def test_engine_programs_read_stacked_weights_where_they_lie(v5e, program,
+                                                              config):
+    """As compiled for the chip, the layer loop holds no ``copy`` and no
+    un-fused slice (``dynamic-slice``, or a fusion that only materialises
+    one) whose result is a layer of a stacked block weight: every matmul
+    reads its layer out of the stacked array inside its own fusion. The
+    parent of PR 30 fails this with 2 copies in ``decode_only_fn`` (the
+    q and k projections) and 3 in ``block_fn`` (q, k and v), each behind
+    a ``constant_dynamic-slice_fusion`` that wrote the slice first: it
+    reshaped q / k / v to [.., heads, hd] for the rotary step, XLA folded
+    that reshape into the projection, and the projection, now batched
+    over heads, wanted its 2048 x 2048 weight transposed, every layer of
+    every step (``models/llama.py rope_lanes``)."""
+    text = _compiled_step(v5e, config, program)[0].as_text()
+    assert text.count("tpu_custom_call") == (2 if program == "block" else 1)
+    moved = _moved_weights(text, ENGINE_CONFIGS[config])
+    assert not moved, moved
+
+
+# (kind, result) of every collective of the tp=2 step, SmolLM2 widths, as
+# PR 30's parent compiled it: the three [tokens, d] sums of a layer (wo,
+# w_down and the embedding lookup's), and the sampler's. No halo exchange
+# (collective-permute), no all-to-all.
+_TOKENS = {"block": "1,%d" % (SLOTS + CHUNK), "decode_only": "%d,1" % SLOTS}
+TP2_COLLECTIVES = {
+    "block": {("all-gather", "f32[2,1,8]"), ("all-gather", "s32[2,1,8]"),
+              ("all-reduce", "(f32[2], f32[2])"),
+              ("all-reduce", "(s32[2], s32[2])"),
+              ("all-reduce", "bf16[%s,2048]" % _TOKENS["block"])},
+    "decode_only": {("all-gather", "f32[2,1,8]"),
+                    ("all-gather", "s32[2,1,8]"),
+                    ("all-reduce", "bf16[%s,2048]" % _TOKENS["decode_only"])},
+}
+
+
+@pytest.mark.parametrize("program", ["block", "decode_only"])
+def test_engine_programs_at_tp2_add_no_collective(v5e, program):
+    """The whole step under ``MeshSpec(tp=2)`` with the engine's rules:
+    the q / k / v lanes are sharded by whole heads and the rotary step
+    shifts lanes, which GSPMD would turn into a halo exchange between the
+    chips; it runs per shard instead. The collectives are the parent's
+    set, the kernel is still there once a lane, and a device's half of a
+    layer's weight is no more copied than the whole is on one chip."""
+    mesh = MeshSpec(tp=2).build(v5e[:2])
+    cfg = SMOLLM2_2L
+    rules = prune_rules_for_mesh(mesh, dict(SlotEngine.SERVE_RULES))
+    specs, _ = _engine_program_specs(cfg, NamedSharding(mesh, P()),
+                                     (mesh, rules))
+    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1, rules)
+    fn = block_fn if program == "block" else decode_only_fn
+    text = under_mesh(mesh, lambda: jax.jit(fn, donate_argnums=(1,)).lower(
+        *specs[program]).compile().as_text())()
+    assert text.count("tpu_custom_call") == (2 if program == "block" else 1)
+    found = {(m.group(2), re.sub(r"\{[^}]*\}", "", m.group(1)))
+             for m in map(_COLLECTIVE.search, text.splitlines()) if m}
+    assert found == TP2_COLLECTIVES[program], found
+    moved = _moved_weights(text, cfg, tp=2)
+    assert not moved, moved
 
 
 def test_paged_kernel_is_sharded_not_partitioned(v5e):
@@ -251,9 +380,6 @@ HEAD_CASES = {
                       parent_temp=1_079_698_432),
 }
 VOCAB = 50304
-_COLLECTIVE = re.compile(
-    r"= (.*?) (all-reduce|all-gather|reduce-scatter|all-to-all)"
-    r"(?:-start)?\(")
 _CALLEE = re.compile(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)")
 
 
