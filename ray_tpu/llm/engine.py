@@ -29,8 +29,9 @@ Design (TPU-first, static shapes throughout):
   cold one — only ``[num_slots]`` int32 tokens cross the device
   boundary per step, never ``[B, vocab]`` logits.
 
-Exactly two compiled programs serve any mix of request lengths; there
-is no shape-dependent recompilation after warmup.
+Exactly two compiled programs serve any mix of request lengths (for a
+family that asks for ``one_program``, the fused one alone); there is no
+shape-dependent recompilation after warmup.
 
 Reference intent matched (and exceeded — the reference never touches
 the accelerator): ``/root/reference/python/ray/serve/_private/replica.py``
@@ -194,8 +195,24 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
     decode_only_fn)``, round the one step of ``cfg``'s family
     (``models/serving.py``). ``SlotEngine`` jits them with the cache
     donated; tests/test_tpu_compile.py compiles the same two for a
-    described chip from shapes alone."""
-    step = serving.model_for(cfg).step
+    described chip from shapes alone.
+
+    A family that names ``step_counters`` returns their counts as a
+    fourth result of its step; they leave the program as further columns
+    of the block's tokens, ``toks_k [K, slots + len(step_counters)]``, so
+    the one fetch that brings the tokens brings them too. A family that
+    names none gets the programs it always got."""
+    model = serving.model_for(cfg)
+    counted = bool(model.step_counters)
+
+    def step(*args):
+        out = model.step(*args, cfg, page_size, rules)
+        return out if counted else out + (None,)
+
+    def with_counts(toks_k, counts_k):
+        """[K, slots] tokens and [K, n] counts side by side."""
+        return (jnp.concatenate([toks_k, counts_k], axis=1) if counted
+                else toks_k)
 
     def block_fn(params, cache, tables, override_vals, override_mask,
                  prev_last, pos, temps, seeds,
@@ -206,30 +223,31 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
         decode batch, so prefill no longer costs a separate full-model
         pass."""
         tokens0 = jnp.where(override_mask, override_vals, prev_last)
-        dec_logits, pre_logits, cache = step(
+        dec_logits, pre_logits, cache, counts = step(
             params, cache, tables, tokens0, pos,
-            (pre_tokens, pre_slot, pre_p0, pre_n_valid), cfg, page_size,
-            rules)
+            (pre_tokens, pre_slot, pre_p0, pre_n_valid))
         tok1 = _sample(dec_logits, temps, seeds, pos + 1)
         pre_tok = _sample(pre_logits[None], pre_temp[None],
                           pre_seed[None],
                           (pre_p0 + pre_n_valid)[None])[0]
 
         if decode_block == 1:  # nothing to scan: trace no second program
-            return tok1[None], tok1, pre_tok, cache
+            return (with_counts(tok1[None], counts[None] if counted
+                                else None), tok1, pre_tok, cache)
 
         def body(carry, _):
             toks, cache, p = carry
-            logits, _, cache = step(params, cache, tables, toks, p, None,
-                                    cfg, page_size, rules)
+            logits, _, cache, c = step(params, cache, tables, toks, p, None)
             nxt = _sample(logits, temps, seeds, p + 1)
-            return (nxt, cache, p + 1), nxt
+            return (nxt, cache, p + 1), (nxt, c)
 
-        (last, cache, _), toks_rest = jax.lax.scan(
+        (last, cache, _), (toks_rest, counts_rest) = jax.lax.scan(
             body, (tok1, cache, pos + 1), None,
             length=decode_block - 1)
         toks_k = jnp.concatenate([tok1[None], toks_rest], axis=0)
-        return toks_k, last, pre_tok, cache
+        if counted:
+            counts_rest = jnp.concatenate([counts[None], counts_rest])
+        return with_counts(toks_k, counts_rest), last, pre_tok, cache
 
     def decode_only_fn(params, cache, tables, override_vals,
                        override_mask, prev_last, pos, temps, seeds):
@@ -240,14 +258,13 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
 
         def body(carry, _):
             toks, cache, p = carry
-            logits, _, cache = step(params, cache, tables, toks, p, None,
-                                    cfg, page_size, rules)
+            logits, _, cache, c = step(params, cache, tables, toks, p, None)
             nxt = _sample(logits, temps, seeds, p + 1)
-            return (nxt, cache, p + 1), nxt
+            return (nxt, cache, p + 1), (nxt, c)
 
-        (last, cache, _), toks_k = jax.lax.scan(
+        (last, cache, _), (toks_k, counts_k) = jax.lax.scan(
             body, (tokens0, cache, pos), None, length=decode_block)
-        return toks_k, last, cache
+        return with_counts(toks_k, counts_k), last, cache
 
     return block_fn, decode_only_fn
 
@@ -264,7 +281,14 @@ class SlotEngine:
     # cumulative per-step accounting, returned by LLMServer.stats()
     STEP_COUNTERS = ("steps_block", "steps_decode_only", "slot_steps",
                      "slot_steps_active", "slot_steps_prefill_wait",
-                     "prefill_tokens", "overshoot_tokens", "kv_pages_read")
+                     "prefill_tokens", "overshoot_tokens", "kv_pages_read",
+                     # what a family's step counts itself (its
+                     # ``step_counters``; zero for a family that names
+                     # none). Expert layers: experts that got a row, summed
+                     # over layers and steps (of held experts x layers x
+                     # steps); rows x picks routed; rows of the fullest
+                     # expert of each layer.
+                     "experts_hit", "expert_rows", "expert_rows_max")
 
     def __init__(self, params, cfg, num_slots: int = 8,
                  chunk: int = 64, seed: int = 0, decode_block: int = 1,
@@ -325,11 +349,19 @@ class SlotEngine:
         self._num_pages = (num_pages if num_pages is not None
                            else num_slots * self._pages_per_seq + 1)
         self._pool = PagePool(self._num_pages)
+        # A family with per-slot state takes no prefix hit: a page does
+        # not hold the state at its boundary (models/serving.py), so
+        # there is no index, every prompt prefills from position 0, and
+        # a session travels as its transcript.
         self._radix: Optional[RadixIndex] = (
-            RadixIndex(self._pool, page_size) if prefix_cache else None)
+            RadixIndex(self._pool, page_size)
+            if prefix_cache and model.slot_state is None else None)
         self._tables = np.zeros((num_slots, self._pages_per_seq),
                                 dtype=np.int32)
         self._cache = model.init_cache(cfg, self._num_pages, page_size)
+        if model.slot_state is not None:
+            self._cache = model.slot_state.attach(cfg, self._cache,
+                                                  num_slots)
         if mesh is not None:
             self._cache = shd.place(mesh, self._cache, model.cache_axes,
                                     self._rules)
@@ -368,6 +400,17 @@ class SlotEngine:
         # warmup() would race a running engine thread's dispatches.
         zero = jnp.zeros((1,), jnp.int32)
         self._cache = self._copy_pages(self._cache, zero, zero)
+        # Per-slot state is zeroed at admission, by a program of its own
+        # that runs between two steps; compiled now, like the page copy.
+        self._reset_slots = None
+        if model.slot_state is not None:
+            self._reset_slots = _maybe_mesh(
+                jax.jit(model.slot_state.reset, donate_argnums=(0,)))
+            self._cache = self._reset_slots(self._cache, zero)
+        unknown = set(model.step_counters) - set(self.STEP_COUNTERS)
+        if unknown:
+            raise ValueError(f"step counters {sorted(unknown)} are not "
+                             "among SlotEngine.STEP_COUNTERS")
         # lag-1 decode pipeline state
         self._inflight = None  # (snapshot, pre_info, toks_k, pre_tok)
         self._last_dev = jnp.zeros((num_slots,), jnp.int32)
@@ -417,6 +460,7 @@ class SlotEngine:
         # pages_per_seq it is the live share of what gathering every
         # table entry reads.
         self.kv_pages_read = 0
+        self.experts_hit = self.expert_rows = self.expert_rows_max = 0
         # The last finished requests' timing: a streamed response
         # carries tokens only, so this is where its stages are read.
         self._timings: deque = deque(maxlen=self.TIMINGS_KEPT)
@@ -656,6 +700,13 @@ class SlotEngine:
                     f"vs engine {ps}")
             transcript = np.asarray(snap["transcript"], dtype=np.int32)
             frames = snap.get("pages_kv")
+            if frames is not None and self._model.slot_state is not None:
+                raise serving.SlotStateError(
+                    "the snapshot carries KV pages, but this model keeps "
+                    "state a slot beside its pages and a page does not "
+                    "hold the state at its boundary: export the session "
+                    "from an engine of this family (transcript only) or "
+                    "drop 'pages_kv', and it re-prefills here")
             n_chunks = int(snap.get("covered_tokens", 0)) // ps
             matched: List[int] = []
             fresh: List[int] = []
@@ -892,6 +943,11 @@ class SlotEngine:
             s.matched_len += n_tok
         self._tables[idx, :n_total] = s.pages
         self._tables[idx, n_total:] = 0
+        if self._reset_slots is not None:
+            # whatever the slot's last request (or a block still in
+            # flight for it) left there is void for this one
+            self._cache = self._reset_slots(
+                self._cache, jnp.asarray([idx], jnp.int32))
         s.prefill_offset = s.matched_len
         s.pos = 0
         hit = s.matched_len > 0
@@ -986,7 +1042,7 @@ class SlotEngine:
             with tracing.step_span("rt.llm.dispatch"):
                 new_block = self._dispatch_block(active, prefill_idx)
         if had_fetch:
-            self._process_fetch()
+            self._process_fetch(sp)
             ran = True
         if new_block is not None:
             self._inflight = new_block
@@ -1033,7 +1089,7 @@ class SlotEngine:
             else:
                 override_vals[i] = s.last_token
         tables = jnp.asarray(self._tables)
-        if prefill_idx is None:
+        if prefill_idx is None and not self._model.one_program:
             # No prompt chunk pending: the cheap pure-decode program.
             toks_k, self._last_dev, self._cache = self._decode_only(
                 self._params, self._cache, tables,
@@ -1045,41 +1101,59 @@ class SlotEngine:
                 s.on_device_chain = True
             return (list(active), None, toks_k, None)
         # Prefill lane: one chunk of one slot's prompt rides the fused
-        # program's first step.
+        # program's first step. With no prompt pending (a family whose
+        # rows repeat bit for bit only within ONE compiled program,
+        # models/serving.py ``one_program``) the lane is empty: n_valid 0
+        # writes nothing, and what it samples nobody reads.
         pre_buf = np.zeros((self.chunk,), dtype=np.int32)
-        s = self._slots[prefill_idx]
-        if s.prefill_start_t == 0.0:
-            s.prefill_start_t = time.monotonic()
-        p0 = s.prefill_offset
-        piece = s.prompt[p0:p0 + self.chunk]
-        n_valid = len(piece)
-        pre_buf[:n_valid] = piece
-        s.prefill_offset = p0 + n_valid
-        final = s.prefill_done
-        if final:
-            s.first_tok_pending = True
-        pre_info = (prefill_idx, s, final)
+        lane_slot = p0 = n_valid = lane_seed = 0
+        lane_temp, pre_info = 0.0, None
+        if prefill_idx is not None:
+            s = self._slots[prefill_idx]
+            if s.prefill_start_t == 0.0:
+                s.prefill_start_t = time.monotonic()
+            p0 = s.prefill_offset
+            piece = s.prompt[p0:p0 + self.chunk]
+            n_valid = len(piece)
+            pre_buf[:n_valid] = piece
+            s.prefill_offset = p0 + n_valid
+            final = s.prefill_done
+            if final:
+                s.first_tok_pending = True
+            pre_info = (prefill_idx, s, final)
+            lane_slot, lane_temp, lane_seed = (prefill_idx, s.temperature,
+                                               s.seed)
         toks_k, self._last_dev, pre_tok, self._cache = self._block(
             self._params, self._cache, tables,
             jnp.asarray(override_vals), jnp.asarray(override_mask),
             self._last_dev, jnp.asarray(pos), jnp.asarray(temps),
             jnp.asarray(seeds),
-            jnp.asarray(pre_buf), jnp.asarray(prefill_idx, jnp.int32),
+            jnp.asarray(pre_buf), jnp.asarray(lane_slot, jnp.int32),
             jnp.asarray(p0, jnp.int32), jnp.asarray(n_valid, jnp.int32),
-            jnp.asarray(s.temperature, jnp.float32),
-            jnp.asarray(s.seed, jnp.int32))
+            jnp.asarray(lane_temp, jnp.float32),
+            jnp.asarray(lane_seed, jnp.int32))
         for i, s in active:
             s.pos += self.decode_block
             s.on_device_chain = True
         return (list(active), pre_info, toks_k, pre_tok)
 
-    def _process_fetch(self) -> None:
+    def _process_fetch(self, step_sp) -> None:
         snapshot, pre_info, toks_k, pre_tok = self._inflight
         self._inflight = None
         with tracing.step_span("rt.llm.fetch"):
             # the lag-1 wait for the device: the block dispatched one
             # step ago is usually ready, so this is a fast fetch
-            arr = np.asarray(toks_k)  # [K, rows]
+            arr = np.asarray(toks_k)  # [K, rows (+ the family's counts)]
+        names = self._model.step_counters
+        if names:
+            # the counts of the block dispatched one step ago, on the
+            # span of the step that fetched them
+            counts = arr[:, self.num_slots:].sum(axis=0)
+            arr = arr[:, :self.num_slots]
+            for name, n in zip(names, counts):
+                setattr(self, name, getattr(self, name) + int(n))
+            if step_sp.recording:
+                step_sp.set(**{k: int(n) for k, n in zip(names, counts)})
         with tracing.step_span("rt.llm.deliver") as sp:
             tokens0, done0 = self.tokens_generated, self.requests_completed
             overshoot = self._deliver_block(snapshot, pre_info, arr,

@@ -1,0 +1,504 @@
+"""LFM2-MoE-architecture LM for the serving engine: gated short
+convolutions among grouped-query attention layers, a dense SwiGLU FFN in
+the leading layers and sigmoid-routed sparse experts in the rest.
+
+The layer, ``u`` the normed input (RMSNorm with the config's ``norm_eps``):
+
+    h = x + Op(RMSNorm_op(x));  y = h + FFN(RMSNorm_ffn(h))
+
+* Op "conv" (``conv_L_cache`` taps, no bias): ``[B, C, X] = split3(W_in
+  u)``, ``z = B * X``, ``c_t = sum_j k[j] * z_{t - (L-1) + j}`` (depthwise,
+  causal, zeros before position 0), ``out = W_out (C * c)``. What a
+  sequence carries from one token to the next is its last ``L - 1`` rows
+  of ``z``: a fixed-size state a SLOT, whatever the sequence's length.
+* Op "full_attention": q / k / v projections without bias, RMSNorm over
+  each q head and each k head (one ``[head_dim]`` weight each), THEN the
+  rotary step, causal softmax, ``W_o``. Its K and V live in pages.
+* FFN of the first ``num_dense_layers`` layers: ``W2(silu(W1 u) * W3 u)``.
+* FFN of the others: ``s = sigmoid(W_g u)`` in float32; ``sel = top_k(s +
+  b)`` with ``b`` the expert bias (selection only); ``w = s[sel]``,
+  normalised to sum 1 (``norm_topk_prob``), times
+  ``routed_scaling_factor``; ``sum_e w_e W2_e(silu(W1_e u) * W3_e u)``.
+  No capacity and no dropped token: rows are sorted by expert and each
+  expert multiplies exactly the rows routed to it
+  (``ops/grouped_matmul.py``).
+
+What it offers the engine (``models/serving.py``): one step over a cache
+of two kinds side by side — ``cache["kv"]``, the page pool of the
+ATTENTION layers only (its leading axis counts attention layers, not
+layers; layout and kernel are ``models/llama.py``'s and
+``ops/paged_attention.py``'s), and ``cache["conv"]``, one ``[slots, L - 1,
+d]`` array a conv layer — and a :class:`serving.SlotState` for the second.
+The layers are unrolled, not scanned: a period holds two kinds of
+operator and the lead another FFN, and an expert layer's weights reach
+the grouped matmul as arrays of their own (sliced out of a stacked array
+they would be copied, 1.2 GB a layer a step).
+
+One program. The TPU's matmuls do not give a row the same bits at 64
+rows as at 128, nor in two programs that multiply the same shapes
+(measured on the chip, PERF.md Findings PR 31: after ONE layer every
+decode row's logits differed between the step with a chunk and the step
+without, by up to 0.014, with the row counts made equal too), and here
+one differing bit in a router's input sooner or later picks another
+expert, after which the sequence is another sequence. A greedy request
+has to repeat, so the family sets ``one_program``: the engine runs the
+step WITH a chunk on every dispatch, an empty one (``n_valid`` 0) when no
+prompt is pending. Within one compiled program a row's bits depend on
+nothing but the row: every product is row-wise, and the grouped product
+takes k whole, one accumulation a tile, wherever the row is sorted to.
+The empty lane costs nothing that is measured: the step is bound by the
+bytes of its weights.
+
+Scope names (``jax.named_scope``; metadata only): ``conv``, ``attn``
+(projections, head norms, rotary step, kernel, ``W_o``), ``mlp`` (the
+lead's dense FFN), ``moe.route``, ``moe.experts``, ``embed``, ``lm_head``.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import paged_attention as paged_attention_op
+from ..ops.grouped_matmul import grouped_matmul
+from . import llama, serving
+from .common import rms_norm
+# the attention layers are the Llama family's paged attention: same pool
+# layout, same kernel, same rotary step on the projections' flat lanes
+from .llama import (PAGED_KV_AXES, _write_and_attend, rope_lane_tables,
+                    rope_lanes)
+
+CONV, ATTN = "conv", "full_attention"
+PERIOD = (ATTN, CONV, CONV, CONV)
+# what a step counts, in this order (SlotEngine.STEP_COUNTERS)
+STEP_COUNTERS = ("experts_hit", "expert_rows", "expert_rows_max")
+
+
+@dataclass(frozen=True)
+class Lfm2Config:
+    vocab_size: int = 65536
+    max_seq: int = 2048
+    d_model: int = 2048
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    layer_types: Tuple[str, ...] = (CONV, CONV) + PERIOD * 9 + (ATTN, CONV)
+    num_dense_layers: int = 2
+    d_mlp: int = 11776            # intermediate_size: the lead's dense FFN
+    d_expert: int = 1536          # moe_intermediate_size
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.num_heads
+
+    def __post_init__(self):
+        unknown = set(self.layer_types) - {CONV, ATTN}
+        if unknown:
+            raise ValueError(f"unknown layer types {sorted(unknown)}")
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("more experts a token than experts")
+
+
+CONFIGS = {
+    # the published LFM2-24B-A2B: 30 conv + 10 attention layers
+    "lfm2-24b-a2b": Lfm2Config(),
+    # one lead layer and one whole period: the CPU tests' size
+    "lfm2-tiny": Lfm2Config(
+        vocab_size=512, max_seq=128, d_model=64, num_heads=4,
+        num_kv_heads=2, layer_types=(CONV,) + PERIOD, num_dense_layers=1,
+        d_mlp=160, d_expert=48, num_experts=8, num_experts_per_tok=2,
+        dtype=jnp.float32),
+}
+
+
+def _layer_kinds(cfg: Lfm2Config):
+    """(operator, "dense" | "moe") of every layer."""
+    return [(op, "dense" if i < cfg.num_dense_layers else "moe")
+            for i, op in enumerate(cfg.layer_types)]
+
+
+def _layer_shapes(cfg: Lfm2Config, op: str, ffn: str) -> Dict[str, tuple]:
+    """name -> (shape, logical axes, init) of one layer's parameters;
+    init is "ones", "bias" or a standard deviation."""
+    d, hd = cfg.d_model, cfg.head_dim
+    kv, e, f = cfg.num_kv_heads * hd, cfg.num_experts, cfg.d_expert
+    out_std = 0.02 / math.sqrt(2 * cfg.num_layers)
+    shapes = {"op_norm": ((d,), (None,), "ones"),
+              "ffn_norm": ((d,), (None,), "ones")}
+    if op == CONV:
+        shapes.update(
+            w_in=((d, 3 * d), ("embed", None), 0.02),
+            # tap j of every channel in row j: conv_k[j] is k[:, j]
+            conv_k=((cfg.conv_L_cache, d), (None, None), 0.3),
+            w_out=((d, d), (None, "embed"), out_std))
+    else:
+        shapes.update(
+            wq=((d, d), ("embed", "qkv"), 0.02),
+            wk=((d, kv), ("embed", "kv"), 0.02),
+            wv=((d, kv), ("embed", "kv"), 0.02),
+            wo=((d, d), ("qkv", "embed"), out_std),
+            q_norm=((hd,), (None,), "ones"),
+            k_norm=((hd,), (None,), "ones"))
+    if ffn == "dense":
+        m = cfg.d_mlp
+        shapes.update(w_gate=((d, m), ("embed", "mlp"), 0.02),
+                      w_up=((d, m), ("embed", "mlp"), 0.02),
+                      w_down=((m, d), ("mlp", "embed"), out_std))
+    else:
+        shapes.update(
+            router=((d, e), ("embed", None), 0.02),
+            expert_bias=((e,), (None,), "bias"),
+            # an expert's W1 (gate) and W3 (up) side by side: columns
+            # [:f] and [f:], so one grouped product reads both
+            w_gate_up=((e, d, 2 * f), (None, "embed", None), 0.02),
+            w_down=((e, f, d), (None, None, "embed"), out_std))
+    return shapes
+
+
+def param_axes(cfg: Lfm2Config = None) -> Dict:
+    cfg = cfg or CONFIGS["lfm2-24b-a2b"]
+    return {"wte": ("vocab", "embed"), "final_norm": (None,),
+            "layers": [{k: axes for k, (_, axes, _) in
+                        _layer_shapes(cfg, op, ffn).items()}
+                       for op, ffn in _layer_kinds(cfg)]}
+
+
+def _draw(key, shape, init, dtype):
+    if init == "ones":
+        return jnp.ones(shape, dtype)
+    # "bias": not zero, so selection by s + b differs from selection by
+    # s ("the bias picks, the score weighs"), and small beside the scores'
+    # own spread, as a bias trained to even the load out is: at 0.1 a few
+    # experts took most rows and a quarter of them none (PERF.md, PR 31)
+    std = 0.01 if init == "bias" else init
+    return (std * jax.random.truncated_normal(
+        key, -2.0, 2.0, shape, jnp.float32)).astype(dtype)
+
+
+def _bulk_key(key):
+    """The caller's (threefry) key as a key of the ``rbg`` generator: the
+    device's own random-bit instruction instead of a few hundred integer
+    operations a word, for the 0.6 G draws of an expert layer."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return jax.random.wrap_key_data(
+        jnp.concatenate([key, key]).astype(jnp.uint32), impl="rbg")
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _init_layer(key, cfg: Lfm2Config, op: str, ffn: str):
+    shapes = _layer_shapes(cfg, op, ffn)
+    keys = jax.random.split(_bulk_key(key), len(shapes))
+    return {name: _draw(k, shape, init, cfg.dtype)
+            for k, (name, (shape, _, init)) in zip(keys, shapes.items())}
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _init_embedding(key, cfg: Lfm2Config):
+    return _draw(_bulk_key(key), (cfg.vocab_size, cfg.d_model), 0.02,
+                 cfg.dtype)
+
+
+def init_params(key, cfg: Lfm2Config) -> Tuple[Dict, Dict]:
+    """Seeded weights in ``cfg.dtype``, drawn by one jitted program a
+    layer (three distinct programs: the kinds of layer there are) and one
+    for the embedding, never op by op and never in float32 first: at the
+    published widths an expert layer is 0.6 G parameters."""
+    keys = jax.random.split(key, cfg.num_layers + 1)
+    params = {
+        "wte": _init_embedding(keys[0], cfg),
+        "final_norm": jnp.ones((cfg.d_model,), cfg.dtype),
+        "layers": [_init_layer(k, cfg, op, ffn)
+                   for k, (op, ffn) in zip(keys[1:], _layer_kinds(cfg))],
+    }
+    return params, param_axes(cfg)
+
+
+# -- the cache: pages for the attention layers, state a slot for the conv ----
+
+def init_cache(cfg: Lfm2Config, num_pages: int, page_size: int):
+    if cfg.max_seq % page_size != 0:
+        raise ValueError(
+            f"page_size ({page_size}) must divide max_seq ({cfg.max_seq})")
+    shape = (cfg.layer_types.count(ATTN), 2, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
+    return {"kv": jnp.zeros(shape, cfg.dtype)}
+
+
+def attach_slot_state(cfg: Lfm2Config, cache, num_slots: int):
+    """The pages' tree with every conv layer's state beside them: the
+    last ``conv_L_cache - 1`` rows of ``z`` of each slot's sequence, zero
+    for a sequence that has not begun."""
+    shape = (num_slots, cfg.conv_L_cache - 1, cfg.d_model)
+    return dict(cache, conv=[jnp.zeros(shape, cfg.dtype)
+                             for _ in range(cfg.layer_types.count(CONV))])
+
+
+def reset_slot_state(cache, slots):
+    """Those slots' conv state zeroed (jit with the cache donated)."""
+    return dict(cache, conv=[c.at[slots].set(0) for c in cache["conv"]])
+
+
+def cache_axes(cfg: Lfm2Config) -> Dict:
+    return {"kv": PAGED_KV_AXES,
+            "conv": [(None, None, None)] * cfg.layer_types.count(CONV)}
+
+
+def copy_pages(cache, src, dst):
+    """``llama.copy_pages`` on the pool; the slots' state is no page."""
+    return dict(cache, **llama.copy_pages(cache, src, dst))
+
+
+def write_pages(cache, dst, values):
+    return dict(cache, **llama.write_pages(cache, dst, values))
+
+
+def check_shardable(cfg: Lfm2Config, tp: int) -> None:
+    if tp > 1:
+        raise ValueError(
+            "the lfm2 family serves on one chip: its expert layer holds "
+            "every expert and no rule shards them yet")
+
+
+# -- the operators ---------------------------------------------------------------
+
+def short_conv(u, state, p, cfg: Lfm2Config, b: int, valid, chunk_at):
+    """The gated short convolution of one layer on a step's rows.
+
+    u [N, d]: rows ``[:b]`` are the decode rows, one token of slot i
+    each; rows ``[b:]`` (if any) are one slot's prompt chunk, in order.
+    state [slots, L - 1, d]: each slot's last L - 1 rows of ``z``.
+    valid [b] bool: decode rows that are not parked. chunk_at: None, or
+    (slot, n_valid) of the chunk; an empty chunk (n_valid 0) leaves its
+    slot's state alone too. Returns (out [N, d], new state): a
+    parked row's state and the state of every slot not in the step are
+    left as they were; the chunk's slot gets the state after its
+    n_valid-th token, which is where its next chunk (or its decode row)
+    starts from."""
+    taps = cfg.conv_L_cache
+    k = p["conv_k"].astype(jnp.float32)
+    gate_b, gate_c, x = jnp.split(u @ p["w_in"].astype(u.dtype), 3, axis=-1)
+    z = gate_b * x                                            # [N, d]
+    zd = z[:b]
+    # a decode row's window: its slot's state, then its own z
+    window = jnp.concatenate([state, zd[:, None]], axis=1)   # [b, L, d]
+    conv = jnp.einsum("bjd,jd->bd", window.astype(jnp.float32), k)
+    new_state = jnp.where(valid[:, None, None], window[:, 1:], state)
+    if chunk_at is not None:
+        slot, n_valid = chunk_at
+        zc = z[b:]
+        c = zc.shape[0]
+        before = jax.lax.dynamic_index_in_dim(state, slot, 0, keepdims=False)
+        zz = jnp.concatenate([before, zc], axis=0)           # [L - 1 + C, d]
+        conv_c = sum(k[j] * zz[j:j + c].astype(jnp.float32)
+                     for j in range(taps))
+        conv = jnp.concatenate([conv, conv_c], axis=0)
+        # rows n_valid .. n_valid + L - 2 of zz are z_{n-L+1} .. z_{n-1}
+        after = jax.lax.dynamic_slice_in_dim(zz, n_valid, taps - 1, 0)
+        kept = jax.lax.dynamic_index_in_dim(new_state, slot, 0,
+                                            keepdims=False)
+        new_state = jax.lax.dynamic_update_slice_in_dim(
+            new_state, jnp.where(n_valid > 0, after, kept)[None], slot, 0)
+    out = (gate_c * conv.astype(u.dtype)) @ p["w_out"].astype(u.dtype)
+    return out, new_state
+
+
+def _head_norm(x, scale, heads: int, eps: float):
+    """RMSNorm over each head's lanes of x [.., heads * hd]."""
+    lead = x.shape[:-1]
+    return rms_norm(x.reshape(lead + (heads, -1)), scale, eps).reshape(
+        x.shape)
+
+
+def route(u, p, cfg: Lfm2Config):
+    """u [N, d] float32, the normed input before it is rounded to the
+    model's dtype -> (experts [N, k] int32, weights [N, k] float32), all
+    in float32 at the highest matmul precision: a bfloat16 score would
+    reorder the 4th and 5th expert far more often than the reference's
+    own near-ties do."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    pick_by = scores
+    if cfg.use_expert_bias:
+        pick_by = scores + p["expert_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(pick_by, cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * cfg.routed_scaling_factor
+
+
+def experts_ffn(u, experts, weights, valid, p, cfg: Lfm2Config):
+    """The routed experts' SwiGLU on u [N, d] -> (out [N, d], counts
+    [3] int32 as STEP_COUNTERS names them).
+
+    Every (row, pick) is one row of a grouped product: sorted by expert,
+    an expert multiplies exactly its own rows, as many as there are —
+    nothing is dropped and nothing is padded to a capacity. Rows that are
+    not ``valid`` (parked decode rows, a chunk's tail) sort behind every
+    expert's and belong to no group, so they cost no product."""
+    n, d = u.shape
+    e, k, f = cfg.num_experts, cfg.num_experts_per_tok, cfg.d_expert
+    flat = jnp.where(valid[:, None], experts, e).reshape(-1)   # [N * k]
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)     # [E]
+    rows = u[order // k]                                       # [N * k, d]
+    hidden = grouped_matmul(rows, p["w_gate_up"].astype(u.dtype), sizes)
+    act = jax.nn.silu(hidden[:, :f]) * hidden[:, f:]
+    y = grouped_matmul(act, p["w_down"].astype(u.dtype), sizes)
+    # what lies behind the last group was never computed: it is whatever
+    # the buffer held, and must not reach a sum even times zero
+    y = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None], y, 0)
+    # back to (row, pick) order; a row's picks are weighed in float32
+    back = jnp.argsort(order)
+    y = y[back].reshape(n, k, d).astype(jnp.float32)
+    w = jnp.where(valid[:, None], weights, 0.0)
+    out = jnp.einsum("nkd,nk->nd", y, w).astype(u.dtype)
+    counts = jnp.stack([(sizes > 0).sum(), sizes.sum(), sizes.max()])
+    return out, counts.astype(jnp.int32)
+
+
+def _dense_ffn(u, p):
+    gate = jax.nn.silu(u @ p["w_gate"].astype(u.dtype))
+    return (gate * (u @ p["w_up"].astype(u.dtype))) @ p["w_down"].astype(
+        u.dtype)
+
+
+def _lm_head(x, params, cfg: Lfm2Config):
+    """[N, d] hidden states -> [N, vocab] float32 logits (tied head)."""
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return jnp.einsum("bd,vd->bv", x, params["wte"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
+
+
+def paged_step(params, cache, tables, tokens, pos, chunk, cfg: Lfm2Config,
+               page_size: int, rules=None):
+    """One continuous-batching step: the contract of
+    ``models/serving.py``'s ``step`` (and of ``llama.paged_step``, whose
+    page tables, row metadata and kernel the attention layers share),
+    with a fourth result: the expert layers' counts, summed over the
+    layers, as :data:`STEP_COUNTERS` names them.
+
+    The rows of a step, all through the same weight products: the B
+    decode rows, then the chunk's C tokens if there is a chunk. A chunk
+    with ``pre_n_valid`` 0 is empty: it writes no page and no state, and
+    its logits mean nothing."""
+    b, s_max = tokens.shape[0], cfg.max_seq
+    h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
+    valid = pos < s_max
+    packed, valid_rows, chunk_at, c = [tokens], [valid], None, 0
+    if chunk is not None:
+        pre_tokens, pre_slot, pre_p0, pre_n_valid = chunk
+        c = pre_tokens.shape[0]
+        n_valid = jnp.clip(jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c)
+        packed.append(pre_tokens)
+        valid_rows.append(jnp.arange(c) < n_valid)
+        chunk_at = (pre_slot, n_valid)
+    valid_rows = jnp.concatenate(valid_rows)
+    with jax.named_scope("embed"):
+        x = params["wte"][jnp.concatenate(packed)].astype(cfg.dtype)
+    # rotary tables and the kernel's row metadata: once a step
+    angles_d = [rope_lane_tables(pos[:, None], n, hd, cfg.rope_theta)
+                for n in (h, hkv)]
+    rows_d = paged_attention_op.row_meta(
+        tables, pos, jnp.where(valid, pos + 1, 0))
+    if chunk is not None:
+        angles_c = [rope_lane_tables((pre_p0 + jnp.arange(c))[None], n, hd,
+                                     cfg.rope_theta) for n in (h, hkv)]
+        rows_c = paged_attention_op.row_meta(
+            jax.lax.dynamic_slice(tables, (pre_slot, 0),
+                                  (1, tables.shape[1])),
+            jnp.reshape(pre_p0, (1,)), jnp.reshape(pre_p0 + n_valid, (1,)))
+
+    def attention(u, kv, p, layer):
+        """u [N, d] -> (out [N, d], pool): decode rows, then the chunk;
+        each writes its own tokens before it attends."""
+        q = _head_norm(u @ p["wq"].astype(u.dtype), p["q_norm"], h,
+                       cfg.norm_eps)
+        k_new = _head_norm(u @ p["wk"].astype(u.dtype), p["k_norm"], hkv,
+                           cfg.norm_eps)
+        v_new = u @ p["wv"].astype(u.dtype)
+        qd, kd = (rope_lanes(a[:b, None], t)
+                  for a, t in zip((q, k_new), angles_d))
+        o, kv = _write_and_attend(qd.reshape(b, 1, h, hd), kd,
+                                  v_new[:b, None], kv, layer, rows_d, cfg,
+                                  page_size, rules)
+        outs = [o[:, 0]]
+        if chunk is not None:
+            qc, kc = (rope_lanes(a[None, b:b + c], t)
+                      for a, t in zip((q, k_new), angles_c))
+            oc, kv = _write_and_attend(qc.reshape(1, c, h, hd), kc,
+                                       v_new[None, b:b + c], kv, layer,
+                                       rows_c, cfg, page_size, rules)
+            outs.append(oc[0])
+        return jnp.concatenate(outs) @ p["wo"].astype(u.dtype), kv
+
+    kv, conv = cache["kv"], list(cache["conv"])
+    counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
+    n_attn = n_conv = 0
+    for p, (op, ffn) in zip(params["layers"], _layer_kinds(cfg)):
+        if op == CONV:
+            with jax.named_scope("conv"):
+                out, conv[n_conv] = short_conv(
+                    rms_norm(x, p["op_norm"], cfg.norm_eps), conv[n_conv],
+                    p, cfg, b, valid, chunk_at)
+            n_conv += 1
+        else:
+            with jax.named_scope("attn"):
+                out, kv = attention(
+                    rms_norm(x, p["op_norm"], cfg.norm_eps), kv, p,
+                    jnp.int32(n_attn))
+            n_attn += 1
+        x = x + out
+        if ffn == "dense":
+            with jax.named_scope("mlp"):
+                x = x + _dense_ffn(rms_norm(x, p["ffn_norm"], cfg.norm_eps),
+                                   p)
+            continue
+        with jax.named_scope("moe.route"):
+            u = rms_norm(x.astype(jnp.float32), p["ffn_norm"], cfg.norm_eps)
+            experts, weights = route(u, p, cfg)
+        with jax.named_scope("moe.experts"):
+            out, layer_counts = experts_ffn(u.astype(x.dtype), experts,
+                                            weights, valid_rows, p, cfg)
+        x = x + out
+        counts = counts + layer_counts
+    cache = {"kv": kv, "conv": conv}
+    if chunk is None:
+        return _lm_head(x[:b], params, cfg), None, cache, counts
+    last = jnp.maximum(pre_n_valid, 1) - 1
+    logits = _lm_head(jnp.concatenate([x[:b], x[b + last][None]], axis=0),
+                      params, cfg)
+    return logits[:b], logits[b], cache, counts
+
+
+# ``param_axes()`` and ``cache_axes`` are read only under a mesh, which
+# ``check_shardable`` refuses for now: they describe the published depth.
+serving.register(serving.ServingModel(
+    config_type=Lfm2Config, configs=CONFIGS, init_params=init_params,
+    param_axes=param_axes, check_shardable=check_shardable,
+    init_cache=init_cache, cache_axes=cache_axes(CONFIGS["lfm2-24b-a2b"]),
+    step=paged_step, copy_pages=copy_pages, write_pages=write_pages,
+    read_pages=llama.read_pages, check_frames=llama.check_frames,
+    slot_state=serving.SlotState(attach=attach_slot_state,
+                                 reset=reset_slot_state),
+    step_counters=STEP_COUNTERS, one_program=True))

@@ -17,16 +17,60 @@ family's own functions look into. The engine owns which PHYSICAL PAGE
 that every invalid write must be routed to. Exported pages travel as
 FRAMES: one host array with the pages along axis 2, so the engine can
 cut and pad a run of pages without knowing the other axes.
+
+Per-slot state. A family may keep, beside its pages, a state of fixed
+size for each of the engine's SLOTS (a short convolution's last inputs,
+a recurrence's carry) and says so with a :class:`SlotState`. The state
+lives in the same cache tree, so the step's signature is the same; the
+engine still looks into neither and keeps four promises about it:
+
+* it zeroes a slot's state (``reset``) when it admits a request into the
+  slot, before the request's first chunk is dispatched;
+* a parked row (``pos >= max_seq``) and the tail of a chunk past
+  ``n_valid`` leave the state as it was: the step sees to that;
+* a prompt's chunks reach one slot in order, each from where the last
+  ended, so the state the step leaves is the state the next chunk (and
+  then the slot's decode row) starts from;
+* pages never travel without the state that goes with them. A page
+  holds what attention needs of a token; the state after that token is
+  not in it. So for such a family the engine TAKES NO PREFIX HIT (it
+  keeps no radix index: every prompt prefills from position 0, and the
+  partial-page copy-on-write of ``copy_pages`` is never asked for), a
+  session is exported as its transcript alone and re-prefills where it
+  is imported, and an import that comes WITH page frames is refused with
+  :class:`SlotStateError`. Keeping a snapshot of the state at each
+  cached page boundary would lift all three; nothing here does yet.
+
+A family without slot state (``slot_state=None``, the default) pays
+nothing: no program, no argument and no branch of its step changes.
 """
 
 from __future__ import annotations
 
 import importlib
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 # Modules of this package that register a ServingModel on import.
-FAMILIES = ("llama",)
+FAMILIES = ("llama", "lfm2")
+
+
+class SlotStateError(ValueError):
+    """Pages were offered without the per-slot state that belongs to
+    them: the family keeps state a slot beside its pages, and a page
+    alone cannot say what that state was at its boundary."""
+
+
+@dataclass(frozen=True)
+class SlotState:
+    """What a family with a fixed-size state per slot adds to its cache
+    contract (the module docstring says what the engine does with it)."""
+    # (cfg, cache, num_slots) -> cache: the pages' tree with the state of
+    # num_slots slots beside them, all zero; ``cache_axes`` describes
+    # this whole tree
+    attach: Callable
+    # (cache, slots [N] int32) -> cache: those slots' state zeroed
+    reset: Callable
 
 
 @dataclass(frozen=True)
@@ -57,6 +101,21 @@ class ServingModel:
     # the frames are pages of such a cache
     read_pages: Callable
     check_frames: Callable
+    # None: the cache is pages alone
+    slot_state: Optional[SlotState] = None
+    # Names of whole-number counts one step returns beside its logits,
+    # as a fourth result [len(step_counters)] int32 (the engine adds
+    # them up under these names and fetches them with the step's tokens).
+    # Empty: the step returns three results, as above.
+    step_counters: Tuple[str, ...] = ()
+    # True: a row's result repeats bit for bit only within ONE compiled
+    # program (the compiler's matmuls round a row differently at other
+    # row counts, and the family amplifies a differing bit: a router that
+    # picks another expert). The engine then runs the fused program on
+    # every step, with an empty chunk (n_valid 0, which the step must
+    # take: nothing written, nothing read) when no prompt is pending, so
+    # a greedy request gives the same tokens whatever else is in flight.
+    one_program: bool = False
 
 
 _MODELS: Dict[type, ServingModel] = {}

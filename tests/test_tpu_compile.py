@@ -24,8 +24,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm.engine import SlotEngine, build_step_programs
-from ray_tpu.models import llama, serving
+from ray_tpu.models import lfm2, llama, serving
 from ray_tpu.ops import attention as A
+from ray_tpu.ops import grouped_matmul as GM
 from ray_tpu.ops import paged_attention as PA
 from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
 from ray_tpu.parallel.sharding import (prune_rules_for_mesh, shardings_for,
@@ -61,6 +62,7 @@ def compiled_for_tpu(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(PA, "_on_tpu", lambda: True)
+    monkeypatch.setattr(GM, "_on_tpu", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -243,6 +245,75 @@ def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
     assert not moved, moved
     pool_bytes = pool.dtype.itemsize * math.prod(pool.shape)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
+# The published LFM2-24B-A2B widths at one lead layer and one whole period
+# (conv + dense FFN, then attention and three conv layers with 64 experts
+# each): what a layer does to the pool and to the slots' state does not
+# depend on how many periods there are. The cell's 64 slots: the step's
+# temporaries grow with the rows of a step, not with the pool.
+LFM2_1P = lfm2.Lfm2Config(
+    layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+    num_dense_layers=1)
+LFM2_SLOTS = 64
+
+
+@pytest.mark.parametrize("program", ["block", "decode_only"])
+def test_lfm2_programs_touch_pool_and_slot_state_only_in_place(v5e, program):
+    """The second family's two step programs are held to what the first's
+    are: the donated cache (the attention layers' page pool AND each conv
+    layer's state a slot) is aliased to the output, no copy or fusion
+    gives a pool-shaped result, and the temporaries are a small fraction
+    of the pool. The experts' grouped products are Mosaic kernels too
+    (``ops/grouped_matmul.py``, two a layer), fed each layer's weights
+    where they lie: no un-fused copy or slice of an expert layer's
+    weights."""
+    cfg = LFM2_1P
+    where = SingleDeviceSharding(v5e[0])
+    model = serving.model_for(cfg)
+    pages = LFM2_SLOTS * cfg.max_seq // PAGE + 1
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), cfg)[0])
+    cache = jax.eval_shape(lambda: model.slot_state.attach(
+        cfg, model.init_cache(cfg, pages, PAGE), LFM2_SLOTS))
+    sds = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=where)
+    params, cache = (jax.tree.map(sds, t) for t in (params, cache))
+    arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=where)
+    common = (params, cache, arg((LFM2_SLOTS, cfg.max_seq // PAGE)),
+              arg((LFM2_SLOTS,)), arg((LFM2_SLOTS,), jnp.bool_), arg((LFM2_SLOTS,)),
+              arg((LFM2_SLOTS,)), arg((LFM2_SLOTS,), jnp.float32), arg((LFM2_SLOTS,)))
+    fused = common + (arg((CHUNK,)), arg(()), arg(()), arg(()),
+                      arg((), jnp.float32), arg(()))
+    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
+    fn, specs = ((block_fn, fused) if program == "block"
+                 else (decode_only_fn, common))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*specs).compile()
+    text = compiled.as_text()
+    # one attention layer's kernel a lane; two grouped products an
+    # expert layer
+    lanes = 2 if program == "block" else 1
+    assert text.count("tpu_custom_call") == lanes + 2 * 4
+    pool = cache["kv"]
+    shapes = {",".join(map(str, pool.shape)),
+              ",".join(map(str, (1,) + pool.shape[1:])),
+              ",".join(map(str, pool.shape[1:]))}
+    e, d, f = cfg.num_experts, cfg.d_model, cfg.d_expert
+    shapes |= {"%d,%d,%d" % dims for dims in ((e, d, 2 * f), (e, f, d))}
+    moved = [line.strip()[:120] for line in _unfused_lines(text)
+             for m in [re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* "
+                                r"(copy|fusion|scatter|gather|transpose|"
+                                r"slice|dynamic-slice|dynamic-update-slice)"
+                                r"\(", line)]
+             if m and m.group(1) in shapes]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.dtype.itemsize * math.prod(x.shape)
+                      for x in jax.tree.leaves(cache))
+    pool_bytes = pool.dtype.itemsize * math.prod(pool.shape)
+    assert mem.alias_size_in_bytes == cache_bytes   # pool and state alike
+    assert mem.temp_size_in_bytes < pool_bytes // 4
 
 
 def _unfused_lines(text):
