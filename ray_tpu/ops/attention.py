@@ -2,18 +2,32 @@
 
 The reference framework has no attention kernels (model code is user-space
 there); this framework ships them because long-context SP/ring attention is
-first-class (SURVEY §5.7). Design follows the standard online-softmax flash
-algorithm, tiled for the MXU:
+first-class (SURVEY §5.7). The standard online-softmax flash algorithm, in
+the form a TPU v5e read fastest (every figure: PERF.md, Findings PR 32,
+kernel alone at ``[8, 20, 1024, 64]`` bfloat16, causal):
 
-  - grid over (batch, query blocks) with ALL heads processed inside each
-    program. At LM training shapes (head_dim 64, seq ~1-8k) the per-head
-    tile work is far smaller than Mosaic's per-program overhead, so a
-    (batch*heads, q-blocks) grid spends most of its time sequencing; head
-    folding raises per-program work ~H× and measured ~4-5× kernel speedup
-  - K/V resident in VMEM per program, streamed in ``block_k`` chunks with
-    running (m, l, acc) online softmax
-  - causal masking skips fully-masked K blocks (block-level early exit)
-  - bf16 matmul operands, fp32 accumulation (``preferred_element_type``)
+  - grid over (batch, head groups): one program holds the whole sequence
+    of q, k and v of its heads in VMEM and loops over heads, then query
+    blocks, then key tiles.
+  - Head dim 64 half-fills the MXU in all seven products (contraction 64,
+    or 64 output lanes), and both kernels run at 75-85 % of that
+    half-filled pace. So their time is the score elements they visit, plus
+    ~0.3 us for EVERY tile of a rolled loop whatever its size: tiles of
+    128 x 128 visit the fewest elements and read 3-4 x slower than tiles
+    of 512 x 512.
+  - Hence two schedules of one walk (``_walk_tiles``). Up to four query
+    blocks are unrolled (STATIC): each block takes the square on its
+    diagonal masked by element and everything before it as ONE unmasked
+    tile (forward 0.61 -> 0.44 ms a call, backward 1.05 -> 0.82 ms).
+    Longer sequences roll both loops over 512-wide tiles: the diagonal's
+    tile first, then a loop over the tiles under it. Tiles above the
+    diagonal are never visited in either.
+  - The backward holds its tiles transposed, [keys, queries], and takes
+    lse and delta as lane-major rows: as ``[.., sq, 1]`` columns XLA pads
+    each to 128 lanes and copies it into that layout before every call
+    (0.12 ms each, outside the kernel).
+  - bf16 matmul operands, fp32 accumulation and softmax statistics; a
+    power-of-two scale is folded into the query, which is exact.
 
 ``flash_attention`` is differentiable end-to-end in Pallas: forward kernel
 plus a fused dq/dk/dv backward kernel (blockwise recompute from the saved
@@ -78,68 +92,140 @@ def mha_reference(q, k, v, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# Pallas forward kernel
+# Pallas kernels. One schedule for both: a query block walks the key tiles
+# it can see. Under the causal mask those are the tile the diagonal crosses
+# (masked by element) and the tiles wholly under it (no mask arithmetic at
+# all); a tile wholly above the diagonal is never visited.
 # ---------------------------------------------------------------------------
 
-def _causal_upper(qi, block_q: int, block_k: int, num_kb: int):
-    """Number of K blocks the online-softmax loop must visit for Q block
-    ``qi`` under causal masking (blocks past the diagonal are all-masked)."""
-    upper = jnp.minimum(
-        num_kb, (qi + 1) * block_q // block_k + (block_q // block_k == 0)
-    )
-    return jnp.maximum(upper, 1)
+# A sequence of at most this many query blocks gets the STATIC schedule:
+# the blocks are unrolled in Python, so each knows its own key extent and
+# takes everything under its diagonal as ONE tile. The chip pays ~0.3 us
+# for every tile of a rolled loop whatever its size (PERF.md, Findings
+# PR 32), so at the training shapes few large tiles beat many small ones.
+# Longer sequences roll both loops: their code must not grow with length.
+_STATIC_MAX_BLOCKS = 4
+# ... and the widest key extent one static tile may span: its float32
+# score tile is [block_q, extent].
+_STATIC_MAX_SK = 2048
+
+
+def _is_static(causal: bool, seq_q: int, seq_k: int, block_q: int) -> bool:
+    return (seq_q // block_q <= _STATIC_MAX_BLOCKS
+            and seq_k <= _STATIC_MAX_SK and (not causal or seq_q == seq_k))
+
+
+def _rows_minus_cols(block_q: int, width: int):
+    """[block_q, width] int32, row - column inside a tile: element (i, j)
+    of the tile at (q0, k0) is visible iff i - j >= k0 - q0."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 1))
+
+
+def _folds_exactly(scale: float) -> bool:
+    """A power of two (1/8 at head dim 64) scales a floating-point query
+    exactly, so it is applied once to the [block_q, d] query and not to
+    every float32 score tile."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _for_each_block(n: int, body, static: bool):
+    """``body(i)`` for every query block: unrolled where ``static`` (``i``
+    is then a Python int), else one rolled loop."""
+    if static:
+        for i in range(n):
+            body(i)
+    else:
+        jax.lax.fori_loop(0, n, lambda i, c: (body(i), c)[1], 0)
+
+
+def _block_rows(qb, block_q: int, static: bool):
+    """(first row, ``pl.ds`` of the rows) of query block ``qb``."""
+    from jax.experimental import pallas as pl
+
+    q0 = qb * block_q
+    return q0, pl.ds(q0 if static else pl.multiple_of(q0, block_q), block_q)
+
+
+def _walk_tiles(tile, carry, q0, block_q: int, block_k: int, seq_k: int,
+                causal: bool, static: bool):
+    """``carry = tile(carry, k0, width, masked)`` over the key tiles the
+    query block at row ``q0`` sees. First the one tile that needs care —
+    under the causal mask the tile the diagonal crosses, where every row
+    sees at least its own position, so a running max starts finite — then
+    the tiles wholly visible, unmasked.
+
+    Static (``q0`` a Python int, ``seq_q == seq_k`` if causal): the tile
+    on the diagonal is [block_q, block_q] and all keys before it are one
+    tile. Rolled: ``block_k``-wide tiles, ``block_q`` dividing ``block_k``
+    so that exactly one tile holds the block's diagonal; a block past the
+    last key (``seq_q > seq_k``) takes the last tile first, all visible.
+    """
+    if static:
+        if not causal:
+            return tile(carry, 0, seq_k, False)
+        carry = tile(carry, q0, block_q, True)
+        return tile(carry, 0, q0, False) if q0 else carry
+    num_kb = seq_k // block_k
+    first = jnp.minimum(q0 // block_k, num_kb - 1) if causal else num_kb - 1
+    carry = tile(carry, first * block_k, block_k, causal)
+    return jax.lax.fori_loop(
+        0, first, lambda kb, c: tile(c, kb * block_k, block_k, False), carry)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      *, block_k: int, seq_k: int, scale: float,
-                      causal: bool, block_q: int, num_heads: int):
+                      *, block_q: int, block_k: int, seq_q: int, seq_k: int,
+                      scale: float, causal: bool, static: bool,
+                      num_heads: int):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(2)
-    num_kb = seq_k // block_k
-    upper = _causal_upper(qi, block_q, block_k, num_kb) if causal else num_kb
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    d = q_ref.shape[-1]
+    fold = _folds_exactly(scale)
+    rel = (_rows_minus_cols(block_q, block_q if static else block_k)
+           if causal else None)
 
     def head_body(hh, _):
-        # CRITICAL for MXU throughput: matmul operands stay in bf16 — only
-        # the accumulator is fp32 (preferred_element_type). Casting inputs
-        # to fp32 first pushes the dots off the fast MXU path (~8x slower).
-        q = q_ref[0, hh]  # [block_q, D], input dtype
+        def q_block(qb):
+            q0, rows = _block_rows(qb, block_q, static)
+            # Matmul operands stay in the input dtype (bf16 on the MXU's
+            # fast path); accumulators and softmax statistics are float32.
+            q = q_ref[0, hh, rows, :]  # [block_q, D]
+            if fold:
+                q = q * scale
 
-        m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((block_q, 1), jnp.float32)
-        acc0 = jnp.zeros((block_q, d), jnp.float32)
+            def tile(carry, k0, width, masked):
+                if not static:
+                    k0 = pl.multiple_of(k0, block_k)
+                k_blk = k_ref[0, hh, pl.ds(k0, width), :]
+                v_blk = v_ref[0, hh, pl.ds(k0, width), :]
+                s = jax.lax.dot_general(
+                    q, k_blk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [block_q, width]
+                if not fold:
+                    s = s * scale
+                if masked:
+                    s = jnp.where(rel >= k0 - q0, s, _NEG_INF)
+                m_new = jnp.max(s, axis=-1, keepdims=True)
+                if carry is not None:
+                    m, l, acc = carry
+                    m_new = jnp.maximum(m, m_new)
+                p = jnp.exp(s - m_new)
+                l_new = jnp.sum(p, axis=-1, keepdims=True)
+                acc_new = jax.lax.dot_general(
+                    p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if carry is not None:
+                    alpha = jnp.exp(m - m_new)
+                    l_new = l * alpha + l_new
+                    acc_new = acc * alpha + acc_new
+                return m_new, l_new, acc_new
 
-        def body(kb, carry):
-            m, l, acc = carry
-            k_blk = k_ref[0, hh, pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[0, hh, pl.ds(kb * block_k, block_k), :]
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [block_q, block_k] fp32
-            if causal:
-                k_pos = (
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1)
-                    + kb * block_k
-                )
-                s = jnp.where(q_pos + qi * block_q >= k_pos, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = acc * alpha + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, acc_new
+            m, l, acc = _walk_tiles(tile, None, q0, block_q, block_k, seq_k,
+                                    causal, static)
+            # l >= 1: the row's largest visible score contributes exp(0)
+            o_ref[0, hh, rows, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
+            lse_ref[0, hh, rows, :] = m + jnp.log(l)  # [block_q, 1]
 
-        m, l, acc = jax.lax.fori_loop(0, upper, body, (m0, l0, acc0))
-        safe_l = jnp.where(l == 0, 1.0, l)
-        o_ref[0, hh] = (acc / safe_l).astype(o_ref.dtype)
-        lse_ref[0, hh] = m + jnp.log(safe_l)  # [block_q, 1]
+        _for_each_block(seq_q // block_q, q_block, static)
         return 0
 
     jax.lax.fori_loop(0, num_heads, head_body, 0)
@@ -170,171 +256,124 @@ def _compiler_params(interpret: bool):
     return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _flash_fwd_single_pass_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                                  *, seq_k: int, scale: float, causal: bool,
-                                  block_q: int, num_heads: int):
-    """Short-sequence forward: the whole K/V fits VMEM, so compute the full
-    [block_q, seq_k] score tile with ONE dot and a single softmax pass —
-    no online-softmax carry chain (whose per-K-block VPU rescales dominate
-    at seq ~1k where there are only 1-2 K blocks anyway)."""
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(2)
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, seq_k), 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, seq_k), 1)
-
-    def head_body(hh, _):
-        q = q_ref[0, hh]          # [block_q, d]
-        k = k_ref[0, hh]          # [seq_k, d]
-        v = v_ref[0, hh]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = jnp.where(q_pos + qi * block_q >= k_pos, s, _NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        safe_l = jnp.where(l == 0, 1.0, l)
-        o_ref[0, hh] = (o / safe_l).astype(o_ref.dtype)
-        lse_ref[0, hh] = m + jnp.log(safe_l)
-        return 0
-
-    jax.lax.fori_loop(0, num_heads, head_body, 0)
-
-
-# Below this K length the single-pass forward kernel (full score tile in
-# VMEM) wins over the online-softmax loop.
-_SINGLE_PASS_MAX_SK = 2048
+def _one_diagonal_tile(causal, static, block_q, block_k):
+    """The rolled causal walk wants ``block_q`` to divide ``block_k``."""
+    if causal and not static and block_k % block_q != 0:
+        return block_k
+    return block_q
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
                       block_q: int, block_k: int, interpret: bool):
+    """ONE pallas call, (q, k, v) -> (o, lse[b, h, sq, 1]); the blocks
+    must divide the sequences."""
     from jax.experimental import pallas as pl
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    static = _is_static(causal, sq, sk, block_q)
+    block_q = _one_diagonal_tile(causal, static, block_q, block_k)
     esize = q.dtype.itemsize
-    # q + o blocks, full-seq k + v, lse; ×2 for pipeline double-buffering.
-    per_head = 2 * (2 * block_q * d * esize + 2 * sk * d * esize
-                    + 4 * block_q)
+    # whole-sequence q, o, k, v and the lse column (a [sq, 1] float32
+    # block is padded to 128 lanes in VMEM); x2 for double-buffering.
+    per_head = 2 * ((2 * sq + 2 * sk) * d * esize + sq * 128 * 4)
     hb = _pick_head_block(h, per_head)
-    grid = (b, h // hb, sq // block_q)
 
-    if sk <= _SINGLE_PASS_MAX_SK:
-        kernel = functools.partial(
-            _flash_fwd_single_pass_kernel, seq_k=sk, scale=scale,
-            causal=causal, block_q=block_q, num_heads=hb,
-        )
-    else:
-        kernel = functools.partial(
-            _flash_fwd_kernel, block_k=block_k, seq_k=sk, scale=scale,
-            causal=causal, block_q=block_q, num_heads=hb,
-        )
-    out_shape = [
-        jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-    ]
-    in_specs = [
-        pl.BlockSpec((1, hb, block_q, d), lambda i, g, j: (i, g, j, 0)),
-        pl.BlockSpec((1, hb, sk, d), lambda i, g, j: (i, g, 0, 0)),
-        pl.BlockSpec((1, hb, sk, d), lambda i, g, j: (i, g, 0, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, hb, block_q, d), lambda i, g, j: (i, g, j, 0)),
-        pl.BlockSpec((1, hb, block_q, 1), lambda i, g, j: (i, g, j, 0)),
-    ]
+    full_q = pl.BlockSpec((1, hb, sq, d), lambda i, g: (i, g, 0, 0))
+    full_k = pl.BlockSpec((1, hb, sk, d), lambda i, g: (i, g, 0, 0))
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        functools.partial(
+            _flash_fwd_kernel, block_q=block_q, block_k=block_k, seq_q=sq,
+            seq_k=sk, scale=scale, causal=causal, static=static,
+            num_heads=hb),
+        grid=(b, h // hb),
+        in_specs=[full_q, full_k, full_k],
+        out_specs=[full_q,
+                   pl.BlockSpec((1, hb, sq, 1), lambda i, g: (i, g, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
     )(q, k, v)
     return o, lse.reshape(b, h, sq)
 
 
-# ---------------------------------------------------------------------------
-# Pallas backward kernels: dq (grid over Q blocks) + dk/dv (grid over K
-# blocks). P/dS tiles live in VMEM — the XLA-recompute fallback materializes
-# them to HBM, which dominates attention cost at training shapes.
-# ---------------------------------------------------------------------------
-
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                             block_q: int, block_k: int, seq_q: int,
                             seq_k: int, scale: float, causal: bool,
-                            num_heads: int):
-    """dq + dk + dv in ONE pallas program (per (batch, head-group)).
+                            static: bool, num_heads: int):
+    """dq + dk + dv in ONE pallas program (per (batch, head-group)): it
+    walks Q blocks, recomputes P per (Q, K) tile from the saved LSE — no
+    S x S array anywhere — and accumulates dk/dv into fp32 VMEM scratch
+    across the Q loop.
 
-    Every pallas_call costs a large fixed launch overhead on TPU relative
-    to this kernel's work, so the two classic backward kernels (dq gridded
-    over Q blocks, dk/dv gridded over K blocks) are fused: one program
-    walks Q blocks, recomputes P per (Q,K) tile from the saved LSE, and
-    accumulates dk/dv into fp32 VMEM scratch across the Q loop.
-    """
+    Tiles are held TRANSPOSED, [keys, queries]: lse and delta then are
+    lane-major rows (``[b, h, sq // block_q, block_q]`` operands, dense in
+    HBM; as ``[.., sq, 1]`` columns XLA pads each to 128 lanes and copies
+    it into that layout before every call), and dv = P^T dO and
+    dk = dS^T Q are plain products; only dq = dS K contracts the tile's
+    leading axis. With a folded scale the scaled query gives S and carries
+    the scale into dk; dq takes it once a block."""
     from jax.experimental import pallas as pl
 
-    num_qb = seq_q // block_q
-    num_kb = seq_k // block_k
     d = q_ref.shape[-1]
-    q_pos0 = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos0 = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    fold = _folds_exactly(scale)
+    # [keys, queries]: query position minus key position inside a tile
+    rel = (-_rows_minus_cols(block_q if static else block_k, block_q)
+           if causal else None)
 
     def head_body(hh, _):
         dk_acc[...] = jnp.zeros((seq_k, d), jnp.float32)
         dv_acc[...] = jnp.zeros((seq_k, d), jnp.float32)
 
-        def q_body(qb, _q):
-            q = q_ref[0, hh, pl.ds(qb * block_q, block_q), :]
-            do = do_ref[0, hh, pl.ds(qb * block_q, block_q), :]
-            lse = lse_ref[0, hh, pl.ds(qb * block_q, block_q), :]
-            delta = delta_ref[0, hh, pl.ds(qb * block_q, block_q), :]
-            upper = (_causal_upper(qb, block_q, block_k, num_kb)
-                     if causal else num_kb)
+        def q_block(qb):
+            q0, rows = _block_rows(qb, block_q, static)
+            q = q_ref[0, hh, rows, :]
+            if fold:
+                q = q * scale
+            do = do_ref[0, hh, rows, :]
+            lse = lse_ref[0, hh, pl.ds(qb, 1), :]  # [1, block_q]
+            delta = delta_ref[0, hh, pl.ds(qb, 1), :]
 
-            def k_body(kb, dq_part):
-                k_blk = k_ref[0, hh, pl.ds(kb * block_k, block_k), :]
-                v_blk = v_ref[0, hh, pl.ds(kb * block_k, block_k), :]
+            def tile(dq_part, k0, width, masked):
+                if not static:
+                    k0 = pl.multiple_of(k0, block_k)
+                cols = pl.ds(k0, width)
+                k_blk = k_ref[0, hh, cols, :]
+                v_blk = v_ref[0, hh, cols, :]
                 s = jax.lax.dot_general(
-                    q, k_blk, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if causal:
-                    s = jnp.where(
-                        q_pos0 + qb * block_q >= k_pos0 + kb * block_k,
-                        s, _NEG_INF)
-                p = jnp.exp(s - lse)  # [bq, bk] fp32
-                p_lo = p.astype(do.dtype)
+                    k_blk, q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [width, block_q]
+                if not fold:
+                    s = s * scale
+                if masked:
+                    s = jnp.where(rel >= k0 - q0, s, _NEG_INF)
+                p = jnp.exp(s - lse)
                 dp = jax.lax.dot_general(
-                    do, v_blk, (((1,), (1,)), ((), ())),
+                    v_blk, do, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                ds = (p * (dp - delta) * scale).astype(q.dtype)
-                dv_acc[pl.ds(kb * block_k, block_k), :] += (
-                    jax.lax.dot_general(
-                        p_lo, do, (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
-                dk_acc[pl.ds(kb * block_k, block_k), :] += (
-                    jax.lax.dot_general(
-                        ds, q, (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
+                ds = p * (dp - delta)
+                if not fold:
+                    ds = ds * scale
+                ds = ds.astype(q.dtype)
+                dv_acc[cols, :] += jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dk_acc[cols, :] += jax.lax.dot_general(
+                    ds, q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
                 return dq_part + jax.lax.dot_general(
-                    ds, k_blk, (((1,), (0,)), ((), ())),
+                    ds, k_blk, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
-            dq = jax.lax.fori_loop(
-                0, upper, k_body, jnp.zeros((block_q, d), jnp.float32))
-            dq_ref[0, hh, pl.ds(qb * block_q, block_q), :] = (
-                dq.astype(dq_ref.dtype))
-            return 0
+            dq = _walk_tiles(tile, jnp.zeros((block_q, d), jnp.float32),
+                             q0, block_q, block_k, seq_k, causal, static)
+            if fold:
+                dq = dq * scale
+            dq_ref[0, hh, rows, :] = dq.astype(dq_ref.dtype)
 
-        jax.lax.fori_loop(0, num_qb, q_body, 0)
+        _for_each_block(seq_q // block_q, q_block, static)
         dk_ref[0, hh] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, hh] = dv_acc[...].astype(dv_ref.dtype)
         return 0
@@ -344,15 +383,18 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
                       block_q, block_k, interpret):
+    """ONE pallas call, (q, k, v, do, lse, delta) -> (dq, dk, dv); the
+    blocks must divide the sequences."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    lse4 = lse.reshape(b, h, sq, 1)
-    delta4 = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                     axis=-1, keepdims=True)  # [b, h, sq, 1] fp32
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    static = _is_static(causal, sq, sk, block_q)
+    block_q = _one_diagonal_tile(causal, static, block_q, block_k)
+    # a row of block_q lanes a query block: see the kernel
+    per_block = (b, h, sq // block_q, block_q)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     esize = q.dtype.itemsize
     # Full-seq q/k/v/do in, dq/dk/dv out, double-buffered, plus fp32
     # compiler temps for the tile chain — empirically ~5.5MB/head at
@@ -361,47 +403,60 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
     hb = _pick_head_block(h, per_head)
 
     full_q = pl.BlockSpec((1, hb, sq, d), lambda i, g: (i, g, 0, 0))
-    full_q1 = pl.BlockSpec((1, hb, sq, 1), lambda i, g: (i, g, 0, 0))
     full_k = pl.BlockSpec((1, hb, sk, d), lambda i, g: (i, g, 0, 0))
-
-    from jax.experimental.pallas import tpu as pltpu
-    scratch = [pltpu.VMEM((sk, d), jnp.float32),
-               pltpu.VMEM((sk, d), jnp.float32)]
-
-    dq, dk, dv = pl.pallas_call(
+    q_rows = pl.BlockSpec((1, hb) + per_block[2:], lambda i, g: (i, g, 0, 0))
+    return pl.pallas_call(
         functools.partial(_flash_bwd_fused_kernel, block_q=block_q,
                           block_k=block_k, seq_q=sq, seq_k=sk, scale=scale,
-                          causal=causal, num_heads=hb),
+                          causal=causal, static=static, num_heads=hb),
         grid=(b, h // hb),
-        in_specs=[full_q, full_k, full_k, full_q, full_q1, full_q1],
+        in_specs=[full_q, full_k, full_k, full_q, q_rows, q_rows],
         out_specs=[full_q, full_k, full_k],
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((sk, d), jnp.float32),
+                        pltpu.VMEM((sk, d), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
-    )(q, k, v, do, lse4, delta4)
-
-    return dq, dk, dv
+    )(q, k, v, do, lse.reshape(per_block), delta.reshape(per_block))
 
 
 # ---------------------------------------------------------------------------
 # Differentiable wrapper: pallas forward, blockwise-recompute backward.
 # ---------------------------------------------------------------------------
 
+def _fwd(q, k, v, causal, scale, block_q, block_k):
+    """The forward at the callers' blocks: 512-row query blocks read
+    fastest on the chip in both schedules (PERF.md, Findings PR 32)."""
+    return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
+                             interpret=not _on_tpu())
+
+
+# The backward's query block where the static schedule can take it: four
+# blocks of 256 visit 0.655 M score elements a head at seq 1024 against
+# 0.786 M for two of 512, and read 15 % faster on the chip (ibid.).
+_BWD_STATIC_BLOCK_Q = 256
+
+
+def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
+    want = _BWD_STATIC_BLOCK_Q
+    if block_q % want == 0 and _is_static(causal, q.shape[2], k.shape[2],
+                                          want):
+        block_q = want
+    return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q,
+                             block_k, interpret=not _on_tpu())
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, scale, block_q, block_k):
-    o, _ = _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                             interpret=not _on_tpu())
-    return o
+    return _fwd(q, k, v, causal, scale, block_q, block_k)[0]
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                               interpret=not _on_tpu())
+    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k)
     # Named so remat policies (gpt2 "dots_attn") can save BOTH outputs:
     # with o and lse saved, the rematerialized forward's kernel call is
     # dead code and the backward never re-runs flash.
@@ -415,9 +470,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
     the saved LSE (no S×S materialization across blocks) with bf16 matmul
     operands and fp32 accumulation. ``flash_attention`` admits only
     shapes the blocks divide, so there is no other path."""
-    q, k, v, o, lse = res
-    return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
-                             block_q, block_k, interpret=not _on_tpu())
+    return _bwd(*res, do, causal, scale, block_q, block_k)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -505,5 +558,4 @@ def attention_with_lse(q, k, v, causal: bool = True,
     if _use_reference(impl, q, k, causal, block_q, block_k):
         return mha_reference_with_lse(q, k, v, causal=causal, scale=scale)
     bq, bk = _blocks_or_raise(q, k, causal, block_q, block_k)
-    return _flash_fwd_pallas(q, k, v, causal, scale, bq, bk,
-                             interpret=not _on_tpu())
+    return _fwd(q, k, v, causal, scale, bq, bk)

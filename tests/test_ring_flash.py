@@ -1,6 +1,8 @@
 """Ring-flash parity: the flash-block ring body must match full
 attention and the einsum ring body (VERDICT r4 item 5)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -45,8 +47,6 @@ def test_ring_flash_pallas_kernel_interpret():
     CPU) inside the ring merge — impl='auto' would silently fall back
     to the reference path off-TPU and leave the kernel's lse contract
     uncovered."""
-    import functools
-
     from ray_tpu.parallel.ring import ring_flash_attention_local
     from ray_tpu.parallel.sharding import smap
     from jax.sharding import PartitionSpec as P
@@ -81,6 +81,25 @@ def test_reference_with_lse_consistent():
     np.testing.assert_allclose(np.asarray(lse), want, atol=1e-3)
 
 
+def _loss_and_grads(fn, q, k, v):
+    """(o, (dq, dk, dv)) of ``(o * cos(o)).sum()`` through ``fn``."""
+    def loss(q, k, v):
+        o = fn(q, k, v)
+        return (o * jnp.cos(o)).sum(), o
+
+    (_, o), g = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return o, g
+
+
+def _assert_matches_reference(o, g, o_ref, g_ref):
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               atol=2e-5, rtol=2e-5)
+    for got, want in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-4, rtol=2e-4)
+
+
 @pytest.mark.parametrize("sharded", [False, True])
 def test_flash_kernel_output_and_grads_match_reference(sharded):
     """The flash forward and the fused dq/dk/dv backward kernel
@@ -96,18 +115,84 @@ def test_flash_kernel_output_and_grads_match_reference(sharded):
     if sharded:
         kw = dict(mesh=MeshSpec(fsdp=2, tp=2).build(jax.devices()[:4]),
                   spec=P("fsdp", "tp", None, None))
+    o_ref, g_ref = _loss_and_grads(
+        functools.partial(attention, causal=True, impl="reference"), q, k, v)
+    o, g = _loss_and_grads(
+        functools.partial(attention, causal=True, impl="flash", **kw),
+        q, k, v)
+    _assert_matches_reference(o, g, o_ref, g_ref)
 
-    def loss(impl, q, k, v, **kw):
-        o = attention(q, k, v, causal=True, impl=impl, **kw)
-        return (o * jnp.cos(o)).sum(), o
 
-    grad = lambda impl, **kw: jax.jit(jax.value_and_grad(
-        lambda q, k, v: loss(impl, q, k, v, **kw), argnums=(0, 1, 2),
-        has_aux=True))(q, k, v)
-    (_, o_ref), g_ref = grad("reference")
-    (_, o), g = grad("flash", **kw)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+# Several tiles on each side. Seq 512 in 128-wide blocks is four query
+# blocks, the most that get the STATIC schedule (each block takes the keys
+# under its diagonal as one unmasked tile and its own diagonal square
+# masked); seq 1024 in 128-wide blocks is eight, so both loops are ROLLED
+# (128- or 256-wide key tiles: the one the diagonal crosses masked, those
+# under it not, those above it never visited). d = 32 gives scale
+# 1/sqrt(32), not a power of two: the scale stays on the scores; d = 64
+# gives 1/8, folded into the query.
+@pytest.mark.parametrize("seq,d,causal,block_q,block_k", [
+    (512, 64, True, 128, 128), (512, 64, False, 128, 128),
+    (512, 32, True, 128, 128), (512, 32, False, 128, 128),
+    (512, 64, True, 256, 128), (512, 32, True, 128, 256),
+    (1024, 64, True, 128, 128), (1024, 32, False, 128, 128),
+    (1024, 32, True, 128, 256), (1024, 64, True, 256, 128),
+])
+def test_flash_kernel_tiled_grid_matches_reference(seq, d, causal, block_q,
+                                                   block_k):
+    from ray_tpu.ops.attention import attention_with_lse, flash_attention
+
+    q, k, v = _qkv(b=1, h=2, s=seq, d=d, seed=13)
+    o_ref, g_ref = _loss_and_grads(
+        functools.partial(mha_reference, causal=causal), q, k, v)
+    o, g = _loss_and_grads(
+        functools.partial(flash_attention, causal=causal, block_q=block_q,
+                          block_k=block_k), q, k, v)
+    _assert_matches_reference(o, g, o_ref, g_ref)
+    _, lse = attention_with_lse(q, k, v, causal=causal, impl="flash",
+                                block_q=block_q, block_k=block_k)
+    _, lse_ref = mha_reference_with_lse(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                atol=2e-5, rtol=2e-5)
-    for got, want in zip(g, g_ref):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-4, rtol=2e-4)
+
+
+def _causal_walk(q, k, v, do):
+    """(o, dq, dk, dv) of the causal kernels in 128-wide blocks."""
+    from ray_tpu.ops.attention import flash_attention
+
+    def run(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128), q, k, v)
+        return (o,) + vjp(do)
+    return jax.jit(run)(q, k, v, do)
+
+
+# 512: the static schedule; 1024: the rolled one (see above)
+@pytest.mark.parametrize("seq", [512, 1024])
+def test_causal_kernels_never_visit_a_key_tile_above_the_diagonal(seq):
+    """NaN in V's LAST key block: a tile that is visited and masked gives
+    0 x NaN = NaN, a tile never visited gives nothing. Every query row
+    before that block must come out finite, in o (forward) and in dq
+    (backward: dP = dO V^T is NaN wherever that tile is computed)."""
+    q, k, v = _qkv(b=1, h=2, s=seq, d=64, seed=17)
+    last = seq - 128
+    v = v.at[:, :, last:, :].set(jnp.nan)
+    o, dq, _, _ = _causal_walk(q, k, v, jnp.ones_like(q))
+    assert np.isfinite(np.asarray(o[:, :, :last])).all()
+    assert np.isfinite(np.asarray(dq[:, :, :last])).all()
+    # and the witness can fail: the rows that do see the block are NaN
+    assert np.isnan(np.asarray(o[:, :, last:])).all()
+
+
+@pytest.mark.parametrize("seq", [512, 1024])
+def test_causal_backward_joins_no_query_tile_above_the_diagonal(seq):
+    """The mirror for dk / dv: q and dO of the FIRST query block NaN.
+    Only tiles above the diagonal join that block to a later key block,
+    so dk and dv of every key block but the first stay finite."""
+    q, k, v = _qkv(b=1, h=2, s=seq, d=64, seed=19)
+    do = jnp.ones_like(q).at[:, :, :128, :].set(jnp.nan)
+    q = q.at[:, :, :128, :].set(jnp.nan)
+    _, _, dk, dv = _causal_walk(q, k, v, do)
+    assert np.isfinite(np.asarray(dk[:, :, 128:])).all()
+    assert np.isfinite(np.asarray(dv[:, :, 128:])).all()
+    assert np.isnan(np.asarray(dk[:, :, :128])).all()

@@ -33,11 +33,13 @@ from ray_tpu.parallel.sharding import (prune_rules_for_mesh, shardings_for,
                                        under_mesh)
 
 # [batch, heads, seq, head_dim] of the training cells (bench.py): gpt2-774m
-# and gpt2-1.5b at batch 8 x seq 1024, and the 355M long-context run at 16k.
+# and gpt2-1.5b at batch 8 x seq 1024, and the 355M long-context run at 16k;
+# gpt2-xl.pretrain_1k_fsdp4's shape a device (batch 24 over fsdp=4).
 TRAIN_SHAPES = {
     "gpt2-774m": (8, 20, 1024, 64),
     "gpt2-1.5b": (8, 25, 1024, 64),
     "seq-16k": (1, 16, 16384, 64),
+    "gpt2-xl-fsdp4": (6, 25, 1024, 64),
 }
 
 
@@ -71,11 +73,36 @@ def compiled_for_tpu(monkeypatch):
     compilation_cache.reset_cache()
 
 
+def _compiled(fn, *specs) -> str:
+    """``fn`` compiled for the specs' (described) devices, as HLO text."""
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
 def _kernels(fn, *specs) -> int:
-    """Compile ``fn`` for the specs' (described) devices; how many
-    Mosaic kernels the compiled program holds."""
-    return jax.jit(fn).lower(*specs).compile().as_text().count(
-        "tpu_custom_call")
+    """How many Mosaic kernels the compiled ``fn`` holds."""
+    return _compiled(fn, *specs).count("tpu_custom_call")
+
+
+def _kernel_shapes(text):
+    """Each Mosaic kernel of a compiled program as the benchmark's trace
+    reduction hands it on (``parse_op``: outputs and operands with their
+    shapes). A trace event spells the operands' shapes inside the call;
+    compiled text has them in ``operand_layout_constraints``."""
+    from benchmark.trace.reduce import parse_op
+
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        head, _, rest = line.strip().partition(" custom-call(")
+        operands = rest.partition("operand_layout_constraints={")[2]
+        out.append(parse_op(
+            f"{head} custom-call({operands.partition('}}')[0]}}})"))
+    return out
+
+
+def _flash_loss(q, k, v):
+    return A.flash_attention(q, k, v).astype(jnp.float32).sum()
 
 
 def test_described_chip_is_the_one_in_the_peaks_table(v5e):
@@ -89,11 +116,26 @@ def test_flash_forward_and_backward_compile(v5e, name):
                              sharding=SingleDeviceSharding(v5e[0]))
     assert _kernels(lambda q, k, v: A.flash_attention(q, k, v), x, x, x) == 1
 
-    def loss(q, k, v):
-        return A.flash_attention(q, k, v).astype(jnp.float32).sum()
-
     # the forward that saves (o, lse) and the fused dq/dk/dv backward
-    assert _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 2
+    assert _kernels(jax.grad(_flash_loss, argnums=(0, 1, 2)), x, x, x) == 2
+
+
+@pytest.mark.parametrize("name", list(TRAIN_SHAPES))
+def test_flash_kernels_are_what_the_benchmark_looks_for(v5e, name):
+    """``kernel.flash_roofline`` and ``kernel.flash_share`` find the two
+    kernels in a trace by the shapes of their operands and results
+    (``benchmark/trace/opsbytes.py classify_flash``): a scalar-prefetch
+    operand in front of q, a backward split in two or another layout of
+    q / k / v would blind both metrics on both train cells."""
+    from benchmark.trace.opsbytes import classify_flash
+
+    b, h, sq, d = TRAIN_SHAPES[name]
+    x = jax.ShapeDtypeStruct((b, h, sq, d), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+
+    text = _compiled(jax.grad(_flash_loss, argnums=(0, 1, 2)), x, x, x)
+    assert sorted(classify_flash(k) for k in _kernel_shapes(text)) == [
+        ("bwd", b, h, sq, sq, d), ("fwd", b, h, sq, sq, d)]
 
 
 @pytest.mark.parametrize("causal", [True, False])
