@@ -231,7 +231,10 @@ def serve_phase(found: dict, seed: int) -> dict:
             f"{stats['steps_decode_only']} decode-only steps, "
             f"{stats['slot_steps_active']} of {stats['slot_steps']} "
             f"slot-steps decoded, {stats['overshoot_tokens']} tokens "
-            f"overshot; {stats['kv_pages_read']} KV pages read a layer")
+            f"overshot; {stats['kv_pages_read']} KV pages read a layer; "
+            f"{stats['prefill_tokens']} prompt tokens through a lane of "
+            f"{stats['prefill_lane']}, prefill_lane_fill "
+            f"{stats['prefill_lane_fill']:.3f}")
         return stats["device"]
     finally:
         try:

@@ -21,7 +21,12 @@ Design (TPU-first, static shapes throughout):
   their garbage writes are routed to the reserved scratch page.
 - The fused program additionally runs one fixed-size prompt chunk in
   the same params read (chunked prefill), so a long prompt admission
-  adds bounded latency to in-flight decodes.
+  adds bounded latency to in-flight decodes. The chunk's length is the
+  width of the PREFILL LANE, ``chunk``: a caller's number, or with
+  ``chunk=None`` what :func:`prefill_lane` derives from the family's
+  record, the device's peaks and ``cfg`` — as many rows as the step's
+  one read of the weights multiplies for nothing on this chip, where
+  only a step with a prompt in hand carries the lane.
 - Sampling is fused into both programs and is DETERMINISTIC PER
   REQUEST: token q of a request is drawn with
   ``fold_in(PRNGKey(request_seed), q)``, so a prefix-hit admission
@@ -40,6 +45,7 @@ request plane + ``/root/reference/python/ray/serve/batching.py``.
 
 from __future__ import annotations
 
+import math
 import queue
 import threading
 import time
@@ -55,6 +61,7 @@ from ..core.exceptions import EngineStoppedError
 from ..models import serving
 from ..observability import tracing
 from ..parallel import sharding as shd
+from ..parallel.mesh import DEVICE_PEAKS
 from .paged import OverloadedError, PagePool, RadixIndex, llm_metrics
 
 # Interned tag keys for the per-stage histogram (request finish path).
@@ -78,6 +85,35 @@ def _sample(logits, temps, seeds, qpos):
 
         sampled = jax.vmap(one)(logits, temps, seeds, qpos)
         return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+
+# The prefill lane where nothing says more: with the decode rows it is
+# one 128-row pass of the MXU at up to 64 slots.
+_LANE = 64
+
+
+def prefill_lane(one_program: bool, peaks: Optional[Dict[str, float]],
+                 dtype, max_seq: int) -> int:
+    """The prefill lane's width, in prompt tokens a step, for an engine
+    whose caller named none.
+
+    A step reads every weight once whatever its rows, so until the
+    matmuls take as long as that read, further rows are free: the ridge,
+    ``flops/s x bytes a weight / (2 x bytes/s)`` rows (240.5 in bfloat16
+    on a TPU v5e), here rounded to the nearest power of two. That is the
+    lane of a family whose fused program runs only while a prompt is
+    pending: a step without a prompt pays nothing for it. A family that
+    carries the lane on EVERY step (``one_program``) pays its width as a
+    tax on decode, and a device without published peaks has no ridge:
+    both get ``_LANE``. The result divides ``max_seq``."""
+    lane = _LANE
+    if not one_program and peaks is not None:
+        ridge = (peaks["bf16_tflops"] * 1e12 * jnp.dtype(dtype).itemsize
+                 / (2 * peaks["hbm_gbps"] * 1e9))
+        lane = 2 ** round(math.log2(ridge))
+    while lane > max_seq or max_seq % lane:
+        lane //= 2
+    return lane
 
 
 @dataclass
@@ -291,13 +327,21 @@ class SlotEngine:
                      "experts_hit", "expert_rows", "expert_rows_max")
 
     def __init__(self, params, cfg, num_slots: int = 8,
-                 chunk: int = 64, seed: int = 0, decode_block: int = 1,
+                 chunk: Optional[int] = None, seed: int = 0,
+                 decode_block: int = 1,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  prefix_cache: bool = True,
                  max_pending: Optional[int] = None,
                  queue_timeout_s: Optional[float] = None,
                  max_sessions: int = 256,
                  mesh=None, rules=None):
+        model = serving.model_for(cfg)
+        if chunk is None:
+            device = (jax.devices()[0] if mesh is None
+                      else mesh.devices.flat[0])
+            chunk = prefill_lane(model.one_program,
+                                 DEVICE_PEAKS.get(device.device_kind),
+                                 cfg.dtype, cfg.max_seq)
         if cfg.max_seq % chunk != 0:
             raise ValueError(
                 f"chunk ({chunk}) must divide max_seq ({cfg.max_seq}): "
@@ -307,7 +351,7 @@ class SlotEngine:
                 f"page_size ({page_size}) must divide max_seq "
                 f"({cfg.max_seq})")
         self.cfg = cfg
-        self._model = model = serving.model_for(cfg)
+        self._model = model
         self.num_slots = num_slots
         self.chunk = chunk
         self.page_size = page_size
@@ -562,6 +606,14 @@ class SlotEngine:
     @property
     def pages_free(self) -> int:
         return self._pool.free_count
+
+    @property
+    def prefill_lane_fill(self) -> float:
+        """Prompt tokens the lane carried over the tokens it had room
+        for, in the steps that had a prompt in it: ``prefill_tokens /
+        (steps_block x chunk)``. Low where prompts are short beside the
+        lane or end in a mostly empty chunk."""
+        return self.prefill_tokens / max(1, self.steps_block * self.chunk)
 
     def prefix_cache_len(self) -> int:
         return 0 if self._radix is None else len(self._radix)
@@ -1026,7 +1078,10 @@ class SlotEngine:
         if sp.recording:
             ps = self.page_size
             sp.set(program=program, active=len(active),
-                   prefill_tokens=pre_tokens, prefill_waiting=waiting,
+                   prefill_tokens=pre_tokens,
+                   # the lane this step's program carried a prompt in
+                   lane=self.chunk if program == "block" else 0,
+                   prefill_waiting=waiting,
                    pending=pending, pages_allocated=self._pool.used_count,
                    pages_read=pages_read if program != "none" else 0,
                    # written so far: a prompt in the lane has pos 0 and
@@ -1067,7 +1122,9 @@ class SlotEngine:
     def _dispatch_block(self, active, prefill_idx):
         """Dispatch one K-step block: every active slot decodes K
         tokens and (when a slot is mid-prompt) ONE prefill chunk rides
-        the first step's fused program. Continuing slots chain their
+        the first step's fused program: up to ``self.chunk`` tokens of
+        one slot's prompt, the width the caller gave or
+        :func:`prefill_lane` derived. Continuing slots chain their
         input token device-side; freshly prefilled slots inject theirs
         via the override vector."""
         cfg = self.cfg

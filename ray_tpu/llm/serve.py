@@ -54,7 +54,7 @@ class LLMServer:
     """
 
     def __init__(self, model: str = "llama-tiny", num_slots: int = 8,
-                 chunk: int = 64, seed: int = 0,
+                 chunk: Optional[int] = None, seed: int = 0,
                  checkpoint_path: Optional[str] = None,
                  default_max_tokens: int = 64,
                  page_size: int = 16, num_pages: Optional[int] = None,
@@ -226,6 +226,10 @@ class LLMServer:
             # rt.llm.step span carries the same counts per step)
             **{k: getattr(self.engine, k)
                for k in SlotEngine.STEP_COUNTERS},
+            # the prefill lane's width, and the share of it that held
+            # prompt tokens in the steps that carried a prompt
+            "prefill_lane": self.engine.chunk,
+            "prefill_lane_fill": self.engine.prefill_lane_fill,
         }
 
     def request_timings(self, since_unix_s: float = 0.0) -> list:
@@ -236,7 +240,7 @@ class LLMServer:
 
 
 def build_llm_app(model: str = "llama-tiny", num_slots: int = 8,
-                  chunk: int = 64, seed: int = 0,
+                  chunk: Optional[int] = None, seed: int = 0,
                   checkpoint_path: Optional[str] = None,
                   name: str = "llm", page_size: int = 16,
                   num_pages: Optional[int] = None,
@@ -245,7 +249,16 @@ def build_llm_app(model: str = "llama-tiny", num_slots: int = 8,
                   queue_timeout_s: Optional[float] = 30.0,
                   decode_block: int = 1, tp: int = 1,
                   **deploy_opts):
-    """Build a Serve application for ``serve.run`` hosting the engine."""
+    """Build a Serve application for ``serve.run`` hosting the engine.
+
+    ``chunk`` is the prefill lane's width in prompt tokens a step. Left
+    ``None``, the engine derives it once, in the replica that holds the
+    chip (``llm/engine.py prefill_lane``): the chip's ridge — the rows a
+    step's one read of the weights multiplies for nothing, 256 in
+    bfloat16 on a TPU v5e — for a family that pays the lane only while a
+    prompt is pending, and 64 for one that carries it on every step
+    (``models/serving.py one_program``) or on a device with no published
+    peaks (``parallel/mesh.py DEVICE_PEAKS``)."""
     from ..serve import deployment
 
     # Mirror the engine's admission knobs into the deployment config so

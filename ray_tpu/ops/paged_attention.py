@@ -52,6 +52,9 @@ _KV_VMEM_BUDGET = 8 * 1024 * 1024
 _MAX_BLOCK_TOKENS = 256
 # Lane chunks unrolled inside the kernel's rolled loop over chunks.
 _CHUNK_UNROLL = 4
+# Pages a row's new tokens may fall in (``window``) up to which the loop
+# over them is unrolled: a chunk of up to 112 tokens at page_size 16.
+_WINDOW_UNROLL = 8
 
 
 def use_kernel() -> bool:
@@ -129,16 +132,27 @@ def _kernel(layer_ref, rows_ref, *refs, scale: float, pages_per_block: int,
     # [s, s + n_new) of its ``window`` pages from logical page
     # start // ps on, s = start % ps.
     # (Loops over rows, pages and chunks are rolled: unrolled, lowering
-    # the kernel took ten seconds of every process's start.)
+    # the kernel took ten seconds of every process's start. The window
+    # of a short chunk stays unrolled, as it always was; a 256-token
+    # lane's 17 pages, unrolled in each of the four passes below, were
+    # most of that kernel's compile.)
     def for_written_pages(act):
         def row(r, _):
             n_new = jnp.maximum(len_of(r) - start_of(r), 0)
             s = start_of(r) % ps
-            for w in range(window):
+
+            def page_of_window(w, _=0):
                 @pl.when(jnp.logical_and(n_new > 0, w * ps < s + n_new))
                 def _():
                     page = rows_ref[r, start_of(r) // ps + w]
                     act(pool_ref.at[layer, :, page], r, w, s, n_new)
+                return 0
+
+            if window <= _WINDOW_UNROLL:
+                for w in range(window):
+                    page_of_window(w)
+            else:
+                jax.lax.fori_loop(0, window, page_of_window, 0)
             return 0
 
         jax.lax.fori_loop(0, rows, row, 0)

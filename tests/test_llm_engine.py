@@ -1,14 +1,17 @@
 """Continuous-batching engine tests: slot-batched output must match the
 single-request decode path token-for-token (VERDICT r4 item 1)."""
 
+import dataclasses
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm.engine import SlotEngine
-from ray_tpu.models import llama
+from ray_tpu.llm.engine import SlotEngine, prefill_lane
+from ray_tpu.models import lfm2, llama, serving
+from ray_tpu.parallel.mesh import DEVICE_PEAKS
 
 CFG = llama.CONFIGS["llama-tiny"]
 
@@ -168,3 +171,92 @@ def test_submit_validation(params):
     with pytest.raises(ValueError):
         engine.submit(list(range(1, 100)),
                       max_new=CFG.max_seq)  # prompt+new > max_seq
+
+
+# -- the prefill lane's width --------------------------------------------------
+
+V5E = {"hbm_gbps": 819.0, "bf16_tflops": 197.0}   # DEVICE_PEAKS' one row
+
+
+@pytest.mark.parametrize("one_program,peaks,dtype,max_seq,want", [
+    # a family that carries the lane on every step: 64 on any device
+    pytest.param(True, V5E, jnp.bfloat16, 2048, 64, id="one-program-v5e"),
+    pytest.param(True, None, jnp.bfloat16, 2048, 64, id="one-program-cpu"),
+    # two programs on a v5e: the ridge, 240.5 rows, to a power of two
+    pytest.param(False, V5E, jnp.bfloat16, 2048, 256, id="ridge-v5e"),
+    # four-byte weights take twice as long to read: twice the rows
+    pytest.param(False, V5E, jnp.float32, 2048, 512, id="ridge-v5e-f32"),
+    # no published peaks, no ridge
+    pytest.param(False, None, jnp.bfloat16, 2048, 64, id="no-peaks"),
+    # held to a divisor of max_seq, and to max_seq itself
+    pytest.param(False, V5E, jnp.bfloat16, 128, 128, id="short-max-seq"),
+    pytest.param(False, V5E, jnp.bfloat16, 96, 32, id="odd-max-seq"),
+    pytest.param(False, None, jnp.float32, 32, 32, id="no-peaks-short"),
+])
+def test_prefill_lane_from_record_peaks_and_config(one_program, peaks,
+                                                   dtype, max_seq, want):
+    assert prefill_lane(one_program, peaks, dtype, max_seq) == want
+
+
+@pytest.mark.parametrize("family,known,chunk,want", [
+    # this CPU has no peaks: today's 64 (llama-tiny's max_seq is 128)
+    pytest.param("llama", False, None, 64, id="llama-cpu"),
+    pytest.param("lfm2", False, None, 64, id="lfm2-cpu"),
+    # the same device with a v5e's peaks: llama-tiny is float32, so the
+    # ridge is 512 rows, held to max_seq; lfm2 stays, by its record
+    pytest.param("llama", True, None, 128, id="llama-peaks"),
+    pytest.param("lfm2", True, None, 64, id="lfm2-peaks"),
+    # a caller's number is the lane, whatever the device
+    pytest.param("llama", True, 16, 16, id="llama-explicit"),
+    pytest.param("lfm2", False, 32, 32, id="lfm2-explicit"),
+])
+def test_engine_resolves_its_lane_once(params, monkeypatch, family, known,
+                                       chunk, want):
+    """``chunk=None`` becomes a width in ``__init__`` from the family's
+    record (``one_program``), ``DEVICE_PEAKS`` and ``cfg``; an explicit
+    ``chunk`` passes through."""
+    if known:
+        monkeypatch.setitem(DEVICE_PEAKS, jax.devices()[0].device_kind, V5E)
+    if family == "lfm2":
+        cfg = lfm2.CONFIGS["lfm2-tiny"]
+        params = lfm2.init_params(jax.random.PRNGKey(0), cfg)[0]
+    else:
+        cfg = CFG
+    eng = SlotEngine(params, cfg, num_slots=2, chunk=chunk, page_size=8)
+    assert eng.chunk == want
+    assert serving.model_for(cfg).one_program == (family == "lfm2")
+
+
+LONG = dataclasses.replace(CFG, max_seq=512)
+# shorter than every lane, equal to each, and spanning the widest
+LANE_PROMPTS = (9, 16, 64, 256, 300)
+
+
+@pytest.fixture(scope="module")
+def lane_reference(params):
+    rng = np.random.default_rng(23)
+    prompts = [[int(t) for t in rng.integers(1, CFG.vocab_size, size=n)]
+               for n in LANE_PROMPTS]
+    want = [[int(t) for t in np.asarray(llama.generate(
+        params, np.asarray([p], dtype=np.int32), LONG, max_new=6))[0, n:]]
+        for p, n in zip(prompts, LANE_PROMPTS)]
+    return prompts, want
+
+
+@pytest.mark.parametrize("lane", [16, 64, 256])
+def test_greedy_tokens_do_not_depend_on_the_lane(params, lane_reference,
+                                                 lane):
+    """One engine run at each lane width: prompts shorter than, equal to
+    and spanning the lane, each admitted while earlier requests decode
+    beside it, give the single-request path's tokens."""
+    prompts, want = lane_reference
+    engine = SlotEngine(params, LONG, num_slots=3, chunk=lane)
+    handles = []
+    for p in prompts:
+        handles.append(engine.submit(p, max_new=6))
+        for _ in range(2):
+            engine.step()
+    drain(engine, handles)
+    assert [h.result(timeout=0).tokens for h in handles] == want
+    assert 0 < engine.prefill_lane_fill <= 1
+    assert engine.prefill_tokens == sum(LANE_PROMPTS)

@@ -264,13 +264,16 @@ def test_multi_turn_session_extends_prefix(engine, params):
     assert engine.prefix_tokens_saved - saved0 >= 16
 
 
-def test_prefix_hit_sampled_bit_for_bit(params):
+@pytest.mark.parametrize("lane", [8, 64])
+def test_prefix_hit_sampled_bit_for_bit(params, lane):
     """Seeded sampling: a prefix-hit request must reproduce the cold
     request's tokens exactly — per-request fold_in streams make the
-    draw independent of how much prefill the hit skipped."""
-    cold_eng = SlotEngine(params, CFG, num_slots=2, chunk=8,
+    draw independent of how much prefill the hit skipped, in a lane
+    narrower than the prompt (the hit skips whole chunks) and in one
+    wider (the hit starts the one chunk inside a page)."""
+    cold_eng = SlotEngine(params, CFG, num_slots=2, chunk=lane,
                           page_size=PS, prefix_cache=False)
-    warm_eng = SlotEngine(params, CFG, num_slots=2, chunk=8,
+    warm_eng = SlotEngine(params, CFG, num_slots=2, chunk=lane,
                           page_size=PS)
     rng = np.random.default_rng(41)
     prompt = [int(t) for t in rng.integers(1, CFG.vocab_size, size=19)]

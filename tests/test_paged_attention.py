@@ -33,17 +33,17 @@ NARROW = {"gqa8-one-head-of-64": (8, 1, 64, 16, jnp.bfloat16),
 PAGES_PER_ROW = 8
 
 
-def _problem(name, rows, t, seed):
+def _problem(name, rows, t, seed, pages_per_row=PAGES_PER_ROW):
     h, hkv, hd, ps, dtype = GEOMETRIES.get(name) or NARROW[name]
     rng = np.random.default_rng(seed)
-    f, n_pages = hkv * hd, rows * PAGES_PER_ROW + 1
+    f, n_pages = hkv * hd, rows * pages_per_row + 1
     pool = jnp.asarray(rng.standard_normal((2, 2, n_pages, ps, f)), dtype)
     q = jnp.asarray(rng.standard_normal((rows, t, h, hd)), dtype)
     k_new, v_new = (jnp.asarray(rng.standard_normal((rows, t, f)), dtype)
                     for _ in range(2))
     # every row its own scattered pages; page 0 is nobody's
     tables = rng.permutation(np.arange(1, n_pages)).reshape(
-        rows, PAGES_PER_ROW).astype(np.int32)
+        rows, pages_per_row).astype(np.int32)
     return q, k_new, v_new, pool, jnp.asarray(tables), ps, dtype
 
 
@@ -91,14 +91,23 @@ def test_decode_rows_match_the_reference(name):
     _check(got, want, pool, q_start, lengths, dtype)
 
 
-@pytest.mark.parametrize("name", list(GEOMETRIES))
-def test_a_prompt_chunk_matches_the_reference(name):
+# The lane a TPU v5e's ridge gives (llm/engine.py prefill_lane), at the
+# benchmark's head_dim and page size: 256 query tokens from inside a
+# page, so their K/V land in a window of 17 pages.
+RIDGE_LANE = 256
+
+
+@pytest.mark.parametrize("name,c", [
+    *[pytest.param(n, None, id=n) for n in GEOMETRIES],
+    pytest.param("mha-hd64", RIDGE_LANE, id="mha-hd64-ridge-lane")])
+def test_a_prompt_chunk_matches_the_reference(name, c):
     """q_len C for the prefill lane's one slot: the chunk starts inside a
     page and straddles the next ones, the in-chunk causal mask holds, and
     its tail past n_valid is written nowhere."""
     _, _, _, ps, _ = GEOMETRIES[name]
-    c = 2 * ps + 8
-    q, k_new, v_new, pool, tables, ps, dtype = _problem(name, 1, c, seed=5)
+    c = c or 2 * ps + 8
+    q, k_new, v_new, pool, tables, ps, dtype = _problem(
+        name, 1, c, seed=5, pages_per_row=max(PAGES_PER_ROW, c // ps + 3))
     p0, n_valid = ps + 3, c - 5
     got, want = _run_both(q, k_new, v_new, pool, tables, [p0],
                           [p0 + n_valid])

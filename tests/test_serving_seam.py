@@ -224,21 +224,30 @@ def test_the_engine_names_no_model_and_models_no_engine():
 
 # -- a config registered after import -------------------------------------------
 
-def test_llm_server_finds_a_config_registered_late(monkeypatch):
+@pytest.mark.parametrize("chunk,lane", [(8, 8), (None, 64)])
+def test_llm_server_finds_a_config_registered_late(monkeypatch, chunk, lane):
     """What the benchmark's replica does: put a config into the family's
     own ``CONFIGS`` after everything is imported, then start a server by
-    that name."""
+    that name — with no scheduling option, as the benchmark passes none:
+    the engine then derives its prefill lane (64 on a device with no
+    published peaks), and ``stats()`` says the lane and how full it
+    ran."""
     cfg = llama.LlamaConfig(vocab_size=256, max_seq=64, num_layers=1,
                             num_heads=2, num_kv_heads=1, d_model=32,
                             d_mlp=64, dtype=jnp.float32, remat=False)
     monkeypatch.setitem(llama.CONFIGS, "late-tiny", cfg)
     found, family = serving.named("late-tiny")
     assert found is cfg and family is serving.model_for(cfg)
-    server = LLMServer(model="late-tiny", num_slots=2, chunk=8, page_size=8)
+    server = LLMServer(model="late-tiny", num_slots=2, chunk=chunk,
+                       page_size=8)
     try:
         assert server.engine.cfg is cfg
         prompt = [5, 6, 7, 8, 9]
         got = server.engine.submit(prompt, max_new=6).result(timeout=120)
+        stats = server.stats()
+        assert stats["prefill_lane"] == lane
+        assert stats["prefill_lane_fill"] == pytest.approx(
+            stats["prefill_tokens"] / (stats["steps_block"] * lane))
         params, _ = llama.init_params(jax.random.PRNGKey(0), cfg)
         want = llama.generate(params, np.asarray([prompt], np.int32), cfg,
                               max_new=6)

@@ -129,6 +129,32 @@ def test_step_spans_children_counts_and_token_invariant(params, tracer,
     assert delta["overshoot_tokens"] >= len(handles)
 
 
+@pytest.mark.parametrize("lane", [8, 16, 64])
+def test_prefill_lane_fill_is_the_spans_tokens_over_their_lanes(
+        params, tracer, lane):
+    """``prefill_lane_fill`` is ``prefill_tokens / (steps_block x lane)``;
+    a step span that dispatched the fused program says ``lane``, any
+    other 0, so the same ratio comes out of the spans alone."""
+    eng = SlotEngine(params, CFG, num_slots=2, chunk=lane,
+                     prefix_cache=False)
+    assert eng.prefill_lane_fill == 0.0   # before any step, not 0 / 0
+    lengths = (5, lane, lane + 3, 39)
+    handles = [eng.submit(list(range(1, 1 + n)), max_new=4)
+               for n in lengths]
+    _drain(eng)
+    assert all(h.result(timeout=0).tokens for h in handles)
+    steps = [s.attributes for s in tracer.spans("rt.llm.")
+             if s.name == "rt.llm.step"]
+    assert all(a["lane"] == (lane if a["program"] == "block" else 0)
+               for a in steps)
+    chunks = sum(-(-n // lane) for n in lengths)
+    assert eng.steps_block == chunks
+    assert sum(a["lane"] for a in steps) == eng.steps_block * lane
+    assert sum(a["prefill_tokens"] for a in steps) == sum(lengths) \
+        == eng.prefill_tokens
+    assert eng.prefill_lane_fill == sum(lengths) / (chunks * lane)
+
+
 def test_request_timings_keep_streamed_requests_and_forget(params,
                                                            monkeypatch):
     """A streamed request (tokens through ``on_token``, as the replica's

@@ -23,7 +23,8 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.llm.engine import SlotEngine, build_step_programs
+from ray_tpu.llm.engine import (SlotEngine, build_step_programs,
+                                prefill_lane)
 from ray_tpu.models import lfm2, llama, serving
 from ray_tpu.ops import attention as A
 from ray_tpu.ops import grouped_matmul as GM
@@ -213,10 +214,19 @@ _COLLECTIVE = re.compile(
     r"collective-permute)(?:-start)?\(")
 
 
-def _engine_program_specs(cfg, sharding, mesh_rules=None):
+def _derived_lane(v5e, cfg):
+    """The prefill lane ``SlotEngine`` gives itself on the described chip
+    when its caller names none."""
+    return prefill_lane(serving.model_for(cfg).one_program,
+                        DEVICE_PEAKS[v5e[0].device_kind], cfg.dtype,
+                        cfg.max_seq)
+
+
+def _engine_program_specs(cfg, sharding, mesh_rules=None, lane=CHUNK):
     """Shapes of both programs' arguments, every one on ``sharding``;
     with ``mesh_rules`` = (mesh, rules) the params and the cache are laid
-    over the mesh as ``SlotEngine`` places them."""
+    over the mesh as ``SlotEngine`` places them. ``lane``: the fused
+    program's prompt chunk."""
     def sds(shape, dtype, where=sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
 
@@ -241,7 +251,7 @@ def _engine_program_specs(cfg, sharding, mesh_rules=None):
     common = (params, cache, i32(SLOTS, cfg.max_seq // PAGE),
               i32(SLOTS), sds((SLOTS,), jnp.bool_), i32(SLOTS), i32(SLOTS),
               sds((SLOTS,), jnp.float32), i32(SLOTS))
-    fused = common + (i32(CHUNK), i32(), i32(), i32(),
+    fused = common + (i32(lane), i32(), i32(), i32(),
                       sds((), jnp.float32), i32())
     return {"block": fused, "decode_only": common}, pool
 
@@ -251,19 +261,35 @@ _COMPILED_STEPS = {}
 
 def _compiled_step(v5e, config, program):
     """``(compiled, pool's shape)`` of one engine program on one chip at
-    a configuration's widths; compiled once for the tests that read it."""
+    a configuration's widths; compiled once for the tests that read it.
+    ``block`` carries the lane the tests here have always compiled
+    (``CHUNK``), ``block-derived`` the one the engine derives for this
+    chip: 256, which is what a deployment that names no ``chunk`` runs."""
     if (config, program) not in _COMPILED_STEPS:
         cfg = ENGINE_CONFIGS[config]
+        lane = _derived_lane(v5e, cfg) if program == "block-derived" else CHUNK
         specs, pool = _engine_program_specs(
-            cfg, SingleDeviceSharding(v5e[0]))
+            cfg, SingleDeviceSharding(v5e[0]), lane=lane)
         block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
-        fn = block_fn if program == "block" else decode_only_fn
+        which = "decode_only" if program == "decode_only" else "block"
+        fn = {"block": block_fn, "decode_only": decode_only_fn}[which]
         _COMPILED_STEPS[config, program] = jax.jit(
-            fn, donate_argnums=(1,)).lower(*specs[program]).compile(), pool
+            fn, donate_argnums=(1,)).lower(*specs[which]).compile(), pool
     return _COMPILED_STEPS[config, program]
 
 
-@pytest.mark.parametrize("program", ["block", "decode_only"])
+PROGRAMS = ["block", "decode_only", "block-derived"]
+
+
+def test_derived_lane_on_the_described_chip(v5e):
+    """256 prompt tokens a step for the two-program family, the 64 the
+    tests below compile for the family that carries its lane always."""
+    assert _derived_lane(v5e, SMOLLM2_2L) == 256
+    assert _derived_lane(v5e, LLAMA1B_2L) == 256
+    assert _derived_lane(v5e, lfm2.Lfm2Config()) == CHUNK
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
     """As compiled for the chip, a step holds the Mosaic kernel and no
     copy or fusion whose result is the pool or one layer's slice of it,
@@ -274,7 +300,8 @@ def test_engine_programs_touch_the_pool_only_in_place(v5e, program):
     compiled, pool = _compiled_step(v5e, "smollm2", program)
     text = compiled.as_text()
     # decode rows, and in the fused program the prompt chunk's lane
-    assert text.count("tpu_custom_call") == (2 if program == "block" else 1)
+    assert text.count("tpu_custom_call") == (
+        1 if program == "decode_only" else 2)
     shapes = {",".join(map(str, pool.shape)),          # the pool
               ",".join(map(str, (1,) + pool.shape[1:])),  # a layer of it
               ",".join(map(str, pool.shape[1:]))}
@@ -392,8 +419,11 @@ def _moved_weights(text, cfg, tp=1):
             if mo and mo.group(1) in layer_shapes]
 
 
-@pytest.mark.parametrize("config", list(ENGINE_CONFIGS))
-@pytest.mark.parametrize("program", ["block", "decode_only"])
+# llama-1b at the derived lane is left out: its wk / wv are [2048, 256]
+# and a 256-token chunk's activations [1, 256, 2048] have that shape too.
+@pytest.mark.parametrize("program,config", [
+    *[(p, c) for p in ("block", "decode_only") for c in ENGINE_CONFIGS],
+    ("block-derived", "smollm2")])
 def test_engine_programs_read_stacked_weights_where_they_lie(v5e, program,
                                                               config):
     """As compiled for the chip, the layer loop holds no ``copy`` and no
@@ -408,7 +438,8 @@ def test_engine_programs_read_stacked_weights_where_they_lie(v5e, program,
     over heads, wanted its 2048 x 2048 weight transposed, every layer of
     every step (``models/llama.py rope_lanes``)."""
     text = _compiled_step(v5e, config, program)[0].as_text()
-    assert text.count("tpu_custom_call") == (2 if program == "block" else 1)
+    assert text.count("tpu_custom_call") == (
+        1 if program == "decode_only" else 2)
     moved = _moved_weights(text, ENGINE_CONFIGS[config])
     assert not moved, moved
 
