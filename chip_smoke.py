@@ -234,7 +234,11 @@ def serve_phase(found: dict, seed: int) -> dict:
             f"overshot; {stats['kv_pages_read']} KV pages read a layer; "
             f"{stats['prefill_tokens']} prompt tokens through a lane of "
             f"{stats['prefill_lane']}, prefill_lane_fill "
-            f"{stats['prefill_lane_fill']:.3f}")
+            f"{stats['prefill_lane_fill']:.3f}; "
+            f"{stats['gc_pauses']} collections stopped the replica for "
+            f"{stats['gc_pause_s']:.3f}s, {stats['compiles']} programs "
+            f"built in {stats['compile_s']:.1f}s, "
+            f"{stats['compile_cache_hits']} of them from the cache")
         return stats["device"]
     finally:
         try:

@@ -305,6 +305,26 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
     return block_fn, decode_only_fn
 
 
+class _acquired:
+    """``with lock:`` for the engine thread, the wait for the lock an
+    ``rt.llm.acquire`` span: between two steps the thread contends with
+    ``submit()`` callers for the engine's one lock, and a loop that is
+    never out of work shows that wait nowhere else."""
+
+    __slots__ = ("_lock",)
+
+    def __init__(self, lock):
+        self._lock = lock
+
+    def __enter__(self):
+        with tracing.step_span("rt.llm.acquire"):
+            self._lock.acquire()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
+
+
 class SlotEngine:
     """Continuous-batching generation over a paged KV-cache pool."""
 
@@ -505,6 +525,7 @@ class SlotEngine:
         # table entry reads.
         self.kv_pages_read = 0
         self.experts_hit = self.expert_rows = self.expert_rows_max = 0
+        self._callbacks = 0  # on_token calls made delivering tokens
         # The last finished requests' timing: a streamed response
         # carries tokens only, so this is where its stages are read.
         self._timings: deque = deque(maxlen=self.TIMINGS_KEPT)
@@ -562,6 +583,9 @@ class SlotEngine:
 
     def start(self) -> "SlotEngine":
         if self._thread is None:
+            # what stops this loop from outside it, as spans and counts
+            tracing.watch_gc()
+            tracing.watch_compiles()
             self._thread = threading.Thread(target=self._run,
                                             name="llm-engine", daemon=True)
             self._thread.start()
@@ -856,7 +880,7 @@ class SlotEngine:
 
     def _run(self) -> None:
         while True:
-            with self._work:
+            with _acquired(self._work):
                 while not self._stop and not self._has_work_locked():
                     with tracing.step_span("rt.llm.wait_work"):
                         self._work.wait()
@@ -1023,16 +1047,18 @@ class SlotEngine:
         decode+prefill block, then fetch the PREVIOUS block's tokens
         (ready by now — lag-1 pipelining). Returns True if any work
         ran."""
-        with self._lock:
+        with _acquired(self._lock):
             if not self._has_work_locked():
                 return False
-        with tracing.step_span("rt.llm.step", slots=self.num_slots,
+        with tracing.step_span("rt.llm.step", cpu=True,
+                               slots=self.num_slots,
                                block=self.decode_block) as sp:
             return self._step(sp)
 
     def _step(self, sp) -> bool:
         ran_control = False
-        with tracing.step_span("rt.llm.schedule") as sched, self._lock:
+        with tracing.step_span("rt.llm.schedule") as sched, \
+                self._lock:
             # Session export/import and friends run HERE, between
             # decode steps: the previous block's cache assignment is
             # complete and the next dispatch hasn't consumed it.
@@ -1094,7 +1120,7 @@ class SlotEngine:
                        for s in live))
         new_block = None
         if program != "none":
-            with tracing.step_span("rt.llm.dispatch"):
+            with tracing.step_span("rt.llm.dispatch", cpu=True):
                 new_block = self._dispatch_block(active, prefill_idx)
         if had_fetch:
             self._process_fetch(sp)
@@ -1129,66 +1155,84 @@ class SlotEngine:
         via the override vector."""
         cfg = self.cfg
         rows = self.num_slots
-        override_vals = np.zeros((rows,), dtype=np.int32)
-        override_mask = np.ones((rows,), dtype=bool)
-        # Parked rows sit AT max_seq: the paged scatter routes any write
-        # at pos >= max_seq to the scratch page, so a parked row can
-        # never touch a live (possibly shared) page.
-        pos = np.full((rows,), cfg.max_seq, dtype=np.int32)
-        temps = np.zeros((rows,), dtype=np.float32)
-        seeds = np.zeros((rows,), dtype=np.int32)
-        for i, s in active:
-            pos[i] = s.pos
-            temps[i] = s.temperature
-            seeds[i] = s.seed
-            if s.on_device_chain:
-                override_mask[i] = False
-            else:
-                override_vals[i] = s.last_token
-        tables = jnp.asarray(self._tables)
-        if prefill_idx is None and not self._model.one_program:
-            # No prompt chunk pending: the cheap pure-decode program.
-            toks_k, self._last_dev, self._cache = self._decode_only(
-                self._params, self._cache, tables,
-                jnp.asarray(override_vals), jnp.asarray(override_mask),
-                self._last_dev, jnp.asarray(pos), jnp.asarray(temps),
-                jnp.asarray(seeds))
+        # The fused program unless no prompt chunk is pending and the
+        # family has the cheap pure-decode program. With no prompt
+        # pending (a family whose rows repeat bit for bit only within ONE
+        # compiled program, models/serving.py ``one_program``) the lane
+        # is empty: n_valid 0 writes nothing, and what it samples nobody
+        # reads.
+        fused = prefill_idx is not None or self._model.one_program
+        with tracing.step_span("rt.llm.dispatch.pack"):
+            override_vals = np.zeros((rows,), dtype=np.int32)
+            override_mask = np.ones((rows,), dtype=bool)
+            # Parked rows sit AT max_seq: the paged scatter routes any
+            # write at pos >= max_seq to the scratch page, so a parked
+            # row can never touch a live (possibly shared) page.
+            pos = np.full((rows,), cfg.max_seq, dtype=np.int32)
+            temps = np.zeros((rows,), dtype=np.float32)
+            seeds = np.zeros((rows,), dtype=np.int32)
             for i, s in active:
-                s.pos += self.decode_block
-                s.on_device_chain = True
-            return (list(active), None, toks_k, None)
-        # Prefill lane: one chunk of one slot's prompt rides the fused
-        # program's first step. With no prompt pending (a family whose
-        # rows repeat bit for bit only within ONE compiled program,
-        # models/serving.py ``one_program``) the lane is empty: n_valid 0
-        # writes nothing, and what it samples nobody reads.
-        pre_buf = np.zeros((self.chunk,), dtype=np.int32)
-        lane_slot = p0 = n_valid = lane_seed = 0
-        lane_temp, pre_info = 0.0, None
-        if prefill_idx is not None:
-            s = self._slots[prefill_idx]
-            if s.prefill_start_t == 0.0:
-                s.prefill_start_t = time.monotonic()
-            p0 = s.prefill_offset
-            piece = s.prompt[p0:p0 + self.chunk]
-            n_valid = len(piece)
-            pre_buf[:n_valid] = piece
-            s.prefill_offset = p0 + n_valid
-            final = s.prefill_done
-            if final:
-                s.first_tok_pending = True
-            pre_info = (prefill_idx, s, final)
-            lane_slot, lane_temp, lane_seed = (prefill_idx, s.temperature,
-                                               s.seed)
-        toks_k, self._last_dev, pre_tok, self._cache = self._block(
-            self._params, self._cache, tables,
-            jnp.asarray(override_vals), jnp.asarray(override_mask),
-            self._last_dev, jnp.asarray(pos), jnp.asarray(temps),
-            jnp.asarray(seeds),
-            jnp.asarray(pre_buf), jnp.asarray(lane_slot, jnp.int32),
-            jnp.asarray(p0, jnp.int32), jnp.asarray(n_valid, jnp.int32),
-            jnp.asarray(lane_temp, jnp.float32),
-            jnp.asarray(lane_seed, jnp.int32))
+                pos[i] = s.pos
+                temps[i] = s.temperature
+                seeds[i] = s.seed
+                if s.on_device_chain:
+                    override_mask[i] = False
+                else:
+                    override_vals[i] = s.last_token
+            # Prefill lane: one chunk of one slot's prompt rides the
+            # fused program's first step.
+            pre_info = None
+            if fused:
+                pre_buf = np.zeros((self.chunk,), dtype=np.int32)
+                lane_slot = p0 = n_valid = lane_seed = 0
+                lane_temp = 0.0
+            if prefill_idx is not None:
+                s = self._slots[prefill_idx]
+                if s.prefill_start_t == 0.0:
+                    s.prefill_start_t = time.monotonic()
+                p0 = s.prefill_offset
+                piece = s.prompt[p0:p0 + self.chunk]
+                n_valid = len(piece)
+                pre_buf[:n_valid] = piece
+                s.prefill_offset = p0 + n_valid
+                final = s.prefill_done
+                if final:
+                    s.first_tok_pending = True
+                pre_info = (prefill_idx, s, final)
+                lane_slot, lane_temp, lane_seed = (
+                    prefill_idx, s.temperature, s.seed)
+        with tracing.step_span("rt.llm.dispatch.upload", cpu=True) as sp:
+            # in the order the programs take them; _last_dev, between
+            # the mask and the positions, never left the device
+            uploaded = [jnp.asarray(self._tables),
+                        jnp.asarray(override_vals),
+                        jnp.asarray(override_mask), jnp.asarray(pos),
+                        jnp.asarray(temps), jnp.asarray(seeds)]
+            if fused:
+                uploaded += [jnp.asarray(pre_buf),
+                             jnp.asarray(lane_slot, jnp.int32),
+                             jnp.asarray(p0, jnp.int32),
+                             jnp.asarray(n_valid, jnp.int32),
+                             jnp.asarray(lane_temp, jnp.float32),
+                             jnp.asarray(lane_seed, jnp.int32)]
+            if sp.recording:
+                sp.set(arrays=len(uploaded),
+                       bytes=sum(a.nbytes for a in uploaded))
+        with tracing.step_span("rt.llm.dispatch.launch") as sp:
+            built = tracing.process_events().compiles
+            pre_tok = None
+            step = self._block if fused else self._decode_only
+            out = step(self._params, self._cache, *uploaded[:3],
+                       self._last_dev, *uploaded[3:])
+            if fused:
+                toks_k, self._last_dev, pre_tok, self._cache = out
+            else:
+                toks_k, self._last_dev, self._cache = out
+            if sp.recording:
+                # the executable called, and the programs built for the
+                # backend meanwhile: 0 in a loop that was warmed up
+                sp.set(program="block" if fused else "decode_only",
+                       compiled=tracing.process_events().compiles - built)
         for i, s in active:
             s.pos += self.decode_block
             s.on_device_chain = True
@@ -1211,14 +1255,17 @@ class SlotEngine:
                 setattr(self, name, getattr(self, name) + int(n))
             if step_sp.recording:
                 step_sp.set(**{k: int(n) for k, n in zip(names, counts)})
-        with tracing.step_span("rt.llm.deliver") as sp:
+        with tracing.step_span("rt.llm.deliver", cpu=True) as sp:
             tokens0, done0 = self.tokens_generated, self.requests_completed
+            calls0 = self._callbacks
             overshoot = self._deliver_block(snapshot, pre_info, arr,
                                             pre_tok)
             self.overshoot_tokens += overshoot
             sp.set(delivered=self.tokens_generated - tokens0,
                    finished=self.requests_completed - done0,
-                   overshoot=overshoot)
+                   overshoot=overshoot,
+                   # on_token calls: each wakes the caller's thread
+                   callbacks=self._callbacks - calls0)
 
     def _deliver_block(self, snapshot, pre_info, arr, pre_tok) -> int:
         """Hand a fetched block's tokens to their requests. Returns the
@@ -1358,6 +1405,7 @@ class SlotEngine:
                 m["ttft"].observe(s.first_tok_t - s.submit_t)
         s.handle._emit(tok)
         if s.on_token:
+            self._callbacks += 1
             s.on_token(tok)
         hit_eos = s.eos_id is not None and tok == s.eos_id
         out_of_room = (len(s.prompt) + s.produced) >= self.cfg.max_seq
@@ -1370,10 +1418,15 @@ class SlotEngine:
                 self._emit_trace_spans(s, s.handle.timing)
             with self._lock:
                 self._timings.append(kept)
+            # counted BEFORE anyone is told: the two calls below wake the
+            # caller's thread, and a caller that then reads the counters
+            # (stats()) must find its request among the completed, however
+            # long this thread then waits for the interpreter lock
+            self.requests_completed += 1
             s.handle._finish("stop" if hit_eos else "length")
             if s.on_token:
+                self._callbacks += 1
                 s.on_token(None)
-            self.requests_completed += 1
             with self._lock:
                 if s.session_id is not None:
                     # Transcript = prompt + everything produced: the

@@ -27,6 +27,7 @@ from typing import Optional
 import jax
 
 from ..models import serving
+from ..observability import tracing
 from .engine import SlotEngine
 from .paged import OverloadedError
 
@@ -63,6 +64,8 @@ class LLMServer:
                  queue_timeout_s: Optional[float] = 30.0,
                  decode_block: int = 1, tp: int = 1):
         t0 = time.monotonic()
+        tracing.watch_compiles()  # set-up's own compiles are counted too
+        built = tracing.process_events().counters()
         params, cfg = _build_params(model, seed, checkpoint_path)
         t_params = time.monotonic()
         self.default_max_tokens = default_max_tokens
@@ -95,10 +98,18 @@ class LLMServer:
         self.engine.warmup()  # compile before the replica is routable
         # Set-up seconds, apart from any request's: building the weights,
         # the engine (placement + page pool), and the warm-up request
-        # that compiles both programs.
-        self._startup_s = {"params": round(t_params - t0, 3),
-                           "engine": round(t_engine - t_params, 3),
-                           "warmup": round(time.monotonic() - t_engine, 3)}
+        # that compiles both programs. Of all three, compile_s went on
+        # building programs for the backend, compile_cache_hits of them
+        # read back from the persistent cache: a cold start against a
+        # warm one, said by the program.
+        now = tracing.process_events().counters()
+        self._startup_s = {
+            "params": round(t_params - t0, 3),
+            "engine": round(t_engine - t_params, 3),
+            "warmup": round(time.monotonic() - t_engine, 3),
+            "compile_s": round(now["compile_s"] - built["compile_s"], 3),
+            "compile_cache_hits": (now["compile_cache_hits"]
+                                   - built["compile_cache_hits"])}
         self.engine.start()
         self._recoveries: list = []  # crash-path restore latencies (ms)
 
@@ -125,8 +136,6 @@ class LLMServer:
         session_id = payload.get("session")
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
-        from ..observability import tracing
-
         handle = self.engine.submit(
             prompt, max_new=max_tokens, temperature=temperature,
             eos_id=None if eos_id is None else int(eos_id),
@@ -230,6 +239,10 @@ class LLMServer:
             # prompt tokens in the steps that carried a prompt
             "prefill_lane": self.engine.chunk,
             "prefill_lane_fill": self.engine.prefill_lane_fill,
+            # what stopped this process's loops from outside them:
+            # garbage collections, and programs built for the backend
+            # (after warm-up there should be none)
+            **tracing.process_events().counters(),
         }
 
     def request_timings(self, since_unix_s: float = 0.0) -> list:

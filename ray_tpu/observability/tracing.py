@@ -18,12 +18,17 @@ a profiler trace's ``/host:CPU`` plane on the clock the device events
 carry, and records a ring span too only when the tracer is on. The
 device's own time is never in a span: it is in the profiler trace, under
 the ``jax.named_scope`` names the programs carry.
+
+Two process-wide events stop a loop whoever runs it, and are spans and
+counts here too (:func:`watch_gc`, :func:`watch_compiles`): a garbage
+collection (``rt.gc``) and a program built for the backend.
 """
 
 from __future__ import annotations
 
 import contextvars
 import functools
+import gc
 import os
 import sys
 import threading
@@ -72,6 +77,11 @@ class Tracer:
         # path (caught by the ISSUE 20 overhead A/B).
         self._spans: deque = deque(maxlen=max_spans)
         self._lock = threading.Lock()
+        # Finished spans from where no lock may be taken (a ``gc.callbacks``
+        # hook runs wherever the interpreter stops, inside ``_lock``'s
+        # blocks too): appended lock-free, moved into the ring by the next
+        # record or read from ordinary code.
+        self._late: deque = deque(maxlen=1024)
         # Export plane (cluster telemetry): when a TelemetryExporter is
         # attached it flips export_enabled and drains finished spans on
         # each flush; bounded the same way so a stalled flusher can't
@@ -90,7 +100,25 @@ class Tracer:
     def disable(self) -> None:
         self.enabled = False
 
+    def record_later(self, span: Span) -> None:
+        """``record`` for a caller that may be running INSIDE one of this
+        tracer's locked blocks on the same thread: takes no lock."""
+        self._late.append(span)
+
+    def _flush_late(self) -> None:
+        while True:
+            try:
+                span = self._late.popleft()
+            except IndexError:
+                return
+            self._store(span)
+
     def record(self, span: Span) -> None:
+        if self._late:
+            self._flush_late()
+        self._store(span)
+
+    def _store(self, span: Span) -> None:
         dropped = 0
         with self._lock:
             if len(self._spans) == self.max_spans:
@@ -117,16 +145,21 @@ class Tracer:
     def drain_export(self) -> List[Span]:
         """Finished spans recorded since the last drain (telemetry
         flush path; worker/daemon processes ship these to the head)."""
+        if self._late:
+            self._flush_late()
         with self._lock:
             out = list(self._export)
             self._export.clear()
         return out
 
     def spans(self, name_prefix: str = "") -> List[Span]:
+        if self._late:
+            self._flush_late()
         with self._lock:
             return [s for s in self._spans if s.name.startswith(name_prefix)]
 
     def clear(self) -> None:
+        self._late.clear()
         with self._lock:
             self._spans.clear()
             self._export.clear()  # cleared means cleared: nothing ships
@@ -138,6 +171,8 @@ class Tracer:
         process (driver / workers / daemons)."""
         import os
 
+        if self._late:
+            self._flush_late()
         with self._lock:
             spans = list(self._spans)
         pid = os.getpid()
@@ -256,6 +291,15 @@ def span(name: str, **attributes):
 _annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded here
 
 
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
 class _BoundsSpanCtx:
     """Ring side of a span that crosses awaits on a shared event-loop
     thread: the thread's span stack cannot hold it, so it is recorded at
@@ -282,21 +326,33 @@ class _StepSpan:
     """What :func:`step_span` returns: the profiler annotation and, when
     the tracer is on, a ring span, entered and left together."""
 
-    __slots__ = ("_ann", "_ctx", "_attributes")
+    __slots__ = ("_ann", "_ctx", "_attributes", "_clocks")
 
-    def __init__(self, ann, ctx, attributes):
+    def __init__(self, ann, ctx, attributes, cpu=False):
         self._ann = ann
         self._ctx = ctx  # ring side: _SpanCtx, _BoundsSpanCtx or None
         self._attributes = attributes  # the ring span's dict too
+        # cpu: True until entered, then the two clocks read at entry, or
+        # None where nobody records the span (nothing is read then)
+        self._clocks = cpu
 
     def __enter__(self) -> "_StepSpan":
         if self._ann is not None:
             self._ann.__enter__()
         if self._ctx is not None:
             self._ctx.__enter__()
+        if self._clocks:
+            self._clocks = (time.perf_counter_ns(), time.thread_time_ns()
+                            ) if self.recording else None
         return self
 
     def __exit__(self, *exc):
+        if self._clocks:
+            # the thread's clock inside the wall clock's interval, so a
+            # clock that is exact never reads more CPU than wall
+            on_cpu = time.thread_time_ns() - self._clocks[1]
+            wall = time.perf_counter_ns() - self._clocks[0]
+            self.set(wall_us=wall / 1e3, off_cpu_us=(wall - on_cpu) / 1e3)
         if self._ctx is not None:
             self._ctx.__exit__(*exc)
         if self._ann is not None:
@@ -319,7 +375,7 @@ class _StepSpan:
             self._ann.set_metadata(**attributes)
 
 
-def step_span(name: str, interleaved: bool = False,
+def step_span(name: str, interleaved: bool = False, cpu: bool = False,
               **attributes) -> _StepSpan:
     """A span round one step of a hot path (names start with ``rt.``).
 
@@ -332,19 +388,138 @@ def step_span(name: str, interleaved: bool = False,
     the thread's current span, so ``rt trace`` and the chrome export show
     steps beside requests. ``interleaved`` is for a span that crosses
     awaits on a shared event-loop thread: the annotation keeps its own
-    start and end there; the ring span is a :class:`_BoundsSpanCtx`."""
-    global _annotation
-    if _annotation is None:
-        jax = sys.modules.get("jax")
-        if jax is not None:
-            _annotation = jax.profiler.TraceAnnotation
+    start and end there; the ring span is a :class:`_BoundsSpanCtx`.
+
+    ``cpu`` is for a span of a loop that should be WORKING, not waiting:
+    where the span is recorded it gets ``wall_us`` and ``off_cpu_us``,
+    its wall time and the part of it this thread was not on a CPU
+    (blocked on the interpreter lock, another lock or the device). Wall
+    time alone cannot tell a starved thread from a busy one. Read it as
+    a SUM over many spans: where the kernel charges a thread its CPU
+    time a scheduler tick at a time (10 ms on the machines the TPU is
+    measured on), one span reads all of its wall time or less than none
+    (``off_cpu_us`` is not clamped at 0, so that the sums stay true)."""
+    annotation = _trace_annotation()
     ctx = None
     if _tracer.enabled:
         ctx = (_BoundsSpanCtx if interleaved else _SpanCtx)(name,
                                                             attributes)
     return _StepSpan(
-        None if _annotation is None else _annotation(name, **attributes),
-        ctx, attributes)
+        None if annotation is None else annotation(name, **attributes),
+        ctx, attributes, cpu)
+
+
+# -- process-wide events that stop a loop -----------------------------------
+
+# jax/_src/pxla.py times ``compile_or_get_cached`` under this name, so a
+# program read back from the persistent cache is counted too, with the
+# seconds the read took; jax/_src/compiler.py records the hit.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class ProcessEvents:
+    """Cumulative counts of garbage collections and of programs built for
+    the backend in this process, since :func:`watch_gc` /
+    :func:`watch_compiles`. Read without a lock (a reader wants a count,
+    not an instant). Collections come one at a time; two threads may
+    compile at once, so those counts are added under a lock."""
+
+    __slots__ = ("gc_pauses", "gc_pause_s", "compiles", "compile_s",
+                 "compile_cache_hits", "_gc", "_watching", "_lock")
+
+    def __init__(self):
+        self.gc_pauses = 0
+        self.gc_pause_s = 0.0
+        # a program built for the backend, from the compiler or from the
+        # persistent cache: either way the caller waited for it
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.compile_cache_hits = 0
+        # (annotation, perf_counter, time) of the collection under way
+        self._gc = None
+        self._watching = set()
+        self._lock = threading.Lock()
+
+    def counters(self) -> Dict[str, Any]:
+        return {"gc_pauses": self.gc_pauses,
+                "gc_pause_s": round(self.gc_pause_s, 6),
+                "compiles": self.compiles,
+                "compile_s": round(self.compile_s, 6),
+                "compile_cache_hits": self.compile_cache_hits}
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        # One collection at a time in a process, start and stop on the
+        # thread that triggered it. The interpreter runs this wherever
+        # that thread stops next, INSIDE the tracer's locked blocks too:
+        # so it takes no lock and records nothing into the ring itself.
+        # The profiler's annotation is a C++ TraceMe (no Python lock);
+        # the ring's span is handed over lock-free.
+        if phase == "start":
+            ann = _trace_annotation()
+            if ann is not None:
+                ann = ann("rt.gc", generation=info["generation"])
+                ann.__enter__()
+            self._gc = (ann, time.perf_counter(), time.time())
+        elif self._gc is not None:
+            (ann, start, start_s), self._gc = self._gc, None
+            self.gc_pauses += 1
+            self.gc_pause_s += time.perf_counter() - start
+            if ann is not None:
+                ann.set_metadata(collected=info["collected"])
+                ann.__exit__(None, None, None)
+            if _tracer.enabled:
+                parent = current_span()
+                _tracer.record_later(Span(
+                    name="rt.gc", span_id=new_span_id(),
+                    parent_id=parent.span_id if parent else None,
+                    trace_id=(parent.trace_id if parent
+                              else os.urandom(16).hex()),
+                    start_s=start_s, end_s=time.time(),
+                    attributes={"generation": info["generation"],
+                                "collected": info["collected"]}))
+
+    def _on_compile(self, event: str, duration: float, **_) -> None:
+        if event == _COMPILE_EVENT:
+            with self._lock:
+                self.compiles += 1
+                self.compile_s += duration
+
+    def _on_cache(self, event: str, **_) -> None:
+        if event == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.compile_cache_hits += 1
+
+
+_events = ProcessEvents()
+
+
+def process_events() -> ProcessEvents:
+    return _events
+
+
+def watch_gc() -> None:
+    """Every garbage collection of this process from now on is an
+    ``rt.gc`` span (``generation``, ``collected``) on the thread it
+    stopped, and counted in ``gc_pauses`` / ``gc_pause_s``. Idempotent;
+    costs nothing between collections."""
+    if "gc" not in _events._watching:
+        _events._watching.add("gc")
+        gc.callbacks.append(_events._on_gc)
+
+
+def watch_compiles() -> None:
+    """Every program JAX builds for the backend from now on is counted in
+    ``compiles`` / ``compile_s``, and in ``compile_cache_hits`` where the
+    persistent cache had it. Idempotent; a process without JAX watches
+    nothing (and may ask again once it has it)."""
+    jax = sys.modules.get("jax")
+    if jax is None or "compiles" in _events._watching:
+        return
+    _events._watching.add("compiles")
+    jax.monitoring.register_event_duration_secs_listener(
+        _events._on_compile)
+    jax.monitoring.register_event_listener(_events._on_cache)
 
 
 def trace_span(name: Optional[str] = None, **attributes):

@@ -277,7 +277,17 @@ def test_the_manifest_resolves_the_new_cell_and_its_configuration():
     per_layer = {m["name"] for m in cell["metrics"]["per_layer"]}
     assert {"step.decode_ms.lfm2", "step.moe_share", "step.conv_share",
             "kernel.moe_roofline", "moe.experts_hit_share",
-            "moe.load_max_share", "engine.slot_occupancy"} == per_layer
+            "moe.load_max_share", "engine.slot_occupancy",
+            # PR 37: the engine's own spans (tests/test_host_metrics.py
+            # holds each to its file and reader)
+            "engine.dispatch_p50_ms.lfm2", "engine.launch_p50_ms.lfm2",
+            "engine.dispatch_off_cpu_share.lfm2",
+            "engine.deliver_off_cpu_share.lfm2",
+            "engine.idle_dispatch_share.lfm2",
+            "engine.idle_unnamed_share.lfm2", "engine.gc_pause_ms.lfm2",
+            "engine.compiles_in_trace.lfm2",
+            "engine.active_slot_share.lfm2",
+            "engine.prefill_wait_share.lfm2"} == per_layer
     cfg = lfm2_config(cell["config"])
     published = lfm2.CONFIGS["lfm2-24b-a2b"]
     # every width as published; the cut is depth and positions alone

@@ -25,6 +25,13 @@ from ray_tpu.observability import tracing
 CFG = llama.CONFIGS["llama-tiny"]
 STEP_CHILDREN = {"rt.llm.schedule", "rt.llm.dispatch", "rt.llm.fetch",
                  "rt.llm.deliver"}
+# where a dispatch's work happens, each once a dispatched step
+DISPATCH_CHILDREN = {"rt.llm.dispatch.pack", "rt.llm.dispatch.upload",
+                     "rt.llm.dispatch.launch"}
+# the spans a metric or PERF.md's stated operator's read takes the
+# thread's clock on; the sleep / spin test below says what the two mean
+CPU_SPANS = {"rt.llm.step", "rt.llm.dispatch", "rt.llm.dispatch.upload",
+             "rt.llm.deliver"}
 COUNTERS = SlotEngine.STEP_COUNTERS
 # The parent commit's greedy tokens for PROMPT on the CPU (scopes are
 # metadata: the same program, the same tokens).
@@ -104,6 +111,22 @@ def test_step_spans_children_counts_and_token_invariant(params, tracer,
         names = {k.name for k in kids}
         assert ("rt.llm.dispatch" in names) == (a["program"] != "none")
         full += names == STEP_CHILDREN
+        for d in (k for k in kids if k.name == "rt.llm.dispatch"):
+            parts = [s for s in spans if s.parent_id == d.span_id]
+            assert sorted(p.name for p in parts) == sorted(DISPATCH_CHILDREN)
+            assert all(d.start_s <= p.start_s and p.end_s <= d.end_s
+                       for p in parts)
+            launch = next(p.attributes for p in parts
+                          if p.name == "rt.llm.dispatch.launch")
+            # a step with no prompt chunk calls the pure-decode program
+            assert launch["program"] == a["program"]
+    for s in spans:
+        # no bound on one span's off_cpu_us: a kernel that charges CPU
+        # time a tick at a time reads below 0 or all of the wall (PERF.md)
+        assert (s.name in CPU_SPANS) == ("wall_us" in s.attributes) \
+            == ("off_cpu_us" in s.attributes), s.name
+        if s.name in CPU_SPANS:
+            assert s.attributes["wall_us"] > 0
     assert full >= len(steps) // 2  # the steady state has all four
     ran = [s.attributes for s in steps if s.attributes["program"] != "none"]
     assert sum(a["active"] for a in ran) * block \
@@ -214,6 +237,241 @@ def test_pages_read_is_the_dispatched_rows_live_pages(params, tracer):
     assert eng.kv_pages_read == sum(handed)
     # against gathering every table entry of every row, every step
     assert 0 < eng.kv_pages_read < eng.slot_steps * eng._pages_per_seq
+
+
+def test_upload_says_the_arrays_and_bytes_the_step_handed_over(params,
+                                                               tracer):
+    """``rt.llm.dispatch.upload`` counts what the dispatch moved to the
+    device: every argument of the step program but the weights, the cache
+    and the last tokens, which never left it. The page table is most of
+    it: ``[num_slots, pages_per_seq]`` int32."""
+    eng = SlotEngine(params, CFG, num_slots=3, chunk=16, page_size=8,
+                     prefix_cache=False)
+    handed = []
+
+    def watching(fn):
+        def call(*args):
+            moved = args[2:5] + args[6:]   # args[5]: _last_dev
+            handed.append((len(moved), sum(a.nbytes for a in moved)))
+            return fn(*args)
+        return call
+
+    eng._decode_only = watching(eng._decode_only)
+    eng._block = watching(eng._block)
+    for i in range(4):
+        eng.submit(list(range(1, 12 + 9 * i)), max_new=5)
+    _drain(eng)
+    up = [s.attributes for s in tracer.spans("rt.llm.dispatch.upload")]
+    assert [(a["arrays"], a["bytes"]) for a in up] == handed
+    table = 3 * (CFG.max_seq // 8) * 4
+    rows = 3 * (4 + 1 + 4 + 4 + 4)
+    assert set(handed) == {(6, table + rows),
+                           (12, table + rows + 16 * 4 + 5 * 4)}
+
+
+def test_deliver_counts_the_callbacks_it_made(params, tracer):
+    """``callbacks`` on ``rt.llm.deliver``: one ``on_token`` call a token
+    and one more at the end of a request that asked for them — the
+    wake-ups of the caller's thread a step. A request is among
+    ``requests_completed`` BEFORE its caller is told it ended: the
+    callback wakes the caller's thread, which may read ``stats()`` before
+    the engine thread runs again (the benchmark's counter check did, on a
+    loaded host)."""
+    eng = SlotEngine(params, CFG, num_slots=2, chunk=16)
+    got, counted_at_end = [], []
+
+    def on_token(tok):
+        got.append(tok)
+        if tok is None:
+            counted_at_end.append(eng.requests_completed)
+
+    eng.submit(PROMPT, max_new=5, on_token=on_token)
+    eng.submit(PROMPT[:7], max_new=3)            # no callback asked for
+    _drain(eng)
+    deliver = [s.attributes for s in tracer.spans("rt.llm.deliver")]
+    assert sum(a["callbacks"] for a in deliver) == len(got) == 6
+    assert counted_at_end == [2]   # the shorter request, then this one
+    assert sum(a["delivered"] for a in deliver) == 8
+
+
+@pytest.mark.parametrize("body", ["sleeps", "spins"])
+def test_cpu_span_tells_a_blocked_thread_from_a_busy_one(tracer, body):
+    """``step_span(cpu=True)``: ``off_cpu_us`` is the part of ``wall_us``
+    this thread was not on a CPU. A body that sleeps 0.2 s reads nearly
+    all of it; one that spins until ITS OWN clock has moved 0.2 s reads
+    that much less than its wall time, however long other processes kept
+    it from its core. A span without ``cpu`` reads no clock. The room is
+    two 10 ms ticks: the machines the chip is measured on charge a thread
+    its CPU time a tick at a time, so ONE short span there reads all of
+    its wall time or less than none (not clamped: sums stay true)."""
+    with tracing.step_span("rt.test.body", cpu=True):
+        if body == "sleeps":
+            time.sleep(0.2)
+        else:
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < 0.2:
+                pass
+    with tracing.step_span("rt.test.plain"):
+        pass
+    (sp,) = tracer.spans("rt.test.body")
+    wall, off = sp.attributes["wall_us"], sp.attributes["off_cpu_us"]
+    assert wall >= 0.19e6
+    on_cpu = wall - off
+    if body == "sleeps":
+        assert -0.02e6 <= on_cpu <= 0.02e6
+    else:
+        assert 0.18e6 <= on_cpu <= 0.24e6
+    assert tracer.spans("rt.test.plain")[0].attributes == {}
+
+
+def test_gc_is_a_span_and_a_count(tracer):
+    """``watch_gc`` (idempotent): a collection is one ``rt.gc`` span with
+    its generation and what it collected, and moves ``gc_pauses`` and
+    ``gc_pause_s``."""
+    import gc
+
+    hooks = len(gc.callbacks)
+    tracing.watch_gc()
+    tracing.watch_gc()
+    assert len(gc.callbacks) <= hooks + 1
+    events = tracing.process_events()
+    gc.collect()    # what was lying about goes here, not into the count
+    tracer.clear()
+    before = events.counters()
+    ring = [[]]
+    ring[0].append(ring)   # one cycle for the collector to find
+    del ring
+    gc.collect()
+    spans = [s for s in tracer.spans("rt.gc")
+             if s.attributes["generation"] == 2]
+    assert len(spans) == 1 and spans[0].attributes["collected"] >= 1
+    assert spans[0].end_s >= spans[0].start_s
+    after = events.counters()
+    assert after["gc_pauses"] >= before["gc_pauses"] + 1
+    assert after["gc_pause_s"] > before["gc_pause_s"]
+
+
+def test_compiles_are_counted_and_a_launch_says_what_it_built(params,
+                                                              tracer):
+    """``watch_compiles``: jitting a new function moves ``compiles`` and
+    ``compile_s``, calling it again does not; the ``rt.llm.dispatch.launch``
+    that first calls a step program says it built one, and no later one
+    does — a compile under load would show as ``compiled`` in a trace."""
+    tracing.watch_compiles()
+    tracing.watch_compiles()
+    events = tracing.process_events()
+    x = jnp.arange(7.0)
+    salt = time.time()   # a constant no earlier test compiled
+
+    @jax.jit
+    def fresh(v):
+        return v * salt + 3.0
+
+    before = events.counters()
+    fresh(x).block_until_ready()
+    built = events.counters()
+    assert built["compiles"] == before["compiles"] + 1
+    assert built["compile_s"] > before["compile_s"]
+    fresh(x).block_until_ready()
+    assert events.counters()["compiles"] == built["compiles"]
+
+    # a geometry no other test of this file builds: both programs are new
+    eng = SlotEngine(params, CFG, num_slots=5, chunk=8, page_size=8)
+    tracer.clear()
+    eng.submit(PROMPT, max_new=6)
+    _drain(eng)
+    launches = [s.attributes for s in tracer.spans("rt.llm.dispatch.launch")]
+    first = {}
+    for a in launches:
+        first.setdefault(a["program"], a["compiled"])
+    assert first["block"] >= 1 and first["decode_only"] >= 1
+    assert sum(a["compiled"] for a in launches) \
+        == first["block"] + first["decode_only"]
+
+
+@pytest.mark.parametrize("how", ["hook_called", "collection_forced"])
+def test_a_collection_inside_the_tracers_lock_does_not_deadlock(tracer, how):
+    """The interpreter runs a scheduled collection wherever the thread
+    stops next, inside ``Tracer._lock``'s blocks too (``record`` holds it
+    round the ring's append on every span of the engine loop). The hook
+    therefore takes no lock: its ``rt.gc`` span waits in a lock-free queue
+    for the next record or read from ordinary code."""
+    import gc
+    import threading
+
+    tracing.watch_gc()
+    events = tracing.process_events()
+    gc.collect()
+    tracer.clear()
+    before = events.gc_pauses
+
+    def inside_the_lock():
+        with tracer._lock:
+            if how == "hook_called":
+                events._on_gc("start", {"generation": 2, "collected": 0,
+                                        "uncollectable": 0})
+                events._on_gc("stop", {"generation": 2, "collected": 3,
+                                       "uncollectable": 0})
+            else:
+                gc.collect()
+
+    th = threading.Thread(target=inside_the_lock, daemon=True)
+    th.start()
+    th.join(timeout=20)
+    assert not th.is_alive(), "the gc hook waited for the tracer's lock"
+    assert events.gc_pauses >= before + 1
+    # nothing reached the ring from the hook itself; a read moves it there
+    assert not [s for s in tracer._spans if s.name == "rt.gc"]
+    (sp,) = [s for s in tracer.spans("rt.gc")
+             if s.attributes["generation"] == 2]
+    assert sp.end_s >= sp.start_s
+    # ... and so does the next span that ordinary code records
+    events._on_gc("start", {"generation": 0, "collected": 0,
+                            "uncollectable": 0})
+    events._on_gc("stop", {"generation": 0, "collected": 0,
+                           "uncollectable": 0})
+    with tracing.step_span("rt.test.after"):
+        pass
+    names = [s.name for s in tracer._spans]
+    assert names[-1] == "rt.test.after" and names[-2] == "rt.gc"
+
+
+def test_a_long_queue_of_collections_is_drained_in_a_loop(tracer):
+    """A traced process that records nothing for a while queues one
+    ``rt.gc`` a collection (the newest 1024 are kept); the next record
+    moves them all into the ring without recursing once a span."""
+    events = tracing.process_events()
+    info = {"generation": 0, "collected": 0, "uncollectable": 0}
+    for _ in range(1500):
+        events._on_gc("start", info)
+        events._on_gc("stop", info)
+    with tracing.step_span("rt.test.after"):
+        pass
+    assert not tracer._late or len(tracer._late) < 5  # a collection since
+    assert len([s for s in tracer._spans if s.name == "rt.gc"]) >= 1024
+    assert tracer.spans("rt.test.after")
+
+
+def test_the_engine_thread_names_its_waits_for_the_lock(params, tracer):
+    """Between two steps the engine thread takes the engine's lock twice
+    (to look for work, to step): each wait is an ``rt.llm.acquire`` span
+    (a wait by its nature: no clock but the wall's), so no stretch of the
+    loop is without a name.
+    Starting the thread watches collections and compiles."""
+    import gc
+
+    eng = SlotEngine(params, CFG, num_slots=2, chunk=16).start()
+    try:
+        assert eng.submit(PROMPT, max_new=6).result(timeout=120).tokens
+    finally:
+        eng.stop()
+    steps = tracer.spans("rt.llm.step")
+    acquires = tracer.spans("rt.llm.acquire")
+    assert len(acquires) >= 2 * len(steps) > 0
+    assert all(a.end_s >= a.start_s for a in acquires)
+    pauses = tracing.process_events().gc_pauses
+    gc.collect()
+    assert tracing.process_events().gc_pauses > pauses
 
 
 def _engine_lowered(eng):
@@ -330,7 +588,14 @@ def test_step_spans_reach_a_profiler_trace(params, tmp_path):
                 if ev.name.startswith("rt.llm."):
                     found.setdefault(ev.name, []).append(
                         (ev.start_ns, ev.duration_ns, dict(ev.stats)))
-    assert STEP_CHILDREN | {"rt.llm.step"} <= set(found)
+    assert STEP_CHILDREN | DISPATCH_CHILDREN | {"rt.llm.step"} <= set(found)
+    for name in CPU_SPANS:
+        assert all(st["wall_us"] > 0 and "off_cpu_us" in st
+                   for _, _, st in found[name]), name
+    upload = found["rt.llm.dispatch.upload"][0][2]
+    assert upload["arrays"] == 12 and upload["bytes"] > 0
+    assert {st["program"] for _, _, st in found["rt.llm.dispatch.launch"]} \
+        == {"block", "decode_only"}
     steps = found["rt.llm.step"]
     assert all(d > 0 for _, d, _ in steps)
     stats = steps[0][2]
