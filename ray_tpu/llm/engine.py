@@ -33,6 +33,11 @@ Design (TPU-first, static shapes throughout):
   (fewer prefill dispatches) produces bit-for-bit the same output as a
   cold one — only ``[num_slots]`` int32 tokens cross the device
   boundary per step, never ``[B, vocab]`` logits.
+- Towards the device a dispatch is ONE transfer: the rows' overrides,
+  positions, temperatures and seeds, the page table and the lane's
+  chunk packed into one int32 vector (:class:`HostInputs`) that the
+  program takes apart by static slices. The TPU's runtime charges a
+  transfer by the call, not by the byte.
 
 Exactly two compiled programs serve any mix of request lengths (for a
 family that asks for ``one_program``, the fused one alone); there is no
@@ -226,12 +231,86 @@ class _Slot:
         return self.prefill_offset >= len(self.prompt)
 
 
-def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
+class HostInputs:
+    """Where a dispatch's host-side inputs lie in the ONE int32 vector
+    that carries them to the device, ``host_in``: the per-row vectors
+    (``rows`` words each, in ``ROWS``' order), the page table (``rows x
+    pages_per_seq``), then, for the fused program (``chunk`` given), the
+    lane's prompt chunk and its five scalars. On the TPU's runtime a
+    transfer costs per call, not per byte (0.25 ms for 64 bytes as for
+    256 KiB, PERF.md Findings PR 38), so everything rides one. The packer
+    (:meth:`idle`, :meth:`views`) and the two programs (:meth:`unpack`)
+    both read the offsets from here, so they cannot drift. A float rides
+    as its bits (``temps`` and ``lane_temp`` are float32 views of their
+    words on the host and a ``bitcast_convert_type`` in the program: no
+    rounding, no convert), the mask as 0 / 1."""
+
+    ROWS = ("override_vals", "override_mask", "pos", "temps", "seeds")
+    LANE = ("lane_slot", "p0", "n_valid", "lane_temp", "lane_seed")
+    FLOATS = ("temps", "lane_temp")
+
+    def __init__(self, rows: int, pages_per_seq: int,
+                 chunk: Optional[int] = None):
+        self.table_shape = (rows, pages_per_seq)
+        sizes = [(name, rows) for name in self.ROWS]
+        sizes.append(("tables", rows * pages_per_seq))
+        if chunk is not None:
+            sizes += [("pre_tokens", chunk)] + [(n, 1) for n in self.LANE]
+        self.fields: Dict[str, slice] = {}
+        at = 0
+        for name, n in sizes:
+            self.fields[name] = slice(at, at + n)
+            at += n
+        self.size = at
+
+    def idle(self, parked_pos: int) -> np.ndarray:
+        """What a dispatch with no active row, no page mapped and an
+        empty lane sends: every row takes its (zero) override and sits
+        at ``parked_pos``, and the lane's n_valid 0 writes nothing."""
+        buf = np.zeros((self.size,), np.int32)
+        buf[self.fields["override_mask"]] = 1
+        buf[self.fields["pos"]] = parked_pos
+        return buf
+
+    def views(self, buf: np.ndarray) -> Dict[str, np.ndarray]:
+        """``buf``'s fields by name, each a writable view of its words
+        (float32 for the temperatures, ``[rows, pages_per_seq]`` for the
+        table)."""
+        out = {name: (buf[at].view(np.float32) if name in self.FLOATS
+                      else buf[at]) for name, at in self.fields.items()}
+        out["tables"] = out["tables"].reshape(self.table_shape)
+        return out
+
+    def unpack(self, host_in) -> Dict[str, jax.Array]:
+        """The same fields inside a jitted program, by static slices: the
+        lane's scalars as scalars, the mask as a bool."""
+        if host_in.shape != (self.size,) or host_in.dtype != jnp.int32:
+            raise TypeError(f"host_in is {host_in.dtype}{host_in.shape}, "
+                            f"this program takes int32[{self.size}]")
+        out = {}
+        for name, at in self.fields.items():
+            x = host_in[at.start] if name in self.LANE else host_in[at]
+            if name in self.FLOATS:
+                x = jax.lax.bitcast_convert_type(x, jnp.float32)
+            out[name] = x
+        out["override_mask"] = out["override_mask"] != 0
+        out["tables"] = out["tables"].reshape(self.table_shape)
+        return out
+
+
+def build_step_programs(cfg, page_size: int, decode_block: int, rows: int,
+                        chunk: int, rules=None):
     """The engine's two step programs, un-jitted: ``(block_fn,
     decode_only_fn)``, round the one step of ``cfg``'s family
-    (``models/serving.py``). ``SlotEngine`` jits them with the cache
-    donated; tests/test_tpu_compile.py compiles the same two for a
-    described chip from shapes alone.
+    (``models/serving.py``). Both take ``(params, cache, prev_last,
+    host_in)``: the last block's tokens (which never left the device)
+    and ONE int32 vector holding everything a dispatch hands over — the
+    rows' vectors, the page table and, for ``block_fn``, the lane — laid
+    out as ``HostInputs(rows, max_seq // page_size, chunk)`` for
+    ``block_fn``, without ``chunk`` for ``decode_only_fn``, and taken
+    apart by static slices before anything else runs. ``SlotEngine``
+    jits them with the cache donated; tests/test_tpu_compile.py compiles
+    the same two for a described chip from shapes alone.
 
     A family that names ``step_counters`` returns their counts as a
     fourth result of its step; they leave the program as further columns
@@ -240,6 +319,9 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
     names none gets the programs it always got."""
     model = serving.model_for(cfg)
     counted = bool(model.step_counters)
+    pages_per_seq = cfg.max_seq // page_size
+    fused_in = HostInputs(rows, pages_per_seq, chunk)
+    decode_in = HostInputs(rows, pages_per_seq)
 
     def step(*args):
         out = model.step(*args, cfg, page_size, rules)
@@ -250,22 +332,23 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
         return (jnp.concatenate([toks_k, counts_k], axis=1) if counted
                 else toks_k)
 
-    def block_fn(params, cache, tables, override_vals, override_mask,
-                 prev_last, pos, temps, seeds,
-                 pre_tokens, pre_slot, pre_p0, pre_n_valid,
-                 pre_temp, pre_seed):
+    def block_fn(params, cache, prev_last, host_in):
         """K-token decode block with the prefill lane fused into the
         FIRST step: a prompt chunk rides the same params read as the
         decode batch, so prefill no longer costs a separate full-model
         pass."""
-        tokens0 = jnp.where(override_mask, override_vals, prev_last)
+        h = fused_in.unpack(host_in)
+        tables, pos, temps, seeds = (h["tables"], h["pos"], h["temps"],
+                                     h["seeds"])
+        tokens0 = jnp.where(h["override_mask"], h["override_vals"],
+                            prev_last)
         dec_logits, pre_logits, cache, counts = step(
             params, cache, tables, tokens0, pos,
-            (pre_tokens, pre_slot, pre_p0, pre_n_valid))
+            (h["pre_tokens"], h["lane_slot"], h["p0"], h["n_valid"]))
         tok1 = _sample(dec_logits, temps, seeds, pos + 1)
-        pre_tok = _sample(pre_logits[None], pre_temp[None],
-                          pre_seed[None],
-                          (pre_p0 + pre_n_valid)[None])[0]
+        pre_tok = _sample(pre_logits[None], h["lane_temp"][None],
+                          h["lane_seed"][None],
+                          (h["p0"] + h["n_valid"])[None])[0]
 
         if decode_block == 1:  # nothing to scan: trace no second program
             return (with_counts(tok1[None], counts[None] if counted
@@ -285,12 +368,15 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rules=None):
             counts_rest = jnp.concatenate([counts[None], counts_rest])
         return with_counts(toks_k, counts_rest), last, pre_tok, cache
 
-    def decode_only_fn(params, cache, tables, override_vals,
-                       override_mask, prev_last, pos, temps, seeds):
+    def decode_only_fn(params, cache, prev_last, host_in):
         """Pure K-step decode block — dispatched whenever no prompt
         chunk is pending, so idle steps never pay the fused
         program's C-token prefill lane."""
-        tokens0 = jnp.where(override_mask, override_vals, prev_last)
+        h = decode_in.unpack(host_in)
+        tables, pos, temps, seeds = (h["tables"], h["pos"], h["temps"],
+                                     h["seeds"])
+        tokens0 = jnp.where(h["override_mask"], h["override_vals"],
+                            prev_last)
 
         def body(carry, _):
             toks, cache, p = carry
@@ -432,7 +518,20 @@ class SlotEngine:
         self._base_seed = seed
         self._req_counter = 0
         block_fn, decode_only_fn = build_step_programs(
-            cfg, page_size, decode_block, self._rules)
+            cfg, page_size, decode_block, num_slots, chunk, self._rules)
+        # What a dispatch packs its rows, the page table and the lane
+        # into, by whether the program is the fused one: the layout, and
+        # the vector of a dispatch with nothing in it. Each dispatch fills
+        # a COPY: a transfer may still be reading the last one (on the CPU
+        # backend the device array may alias it outright). Parked rows sit
+        # AT max_seq: the paged scatter routes any write at pos >= max_seq
+        # to the scratch page, so a parked row can never touch a live
+        # (possibly shared) page.
+        self._host_in = {
+            fused: (layout, layout.idle(cfg.max_seq))
+            for fused, layout in (
+                (True, HostInputs(num_slots, self._pages_per_seq, chunk)),
+                (False, HostInputs(num_slots, self._pages_per_seq)))}
 
         # The cache is donated, and as compiled for the TPU a step
         # touches it only in place: the layer loop carries the pool whole
@@ -1153,8 +1252,6 @@ class SlotEngine:
         :func:`prefill_lane` derived. Continuing slots chain their
         input token device-side; freshly prefilled slots inject theirs
         via the override vector."""
-        cfg = self.cfg
-        rows = self.num_slots
         # The fused program unless no prompt chunk is pending and the
         # family has the cheap pure-decode program. With no prompt
         # pending (a family whose rows repeat bit for bit only within ONE
@@ -1162,30 +1259,27 @@ class SlotEngine:
         # is empty: n_valid 0 writes nothing, and what it samples nobody
         # reads.
         fused = prefill_idx is not None or self._model.one_program
+        layout, idle = self._host_in[fused]
         with tracing.step_span("rt.llm.dispatch.pack"):
-            override_vals = np.zeros((rows,), dtype=np.int32)
-            override_mask = np.ones((rows,), dtype=bool)
-            # Parked rows sit AT max_seq: the paged scatter routes any
-            # write at pos >= max_seq to the scratch page, so a parked
-            # row can never touch a live (possibly shared) page.
-            pos = np.full((rows,), cfg.max_seq, dtype=np.int32)
-            temps = np.zeros((rows,), dtype=np.float32)
-            seeds = np.zeros((rows,), dtype=np.int32)
+            host_in = idle.copy()
+            f = layout.views(host_in)
+            # the table as it stands NOW: the live one is written again
+            # at the next admission or release
+            f["tables"][:] = self._tables
+            pos, temps, seeds = f["pos"], f["temps"], f["seeds"]
+            override_vals, override_mask = (f["override_vals"],
+                                            f["override_mask"])
             for i, s in active:
                 pos[i] = s.pos
                 temps[i] = s.temperature
                 seeds[i] = s.seed
                 if s.on_device_chain:
-                    override_mask[i] = False
+                    override_mask[i] = 0
                 else:
                     override_vals[i] = s.last_token
             # Prefill lane: one chunk of one slot's prompt rides the
             # fused program's first step.
             pre_info = None
-            if fused:
-                pre_buf = np.zeros((self.chunk,), dtype=np.int32)
-                lane_slot = p0 = n_valid = lane_seed = 0
-                lane_temp = 0.0
             if prefill_idx is not None:
                 s = self._slots[prefill_idx]
                 if s.prefill_start_t == 0.0:
@@ -1193,37 +1287,25 @@ class SlotEngine:
                 p0 = s.prefill_offset
                 piece = s.prompt[p0:p0 + self.chunk]
                 n_valid = len(piece)
-                pre_buf[:n_valid] = piece
+                f["pre_tokens"][:n_valid] = piece
                 s.prefill_offset = p0 + n_valid
                 final = s.prefill_done
                 if final:
                     s.first_tok_pending = True
                 pre_info = (prefill_idx, s, final)
-                lane_slot, lane_temp, lane_seed = (
-                    prefill_idx, s.temperature, s.seed)
+                f["lane_slot"][0], f["p0"][0] = prefill_idx, p0
+                f["n_valid"][0], f["lane_seed"][0] = n_valid, s.seed
+                f["lane_temp"][0] = s.temperature
         with tracing.step_span("rt.llm.dispatch.upload", cpu=True) as sp:
-            # in the order the programs take them; _last_dev, between
-            # the mask and the positions, never left the device
-            uploaded = [jnp.asarray(self._tables),
-                        jnp.asarray(override_vals),
-                        jnp.asarray(override_mask), jnp.asarray(pos),
-                        jnp.asarray(temps), jnp.asarray(seeds)]
-            if fused:
-                uploaded += [jnp.asarray(pre_buf),
-                             jnp.asarray(lane_slot, jnp.int32),
-                             jnp.asarray(p0, jnp.int32),
-                             jnp.asarray(n_valid, jnp.int32),
-                             jnp.asarray(lane_temp, jnp.float32),
-                             jnp.asarray(lane_seed, jnp.int32)]
+            # ONE transfer a dispatch; _last_dev never left the device
+            host_dev = jnp.asarray(host_in)
             if sp.recording:
-                sp.set(arrays=len(uploaded),
-                       bytes=sum(a.nbytes for a in uploaded))
+                sp.set(arrays=1, bytes=host_in.nbytes)
         with tracing.step_span("rt.llm.dispatch.launch") as sp:
             built = tracing.process_events().compiles
             pre_tok = None
             step = self._block if fused else self._decode_only
-            out = step(self._params, self._cache, *uploaded[:3],
-                       self._last_dev, *uploaded[3:])
+            out = step(self._params, self._cache, self._last_dev, host_dev)
             if fused:
                 toks_k, self._last_dev, pre_tok, self._cache = out
             else:

@@ -156,6 +156,37 @@ def test_engine_serves_a_stub_model(toy, decode_block):
     assert eng.requests_completed == 3
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.7],
+                         ids=["greedy", "sampled"])
+def test_stub_model_through_the_packed_dispatch(toy, temperature):
+    """The third family of tests/test_packed_dispatch.py's first test: a
+    record the engine never heard of gets its rows, lane and sampling
+    stream through the same one vector a dispatch. Four requests over two
+    slots (prompts admitted while the other slot decodes), each token
+    drawn as ``engine._sample`` draws it from what the toy says next."""
+    eng = toy_engine(toy, prefix_cache=False)
+    prompts = [list(range(3, 3 + n)) for n in (13, 5, 22, 9)]
+    seeds = [11, 2**31 - 9, 5, 77]
+    handles = [eng.submit(p, max_new=7 + i, temperature=temperature, seed=s)
+               for i, (p, s) in enumerate(zip(prompts, seeds))]
+    drain(eng, handles)
+    assert eng.steps_block > 0 and eng.steps_decode_only > 0
+    for prompt, seed, h in zip(prompts, seeds, handles):
+        history, want = list(prompt), []
+        for _ in h.result(timeout=0).tokens:
+            tok = toy_next(history)
+            if temperature > 0:
+                logits = jax.nn.one_hot(tok, toy.vocab_size,
+                                        dtype=jnp.float32)
+                key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                         len(history))
+                tok = int(jax.random.categorical(
+                    key, logits / jnp.float32(temperature)))
+            want.append(tok)
+            history.append(tok)
+        assert h.result(timeout=0).tokens == want
+
+
 def test_stub_model_session_export_import(toy):
     """A session leaves one engine as frames only the record can read
     and continues on another from the imported pages — both leaves of
@@ -185,7 +216,7 @@ def test_a_config_of_no_family_is_refused():
         max_seq: int = 64
 
     with pytest.raises(TypeError, match="Orphan"):
-        build_step_programs(Orphan(), 4, 1)
+        build_step_programs(Orphan(), 4, 1, 2, 8)
     with pytest.raises(KeyError):
         serving.named("no-such-model")
 

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm.engine import SlotEngine
+from ray_tpu.llm.engine import HostInputs, SlotEngine
 from ray_tpu.models import gpt2, llama
 from ray_tpu.observability import tracing
 
@@ -216,12 +216,14 @@ def test_pages_read_is_the_dispatched_rows_live_pages(params, tracer):
     handed = []
 
     def watching(fn, lane):
+        layout = HostInputs(3, CFG.max_seq // ps, 16 if lane else None)
+
         def call(*args):
-            pos = np.asarray(args[6])
-            pages = sum(-(-(int(p) + k + 1) // ps) for p in pos
+            h = layout.views(np.asarray(args[3]))
+            pages = sum(-(-(int(p) + k + 1) // ps) for p in h["pos"]
                         for k in range(block) if p + k < CFG.max_seq)
             if lane:
-                pages += -(-(int(args[11]) + int(args[12])) // ps)
+                pages += -(-(int(h["p0"][0]) + int(h["n_valid"][0])) // ps)
             handed.append(pages)
             return fn(*args)
         return call
@@ -242,16 +244,17 @@ def test_pages_read_is_the_dispatched_rows_live_pages(params, tracer):
 def test_upload_says_the_arrays_and_bytes_the_step_handed_over(params,
                                                                tracer):
     """``rt.llm.dispatch.upload`` counts what the dispatch moved to the
-    device: every argument of the step program but the weights, the cache
-    and the last tokens, which never left it. The page table is most of
-    it: ``[num_slots, pages_per_seq]`` int32."""
+    device: ONE packed vector — five words a row, the page table
+    (``[num_slots, pages_per_seq]`` int32 and most of the bytes) and, in
+    the fused program's, the lane's chunk and five scalars. The weights,
+    the cache and the last tokens never left the device."""
     eng = SlotEngine(params, CFG, num_slots=3, chunk=16, page_size=8,
                      prefix_cache=False)
     handed = []
 
     def watching(fn):
         def call(*args):
-            moved = args[2:5] + args[6:]   # args[5]: _last_dev
+            moved = args[3:]    # params, cache, _last_dev, then host_in
             handed.append((len(moved), sum(a.nbytes for a in moved)))
             return fn(*args)
         return call
@@ -264,9 +267,9 @@ def test_upload_says_the_arrays_and_bytes_the_step_handed_over(params,
     up = [s.attributes for s in tracer.spans("rt.llm.dispatch.upload")]
     assert [(a["arrays"], a["bytes"]) for a in up] == handed
     table = 3 * (CFG.max_seq // 8) * 4
-    rows = 3 * (4 + 1 + 4 + 4 + 4)
-    assert set(handed) == {(6, table + rows),
-                           (12, table + rows + 16 * 4 + 5 * 4)}
+    rows = 3 * 5 * 4
+    assert set(handed) == {(1, table + rows),
+                           (1, table + rows + 16 * 4 + 5 * 4)}
 
 
 def test_deliver_counts_the_callbacks_it_made(params, tracer):
@@ -477,13 +480,12 @@ def test_the_engine_thread_names_its_waits_for_the_lock(params, tracer):
 def _engine_lowered(eng):
     rows = eng.num_slots
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    common = (eng._params, eng._cache, i32(rows, eng._pages_per_seq),
-              i32(rows), jax.ShapeDtypeStruct((rows,), jnp.bool_),
-              i32(rows), i32(rows),
-              jax.ShapeDtypeStruct((rows,), jnp.float32), i32(rows))
-    fused = common + (i32(eng.chunk), i32(), i32(), i32(),
-                      jax.ShapeDtypeStruct((), jnp.float32), i32())
-    return eng._block.lower(*fused), eng._decode_only.lower(*common)
+    common = (eng._params, eng._cache, i32(rows))
+    pages = eng._pages_per_seq
+    return (eng._block.lower(
+                *common, i32(HostInputs(rows, pages, eng.chunk).size)),
+            eng._decode_only.lower(
+                *common, i32(HostInputs(rows, pages).size)))
 
 
 def _train_step():
@@ -593,7 +595,7 @@ def test_step_spans_reach_a_profiler_trace(params, tmp_path):
         assert all(st["wall_us"] > 0 and "off_cpu_us" in st
                    for _, _, st in found[name]), name
     upload = found["rt.llm.dispatch.upload"][0][2]
-    assert upload["arrays"] == 12 and upload["bytes"] > 0
+    assert upload["arrays"] == 1 and upload["bytes"] > 0
     assert {st["program"] for _, _, st in found["rt.llm.dispatch.launch"]} \
         == {"block", "decode_only"}
     steps = found["rt.llm.step"]

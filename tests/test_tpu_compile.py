@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.llm.engine import (SlotEngine, build_step_programs,
+from ray_tpu.llm.engine import (HostInputs, SlotEngine, build_step_programs,
                                 prefill_lane)
 from ray_tpu.models import lfm2, llama, serving
 from ray_tpu.ops import attention as A
@@ -247,13 +247,14 @@ def _engine_program_specs(cfg, sharding, mesh_rules=None, lane=CHUNK):
     cache = jax.tree.map(lambda x, w: sds(x.shape, x.dtype, w),
                          cache, placed[1])
     (pool,) = jax.tree.leaves(cache)
+    # params, cache, the last tokens, and the ONE packed vector of
+    # everything a dispatch hands over (rows, page table, lane)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
-    common = (params, cache, i32(SLOTS, cfg.max_seq // PAGE),
-              i32(SLOTS), sds((SLOTS,), jnp.bool_), i32(SLOTS), i32(SLOTS),
-              sds((SLOTS,), jnp.float32), i32(SLOTS))
-    fused = common + (i32(lane), i32(), i32(), i32(),
-                      sds((), jnp.float32), i32())
-    return {"block": fused, "decode_only": common}, pool
+    common = (params, cache, i32(SLOTS))
+    tables = cfg.max_seq // PAGE
+    return {"block": common + (i32(HostInputs(SLOTS, tables, lane).size),),
+            "decode_only": common + (i32(HostInputs(SLOTS, tables).size),)
+            }, pool
 
 
 _COMPILED_STEPS = {}
@@ -270,7 +271,8 @@ def _compiled_step(v5e, config, program):
         lane = _derived_lane(v5e, cfg) if program == "block-derived" else CHUNK
         specs, pool = _engine_program_specs(
             cfg, SingleDeviceSharding(v5e[0]), lane=lane)
-        block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
+        block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1, SLOTS,
+                                                       lane)
         which = "decode_only" if program == "decode_only" else "block"
         fn = {"block": block_fn, "decode_only": decode_only_fn}[which]
         _COMPILED_STEPS[config, program] = jax.jit(
@@ -350,12 +352,12 @@ def test_lfm2_programs_touch_pool_and_slot_state_only_in_place(v5e, program):
     params, cache = (jax.tree.map(sds, t) for t in (params, cache))
     arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=where)
-    common = (params, cache, arg((LFM2_SLOTS, cfg.max_seq // PAGE)),
-              arg((LFM2_SLOTS,)), arg((LFM2_SLOTS,), jnp.bool_), arg((LFM2_SLOTS,)),
-              arg((LFM2_SLOTS,)), arg((LFM2_SLOTS,), jnp.float32), arg((LFM2_SLOTS,)))
-    fused = common + (arg((CHUNK,)), arg(()), arg(()), arg(()),
-                      arg((), jnp.float32), arg(()))
-    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1)
+    common = (params, cache, arg((LFM2_SLOTS,)))
+    tables = cfg.max_seq // PAGE
+    fused = common + (arg((HostInputs(LFM2_SLOTS, tables, CHUNK).size,)),)
+    common += (arg((HostInputs(LFM2_SLOTS, tables).size,)),)
+    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1, LFM2_SLOTS,
+                                                   CHUNK)
     fn, specs = ((block_fn, fused) if program == "block"
                  else (decode_only_fn, common))
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*specs).compile()
@@ -473,7 +475,8 @@ def test_engine_programs_at_tp2_add_no_collective(v5e, program):
     rules = prune_rules_for_mesh(mesh, dict(SlotEngine.SERVE_RULES))
     specs, _ = _engine_program_specs(cfg, NamedSharding(mesh, P()),
                                      (mesh, rules))
-    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1, rules)
+    block_fn, decode_only_fn = build_step_programs(cfg, PAGE, 1, SLOTS,
+                                                   CHUNK, rules)
     fn = block_fn if program == "block" else decode_only_fn
     text = under_mesh(mesh, lambda: jax.jit(fn, donate_argnums=(1,)).lower(
         *specs[program]).compile().as_text())()
