@@ -20,8 +20,9 @@ The layer, ``u`` the normed input (RMSNorm with the config's ``norm_eps``):
   normalised to sum 1 (``norm_topk_prob``), times
   ``routed_scaling_factor``; ``sum_e w_e W2_e(silu(W1_e u) * W3_e u)``.
   No capacity and no dropped token: rows are sorted by expert and each
-  expert multiplies exactly the rows routed to it
-  (``ops/grouped_matmul.py``).
+  expert multiplies exactly the rows routed to it (``models/moe.py``,
+  the expert layer the sparse serving families share;
+  ``ops/grouped_matmul.py``).
 
 What it offers the engine (``models/serving.py``): one step over a cache
 of two kinds side by side — ``cache["kv"]``, the page pool of the
@@ -65,18 +66,20 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import paged_attention as paged_attention_op
-from ..ops.grouped_matmul import grouped_matmul
 from . import llama, serving
 from .common import rms_norm
 # the attention layers are the Llama family's paged attention: same pool
 # layout, same kernel, same rotary step on the projections' flat lanes
 from .llama import (PAGED_KV_AXES, _write_and_attend, rope_lane_tables,
                     rope_lanes)
+# the expert layer is the one every sparse serving family calls; this
+# family holds all of its experts (``held=None``)
+from .moe import EXPERT_COUNTERS, experts_ffn, route  # noqa: F401
 
 CONV, ATTN = "conv", "full_attention"
 PERIOD = (ATTN, CONV, CONV, CONV)
 # what a step counts, in this order (SlotEngine.STEP_COUNTERS)
-STEP_COUNTERS = ("experts_hit", "expert_rows", "expert_rows_max")
+STEP_COUNTERS = EXPERT_COUNTERS
 
 
 @dataclass(frozen=True)
@@ -325,55 +328,6 @@ def _head_norm(x, scale, heads: int, eps: float):
     lead = x.shape[:-1]
     return rms_norm(x.reshape(lead + (heads, -1)), scale, eps).reshape(
         x.shape)
-
-
-def route(u, p, cfg: Lfm2Config):
-    """u [N, d] float32, the normed input before it is rounded to the
-    model's dtype -> (experts [N, k] int32, weights [N, k] float32), all
-    in float32 at the highest matmul precision: a bfloat16 score would
-    reorder the 4th and 5th expert far more often than the reference's
-    own near-ties do."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        u.astype(jnp.float32), p["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    pick_by = scores
-    if cfg.use_expert_bias:
-        pick_by = scores + p["expert_bias"].astype(jnp.float32)
-    _, experts = jax.lax.top_k(pick_by, cfg.num_experts_per_tok)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
-    if cfg.norm_topk_prob:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
-    return experts.astype(jnp.int32), weights * cfg.routed_scaling_factor
-
-
-def experts_ffn(u, experts, weights, valid, p, cfg: Lfm2Config):
-    """The routed experts' SwiGLU on u [N, d] -> (out [N, d], counts
-    [3] int32 as STEP_COUNTERS names them).
-
-    Every (row, pick) is one row of a grouped product: sorted by expert,
-    an expert multiplies exactly its own rows, as many as there are —
-    nothing is dropped and nothing is padded to a capacity. Rows that are
-    not ``valid`` (parked decode rows, a chunk's tail) sort behind every
-    expert's and belong to no group, so they cost no product."""
-    n, d = u.shape
-    e, k, f = cfg.num_experts, cfg.num_experts_per_tok, cfg.d_expert
-    flat = jnp.where(valid[:, None], experts, e).reshape(-1)   # [N * k]
-    order = jnp.argsort(flat, stable=True)
-    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)     # [E]
-    rows = u[order // k]                                       # [N * k, d]
-    hidden = grouped_matmul(rows, p["w_gate_up"].astype(u.dtype), sizes)
-    act = jax.nn.silu(hidden[:, :f]) * hidden[:, f:]
-    y = grouped_matmul(act, p["w_down"].astype(u.dtype), sizes)
-    # what lies behind the last group was never computed: it is whatever
-    # the buffer held, and must not reach a sum even times zero
-    y = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None], y, 0)
-    # back to (row, pick) order; a row's picks are weighed in float32
-    back = jnp.argsort(order)
-    y = y[back].reshape(n, k, d).astype(jnp.float32)
-    w = jnp.where(valid[:, None], weights, 0.0)
-    out = jnp.einsum("nkd,nk->nd", y, w).astype(u.dtype)
-    counts = jnp.stack([(sizes > 0).sum(), sizes.sum(), sizes.max()])
-    return out, counts.astype(jnp.int32)
 
 
 def _dense_ffn(u, p):

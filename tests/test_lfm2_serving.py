@@ -432,3 +432,70 @@ def test_one_program_serves_every_step_and_an_empty_chunk_is_inert(params):
                                np.asarray(alone[2]["kv"][:, :, 1:]),
                                atol=1e-6)
     assert np.asarray(fused[3]).tolist() == np.asarray(alone[3]).tolist()
+
+
+def _route_as_it_was(u, p, cfg):
+    """``lfm2.route`` as ``models/lfm2.py`` had it before the expert
+    layer moved to ``models/moe.py`` (PR 38's text)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    pick_by = scores
+    if cfg.use_expert_bias:
+        pick_by = scores + p["expert_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(pick_by, cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * cfg.routed_scaling_factor
+
+
+def _experts_ffn_as_it_was(u, experts, weights, valid, p, cfg):
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    n, d = u.shape
+    e, k, f = cfg.num_experts, cfg.num_experts_per_tok, cfg.d_expert
+    flat = jnp.where(valid[:, None], experts, e).reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    rows = u[order // k]
+    hidden = grouped_matmul(rows, p["w_gate_up"].astype(u.dtype), sizes)
+    act = jax.nn.silu(hidden[:, :f]) * hidden[:, f:]
+    y = grouped_matmul(act, p["w_down"].astype(u.dtype), sizes)
+    y = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None], y, 0)
+    back = jnp.argsort(order)
+    y = y[back].reshape(n, k, d).astype(jnp.float32)
+    w = jnp.where(valid[:, None], weights, 0.0)
+    out = jnp.einsum("nkd,nk->nd", y, w).astype(u.dtype)
+    counts = jnp.stack([(sizes > 0).sum(), sizes.sum(), sizes.max()])
+    return out, counts.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("with_chunk", [True, False])
+def test_the_lowered_step_is_unchanged_by_the_expert_layers_move(
+        params, monkeypatch, with_chunk):
+    """``route`` and ``experts_ffn`` live in ``models/moe.py`` now, shared
+    with the family that holds a SHARE of its experts; with ``held=None``
+    the step this family lowers is, instruction for instruction, the one
+    it lowered with its own copies."""
+    from ray_tpu.models import moe
+
+    assert lfm2.route is moe.route and lfm2.experts_ffn is moe.experts_ffn
+    assert lfm2.STEP_COUNTERS == moe.EXPERT_COUNTERS
+    model = serving.model_for(CFG)
+    cache = jax.eval_shape(lambda: model.slot_state.attach(
+        CFG, model.init_cache(CFG, 33, PAGE), SLOTS))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    chunk = (i32(CHUNK), i32(), i32(), i32()) if with_chunk else None
+
+    def lowered():
+        return jax.jit(lambda p, c, tables, toks, pos, chunk: lfm2.paged_step(
+            p, c, tables, toks, pos, chunk, CFG, PAGE)).lower(
+            params, cache, i32(SLOTS, CFG.max_seq // PAGE), i32(SLOTS),
+            i32(SLOTS), chunk).as_text()
+
+    now = lowered()
+    monkeypatch.setattr(lfm2, "route", _route_as_it_was)
+    monkeypatch.setattr(lfm2, "experts_ffn", _experts_ffn_as_it_was)
+    assert now == lowered()
+    assert "stablehlo.sort" in now  # the expert layer's sort is in it

@@ -25,8 +25,9 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm.engine import (HostInputs, SlotEngine, build_step_programs,
                                 prefill_lane)
-from ray_tpu.models import lfm2, llama, serving
+from ray_tpu.models import lfm2, llama, serving, solar
 from ray_tpu.ops import attention as A
+from ray_tpu.ops import delta_rule as DR
 from ray_tpu.ops import grouped_matmul as GM
 from ray_tpu.ops import paged_attention as PA
 from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
@@ -66,6 +67,7 @@ def compiled_for_tpu(monkeypatch):
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(PA, "_on_tpu", lambda: True)
     monkeypatch.setattr(GM, "_on_tpu", lambda: True)
+    monkeypatch.setattr(DR, "_on_tpu", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -385,6 +387,76 @@ def test_lfm2_programs_touch_pool_and_slot_state_only_in_place(v5e, program):
     pool_bytes = pool.dtype.itemsize * math.prod(pool.shape)
     assert mem.alias_size_in_bytes == cache_bytes   # pool and state alike
     assert mem.temp_size_in_bytes < pool_bytes // 4
+
+
+# Solar-Open2-250B at its published widths, this chip's share of the
+# cell's deployment (20 of 320 routed experts, an eighth of the
+# vocabulary, 1280 positions, 128 slots) and ONE whole period: what a
+# layer does to the pool and to the slots' state does not depend on how
+# many periods there are.
+SOLAR_1P = solar.SolarConfig(
+    max_seq=1280, layer_types=solar.PERIOD, experts_held=(100, 20),
+    vocab_held=(0, 24576))
+SOLAR_SLOTS = 128
+
+
+def test_solar_step_updates_the_matrix_state_in_place(v5e):
+    """The third family's one step program at the cell's geometry: the
+    donated cache (the GQA layers' pages at head dim 128, the KDA layers'
+    ``[3, 128, 64, 128, 128]`` float32 matrix states, the convolutions'
+    windows) is aliased to the output whole; nothing but the delta-rule
+    kernel has a state-shaped result (no copy, no slice of a layer, no
+    scatter); the temporaries of a step stay under half a GB beside 1.6 GB
+    of state (3.3 GB at the cell's two periods); and the kernels are the
+    ones counted: paged attention and the delta rule for the decode rows
+    and for the lane, two grouped products an expert layer."""
+    cfg = SOLAR_1P
+    where = SingleDeviceSharding(v5e[0])
+    model = serving.model_for(cfg)
+    pages = SOLAR_SLOTS * cfg.max_seq // PAGE + 1
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), cfg)[0])
+    cache = jax.eval_shape(lambda: model.slot_state.attach(
+        cfg, model.init_cache(cfg, pages, PAGE), SOLAR_SLOTS))
+    sds = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=where)
+    params, cache = (jax.tree.map(sds, t) for t in (params, cache))
+    arg = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=where)
+    tables = cfg.max_seq // PAGE
+    specs = (params, cache, arg((SOLAR_SLOTS,)),
+             arg((HostInputs(SOLAR_SLOTS, tables, CHUNK).size,)))
+    block_fn, _ = build_step_programs(cfg, PAGE, 1, SOLAR_SLOTS, CHUNK)
+    compiled = jax.jit(block_fn, donate_argnums=(1,)).lower(*specs).compile()
+    text = compiled.as_text()
+    # decode rows and the lane: 1 GQA layer's paged kernel, 3 KDA layers'
+    # delta rule; two grouped products in each of the 4 expert layers
+    assert text.count("tpu_custom_call") == 2 * 1 + 2 * 3 + 2 * 4
+    state = cache["kda"]
+    assert state.shape == (3, SOLAR_SLOTS, 64, 128, 128)
+    assert state.dtype == jnp.float32
+    shape = ",".join(map(str, state.shape))
+    made = [line.strip()[:160] for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?\S+ = f32\[" + shape + r"\]", line)
+            and not re.search(r" (custom-call|parameter|get-tuple-element|"
+                              r"bitcast)\(", line)]
+    assert not made, made
+    # nor a layer's states or a slot's cut out of it
+    parts = {",".join(map(str, dims)) for dims in (
+        state.shape[1:], (1,) + state.shape[1:], state.shape[2:],
+        (1,) + state.shape[2:], (1, 1) + state.shape[2:])}
+    sliced = [line.strip()[:160] for line in _unfused_lines(text)
+              for m in [re.match(r"\s*(?:ROOT )?\S+ = f32\[([\d,]+)\]\S* "
+                                 r"(copy|fusion|slice|dynamic-slice|gather|"
+                                 r"scatter|dynamic-update-slice)\(", line)]
+              if m and m.group(1) in parts]
+    assert not sliced, sliced
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.dtype.itemsize * math.prod(x.shape)
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert state.dtype.itemsize * math.prod(state.shape) > 1.6e9
 
 
 def _unfused_lines(text):
