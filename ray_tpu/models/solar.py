@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import paged_attention as paged_attention_op
-from ..ops.delta_rule import delta_rule
+from ..ops.delta_rule import delta_rule, step_plan
 from . import lfm2, llama, serving
 from .common import rms_norm
 from .llama import PAGED_KV_AXES, _write_and_attend
@@ -361,10 +361,11 @@ def _kda_output(o, gate, p, cfg: SolarConfig, dtype):
 
 
 def kda(u, scan_state, conv_state, p, cfg: SolarConfig, layer, b: int,
-        valid, chunk_at):
+        valid, chunk_at, plan):
     """One KDA layer on a step's rows u [N, d] -> (out [N, d], the
     layers' matrix states, this layer's conv state). ``layer`` indexes
-    ``scan_state``'s leading axis."""
+    ``scan_state``'s leading axis; ``plan`` is the step's
+    ``delta_rule.step_plan(valid, chunk_at)``, the same for every layer."""
     with jax.named_scope("kda.proj"):
         qkv, g, beta, gate = _kda_project(u, p, cfg)
     with jax.named_scope("kda.conv"):
@@ -373,20 +374,10 @@ def kda(u, scan_state, conv_state, p, cfg: SolarConfig, layer, b: int,
             chunk_at)
         q, k, v = _kda_heads(conv, cfg)
     with jax.named_scope("kda.scan"):
-        # the decode rows: one token of slot i each; a parked row is not
+        # the step's rows as they are: the decode rows, one token of slot
+        # i each, then the chunk; a parked row and an empty chunk are not
         # in the step
-        o, scan_state = delta_rule(
-            scan_state, layer, jnp.arange(b, dtype=jnp.int32),
-            valid.astype(jnp.int32), q[:b, None], k[:b, None], v[:b, None],
-            g[:b, None], beta[:b, None])
-        o = o[:, 0]
-        if chunk_at is not None:
-            slot, n_valid = chunk_at
-            oc, scan_state = delta_rule(
-                scan_state, layer, jnp.reshape(slot, (1,)).astype(jnp.int32),
-                jnp.reshape(n_valid, (1,)).astype(jnp.int32), q[None, b:],
-                k[None, b:], v[None, b:], g[None, b:], beta[None, b:])
-            o = jnp.concatenate([o, oc[0]], axis=0)              # [N, H, dv]
+        o, scan_state = delta_rule(scan_state, layer, plan, q, k, v, g, beta)
     with jax.named_scope("kda.out"):
         out = _kda_output(o, gate, p, cfg, u.dtype)
     return out, scan_state, conv_state
@@ -465,6 +456,10 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: SolarConfig,
             outs.append(oc[0])
         return _gqa_gated(jnp.concatenate(outs), u, p), kv
 
+    # which rows' matrix states the step reads and writes: once a step,
+    # for every KDA layer
+    with jax.named_scope("kda.scan"):
+        plan = step_plan(valid, chunk_at)
     kv, scan, conv = cache["kv"], cache["kda"], list(cache["conv"])
     counts = jnp.zeros((len(EXPERT_COUNTERS),), jnp.int32)
     n_gqa = n_kda = 0
@@ -473,7 +468,7 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: SolarConfig,
         if op == KDA:
             out, scan, conv[n_kda] = kda(u, scan, conv[n_kda], p, cfg,
                                          jnp.int32(n_kda), b, valid,
-                                         chunk_at)
+                                         chunk_at, plan)
             n_kda += 1
         else:
             with jax.named_scope("attn"):

@@ -11,25 +11,28 @@ which is ``S_t = (I - b k k^T) Diag(a) S_{t-1} + b k v^T`` (Kimi Delta
 Attention; with ``b`` up to 2 the transition has negative eigenvalues).
 
 The serving engine keeps every layer's states in ONE array, ``state [L,
-slots, H, dk, dv]``, in the cache tree beside the KV pages. The kernel
-takes the WHOLE array and a layer index, aliased to its output, and
-streams each active sequence's heads through VMEM once: a block of heads
-is read, carried through the sequence's tokens with ``S`` resident (one
-token for a decode row, the chunk's tokens for the prefill lane), and
-written back where it came from. Nothing slices a layer or a slot out of
-the array and no XLA operation touches it. A sequence that is not in the
-step (``n_tok`` 0: a parked decode row, an empty chunk) is neither read
-nor written: the active sequences are visited first and the grid steps
-left over are aimed at the block the last active one ended on, which the
-pipeline then neither fetches again nor writes back early.
+slots, H, dk, dv]``, in the cache tree beside the KV pages. One call a
+layer takes the WHOLE array where it lies in HBM and a layer index,
+aliased to its output, and a step's rows as the layer computes them: the
+decode rows, one token of slot i each, then one slot's chunk. The kernel
+moves the states itself: the block of heads of each active decode row is
+copied into VMEM, carried through the row's token in place and copied
+back, a row a grid step, and the chunk's state stays in VMEM from the
+first step to the last while its tokens are carried through it beside the
+decode rows (their stream leaves the vector units half idle). Nothing
+slices a layer or a slot out of the array and no XLA operation touches
+it. A row that is not in the step (a parked decode row, an empty chunk,
+the chunk's tokens past its last real one) starts no DMA: its state is
+neither read nor written.
 
-A token's ``dk`` vectors (a, k, q) reach the kernel with ``dk`` along
-sublanes, a head a lane, so that a head's column is one lane slice and
-multiplies ``S [dk, dv]`` by a lane broadcast; its ``dv`` vectors (v, b)
-and the output with ``dv`` along lanes, a head a sublane row. The sums
-over ``dk`` are sublane sums. Everything is float32 on the VPU: the
-state is carried over thousands of tokens and a bfloat16 product in the
-recurrence compounds.
+A row's ``dk`` vectors (g, k, q) arrive ``[H, dk]``, ``dk`` along lanes;
+the kernel takes ``exp`` and transposes a block's tiles on the XLU, so
+that ``dk`` lies along sublanes, a head a lane: a head's column is then
+one lane slice and multiplies ``S [dk, dv]`` by a lane broadcast. Its
+``dv`` vectors (v, b) and the output have ``dv`` along lanes, a head a
+sublane row. The sums over ``dk`` are sublane sums. Everything is float32
+on the VPU: the state is carried over thousands of tokens and a bfloat16
+product in the recurrence compounds.
 
 Off the TPU callers get :func:`delta_rule_reference` (:func:`recurrence`,
 a ``lax.scan`` over tokens, from and to the slots' states); the tests run
@@ -46,15 +49,12 @@ import jax.numpy as jnp
 from .attention import _on_tpu
 
 _LANES = 128
-# heads a block: [hb, 128, 128] float32 is hb x 64 KiB, in and out, each
-# double-buffered (8 MiB at 32). The kernel's own time hardly depends on
-# it (1.644 / 1.633 / 1.635 ms a layer of 128 rows at 16 / 32 / 64 on a
-# v5e: ~187 cycles a head of VPU work against 150 of HBM); what XLA lays
-# out round it does (3 x hb columns padded to 128 lanes): the scope took
-# 13.3 / 12.5 / 12.3 ms a step (PERF.md Findings PR 39).
-_HEAD_BLOCK = 32
-# tokens of a sequence a grid step carries the state through
-_TOKEN_BLOCK = 8
+# heads a block: a slot's 64 heads are 4 MiB contiguous, one DMA a row each
+# way. On a v5e a call of 128 rows takes 1.63 ms at 64 heads a block and
+# 1.72 at 32 (PERF.md Findings PR 41: the stream binds, and a grid step's
+# fixed costs are exposed beside it); three blocks of states, the chunk's,
+# and every row's o twice are 29 MiB of VMEM.
+_HEAD_BLOCK = 64
 _VMEM_LIMIT = 48 * 1024 * 1024
 
 
@@ -84,169 +84,280 @@ def recurrence(s0, n_tok, q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1), s
 
 
-def delta_rule_reference(state, layer, slot_of, n_tok, q, k, v, g, beta):
+def step_plan(valid, chunk_at=None):
+    """What of a step's rows is in the step, as the kernel reads it: an
+    int32 vector ``[active decode rows, the chunk's slot, its live
+    tokens, the decode rows with the active ones first]``. Built once a
+    step, on the device, from ``valid [B]`` bool and ``chunk_at`` = None
+    or (slot, n_valid); every layer's :func:`delta_rule` takes the same
+    one."""
+    slot, n_valid = (0, 0) if chunk_at is None else chunk_at
+    order = jnp.argsort(~valid, stable=True)
+    return jnp.concatenate([
+        jnp.stack([valid.sum(), slot, n_valid]).astype(jnp.int32),
+        order.astype(jnp.int32)])
+
+
+def delta_rule_reference(state, layer, plan, q, k, v, g, beta):
     """:func:`recurrence` from and to the slots' states of ``layer``; the
-    contract of :func:`delta_rule`. A sequence that is not in the step
-    writes its slot's state back as it read it; two sequences never share
-    a slot."""
-    o, s = recurrence(state[layer, slot_of], n_tok, q, k, v, g, beta)
-    return o, state.at[layer, slot_of].set(s)
+    contract of :func:`delta_rule`. A row that is not in the step writes
+    its slot's state back as it read it."""
+    b = plan.shape[0] - 3
+    valid = jnp.zeros((b,), bool).at[plan[3:]].set(jnp.arange(b) < plan[0])
+    xs = (q, k, v, g, beta)
+    o, s = recurrence(state[layer, :b], valid.astype(jnp.int32),
+                      *(x[:b, None] for x in xs))
+    o, state = o[:, 0], state.at[layer, :b].set(s)
+    if q.shape[0] > b:
+        oc, s = recurrence(state[layer, plan[1]][None], plan[2][None],
+                           *(x[None, b:] for x in xs))
+        o = jnp.concatenate([o, oc[0]], axis=0)
+        state = state.at[layer, plan[1]].set(s[0])
+    return o, state
 
 
-def _kernel(meta_ref, s_ref, cols_ref, rows_ref, o_ref, so_ref, *, hb: int,
-            tb: int, blocks: int):
+def _prepare(q_ref, k_ref, g_ref, b_ref, row, first_head, cols_ref,
+             beta_ref):
+    """A row's per-head vectors as :func:`_through` reads them: a (the
+    decay), k and q with dk along sublanes, a head a lane (the row's
+    ``[hb, dk]`` tiles transposed on the XLU, 128 rows at a time), and b a
+    head broadcast along lanes."""
     from jax.experimental import pallas as pl
 
-    i, tt = pl.program_id(0), pl.program_id(2)
-    n_active = meta_ref[1]
-
-    def token(t, src):
-        """Token t of the block through every head of the block: the
-        heads' chains are independent, so the straight-line code of one
-        token lets the scheduler interleave them (a loop over tokens a
-        head runs one dependent chain at a time: ~200 cycles a token
-        where this takes ~50)."""
-        tile = cols_ref[0, 0, t]                                 # [dk, W]
-        for h in range(hb):
-            a = tile[:, h:h + 1]
-            kk = tile[:, hb + h:hb + h + 1]
-            qq = tile[:, 2 * hb + h:2 * hb + h + 1]
-            v = rows_ref[0, 0, t, 0, h:h + 1, :]                 # [1, dv]
-            b = rows_ref[0, 0, t, 1, h:h + 1, :]
-            s = src[0, 0, h] * a                                 # [dk, dv]
-            ks = jnp.sum(s * kk, axis=0, keepdims=True)
-            s = s + kk * (b * (v - ks))
-            so_ref[0, 0, h] = s
-            o_ref[0, 0, t, h:h + 1, :] = jnp.sum(s * qq, axis=0,
-                                                 keepdims=True)
-
-    active = i < n_active
-    if tb == 1 and blocks == 1:
-        # a decode row: read where the state came in, write where it goes
-        pl.when(active)(lambda: token(0, s_ref))
-    else:
-        # a chunk: the state moves to the output block once and is
-        # carried there, token by token, through the sequence's blocks
-        @pl.when(active & (tt == 0))
-        def _():
-            so_ref[...] = s_ref[...]
-
-        @pl.when(active)
-        def _():
-            def step(t, carry):
-                token(t, so_ref)
-                return carry
-
-            jax.lax.fori_loop(0, tb, step, 0)
-
-    # no sequence in the step: every grid step is aimed at one block, and
-    # what is written back at the end must be what was there
-    @pl.when((n_active == 0) & (i == 0) & (pl.program_id(1) == 0)
-             & (tt == 0))
-    def _():
-        so_ref[...] = s_ref[...]
-        o_ref[...] = jnp.zeros_like(o_ref)
+    f32 = jnp.float32
+    hb, dv = beta_ref.shape
+    kk = k_ref[0].astype(f32)                                    # [hb, dk]
+    stack = [jnp.exp(g_ref[0].astype(f32)), kk, q_ref[0].astype(f32)]
+    width = cols_ref.shape[1]
+    if width > 3 * hb:
+        stack.append(jnp.zeros((width - 3 * hb, kk.shape[1]), f32))
+    stack = jnp.concatenate(stack, axis=0)                       # [W, dk]
+    for w in range(0, width, _LANES):
+        cols_ref[:, w:w + _LANES] = stack[w:w + _LANES].T
+    # the row's beta [1, H] -> this block's heads down a column
+    heads = b_ref.shape[1]
+    mine = (jax.lax.broadcasted_iota(jnp.int32, (hb, heads), 0) + first_head
+            == jax.lax.broadcasted_iota(jnp.int32, (hb, heads), 1))
+    beta = jnp.sum(jnp.where(mine, b_ref[pl.ds(row, 1), :].astype(f32), 0.0),
+                   axis=1, keepdims=True)                        # [hb, 1]
+    beta_ref[...] = jnp.broadcast_to(beta, (hb, dv))
 
 
-def delta_rule(state, layer, slot_of, n_tok, q, k, v, g, beta,
-               interpret: bool = False, head_block: int = None,
-               token_block: int = None):
-    """R sequences of T tokens through the recurrence, each from and to
-    its slot's state of ``layer`` -> (o [R, T, H, dv] float32, state).
+def _through(state, cols_ref, v_ref, beta_ref, o_ref, row, lo: int, hi: int):
+    """The row's token through heads lo .. hi of the block, in place in
+    ``state [hb, dk, dv]``: the heads' chains are independent, so one
+    straight-line body lets the scheduler interleave them (a loop over
+    tokens a head runs one dependent chain at a time: ~200 cycles a token
+    where this takes ~95)."""
+    from jax.experimental import pallas as pl
 
-    state [L, slots, H, dk, dv] float32 (donate it: it is updated in
-    place); slot_of [R] int32, distinct; n_tok [R] int32: the first
-    ``n_tok`` of a sequence's T tokens are real, and a sequence with none
-    is not in the step: its state is neither read nor written and its
-    ``o`` is unspecified (as are the ``o`` of tokens past ``n_tok``).
-    q, k [R, T, H, dk]; v [R, T, H, dv]; g [R, T, H, dk] the log decay
-    (<= 0); beta [R, T, H]."""
-    if not (interpret or use_kernel()):
-        return delta_rule_reference(state, layer, slot_of, n_tok, q, k, v,
-                                    g, beta)
+    hb = beta_ref.shape[0]
+    tiles = [cols_ref[:, w:w + _LANES]
+             for w in range(0, cols_ref.shape[1], _LANES)]
+
+    def col(at):
+        return tiles[at // _LANES][:, at % _LANES:at % _LANES + 1]
+
+    for h in range(lo, hi):
+        a, kk, qq = col(h), col(hb + h), col(2 * hb + h)         # [dk, 1]
+        v = v_ref[0, h:h + 1, :].astype(jnp.float32)             # [1, dv]
+        s1 = state[h] * a                                        # [dk, dv]
+        ks = jnp.sum(s1 * kk, axis=0, keepdims=True)
+        s2 = s1 + kk * (beta_ref[h:h + 1, :] * (v - ks))
+        state[h] = s2
+        o_ref[pl.ds(row, 1), h:h + 1, :] = jnp.sum(
+            s2 * qq, axis=0, keepdims=True)[None]
+
+
+def _kernel(plan_ref, layer_ref, *refs, hb: int, b: int, c: int, stride: int,
+            parts: int, steps: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    r, t, h, dk = q.shape
+    refs = list(refs)
+    s_hbm = refs.pop(0)
+    dec = [refs.pop(0) for _ in range(4)] if b else None    # q, k, v, g
+    chk = [refs.pop(0) for _ in range(4)] if c else None
+    (b_ref, o_ref, so_hbm, sbuf, cbuf, cols_d, cols_c, beta_d, beta_c, rsem,
+     wsem, csem) = refs
+    j, s = pl.program_id(0), pl.program_id(1)
+    layer, heads = layer_ref[0], pl.ds(j * hb, hb)
+    n_active, slot_c, n_valid = plan_ref[0], plan_ref[1], plan_ref[2]
+
+    def row_of(i):
+        """The i-th active decode row, which is its slot."""
+        return plan_ref[3 + jnp.clip(i, 0, b - 1)] if b else jnp.int32(0)
+
+    def read(i):
+        return pltpu.make_async_copy(s_hbm.at[layer, row_of(i), heads],
+                                     sbuf.at[i % 3], rsem.at[i % 3])
+
+    def write(i):
+        return pltpu.make_async_copy(sbuf.at[i % 3],
+                                     so_hbm.at[layer, row_of(i), heads],
+                                     wsem.at[i % 3])
+
+    chunk_in = pltpu.make_async_copy(s_hbm.at[layer, slot_c, heads], cbuf,
+                                     csem.at[0])
+    chunk_out = pltpu.make_async_copy(cbuf, so_hbm.at[layer, slot_c, heads],
+                                      csem.at[1])
+
+    # this step's decode row (the s-th active one) and its share of the
+    # chunk: heads group r of token t
+    has_d = s < n_active
+    t, r = s // stride, s % stride
+    has_c = (t < n_valid) & (r < parts)
+    row_d, row_c = row_of(s), b + t
+    group = hb // parts
+    halves = [(0, -(-hb // 2)), (-(-hb // 2), hb)]
+    chunk_halves = [(0, -(-group // 2)), (-(-group // 2), group)]
+
+    def if_row(i, dma):
+        """``dma(i)`` if i is an active decode row."""
+        pl.when((i >= 0) & (i < n_active))(lambda: dma(i))
+
+    @pl.when(s == 0)
+    def _():
+        pl.when(n_valid > 0)(chunk_in.start)
+
+        @pl.when(n_active > 0)
+        def _():
+            read(0).start()
+            read(0).wait()
+
+        pl.when(n_valid > 0)(chunk_in.wait)
+
+    # Two streams a step, a read then a write, never both at once: HBM
+    # gives the two together 80 % of its rate and one after the other
+    # 85 % (PERF.md Findings PR 41). The next row's state comes in while
+    # this row's vectors are laid out and half of its heads (and of the
+    # chunk's share) are carried through their token; the last row's
+    # goes back during the other half. (A write left in flight across
+    # the step's boundary runs beside the next rows' fetches: slower.)
+    if_row(s + 1, lambda i: read(i).start())
+    if b:
+        pl.when(has_d)(lambda: _prepare(dec[0], dec[1], dec[3], b_ref, row_d,
+                                        j * hb, cols_d, beta_d))
+    if c:
+        pl.when(has_c & (r == 0))(lambda: _prepare(
+            chk[0], chk[1], chk[3], b_ref, row_c, j * hb, cols_c, beta_c))
+    for phase, ((lo, hi), (clo, chi)) in enumerate(zip(halves, chunk_halves)):
+        if phase == 1:
+            if_row(s + 1, lambda i: read(i).wait())
+            if_row(s - 1, lambda i: write(i).start())
+        if b and hi > lo:
+            pl.when(has_d)(lambda lo=lo, hi=hi: _through(
+                sbuf.at[s % 3], cols_d, dec[2], beta_d, o_ref, row_d, lo, hi))
+        if c and chi > clo:
+            for g in range(parts):
+                pl.when(has_c & (r == g))(
+                    lambda g=g, clo=clo, chi=chi: _through(
+                        cbuf, cols_c, chk[2], beta_c, o_ref, row_c,
+                        g * group + clo, g * group + chi))
+    if_row(s - 1, lambda i: write(i).wait())
+
+    # the chunk's state goes back after its last token's last heads
+    pl.when(has_c & (t == n_valid - 1) & (r == parts - 1))(chunk_out.start)
+
+    @pl.when(s == steps - 1)
+    def _():
+        @pl.when(has_d)
+        def _():
+            write(s).start()
+            write(s).wait()
+
+        pl.when(n_valid > 0)(chunk_out.wait)
+
+
+def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
+               head_block: int = None):
+    """A step's N = B + C rows through the recurrence, each from and to
+    its slot's state of ``layer`` -> (o [N, H, dv] float32, state).
+
+    Rows ``[:B]`` are one token of slot i each, rows ``[B:]`` one slot's
+    chunk in order; ``plan`` (:func:`step_plan`, B = its length - 3) says
+    which decode rows are in the step, and the chunk's slot and how many
+    of its C tokens are real. state [L, slots, H, dk, dv] float32 (donate
+    it: it is updated in place). A decode row that is not in the step,
+    and an empty chunk, are neither read nor written, and their ``o`` is
+    unspecified (as are the ``o`` of the chunk's tokens past its last
+    real one); the chunk's slot is no active decode row's. q, k, g [N, H,
+    dk] (g the log decay, <= 0); v [N, H, dv]; beta [N, H]: the rows as
+    the layer computes them, in any float dtype.
+
+    The state never leaves HBM but by the kernel's own DMAs: a grid step
+    takes the next active decode row's block of heads into VMEM, carries
+    it through the row's token and sends it back, and beside it carries
+    a share of the chunk's heads through one of its tokens, the chunk's
+    state resident from the first step to the last."""
+    if not (interpret or use_kernel()):
+        return delta_rule_reference(state, layer, plan, q, k, v, g, beta)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, h, dk = q.shape
     dv = v.shape[-1]
+    b = plan.shape[0] - 3
+    c = n - b
     hb = min(head_block or _HEAD_BLOCK, h)
-    tb = min(token_block or _TOKEN_BLOCK, t)
-    if h % hb or t % tb:
-        raise ValueError(f"delta_rule cannot tile {h} heads by {hb} or {t} "
-                         f"tokens by {tb}")
-    if not interpret and (dk % 8 or dv % _LANES or hb % 8):
+    if h % hb:
+        raise ValueError(f"delta_rule cannot tile {h} heads by {hb}")
+    if not interpret and (dk % _LANES or dv % _LANES or hb % 8):
         raise ValueError(
-            f"delta_rule: dk {dk} must fill sublanes, dv {dv} lanes and "
-            f"the head block {hb} sublane rows")
-    jn, tn = h // hb, t // tb
+            f"delta_rule: dk {dk} and dv {dv} must fill lanes and the "
+            f"head block {hb} sublane rows")
+    # a chunk token every `stride` steps, its heads over `parts` of them:
+    # the decode rows' stream has vector time to spare, and the chunk's
+    # tokens take it in shares small enough to hide there
+    stride = max(1, b // c) if c else 1
+    parts = stride if hb % stride == 0 else 1
+    steps = max(b, c * stride)
     width = -(-3 * hb // _LANES) * _LANES
-    f32 = jnp.float32
-    live = jnp.arange(t)[None, :] < n_tok[:, None]               # [R, T]
-    # a token past n_tok leaves the state as it is: S * 1 + k * 0
-    a = jnp.where(live[..., None, None], jnp.exp(g.astype(f32)), 1.0)
-    beta = jnp.where(live[..., None], beta.astype(f32), 0.0)
 
-    def columns(x):  # [R, T, H, dk] -> [R, J, T, dk, hb]
-        return x.astype(f32).reshape(r, t, jn, hb, dk).transpose(
-            0, 2, 1, 4, 3)
+    def decode_row(j, s, plan_ref, layer_ref):
+        at = jnp.clip(jnp.minimum(s, plan_ref[0] - 1), 0, b - 1)
+        return plan_ref[3 + at], j, 0
 
-    cols = jnp.concatenate([columns(a), columns(k), columns(q)], axis=-1)
-    if width > 3 * hb:
-        cols = jnp.pad(cols, ((0, 0),) * 4 + ((0, width - 3 * hb),))
+    def chunk_row(j, s, plan_ref, layer_ref):
+        return b + jnp.clip(jnp.minimum(s // stride, plan_ref[2] - 1), 0,
+                            c - 1), j, 0
 
-    def lanes(x):  # [R, T, H, dv] -> [R, J, T, hb, dv]
-        return x.astype(f32).reshape(r, t, jn, hb, dv).transpose(
-            0, 2, 1, 3, 4)
-
-    rows = jnp.stack([lanes(v), lanes(jnp.broadcast_to(
-        beta[..., None], (r, t, h, dv)))], axis=3)       # [R, J, T, 2, hb, dv]
-    # active sequences first, in their own order
-    on = n_tok > 0
-    order = jnp.argsort(~on, stable=True).astype(jnp.int32)
-    meta = jnp.concatenate([
-        jnp.stack([jnp.asarray(layer, jnp.int32),
-                   on.sum().astype(jnp.int32)]),
-        order, slot_of.astype(jnp.int32)])
-
-    def where(i, j, tt, meta_ref):
-        n_active = meta_ref[1]
-        act = i < n_active
-        seq = meta_ref[2 + jnp.where(act, i, jnp.maximum(n_active - 1, 0))]
-        return (act, seq, jnp.where(act, j, jn - 1),
-                jnp.where(act, tt, tn - 1))
-
-    def state_at(i, j, tt, meta_ref):
-        _, seq, jj, _ = where(i, j, tt, meta_ref)
-        return meta_ref[0], meta_ref[2 + r + seq], jj, 0, 0
-
-    def token_at(trailing):
-        def at(i, j, tt, meta_ref):
-            _, seq, jj, tk = where(i, j, tt, meta_ref)
-            return (seq, jj, tk) + (0,) * trailing
-        return at
-
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    rows = ([pl.BlockSpec((1, hb, x.shape[-1]), decode_row)
+             for x in (q, k, v, g)] if b else []) + (
+        [pl.BlockSpec((1, hb, x.shape[-1]), chunk_row)
+         for x in (q, k, v, g)] if c else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(r, jn, tn),
-        in_specs=[
-            pl.BlockSpec((1, 1, hb, dk, dv), state_at),
-            pl.BlockSpec((1, 1, tb, dk, width), token_at(2)),
-            pl.BlockSpec((1, 1, tb, 2, hb, dv), token_at(3)),
-        ],
+        num_scalar_prefetch=2, grid=(h // hb, steps),
+        in_specs=[hbm] + rows + [
+            # every row's beta, fetched once: a row is a sublane
+            pl.BlockSpec((n, h), lambda j, s, *_: (0, 0))],
         out_specs=[
-            pl.BlockSpec((1, 1, tb, hb, dv), token_at(2)),
-            pl.BlockSpec((1, 1, hb, dk, dv), state_at),
-        ])
-    o, state = pl.pallas_call(
-        functools.partial(_kernel, hb=hb, tb=tb, blocks=tn),
+            # every row's o, written back once a block of heads
+            pl.BlockSpec((n, hb, dv), lambda j, s, *_: (0, j, 0)),
+            hbm],
+        scratch_shapes=[
+            pltpu.VMEM((3, hb, dk, dv), jnp.float32),    # decode rows' states
+            pltpu.VMEM((hb, dk, dv), jnp.float32),       # the chunk's
+            pltpu.VMEM((dk, width), jnp.float32),
+            pltpu.VMEM((dk, width), jnp.float32),
+            pltpu.VMEM((hb, dv), jnp.float32),
+            pltpu.VMEM((hb, dv), jnp.float32),
+            pltpu.SemaphoreType.DMA((3,)), pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SemaphoreType.DMA((2,))])
+    operands = (q, k, v, g) * (bool(b) + bool(c))
+    return pl.pallas_call(
+        functools.partial(_kernel, hb=hb, b=b, c=c, stride=stride,
+                          parts=parts, steps=steps),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((r, jn, t, hb, dv), f32),
+        out_shape=[jax.ShapeDtypeStruct((n, h, dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operand 0 is the scalar-prefetched meta; the state is operand 1
-        input_output_aliases={1: 1},
+        # operands 0 and 1 are scalar-prefetched; the state is operand 2
+        input_output_aliases={2: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the state output is written by the kernel's DMAs alone
+            vmem_limit_bytes=_VMEM_LIMIT, has_side_effects=True),
         interpret=interpret, name="delta_rule",
-    )(meta, state, cols, rows)
-    # [R, J, T, hb, dv] -> [R, T, H, dv]
-    return o.transpose(0, 2, 1, 3, 4).reshape(r, t, h, dv), state
+    )(plan, jnp.reshape(layer, (1,)).astype(jnp.int32), state, *operands,
+      beta)

@@ -1,30 +1,35 @@
 """``ops/delta_rule.py``: the Pallas kernel, interpreted, against the
-sequential recurrence at the published head size (d_k = d_v = 128): one
-token a row (the decode rows), a chunk of tokens of one slot (the prefill
-lane), sequences that are not in the step, the corners of the transition
-(``b`` near 2: negative eigenvalues; ``a`` near 1 and near 0), and a state
-carried over many steps."""
+sequential recurrence at the published head size (d_k = d_v = 128), on
+the contract of one call a layer: a step's rows as the layer computes
+them, decode rows first (one token of slot i each) and then one slot's
+chunk, with the step's plan. One token a row with a parked row between,
+a chunk that stops at its last valid token, both in one call, no row in
+the step, the corners of the transition (``b`` near 2: negative
+eigenvalues; ``a`` near 1 and near 0), and a state carried over many
+steps."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.delta_rule import delta_rule, delta_rule_reference
+from ray_tpu.ops.delta_rule import (delta_rule, delta_rule_reference,
+                                    recurrence, step_plan)
 
 H, D = 8, 128
 LAYERS, SLOTS = 2, 5
 
 
-def _inputs(seed, r, t, beta=None, decay=None):
+def _inputs(seed, n, beta=None, decay=None, slots=SLOTS):
+    """A state and n rows' q, k, v, g [n, H, D] and beta [n, H]."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    state = jax.random.normal(ks[0], (LAYERS, SLOTS, H, D, D), jnp.float32)
+    state = jax.random.normal(ks[0], (LAYERS, slots, H, D, D), jnp.float32)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[1], (r, t, H, D))) * D ** -0.5
-    k = unit(jax.random.normal(ks[2], (r, t, H, D)))
-    v = jax.random.normal(ks[3], (r, t, H, D))
-    g = -jnp.exp(jax.random.normal(ks[4], (r, t, H, D)) - 2.0)
-    b = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (r, t, H)))
+    q = unit(jax.random.normal(ks[1], (n, H, D))) * D ** -0.5
+    k = unit(jax.random.normal(ks[2], (n, H, D)))
+    v = jax.random.normal(ks[3], (n, H, D))
+    g = -jnp.exp(jax.random.normal(ks[4], (n, H, D)) - 2.0)
+    b = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (n, H)))
     if decay is not None:
         g = jnp.full_like(g, np.log(decay))
     if beta is not None:
@@ -47,19 +52,24 @@ def _sequential(state, q, k, v, g, b):
     return out, s
 
 
-def _both(layer, slot_of, n_tok, *args, **kw):
-    slot_of = jnp.asarray(slot_of, jnp.int32)
-    n_tok = jnp.asarray(n_tok, jnp.int32)
-    want = delta_rule_reference(args[0], layer, slot_of, n_tok, *args[1:])
-    got = delta_rule(args[0], layer, slot_of, n_tok, *args[1:],
-                     interpret=True, **kw)
+def _plan(valid, chunk_at=None):
+    """``step_plan`` of B = len(valid) decode rows and a chunk (slot, its
+    live tokens)."""
+    if chunk_at is not None:
+        chunk_at = tuple(jnp.int32(x) for x in chunk_at)
+    return step_plan(jnp.asarray(valid, bool), chunk_at)
+
+
+def _both(layer, valid, chunk_at, *args, **kw):
+    plan = _plan(valid, chunk_at)
+    want = delta_rule_reference(args[0], layer, plan, *args[1:])
+    got = delta_rule(args[0], layer, plan, *args[1:], interpret=True, **kw)
     return got, want
 
 
 def test_one_token_a_row_with_a_parked_row_between():
-    state, *rest = _inputs(0, 4, 1)
-    (o, s), (o_ref, s_ref) = _both(1, [0, 1, 2, 3], [1, 0, 1, 1], state,
-                                   *rest)
+    state, *rest = _inputs(0, 4)
+    (o, s), (o_ref, s_ref) = _both(1, [1, 0, 1, 1], None, state, *rest)
     live = np.asarray([True, False, True, True])
     np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
                                atol=2e-6)
@@ -70,31 +80,79 @@ def test_one_token_a_row_with_a_parked_row_between():
     assert (np.asarray(s[1, 1]) == np.asarray(state[1, 1])).all()
     assert (np.asarray(s[1, 4]) == np.asarray(state[1, 4])).all()
     # and the equations themselves, for one row
-    want_o, want_s = _sequential(state[1, 2], *(x[2] for x in rest))
-    np.testing.assert_allclose(np.asarray(o[2]), want_o, atol=1e-5)
+    want_o, want_s = _sequential(state[1, 2], *(x[2:3] for x in rest))
+    np.testing.assert_allclose(np.asarray(o[2:3]), want_o, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s[1, 2]), want_s, atol=1e-5)
 
 
 @pytest.mark.parametrize("n_valid", [64, 37, 1])
 def test_a_chunk_of_one_slot_stops_at_its_last_valid_token(n_valid):
-    state, *rest = _inputs(1, 1, 64)
-    (o, s), (o_ref, s_ref) = _both(0, [3], [n_valid], state, *rest)
-    np.testing.assert_allclose(np.asarray(o)[0, :n_valid],
-                               np.asarray(o_ref)[0, :n_valid], atol=5e-6)
+    state, *rest = _inputs(1, 64)
+    (o, s), (o_ref, s_ref) = _both(0, [], (3, n_valid), state, *rest)
+    np.testing.assert_allclose(np.asarray(o)[:n_valid],
+                               np.asarray(o_ref)[:n_valid], atol=5e-6)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=5e-6)
-    want_o, want_s = _sequential(state[0, 3],
-                                 *(x[0, :n_valid] for x in rest))
-    np.testing.assert_allclose(np.asarray(o)[0, :n_valid], want_o, atol=2e-5)
+    want_o, want_s = _sequential(state[0, 3], *(x[:n_valid] for x in rest))
+    np.testing.assert_allclose(np.asarray(o)[:n_valid], want_o, atol=2e-5)
     np.testing.assert_allclose(np.asarray(s[0, 3]), want_s, atol=2e-5)
     touched = np.zeros((LAYERS, SLOTS), bool)
     touched[0, 3] = True
     assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
 
 
-@pytest.mark.parametrize("r,t", [(4, 1), (1, 64)])
-def test_no_sequence_in_the_step_changes_nothing(r, t):
-    state, *rest = _inputs(2, r, t)
-    (_, s), _ = _both(1, list(range(r)), [0] * r, state, *rest)
+@pytest.mark.parametrize("n_valid", [0, 1, 63, 64])
+@pytest.mark.parametrize("slot", [4, 1])
+def test_decode_rows_and_a_chunk_in_one_call(slot, n_valid):
+    """Four decode rows, row 1 parked, and a 64-token chunk of a slot
+    that is no decode row's (4) or the parked row's (1), against the
+    recurrence itself run on each sequence alone."""
+    valid = [True, False, True, True]
+    state, *rest = _inputs(5, 4 + 64)
+    plan = _plan(valid, (slot, n_valid))
+    o, s = delta_rule(state, 1, plan, *rest, interpret=True)
+    want = np.array(state)
+    o_d, s_d = recurrence(state[1, :4], jnp.asarray(valid, jnp.int32),
+                          *(x[:4, None] for x in rest))
+    want[1, :4] = np.asarray(s_d)
+    o_c, s_c = recurrence(state[1, slot][None],
+                          jnp.asarray([n_valid], jnp.int32),
+                          *(x[None, 4:] for x in rest))
+    want[1, slot] = np.asarray(s_c[0])
+    live = np.asarray(valid)
+    np.testing.assert_allclose(np.asarray(o)[:4][live],
+                               np.asarray(o_d)[live, 0], atol=2e-6)
+    np.testing.assert_allclose(np.asarray(o)[4:4 + n_valid],
+                               np.asarray(o_c)[0, :n_valid], atol=5e-6)
+    np.testing.assert_allclose(np.asarray(s), want, atol=5e-6)
+    # what no row of the step names is bit for bit what it was
+    touched = np.zeros((LAYERS, SLOTS), bool)
+    touched[1, [0, 2, 3]] = True
+    touched[1, slot] = n_valid > 0
+    assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
+
+
+@pytest.mark.parametrize("b,c,n_valid", [(8, 2, 2), (8, 4, 3), (6, 2, 1)])
+def test_a_chunk_shorter_than_the_decode_rows_is_spread_over_their_steps(
+        b, c, n_valid):
+    """With B >= 2 C a chunk token comes every B // C grid steps and its
+    heads go through it over as many of them as divide the block (4, 2
+    and, for 3 steps, all at once): the same numbers, with a parked row
+    among the decode rows and slot ``b`` the chunk's."""
+    valid = [i != 2 for i in range(b)]
+    state, *rest = _inputs(6, b + c, slots=b + 1)
+    (o, s), (o_ref, s_ref) = _both(0, valid, (b, n_valid), state, *rest)
+    live = np.asarray(valid + [i < n_valid for i in range(c)])
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
+                               atol=2e-6)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=2e-6)
+    assert (np.asarray(s[0, 2]) == np.asarray(state[0, 2])).all()
+    assert (np.asarray(s[1]) == np.asarray(state[1])).all()
+
+
+@pytest.mark.parametrize("b,c", [(4, 0), (0, 64), (4, 64)])
+def test_no_sequence_in_the_step_changes_nothing(b, c):
+    state, *rest = _inputs(2, b + c)
+    (_, s), _ = _both(1, [False] * b, (2, 0) if c else None, state, *rest)
     assert (np.asarray(s) == np.asarray(state)).all()
 
 
@@ -104,25 +162,27 @@ def test_the_corners_of_the_transition(beta, decay):
     """b near 2 reflects the state across k (eigenvalue -1), a near 1
     forgets nothing, a near 0 forgets everything: each as the equations
     say, over a chunk."""
-    state, *rest = _inputs(3, 1, 16, beta=beta, decay=decay)
-    (o, s), _ = _both(0, [0], [16], state, *rest, token_block=8)
-    want_o, want_s = _sequential(state[0, 0], *(x[0] for x in rest))
-    np.testing.assert_allclose(np.asarray(o)[0], want_o, atol=2e-5)
+    state, *rest = _inputs(3, 16, beta=beta, decay=decay)
+    (o, s), _ = _both(0, [], (0, 16), state, *rest)
+    want_o, want_s = _sequential(state[0, 0], *rest)
+    np.testing.assert_allclose(np.asarray(o), want_o, atol=2e-5)
     np.testing.assert_allclose(np.asarray(s[0, 0]), want_s, atol=2e-5)
     if decay < 1e-3:   # nothing of the old state is left after 16 tokens
         assert np.abs(want_s).max() < 10
 
 
-def test_head_blocks_and_token_blocks_give_the_same_numbers():
-    state, *rest = _inputs(4, 2, 16)
-    outs = [delta_rule(state, 1, jnp.asarray([4, 0]), jnp.asarray([16, 9]),
-                       *rest, interpret=True, head_block=hb, token_block=tb)
-            for hb, tb in ((8, 8), (8, 16), (8, 1))]
+def test_head_blocks_give_the_same_numbers():
+    """Two decode rows and 9 live tokens of a 16-token chunk, at 8, 4
+    and 2 heads a grid step."""
+    state, *rest = _inputs(4, 2 + 16)
+    plan = _plan([True, True], (4, 9))
+    outs = [delta_rule(state, 1, plan, *rest, interpret=True, head_block=hb)
+            for hb in (8, 4, 2)]
     for o, s in outs[1:]:
         np.testing.assert_allclose(np.asarray(s), np.asarray(outs[0][1]),
                                    atol=1e-6)
-        np.testing.assert_allclose(np.asarray(o)[1, :9],
-                                   np.asarray(outs[0][0])[1, :9], atol=1e-6)
+        np.testing.assert_allclose(np.asarray(o)[:2 + 9],
+                                   np.asarray(outs[0][0])[:2 + 9], atol=1e-6)
 
 
 def test_a_state_carried_over_1024_steps_stays_on_the_references():
@@ -138,26 +198,25 @@ def test_a_state_carried_over_1024_steps_stays_on_the_references():
     g = -jnp.exp(jax.random.normal(ks[3], (steps, h, D)) * 2 - 4.0)
     b = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (steps, h)) * 3)
     state = jnp.zeros((1, 1, h, D, D), jnp.float32)
-    slot, one = jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32)
+    lane, row = _plan([], (0, 64)), _plan([True])
 
-    # 1024 tokens as 16 calls of a 64-token chunk, then checked against
-    # one-token calls on the last 8 tokens
+    # 1024 tokens as 15 calls of a 64-token chunk, then one-token calls
+    # (a decode row) on the last 64 tokens
     @jax.jit
     def chunk(state, i):
-        sl = lambda x: jax.lax.dynamic_slice_in_dim(x, i * 64, 64)[None]  # noqa: E731
-        return delta_rule(state, 0, slot, one * 64, sl(q), sl(k), sl(v),
-                          sl(g), sl(b), interpret=True)
+        sl = lambda x: jax.lax.dynamic_slice_in_dim(x, i * 64, 64)  # noqa: E731
+        return delta_rule(state, 0, lane, sl(q), sl(k), sl(v), sl(g), sl(b),
+                          interpret=True)
 
     outs = []
     for i in range(steps // 64 - 1):
         o, state = chunk(state, i)
-        outs.append(o[0])
+        outs.append(o)
     for t in range(steps - 64, steps):
-        o, state = delta_rule(state, 0, slot, one, q[None, t:t + 1],
-                              k[None, t:t + 1], v[None, t:t + 1],
-                              g[None, t:t + 1], b[None, t:t + 1],
+        o, state = delta_rule(state, 0, row, q[t:t + 1], k[t:t + 1],
+                              v[t:t + 1], g[t:t + 1], b[t:t + 1],
                               interpret=True)
-        outs.append(o[0])
+        outs.append(o)
     got = np.concatenate([np.asarray(o) for o in outs])
     want_o, want_s = _sequential(np.zeros((h, D, D)), q, k, v, g, b)
     assert np.isfinite(got).all() and np.abs(want_s).max() < 100

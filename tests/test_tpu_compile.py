@@ -408,8 +408,10 @@ def test_solar_step_updates_the_matrix_state_in_place(v5e):
     kernel has a state-shaped result (no copy, no slice of a layer, no
     scatter); the temporaries of a step stay under half a GB beside 1.6 GB
     of state (3.3 GB at the cell's two periods); and the kernels are the
-    ones counted: paged attention and the delta rule for the decode rows
-    and for the lane, two grouped products an expert layer."""
+    ones counted: paged attention for the decode rows and for the lane,
+    ONE delta-rule call a KDA layer for both, two grouped products an
+    expert layer; and under ``kda.scan`` XLA runs the step's plan, once,
+    and nothing that lays an operand of the kernel out."""
     cfg = SOLAR_1P
     where = SingleDeviceSharding(v5e[0])
     model = serving.model_for(cfg)
@@ -429,9 +431,23 @@ def test_solar_step_updates_the_matrix_state_in_place(v5e):
     block_fn, _ = build_step_programs(cfg, PAGE, 1, SOLAR_SLOTS, CHUNK)
     compiled = jax.jit(block_fn, donate_argnums=(1,)).lower(*specs).compile()
     text = compiled.as_text()
-    # decode rows and the lane: 1 GQA layer's paged kernel, 3 KDA layers'
-    # delta rule; two grouped products in each of the 4 expert layers
-    assert text.count("tpu_custom_call") == 2 * 1 + 2 * 3 + 2 * 4
+    # decode rows and the lane: 1 GQA layer's paged kernel twice, 3 KDA
+    # layers' delta rule once each; two grouped products in each of the 4
+    # expert layers
+    assert text.count("tpu_custom_call") == 2 * 1 + 1 * 3 + 2 * 4
+    # between the convolutions and the kernel the rows stay as they are:
+    # no float32 array with a 128 in its last two dimensions (a [..,
+    # heads, 128] row tile or its transpose) is transposed, padded,
+    # concatenated, sorted or copied under the scope, fused or not, and
+    # the plan's sort is the step's, not a layer's
+    scan = [line for line in text.splitlines() if "kda.scan" in line]
+    assert sum(" sort(" in line for line in scan) <= 1
+    laid_out = [line.strip()[:160] for line in scan
+                for m in [re.match(r"\s*(?:ROOT )?\S+ = f32\[([\d,]+)\]\S* "
+                                   r"(transpose|pad|concatenate|sort|copy)\(",
+                                   line)]
+                if m and "128" in m.group(1).split(",")[-2:]]
+    assert not laid_out, laid_out
     state = cache["kda"]
     assert state.shape == (3, SOLAR_SLOTS, 64, 128, 128)
     assert state.dtype == jnp.float32
