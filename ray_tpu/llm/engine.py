@@ -434,7 +434,7 @@ class SlotEngine:
                      # whose state a step read and wrote, summed over
                      # those layers.
                      "experts_hit", "expert_rows", "expert_rows_max",
-                     "expert_picks", "kda_rows")
+                     "expert_picks", "kda_rows", "ssm_rows")
 
     def __init__(self, params, cfg, num_slots: int = 8,
                  chunk: Optional[int] = None, seed: int = 0,
@@ -628,7 +628,7 @@ class SlotEngine:
         # table entry reads.
         self.kv_pages_read = 0
         self.experts_hit = self.expert_rows = self.expert_rows_max = 0
-        self.expert_picks = self.kda_rows = 0
+        self.expert_picks = self.kda_rows = self.ssm_rows = 0
         self._callbacks = 0  # on_token calls made delivering tokens
         # The last finished requests' timing: a streamed response
         # carries tokens only, so this is where its stages are read.

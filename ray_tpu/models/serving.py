@@ -52,7 +52,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 # Modules of this package that register a ServingModel on import.
-FAMILIES = ("llama", "lfm2", "solar")
+FAMILIES = ("llama", "lfm2", "solar", "granite")
 
 
 class SlotStateError(ValueError):

@@ -290,10 +290,11 @@ def check_shardable(cfg: SolarConfig, tp: int) -> None:
 
 # -- the operators ---------------------------------------------------------------
 
-def carried_conv(z, state, k, b: int, valid, chunk_at):
+def carried_conv(z, state, k, b: int, valid, chunk_at, bias=None):
     """A causal depthwise convolution on a step's rows, its window carried
     across decode rows, chunk and chunk boundary the way
-    ``lfm2.short_conv`` carries its own.
+    ``lfm2.short_conv`` carries its own; ``bias [ch]`` float32, if given,
+    is added to every row's result (``models/granite.py``).
 
     z [N, ch]: rows ``[:b]`` one token of slot i each, rows ``[b:]`` (if
     any) one slot's prompt chunk in order. state [slots, L - 1, ch]: each
@@ -320,6 +321,8 @@ def carried_conv(z, state, k, b: int, valid, chunk_at):
                                             keepdims=False)
         new_state = jax.lax.dynamic_update_slice_in_dim(
             new_state, jnp.where(n_valid > 0, after, kept)[None], slot, 0)
+    if bias is not None:
+        conv = conv + bias
     return conv, new_state
 
 

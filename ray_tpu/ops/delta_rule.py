@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 
 from .attention import _on_tpu
+from .slot_stream import (plan_valid, row_maps, step_plan,  # noqa: F401
+                          stream_geometry, stream_rows)
 
 _LANES = 128
 # heads a block: a slot's 64 heads are 4 MiB contiguous, one DMA a row each
@@ -84,28 +86,13 @@ def recurrence(s0, n_tok, q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1), s
 
 
-def step_plan(valid, chunk_at=None):
-    """What of a step's rows is in the step, as the kernel reads it: an
-    int32 vector ``[active decode rows, the chunk's slot, its live
-    tokens, the decode rows with the active ones first]``. Built once a
-    step, on the device, from ``valid [B]`` bool and ``chunk_at`` = None
-    or (slot, n_valid); every layer's :func:`delta_rule` takes the same
-    one."""
-    slot, n_valid = (0, 0) if chunk_at is None else chunk_at
-    order = jnp.argsort(~valid, stable=True)
-    return jnp.concatenate([
-        jnp.stack([valid.sum(), slot, n_valid]).astype(jnp.int32),
-        order.astype(jnp.int32)])
-
-
 def delta_rule_reference(state, layer, plan, q, k, v, g, beta):
     """:func:`recurrence` from and to the slots' states of ``layer``; the
     contract of :func:`delta_rule`. A row that is not in the step writes
     its slot's state back as it read it."""
     b = plan.shape[0] - 3
-    valid = jnp.zeros((b,), bool).at[plan[3:]].set(jnp.arange(b) < plan[0])
     xs = (q, k, v, g, beta)
-    o, s = recurrence(state[layer, :b], valid.astype(jnp.int32),
+    o, s = recurrence(state[layer, :b], plan_valid(plan).astype(jnp.int32),
                       *(x[:b, None] for x in xs))
     o, state = o[:, 0], state.at[layer, :b].set(s)
     if q.shape[0] > b:
@@ -169,104 +156,26 @@ def _through(state, cols_ref, v_ref, beta_ref, o_ref, row, lo: int, hi: int):
             s2 * qq, axis=0, keepdims=True)[None]
 
 
-def _kernel(plan_ref, layer_ref, *refs, hb: int, b: int, c: int, stride: int,
-            parts: int, steps: int):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+def _kernel(plan_ref, layer_ref, *refs, hb: int, b: int, c: int, **geo):
+    """``slot_stream.stream_rows`` with the delta rule's arithmetic: a
+    block of ``hb`` heads a grid step."""
     refs = list(refs)
     s_hbm = refs.pop(0)
     dec = [refs.pop(0) for _ in range(4)] if b else None    # q, k, v, g
     chk = [refs.pop(0) for _ in range(4)] if c else None
     (b_ref, o_ref, so_hbm, sbuf, cbuf, cols_d, cols_c, beta_d, beta_c, rsem,
      wsem, csem) = refs
-    j, s = pl.program_id(0), pl.program_id(1)
-    layer, heads = layer_ref[0], pl.ds(j * hb, hb)
-    n_active, slot_c, n_valid = plan_ref[0], plan_ref[1], plan_ref[2]
-
-    def row_of(i):
-        """The i-th active decode row, which is its slot."""
-        return plan_ref[3 + jnp.clip(i, 0, b - 1)] if b else jnp.int32(0)
-
-    def read(i):
-        return pltpu.make_async_copy(s_hbm.at[layer, row_of(i), heads],
-                                     sbuf.at[i % 3], rsem.at[i % 3])
-
-    def write(i):
-        return pltpu.make_async_copy(sbuf.at[i % 3],
-                                     so_hbm.at[layer, row_of(i), heads],
-                                     wsem.at[i % 3])
-
-    chunk_in = pltpu.make_async_copy(s_hbm.at[layer, slot_c, heads], cbuf,
-                                     csem.at[0])
-    chunk_out = pltpu.make_async_copy(cbuf, so_hbm.at[layer, slot_c, heads],
-                                      csem.at[1])
-
-    # this step's decode row (the s-th active one) and its share of the
-    # chunk: heads group r of token t
-    has_d = s < n_active
-    t, r = s // stride, s % stride
-    has_c = (t < n_valid) & (r < parts)
-    row_d, row_c = row_of(s), b + t
-    group = hb // parts
-    halves = [(0, -(-hb // 2)), (-(-hb // 2), hb)]
-    chunk_halves = [(0, -(-group // 2)), (-(-group // 2), group)]
-
-    def if_row(i, dma):
-        """``dma(i)`` if i is an active decode row."""
-        pl.when((i >= 0) & (i < n_active))(lambda: dma(i))
-
-    @pl.when(s == 0)
-    def _():
-        pl.when(n_valid > 0)(chunk_in.start)
-
-        @pl.when(n_active > 0)
-        def _():
-            read(0).start()
-            read(0).wait()
-
-        pl.when(n_valid > 0)(chunk_in.wait)
-
-    # Two streams a step, a read then a write, never both at once: HBM
-    # gives the two together 80 % of its rate and one after the other
-    # 85 % (PERF.md Findings PR 41). The next row's state comes in while
-    # this row's vectors are laid out and half of its heads (and of the
-    # chunk's share) are carried through their token; the last row's
-    # goes back during the other half. (A write left in flight across
-    # the step's boundary runs beside the next rows' fetches: slower.)
-    if_row(s + 1, lambda i: read(i).start())
-    if b:
-        pl.when(has_d)(lambda: _prepare(dec[0], dec[1], dec[3], b_ref, row_d,
-                                        j * hb, cols_d, beta_d))
-    if c:
-        pl.when(has_c & (r == 0))(lambda: _prepare(
-            chk[0], chk[1], chk[3], b_ref, row_c, j * hb, cols_c, beta_c))
-    for phase, ((lo, hi), (clo, chi)) in enumerate(zip(halves, chunk_halves)):
-        if phase == 1:
-            if_row(s + 1, lambda i: read(i).wait())
-            if_row(s - 1, lambda i: write(i).start())
-        if b and hi > lo:
-            pl.when(has_d)(lambda lo=lo, hi=hi: _through(
-                sbuf.at[s % 3], cols_d, dec[2], beta_d, o_ref, row_d, lo, hi))
-        if c and chi > clo:
-            for g in range(parts):
-                pl.when(has_c & (r == g))(
-                    lambda g=g, clo=clo, chi=chi: _through(
-                        cbuf, cols_c, chk[2], beta_c, o_ref, row_c,
-                        g * group + clo, g * group + chi))
-    if_row(s - 1, lambda i: write(i).wait())
-
-    # the chunk's state goes back after its last token's last heads
-    pl.when(has_c & (t == n_valid - 1) & (r == parts - 1))(chunk_out.start)
-
-    @pl.when(s == steps - 1)
-    def _():
-        @pl.when(has_d)
-        def _():
-            write(s).start()
-            write(s).wait()
-
-        pl.when(n_valid > 0)(chunk_out.wait)
+    stream_rows(
+        plan_ref, layer_ref, s_hbm, so_hbm, sbuf, cbuf, rsem, wsem, csem,
+        ub=hb, b=b, c=c, **geo,
+        prepare_d=lambda row, j: _prepare(dec[0], dec[1], dec[3], b_ref, row,
+                                          j * hb, cols_d, beta_d),
+        through_d=lambda state, row, lo, hi: _through(
+            state, cols_d, dec[2], beta_d, o_ref, row, lo, hi),
+        prepare_c=lambda row, j: _prepare(chk[0], chk[1], chk[3], b_ref, row,
+                                          j * hb, cols_c, beta_c),
+        through_c=lambda state, row, lo, hi: _through(
+            state, cols_c, chk[2], beta_c, o_ref, row, lo, hi))
 
 
 def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
@@ -306,21 +215,9 @@ def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
         raise ValueError(
             f"delta_rule: dk {dk} and dv {dv} must fill lanes and the "
             f"head block {hb} sublane rows")
-    # a chunk token every `stride` steps, its heads over `parts` of them:
-    # the decode rows' stream has vector time to spare, and the chunk's
-    # tokens take it in shares small enough to hide there
-    stride = max(1, b // c) if c else 1
-    parts = stride if hb % stride == 0 else 1
-    steps = max(b, c * stride)
+    stride, parts, steps = stream_geometry(b, c, hb)
+    decode_row, chunk_row = row_maps(b, c, stride)
     width = -(-3 * hb // _LANES) * _LANES
-
-    def decode_row(j, s, plan_ref, layer_ref):
-        at = jnp.clip(jnp.minimum(s, plan_ref[0] - 1), 0, b - 1)
-        return plan_ref[3 + at], j, 0
-
-    def chunk_row(j, s, plan_ref, layer_ref):
-        return b + jnp.clip(jnp.minimum(s // stride, plan_ref[2] - 1), 0,
-                            c - 1), j, 0
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     rows = ([pl.BlockSpec((1, hb, x.shape[-1]), decode_row)

@@ -25,11 +25,12 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm.engine import (HostInputs, SlotEngine, build_step_programs,
                                 prefill_lane)
-from ray_tpu.models import lfm2, llama, serving, solar
+from ray_tpu.models import granite, lfm2, llama, serving, solar
 from ray_tpu.ops import attention as A
 from ray_tpu.ops import delta_rule as DR
 from ray_tpu.ops import grouped_matmul as GM
 from ray_tpu.ops import paged_attention as PA
+from ray_tpu.ops import ssm_scan as SS
 from ray_tpu.parallel.mesh import DEVICE_PEAKS, MeshSpec
 from ray_tpu.parallel.sharding import (prune_rules_for_mesh, shardings_for,
                                        under_mesh)
@@ -68,6 +69,7 @@ def compiled_for_tpu(monkeypatch):
     monkeypatch.setattr(PA, "_on_tpu", lambda: True)
     monkeypatch.setattr(GM, "_on_tpu", lambda: True)
     monkeypatch.setattr(DR, "_on_tpu", lambda: True)
+    monkeypatch.setattr(SS, "_on_tpu", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -473,6 +475,96 @@ def test_solar_step_updates_the_matrix_state_in_place(v5e):
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < 0.5e9
     assert state.dtype.itemsize * math.prod(state.shape) > 1.6e9
+
+
+# Granite-4.0-H-Micro at its published widths and the cell's deployment
+# (1536 positions, 64 slots, the whole vocabulary) and ONE whole period of
+# its four: what a layer does to the pool and to the slots' state does not
+# depend on how many periods the loop over them runs.
+GRANITE_1P = granite.GraniteConfig(max_seq=1536, layer_types=granite.PERIOD)
+GRANITE_SLOTS = 64
+
+
+def test_granite_step_updates_the_state_in_place(v5e):
+    """The fourth family's one step program at the cell's geometry (64
+    slots, the lane of 128 its deployment names): the donated cache (the
+    attention layer's pages at head dim 64, the mamba layers' ``[9, 64,
+    32, 128, 128]`` float32 states, the convolution's windows) is aliased
+    to the output whole; nothing but the state-space kernel has a
+    state-shaped result (no copy, no slice of a layer or a slot, no
+    scatter); the temporaries of a step stay under 0.2 GB beside 1.2 GB
+    of state (4.8 GB at the cell's four periods); the kernels are the ones
+    counted (paged attention for the decode rows and for the lane; ONE
+    state-space call a mamba layer for both: the period's runs of 5 and 4
+    mamba layers compile as two loop bodies); and no matmul copies its
+    layer of the stacked weights first, nor the stack (a fused [2048,
+    8512] input projection did: every step copied all 36 layers of it,
+    1.25 GB, into the products' layout)."""
+    cfg = GRANITE_1P
+    where = SingleDeviceSharding(v5e[0])
+    model = serving.model_for(cfg)
+    lane = 128
+    assert model.one_program and _derived_lane(v5e, cfg) == 64
+    pages = GRANITE_SLOTS * cfg.max_seq // PAGE + 1
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), cfg)[0])
+    cache = jax.eval_shape(lambda: model.slot_state.attach(
+        cfg, model.init_cache(cfg, pages, PAGE), GRANITE_SLOTS))
+    sds = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=where)
+    params, cache = (jax.tree.map(sds, t) for t in (params, cache))
+    arg = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=where)
+    tables = cfg.max_seq // PAGE
+    layout = HostInputs(GRANITE_SLOTS, tables, lane)
+    fn = build_step_programs(cfg, PAGE, 1, GRANITE_SLOTS, lane)[0]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, arg((GRANITE_SLOTS,)), arg((layout.size,))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 + 2
+    state = cache["ssm"]
+    assert state.shape == (9, GRANITE_SLOTS, 32, 128, 128)
+    assert state.dtype == jnp.float32
+    shape = ",".join(map(str, state.shape))
+    made = [line.strip()[:160] for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?\S+ = f32\[" + shape + r"\]", line)
+            and not re.search(r" (custom-call|parameter|get-tuple-element|"
+                              r"bitcast)\(", line)]
+    assert not made, made
+    # nor a layer's states or a slot's cut out of it
+    parts = {",".join(map(str, dims)) for dims in (
+        state.shape[1:], (1,) + state.shape[1:], state.shape[2:],
+        (1,) + state.shape[2:], (1, 1) + state.shape[2:])}
+    sliced = [line.strip()[:160] for line in _unfused_lines(text)
+              for m in [re.match(r"\s*(?:ROOT )?\S+ = f32\[([\d,]+)\]\S* "
+                                 r"(copy|fusion|slice|dynamic-slice|gather|"
+                                 r"scatter|dynamic-update-slice)\(", line)]
+              if m and m.group(1) in parts]
+    assert not sliced, sliced
+    # every stacked weight is read where it lies: no instruction of the
+    # program's own computations has the shape of a stack or of one layer
+    # of it (as stored or transposed) but the parameter itself
+    weights = set()
+    for kind in (granite.MAMBA, granite.ATTENTION):
+        for x in jax.tree.leaves(params[kind]):
+            # (``w_dt`` [64, 2048] has the shape of a step's rows)
+            if x.ndim == 3 and min(x.shape[1:]) >= 128:
+                n, a, b = x.shape
+                for dims in ((a, b), (b, a)):
+                    weights |= {"%d,%d" % dims, "1,%d,%d" % dims,
+                                "%d,%d,%d" % ((n,) + dims)}
+    moved = [line.strip()[:160] for line in _unfused_lines(text)
+             for m in [re.match(r"\s*(?:ROOT )?\S+ = bf16\[([\d,]+)\]\S* "
+                                r"(copy|transpose|fusion|slice|"
+                                r"dynamic-slice)\(", line)]
+             if m and m.group(1) in weights]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.dtype.itemsize * math.prod(x.shape)
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 0.2e9
+    assert state.dtype.itemsize * math.prod(state.shape) > 1.2e9
 
 
 def _unfused_lines(text):
