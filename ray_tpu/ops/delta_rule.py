@@ -17,9 +17,11 @@ aliased to its output, and a step's rows as the layer computes them: the
 decode rows, one token of slot i each, then one slot's chunk. The kernel
 moves the states itself: the block of heads of each active decode row is
 copied into VMEM, carried through the row's token in place and copied
-back, a row a grid step, and the chunk's state stays in VMEM from the
-first step to the last while its tokens are carried through it beside the
-decode rows (their stream leaves the vector units half idle). Nothing
+back, a row a grid step (a burst of one: a row's 64 heads are the 4 MiB
+``ops/slot_stream.py`` asks of a burst), and the chunk's state stays in
+VMEM from the first step to the last while its tokens are carried through
+it beside the decode rows (their stream leaves the vector units half
+idle). Nothing
 slices a layer or a slot out of the array and no XLA operation touches
 it. A row that is not in the step (a parked decode row, an empty chunk,
 the chunk's tokens past its last real one) starts no DMA: its state is
@@ -47,8 +49,9 @@ import jax
 import jax.numpy as jnp
 
 from .attention import _on_tpu
-from .slot_stream import (plan_valid, row_maps, step_plan,  # noqa: F401
-                          stream_geometry, stream_rows)
+from .slot_stream import (burst_rows, one_row, plan_valid,  # noqa: F401
+                          row_maps, rows_block, step_plan, stream_geometry,
+                          stream_rows)
 
 _LANES = 128
 # heads a block: a slot's 64 heads are 4 MiB contiguous, one DMA a row each
@@ -156,30 +159,46 @@ def _through(state, cols_ref, v_ref, beta_ref, o_ref, row, lo: int, hi: int):
             s2 * qq, axis=0, keepdims=True)[None]
 
 
-def _kernel(plan_ref, layer_ref, *refs, hb: int, b: int, c: int, **geo):
+def _kernel(plan_ref, layer_ref, *refs, hb: int, b: int, c: int, burst: int,
+            **geo):
     """``slot_stream.stream_rows`` with the delta rule's arithmetic: a
-    block of ``hb`` heads a grid step."""
+    block of ``hb`` heads a grid step, of each row of a burst."""
     refs = list(refs)
+    rows = range(burst)
     s_hbm = refs.pop(0)
-    dec = [refs.pop(0) for _ in range(4)] if b else None    # q, k, v, g
+    # q, k, v, g: a row of the burst each, then the chunk's tokens of the step
+    dec = [[refs.pop(0) for _ in range(4)] for _ in rows] if b else None
     chk = [refs.pop(0) for _ in range(4)] if c else None
-    (b_ref, o_ref, so_hbm, sbuf, cbuf, cols_d, cols_c, beta_d, beta_c, rsem,
-     wsem, csem) = refs
+    b_ref, o_ref, so_hbm = (refs.pop(0) for _ in range(3))
+    sbuf = [refs.pop(0) for _ in rows]
+    cbuf = refs.pop(0)
+    cols_d = [refs.pop(0) for _ in rows]
+    cols_c = refs.pop(0)
+    beta_d = [refs.pop(0) for _ in rows]
+    beta_c = refs.pop(0)
+    rsem, wsem = ([refs.pop(0) for _ in rows] for _ in range(2))
+    csem, = refs
+
+    def prepare_d(row, j, k):
+        q, kk, _, g = dec[k]
+        _prepare(q, kk, g, b_ref, row, j * hb, cols_d[k], beta_d[k])
+
+    def prepare_c(row, j, i):
+        q, kk, _, g = (one_row(x, i) for x in chk)
+        _prepare(q, kk, g, b_ref, row, j * hb, cols_c, beta_c)
+
     stream_rows(
         plan_ref, layer_ref, s_hbm, so_hbm, sbuf, cbuf, rsem, wsem, csem,
-        ub=hb, b=b, c=c, **geo,
-        prepare_d=lambda row, j: _prepare(dec[0], dec[1], dec[3], b_ref, row,
-                                          j * hb, cols_d, beta_d),
-        through_d=lambda state, row, lo, hi: _through(
-            state, cols_d, dec[2], beta_d, o_ref, row, lo, hi),
-        prepare_c=lambda row, j: _prepare(chk[0], chk[1], chk[3], b_ref, row,
-                                          j * hb, cols_c, beta_c),
-        through_c=lambda state, row, lo, hi: _through(
-            state, cols_c, chk[2], beta_c, o_ref, row, lo, hi))
+        ub=hb, b=b, c=c, burst=burst, **geo,
+        prepare_d=prepare_d, prepare_c=prepare_c,
+        through_d=lambda state, row, lo, hi, k: _through(
+            state, cols_d[k], dec[k][2], beta_d[k], o_ref, row, lo, hi),
+        through_c=lambda state, row, lo, hi, i: _through(
+            state, cols_c, one_row(chk[2], i), beta_c, o_ref, row, lo, hi))
 
 
 def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
-               head_block: int = None):
+               head_block: int = None, burst: int = None):
     """A step's N = B + C rows through the recurrence, each from and to
     its slot's state of ``layer`` -> (o [N, H, dv] float32, state).
 
@@ -192,11 +211,14 @@ def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
     unspecified (as are the ``o`` of the chunk's tokens past its last
     real one); the chunk's slot is no active decode row's. q, k, g [N, H,
     dk] (g the log decay, <= 0); v [N, H, dv]; beta [N, H]: the rows as
-    the layer computes them, in any float dtype.
+    the layer computes them, in any float dtype. ``head_block`` and
+    ``burst`` are the tests' and the probes': heads a grid step, and
+    decode rows a burst where ``slot_stream.burst_rows`` is not to say.
 
     The state never leaves HBM but by the kernel's own DMAs: a grid step
-    takes the next active decode row's block of heads into VMEM, carries
-    it through the row's token and sends it back, and beside it carries
+    takes the next burst of active decode rows' blocks of heads into VMEM
+    (one row where a block is 4 MiB: ``ops/slot_stream.py``), carries
+    each through its row's token and sends it back, and beside it carries
     a share of the chunk's heads through one of its tokens, the chunk's
     state resident from the first step to the last."""
     if not (interpret or use_kernel()):
@@ -215,14 +237,15 @@ def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
         raise ValueError(
             f"delta_rule: dk {dk} and dv {dv} must fill lanes and the "
             f"head block {hb} sublane rows")
-    stride, parts, steps = stream_geometry(b, c, hb)
-    decode_row, chunk_row = row_maps(b, c, stride)
+    burst = burst or burst_rows(hb * dk * dv * state.dtype.itemsize, b)
+    tokens, stride, parts, steps = stream_geometry(b, c, hb, burst)
+    decode_rows, chunk_row = row_maps(b, c, hb, burst, tokens, stride)
     width = -(-3 * hb // _LANES) * _LANES
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    rows = ([pl.BlockSpec((1, hb, x.shape[-1]), decode_row)
-             for x in (q, k, v, g)] if b else []) + (
-        [pl.BlockSpec((1, hb, x.shape[-1]), chunk_row)
+    rows = [pl.BlockSpec((1, hb, x.shape[-1]), row)
+            for row in decode_rows[:burst * bool(b)] for x in (q, k, v, g)] + (
+        [pl.BlockSpec(rows_block(tokens, hb, x.shape[-1]), chunk_row)
          for x in (q, k, v, g)] if c else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(h // hb, steps),
@@ -234,18 +257,18 @@ def delta_rule(state, layer, plan, q, k, v, g, beta, interpret: bool = False,
             pl.BlockSpec((n, hb, dv), lambda j, s, *_: (0, j, 0)),
             hbm],
         scratch_shapes=[
-            pltpu.VMEM((3, hb, dk, dv), jnp.float32),    # decode rows' states
+            # the decode rows' states, a ring over bursts a row of a burst
+            *[pltpu.VMEM((3, hb, dk, dv), jnp.float32)] * burst,
             pltpu.VMEM((hb, dk, dv), jnp.float32),       # the chunk's
-            pltpu.VMEM((dk, width), jnp.float32),
-            pltpu.VMEM((dk, width), jnp.float32),
-            pltpu.VMEM((hb, dv), jnp.float32),
-            pltpu.VMEM((hb, dv), jnp.float32),
-            pltpu.SemaphoreType.DMA((3,)), pltpu.SemaphoreType.DMA((3,)),
+            *[pltpu.VMEM((dk, width), jnp.float32)] * (burst + 1),
+            *[pltpu.VMEM((hb, dv), jnp.float32)] * (burst + 1),
+            *[pltpu.SemaphoreType.DMA((3,))] * (2 * burst),
             pltpu.SemaphoreType.DMA((2,))])
-    operands = (q, k, v, g) * (bool(b) + bool(c))
+    operands = (q, k, v, g) * (burst * bool(b) + bool(c))
     return pl.pallas_call(
-        functools.partial(_kernel, hb=hb, b=b, c=c, stride=stride,
-                          parts=parts, steps=steps),
+        functools.partial(_kernel, hb=hb, b=b, c=c, burst=burst,
+                          tokens=tokens, stride=stride, parts=parts,
+                          steps=steps),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n, h, dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],

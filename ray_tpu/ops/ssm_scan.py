@@ -28,12 +28,12 @@ P, N]``) every head would need eight lane reductions a token, 512 a row.
 The engine keeps every layer's states in that ONE array beside the KV
 pages; one call a layer takes the whole array where it lies in HBM,
 aliased to its output, a layer index and a step's rows as the layer
-computes them, and moves the states with its own DMAs:
-``ops/slot_stream.py``, shared with the delta rule, says how. A prompt
-chunk's tokens are carried through the chunk's resident state one after
-the other, beside the decode rows' stream: the recurrence itself, in
-float32, and not its chunked matmul form (PERF.md Findings PR 43 says
-what that costs).
+computes them, and moves the states with its own DMAs, a burst of decode
+rows a grid step: ``ops/slot_stream.py``, shared with the delta rule,
+says how. A prompt chunk's tokens are carried through the chunk's
+resident state one after the other, several a grid step, beside the
+decode rows' stream: the recurrence itself, in float32, and not its
+chunked matmul form (PERF.md Findings PR 43 says what that costs).
 
 Off the TPU callers get :func:`ssm_scan_reference` (:func:`recurrence`, a
 ``lax.scan`` over tokens); the tests run the kernel interpreted against
@@ -48,13 +48,15 @@ import jax
 import jax.numpy as jnp
 
 from .attention import _on_tpu
-from .slot_stream import (plan_valid, row_maps, step_plan,  # noqa: F401
-                          stream_geometry, stream_rows)
+from .slot_stream import (burst_rows, one_row, plan_valid,  # noqa: F401
+                          row_maps, rows_block, step_plan, stream_geometry,
+                          stream_rows)
 
 _LANES = 128
 # blocks of 128 channels a grid step: a slot's 32 blocks (64 heads of 64)
-# are 2 MiB contiguous, one DMA a row each way; three of them, the chunk's
-# and every row's y twice are ~19 MiB of VMEM at 64 rows and a lane of 256.
+# are 2 MiB contiguous, one DMA a row each way and two rows a burst
+# (``slot_stream.BURST_BYTES``); three bursts, the chunk's block and every
+# row's y twice are ~25 MiB of VMEM at 64 rows and a lane of 256.
 _BLOCKS = 32
 _VMEM_LIMIT = 48 * 1024 * 1024
 
@@ -143,27 +145,38 @@ def _through(state, x_ref, a_ref, b_tile, c_tile, y_ref, row, lo: int,
             s1 * c_tile[...], axis=0, keepdims=True)[None]
 
 
-def _kernel(plan_ref, layer_ref, *refs, gb: int, b: int, c: int, **geo):
+def _kernel(plan_ref, layer_ref, *refs, gb: int, b: int, c: int, burst: int,
+            **geo):
     """``slot_stream.stream_rows`` with this recurrence's arithmetic: a
-    block of ``gb`` blocks of channels a grid step."""
+    block of ``gb`` blocks of channels a grid step, of each row of a
+    burst."""
     refs = list(refs)
+    rows = range(burst)
     s_hbm = refs.pop(0)
-    dec = [refs.pop(0) for _ in range(3)] if b else None    # x, a, bc
+    # x, a, bc: a row of the burst each, then the chunk's tokens of the step
+    dec = [[refs.pop(0) for _ in range(3)] for _ in rows] if b else None
     chk = [refs.pop(0) for _ in range(3)] if c else None
-    (y_ref, so_hbm, sbuf, cbuf, bd, cd, bcc, ccc, rsem, wsem, csem) = refs
+    y_ref, so_hbm = refs.pop(0), refs.pop(0)
+    sbuf = [refs.pop(0) for _ in rows]
+    cbuf = refs.pop(0)
+    tiles = [[refs.pop(0), refs.pop(0)] for _ in rows]       # B, C a row
+    bcc, ccc = refs.pop(0), refs.pop(0)
+    rsem, wsem = ([refs.pop(0) for _ in rows] for _ in range(2))
+    csem, = refs
     stream_rows(
         plan_ref, layer_ref, s_hbm, so_hbm, sbuf, cbuf, rsem, wsem, csem,
-        ub=gb, b=b, c=c, **geo,
-        prepare_d=lambda row, j: _prepare(dec[2], bd, cd),
-        through_d=lambda state, row, lo, hi: _through(
-            state, dec[0], dec[1], bd, cd, y_ref, row, lo, hi),
-        prepare_c=lambda row, j: _prepare(chk[2], bcc, ccc),
-        through_c=lambda state, row, lo, hi: _through(
-            state, chk[0], chk[1], bcc, ccc, y_ref, row, lo, hi))
+        ub=gb, b=b, c=c, burst=burst, **geo,
+        prepare_d=lambda row, j, k: _prepare(dec[k][2], *tiles[k]),
+        through_d=lambda state, row, lo, hi, k: _through(
+            state, dec[k][0], dec[k][1], *tiles[k], y_ref, row, lo, hi),
+        prepare_c=lambda row, j, i: _prepare(one_row(chk[2], i), bcc, ccc),
+        through_c=lambda state, row, lo, hi, i: _through(
+            state, one_row(chk[0], i), one_row(chk[1], i), bcc, ccc, y_ref,
+            row, lo, hi))
 
 
 def ssm_scan(state, layer, plan, x, a, bc, interpret: bool = False,
-             blocks: int = None):
+             blocks: int = None, burst: int = None):
     """A step's R = B + C rows through the recurrence, each from and to
     its slot's state of ``layer`` -> (y [R, G, W] float32, state).
 
@@ -178,7 +191,9 @@ def ssm_scan(state, layer, plan, x, a, bc, interpret: bool = False,
     step's rows masks them; the chunk's slot is no active decode row's. x [R, G,
     W]: the rows' inputs times their step sizes; a [R, G, W]: the decay,
     a head's number in each of its channels; bc [R, 2, N]: B, then C; in
-    any float dtype."""
+    any float dtype. ``blocks`` and ``burst`` are the tests' and the
+    probes': blocks of channels a grid step, and decode rows a burst
+    where ``slot_stream.burst_rows`` is not to say."""
     if not (interpret or use_kernel()):
         return ssm_scan_reference(state, layer, plan, x, a, bc)
     from jax.experimental import pallas as pl
@@ -195,34 +210,40 @@ def ssm_scan(state, layer, plan, x, a, bc, interpret: bool = False,
         raise ValueError(
             f"ssm_scan: the state size {n} must be {_LANES} and a block's "
             f"{w} channels must fill lanes")
-    stride, parts, steps = stream_geometry(b, c, gb)
-    decode_row, chunk_row = row_maps(b, c, stride)
+    burst = burst or burst_rows(gb * n * w * state.dtype.itemsize, b)
+    tokens, stride, parts, steps = stream_geometry(b, c, gb, burst)
+    decode_rows, chunk_row = row_maps(b, c, gb, burst, tokens, stride)
 
-    def specs(row):
-        return [pl.BlockSpec((1, gb, w), row), pl.BlockSpec((1, gb, w), row),
-                pl.BlockSpec((1, 2, n), lambda j, s, *refs: row(
+    def specs(row, rows):
+        block = functools.partial(rows_block, rows)
+        return [pl.BlockSpec(block(gb, w), row), pl.BlockSpec(block(gb, w), row),
+                pl.BlockSpec(block(2, n), lambda j, s, *refs: row(
                     0, s, *refs))]
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # the decode rows' states, a ring over bursts a row of a burst
+    ring, tile = pltpu.VMEM((3, gb, n, w), jnp.float32), pltpu.VMEM(
+        (n, w), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(g // gb, steps),
-        in_specs=[hbm] + (specs(decode_row) if b else [])
-        + (specs(chunk_row) if c else []),
+        in_specs=[hbm] + [spec for row in decode_rows[:burst * bool(b)]
+                          for spec in specs(row, 1)]
+        + (specs(chunk_row, tokens) if c else []),
         out_specs=[
             # every row's y, written back once a block of channels
             pl.BlockSpec((r, gb, w), lambda j, s, *_: (0, j, 0)),
             hbm],
         scratch_shapes=[
-            pltpu.VMEM((3, gb, n, w), jnp.float32),      # decode rows' states
+            *[ring] * burst,
             pltpu.VMEM((gb, n, w), jnp.float32),         # the chunk's
-            pltpu.VMEM((n, w), jnp.float32), pltpu.VMEM((n, w), jnp.float32),
-            pltpu.VMEM((n, w), jnp.float32), pltpu.VMEM((n, w), jnp.float32),
-            pltpu.SemaphoreType.DMA((3,)), pltpu.SemaphoreType.DMA((3,)),
+            *[tile] * (2 * burst + 2),
+            *[pltpu.SemaphoreType.DMA((3,))] * (2 * burst),
             pltpu.SemaphoreType.DMA((2,))])
-    operands = (x, a, bc) * (bool(b) + bool(c))
+    operands = (x, a, bc) * (burst * bool(b) + bool(c))
     return pl.pallas_call(
-        functools.partial(_kernel, gb=gb, b=b, c=c, stride=stride,
-                          parts=parts, steps=steps),
+        functools.partial(_kernel, gb=gb, b=b, c=c, burst=burst,
+                          tokens=tokens, stride=stride, parts=parts,
+                          steps=steps),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((r, g, w), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],

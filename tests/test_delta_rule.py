@@ -5,14 +5,18 @@ them, decode rows first (one token of slot i each) and then one slot's
 chunk, with the step's plan. One token a row with a parked row between,
 a chunk that stops at its last valid token, both in one call, no row in
 the step, the corners of the transition (``b`` near 2: negative
-eigenvalues; ``a`` near 1 and near 0), and a state carried over many
-steps."""
+eigenvalues; ``a`` near 1 and near 0), a state carried over many steps,
+and the skeleton it shares with ``ops/ssm_scan.py`` at two decode rows a
+burst (``burst``: the cell's blocks are 4 MiB and go a row a burst, so
+only these tests run the shared path's bursts with this arithmetic), with
+the rule that says how many rows a burst has."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import slot_stream
 from ray_tpu.ops.delta_rule import (delta_rule, delta_rule_reference,
                                     recurrence, step_plan)
 
@@ -67,9 +71,11 @@ def _both(layer, valid, chunk_at, *args, **kw):
     return got, want
 
 
-def test_one_token_a_row_with_a_parked_row_between():
+@pytest.mark.parametrize("burst", [1, 2])
+def test_one_token_a_row_with_a_parked_row_between(burst):
     state, *rest = _inputs(0, 4)
-    (o, s), (o_ref, s_ref) = _both(1, [1, 0, 1, 1], None, state, *rest)
+    (o, s), (o_ref, s_ref) = _both(1, [1, 0, 1, 1], None, state, *rest,
+                                   burst=burst)
     live = np.asarray([True, False, True, True])
     np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
                                atol=2e-6)
@@ -131,22 +137,91 @@ def test_decode_rows_and_a_chunk_in_one_call(slot, n_valid):
     assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
 
 
+@pytest.mark.parametrize("burst", [1, 2])
 @pytest.mark.parametrize("b,c,n_valid", [(8, 2, 2), (8, 4, 3), (6, 2, 1)])
 def test_a_chunk_shorter_than_the_decode_rows_is_spread_over_their_steps(
-        b, c, n_valid):
-    """With B >= 2 C a chunk token comes every B // C grid steps and its
-    heads go through it over as many of them as divide the block (4, 2
-    and, for 3 steps, all at once): the same numbers, with a parked row
-    among the decode rows and slot ``b`` the chunk's."""
+        b, c, n_valid, burst):
+    """With as many bursts as 2 C or more a chunk token comes every
+    bursts // C grid steps and its heads go through it over as many of
+    them as divide the block (4, 2 and, for 3 steps, all at once): the
+    same numbers, with a parked row among the decode rows (inside a
+    burst of 2) and slot ``b`` the chunk's."""
     valid = [i != 2 for i in range(b)]
     state, *rest = _inputs(6, b + c, slots=b + 1)
-    (o, s), (o_ref, s_ref) = _both(0, valid, (b, n_valid), state, *rest)
+    (o, s), (o_ref, s_ref) = _both(0, valid, (b, n_valid), state, *rest,
+                                   burst=burst)
     live = np.asarray(valid + [i < n_valid for i in range(c)])
     np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
                                atol=2e-6)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=2e-6)
     assert (np.asarray(s[0, 2]) == np.asarray(state[0, 2])).all()
     assert (np.asarray(s[1]) == np.asarray(state[1])).all()
+
+
+@pytest.mark.parametrize("b,c,valid,chunk_at", [
+    # an odd count of active rows: the last burst is part-filled
+    (7, 0, [1, 1, 1, 0, 1, 1, 0], None),
+    # fewer active rows than a burst holds
+    (8, 0, [0, 0, 0, 0, 0, 1, 0, 0], None),
+    # parked rows between the active ones of one burst, and a chunk with
+    # more tokens than grid steps that ends mid-step (12 over 4 bursts: 3
+    # a grid step, 7 live)
+    (8, 12, [1, 0, 0, 1, 1, 0, 1, 1], (8, 7)),
+    # a chunk alone
+    (0, 12, [], (2, 12)),
+])
+def test_bursts_of_two_rows_give_what_a_row_a_grid_step_gives(
+        b, c, valid, chunk_at):
+    valid = [bool(v) for v in valid]
+    state, *rest = _inputs(8, b + c, slots=9)
+    (o, s), (o_ref, s_ref) = _both(1, valid, chunk_at, state, *rest, burst=2)
+    live = np.asarray(valid + [i < (chunk_at or (0, 0))[1] for i in range(c)])
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
+                               atol=5e-6)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=5e-6)
+    touched = np.zeros((LAYERS, 9), bool)
+    touched[1, :b] = valid
+    if chunk_at:
+        touched[1, chunk_at[0]] = True
+    assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
+    o1, s1 = delta_rule(state, 1, _plan(valid, chunk_at), *rest,
+                        interpret=True, burst=1)
+    assert (np.asarray(s) == np.asarray(s1)).all()
+    assert (np.asarray(o)[live] == np.asarray(o1)[live]).all()
+
+
+def test_the_rule_of_rows_a_burst_places_every_row_and_token_once():
+    """``slot_stream``'s rule in plain Python: a row a burst at the delta
+    rule's block (64 heads x 128 x 128 float32 = 4 MiB), two or more at
+    the state-space scan's (32 blocks x 128 x 128 = 2 MiB); and for a
+    sweep of decode rows, chunk sizes, active rows and live tokens, every
+    active row is in exactly one burst and every live chunk token at
+    exactly one grid step (every share of its units once)."""
+    f32 = 4
+    assert slot_stream.burst_rows(64 * 128 * 128 * f32, 128) == 1
+    assert slot_stream.burst_rows(32 * 128 * 128 * f32, 64) >= 2
+    ub = 4
+    for b in (0, 1, 3, 8, 13):
+        for c in (0, 1, 2, 7, 12, 16):
+            for burst in (1, 2, 3, 4):
+                tokens, stride, parts, steps = slot_stream.stream_geometry(
+                    b, c, ub, burst)
+                assert tokens == 1 or c % tokens == 0
+                assert ub % parts == 0 and parts <= stride
+                for n_active in {0, 1, b // 2, b - 1, b} & set(range(b + 1)):
+                    rows = [slot_stream.burst_row(s, k, burst)
+                            for s in range(steps) for k in range(burst)]
+                    assert sorted(i for i in rows if i < n_active) == list(
+                        range(n_active)), (b, c, burst, n_active)
+                for n_valid in {0, 1, c // 2, c - 1, c} & set(range(c + 1)):
+                    shares = []
+                    for s in range(steps):
+                        t, r = slot_stream.chunk_tokens(s, tokens, stride)
+                        shares += [(t + i, r) for i in range(tokens)
+                                   if t + i < n_valid and r < parts]
+                    assert sorted(shares) == [
+                        (t, r) for t in range(n_valid) for r in range(parts)
+                    ], (b, c, burst, n_valid)
 
 
 @pytest.mark.parametrize("b,c", [(4, 0), (0, 64), (4, 64)])

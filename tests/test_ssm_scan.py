@@ -6,7 +6,11 @@ first (one token of slot i each) and then one slot's chunk, with the
 step's plan. One token a row with a parked row between, a chunk that stops
 at its last valid token, both in one call, no row in the step, the corners
 of the decay (``a`` near 1 and near 0), a state carried over many steps,
-and the plan and the DMA skeleton it shares with ``ops/delta_rule.py``."""
+and the plan and the DMA skeleton it shares with ``ops/delta_rule.py``:
+each at 1, 2 and 4 decode rows a burst (``burst``: the kernel's own count
+follows the bytes of a row's block, and a test's blocks are small), with
+part-filled bursts, parked rows inside a burst and several chunk tokens a
+grid step."""
 
 import jax
 import jax.numpy as jnp
@@ -67,9 +71,14 @@ def _both(layer, valid, chunk_at, *args, **kw):
     return got, want
 
 
-def test_one_token_a_row_with_a_parked_row_between():
+BURSTS = [1, 2, 4]
+
+
+@pytest.mark.parametrize("burst", BURSTS)
+def test_one_token_a_row_with_a_parked_row_between(burst):
     state, *rest = _inputs(0, 4)
-    (y, s), (y_ref, s_ref) = _both(1, [1, 0, 1, 1], None, state, *rest)
+    (y, s), (y_ref, s_ref) = _both(1, [1, 0, 1, 1], None, state, *rest,
+                                   burst=burst)
     live = np.asarray([True, False, True, True])
     np.testing.assert_allclose(np.asarray(y)[live], np.asarray(y_ref)[live],
                                atol=2e-5)
@@ -100,16 +109,19 @@ def test_a_chunk_of_one_slot_stops_at_its_last_valid_token(n_valid):
     assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
 
 
+@pytest.mark.parametrize("burst", BURSTS)
 @pytest.mark.parametrize("n_valid", [0, 1, 15, 16])
 @pytest.mark.parametrize("slot", [4, 1])
-def test_decode_rows_and_a_chunk_in_one_call(slot, n_valid):
+def test_decode_rows_and_a_chunk_in_one_call(slot, n_valid, burst):
     """Four decode rows, row 1 parked, and a 16-token chunk of a slot
     that is no decode row's (4) or the parked row's (1), against the
-    recurrence itself run on each sequence alone."""
+    recurrence itself run on each sequence alone: three active rows are a
+    part-filled last burst of 2 and fewer rows than a burst of 4, and 16
+    tokens over 4, 2 and 1 grid steps are 4, 8 and 16 a step."""
     valid = [True, False, True, True]
     state, *rest = _inputs(5, 4 + 16)
     plan = _plan(valid, (slot, n_valid))
-    y, s = ssm_scan(state, 1, plan, *rest, interpret=True)
+    y, s = ssm_scan(state, 1, plan, *rest, interpret=True, burst=burst)
     want = np.array(state)
     y_d, s_d = recurrence(state[1, :4], jnp.asarray(valid, jnp.int32),
                           *(v[:4, None] for v in rest))
@@ -131,16 +143,20 @@ def test_decode_rows_and_a_chunk_in_one_call(slot, n_valid):
     assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
 
 
+@pytest.mark.parametrize("burst", BURSTS)
 @pytest.mark.parametrize("b,c,n_valid", [(8, 2, 2), (8, 4, 3), (6, 2, 1)])
 def test_a_chunk_shorter_than_the_decode_rows_is_spread_over_their_steps(
-        b, c, n_valid):
-    """With B >= 2 C a chunk token comes every B // C grid steps and its
-    blocks go through it over as many of them as divide the four (4, 2
-    and, for 3 steps, all at once): the same numbers, with a parked row
-    among the decode rows and slot ``b`` the chunk's."""
+        b, c, n_valid, burst):
+    """With as many bursts as 2 C or more a chunk token comes every
+    bursts // C grid steps and its blocks go through it over as many of
+    them as divide the four (4, 2 and, for 3 steps, all at once); with
+    fewer bursts than tokens, several tokens a grid step: the same
+    numbers, with a parked row among the decode rows (inside a burst) and
+    slot ``b`` the chunk's."""
     valid = [i != 2 for i in range(b)]
     state, *rest = _inputs(6, b + c, slots=b + 1)
-    (y, s), (y_ref, s_ref) = _both(0, valid, (b, n_valid), state, *rest)
+    (y, s), (y_ref, s_ref) = _both(0, valid, (b, n_valid), state, *rest,
+                                   burst=burst)
     live = np.asarray(valid + [i < n_valid for i in range(c)])
     np.testing.assert_allclose(np.asarray(y)[live], np.asarray(y_ref)[live],
                                atol=2e-5)
@@ -172,6 +188,45 @@ def test_the_corners_of_the_decay(decay):
             * np.asarray(rest[0][-1])[:, None, :], atol=1e-2)
 
 
+@pytest.mark.parametrize("b,c,valid,chunk_at", [
+    # an odd count of active rows: the last burst of 2 or 4 is part-filled
+    (7, 0, [1, 1, 1, 0, 1, 1, 0], None),
+    # fewer active rows than a burst of 2 or 4 holds
+    (8, 0, [0, 0, 0, 0, 0, 1, 0, 0], None),
+    # parked rows between the active ones of one burst, and beside them a
+    # chunk with more tokens than grid steps that ends mid-step: 12 tokens
+    # over 4 and 2 bursts are 3 and 6 a grid step, 7 of them live
+    (8, 12, [1, 0, 0, 1, 1, 0, 1, 1], (8, 7)),
+    # a chunk of a prime count of tokens beside three bursts of 2: all
+    # seven in the first grid step
+    (5, 7, [1, 1, 1, 0, 1], (6, 6)),
+    # a chunk alone: a token a grid step, whatever the burst
+    (0, 12, [], (2, 12)),
+])
+@pytest.mark.parametrize("burst", BURSTS)
+def test_bursts_part_filled_parked_inside_and_tokens_a_grid_step(
+        burst, b, c, valid, chunk_at):
+    valid = [bool(v) for v in valid]
+    state, *rest = _inputs(8, b + c, slots=9)
+    (y, s), (y_ref, s_ref) = _both(1, valid, chunk_at, state, *rest,
+                                   burst=burst)
+    live = np.asarray(valid + [i < (chunk_at or (0, 0))[1] for i in range(c)])
+    np.testing.assert_allclose(np.asarray(y)[live], np.asarray(y_ref)[live],
+                               atol=5e-5)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=1e-5)
+    # what no row of the step names is bit for bit what it was
+    touched = np.zeros((LAYERS, 9), bool)
+    touched[1, :b] = valid
+    if chunk_at:
+        touched[1, chunk_at[0]] = True
+    assert (np.asarray(s)[~touched] == np.asarray(state)[~touched]).all()
+    # and a burst changes no bit of what one row a grid step gives
+    y1, s1 = ssm_scan(state, 1, _plan(valid, chunk_at), *rest,
+                      interpret=True, burst=1)
+    assert (np.asarray(s) == np.asarray(s1)).all()
+    assert (np.asarray(y)[live] == np.asarray(y1)[live]).all()
+
+
 def test_blocks_a_grid_step_give_the_same_numbers():
     """Two decode rows and 5 live tokens of an 8-token chunk, at 4, 2 and
     1 blocks of channels a grid step."""
@@ -184,28 +239,46 @@ def test_blocks_a_grid_step_give_the_same_numbers():
         assert (np.asarray(y)[:2 + 5] == np.asarray(outs[0][0])[:2 + 5]).all()
 
 
-def test_a_state_carried_over_512_steps_stays_on_the_references():
+@pytest.mark.parametrize("burst", [1, 2])
+def test_a_state_carried_over_512_steps_stays_on_the_references(burst):
     """The decode path's own loop: the kernel's state fed back to it, 448
     tokens as seven chunks and 64 one-token calls, against float64: the
-    float32 state neither drifts nor blows up (a reaches 1)."""
+    float32 state neither drifts nor blows up (a reaches 1). At a burst
+    of 2 the sequence is slot 1's, the second row of its burst, beside
+    two decode rows that carry states of their own (the chunks then go 32
+    tokens a grid step beside the one burst)."""
     steps, g = 512, 1
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     x = jax.random.normal(ks[0], (steps, g, W)) * 0.05
     a = jax.nn.sigmoid(jax.random.normal(ks[1], (steps, 2, 1)) * 3 + 4)
     a = jnp.broadcast_to(a, (steps, 2, W // 2)).reshape(steps, g, W)
     bc = jax.random.normal(ks[2], (steps, 2, N))
-    state = jnp.zeros((1, 1, g, N, W), jnp.float32)
-    lane, row = _plan([], (0, 64)), _plan([True])
+    b = 3 if burst > 1 else 1         # decode rows; the sequence's is b - 2
+    mine = max(b - 2, 0)
+    state = jnp.zeros((1, b, g, N, W), jnp.float32)
+    lane = _plan([i != mine for i in range(b)] if b > 1 else [], (mine, 64))
+    row = _plan([True] * b)
+
+    def rows(v, at):
+        """The b decode rows' operands: the sequence's token ``at`` in
+        row ``mine``, other tokens of it in the rows beside."""
+        return jnp.concatenate([jax.lax.dynamic_slice_in_dim(
+            v, (at + 7 * (i - mine)) % steps, 1) for i in range(b)])
 
     @jax.jit
     def chunk(state, i):
-        sl = lambda v: jax.lax.dynamic_slice_in_dim(v, i * 64, 64)  # noqa: E731
-        return ssm_scan(state, 0, lane, sl(x), sl(a), sl(bc), interpret=True)
+        sl = lambda v: jnp.concatenate(  # noqa: E731
+            [rows(v, i)[:b if b > 1 else 0],
+             jax.lax.dynamic_slice_in_dim(v, i * 64, 64)])
+        y, state = ssm_scan(state, 0, lane, sl(x), sl(a), sl(bc),
+                            interpret=True, burst=burst)
+        return y[b if b > 1 else 0:], state
 
     @jax.jit
     def one(state, t):
-        sl = lambda v: jax.lax.dynamic_slice_in_dim(v, t, 1)  # noqa: E731
-        return ssm_scan(state, 0, row, sl(x), sl(a), sl(bc), interpret=True)
+        y, state = ssm_scan(state, 0, row, rows(x, t), rows(a, t),
+                            rows(bc, t), interpret=True, burst=burst)
+        return y[mine:mine + 1], state
 
     outs = []
     for i in range(steps // 64 - 1):
@@ -218,7 +291,7 @@ def test_a_state_carried_over_512_steps_stays_on_the_references():
     want_y, want_s = _sequential(np.zeros((g, N, W)), x, a, bc, heads=2)
     assert np.isfinite(got).all() and np.abs(want_s).max() < 100
     np.testing.assert_allclose(got, want_y, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(state[0, 0]), want_s, atol=5e-5)
+    np.testing.assert_allclose(np.asarray(state[0, mine]), want_s, atol=5e-5)
 
 
 def test_heads_view_is_the_state_as_the_equations_index_it():
@@ -245,8 +318,35 @@ def test_the_plan_and_the_skeleton_are_the_delta_rules_too():
     assert (np.asarray(slot_stream.plan_valid(plan))
             == np.asarray(valid)).all()
     assert np.asarray(step_plan(valid)).tolist()[:3] == [3, 0, 0]
-    # a chunk token every stride steps, its units over `parts` of them
-    assert slot_stream.stream_geometry(64, 256, 32) == (1, 1, 256)
-    assert slot_stream.stream_geometry(128, 64, 64) == (2, 2, 128)
-    assert slot_stream.stream_geometry(6, 2, 4) == (3, 1, 6)
-    assert slot_stream.stream_geometry(64, 0, 32) == (1, 1, 64)
+    # (tokens a grid step, a chunk token every stride steps, its units
+    # over `parts` of them, grid steps) for (B, C, units a block, rows a
+    # burst): the delta rule's cell as it was, a row a burst
+    geometry = slot_stream.stream_geometry
+    assert geometry(128, 64, 64, 1) == (1, 2, 2, 128)
+    assert geometry(6, 2, 4, 1) == (1, 3, 1, 6)
+    assert geometry(64, 0, 32, 1) == (1, 1, 1, 64)
+    # more tokens than bursts: several a grid step, and no step without a
+    # burst (a row a burst ran 256 steps here, 192 of them a token alone)
+    assert geometry(64, 256, 32, 1) == (4, 1, 1, 64)
+    # the state-space cell, two rows a burst: 32 bursts, the lane's 128
+    # tokens 4 a step (the probe's 256: 8), and at four rows 8 (16)
+    assert geometry(64, 128, 32, 2) == (4, 1, 1, 32)
+    assert geometry(64, 256, 32, 2) == (8, 1, 1, 32)
+    assert geometry(64, 128, 32, 4) == (8, 1, 1, 16)
+    assert geometry(64, 0, 32, 2) == (1, 1, 1, 32)
+    # fewer tokens than bursts: one every bursts // C steps, as before
+    assert geometry(128, 16, 64, 2) == (1, 4, 4, 64)
+    # a step's tokens are one block of the rows: a count that divides C
+    assert geometry(5, 7, 4, 2) == (7, 1, 1, 3)
+    assert geometry(8, 12, 4, 2) == (3, 1, 1, 4)
+    # a chunk alone: a token a grid step
+    assert geometry(0, 16, 4, 2) == (1, 1, 1, 16)
+    # rows a burst: the fewest whose blocks are 4 MiB, and no more than B
+    mib = 1024 * 1024
+    assert slot_stream.BURST_BYTES == 4 * mib
+    assert slot_stream.burst_rows(2 * mib, 64) == 2
+    assert slot_stream.burst_rows(4 * mib, 128) == 1
+    assert slot_stream.burst_rows(3 * mib, 64) == 2
+    assert slot_stream.burst_rows(mib, 64) == 4
+    assert slot_stream.burst_rows(mib // 4, 5) == 5
+    assert slot_stream.burst_rows(mib, 0) == 1

@@ -495,8 +495,9 @@ def test_granite_step_updates_the_state_in_place(v5e):
     scatter); the temporaries of a step stay under 0.2 GB beside 1.2 GB
     of state (4.8 GB at the cell's four periods); the kernels are the ones
     counted (paged attention for the decode rows and for the lane; ONE
-    state-space call a mamba layer for both: the period's runs of 5 and 4
-    mamba layers compile as two loop bodies); and no matmul copies its
+    state-space call a mamba layer for both, and no XLA operation a layer
+    beside it under ``ssm.scan``: the period's runs of 5 and 4 mamba
+    layers compile as two loop bodies); and no matmul copies its
     layer of the stacked weights first, nor the stack (a fused [2048,
     8512] input projection did: every step copied all 36 layers of it,
     1.25 GB, into the products' layout)."""
@@ -522,6 +523,20 @@ def test_granite_step_updates_the_state_in_place(v5e):
         params, cache, arg((GRANITE_SLOTS,)), arg((layout.size,))).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2 + 2
+    # ONE state-space call a mamba layer (one in each of the two loop
+    # bodies) on the step's rows as the layer computes them: inside the
+    # layers' loop XLA runs NOTHING under ``ssm.scan`` but the kernel (no
+    # gather or reordering of a burst's rows, no operand laid out again),
+    # and the plan's sort is the step's, outside the loop, not a layer's
+    scan = [line for line in text.splitlines() if "ssm.scan" in line]
+    a_layer = [line.strip()[:160] for line in scan
+               if "/while/body/" in line and not re.search(
+                   r" (custom-call|get-tuple-element|bitcast)\(", line)]
+    assert not a_layer, a_layer
+    assert sum(" custom-call(" in line for line in scan) == 2
+    assert sum(" sort(" in line for line in scan) == 1
+    assert not any(" sort(" in line and "/while/body/" in line
+                   for line in scan)
     state = cache["ssm"]
     assert state.shape == (9, GRANITE_SLOTS, 32, 128, 128)
     assert state.dtype == jnp.float32
