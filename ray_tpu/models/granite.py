@@ -45,7 +45,9 @@ whole periods of ``layer_types``, inside it one loop a run of mamba
 layers: 40 layers compile as two mamba bodies and one attention body),
 indexing a layer of the stacked weights where it lies. ``one_program``,
 as the two sparse families: no router amplifies an ulp here, but hundreds
-of greedy tokens do (the record below says what the chip showed).
+of greedy tokens do (the record below says what the chip showed). A
+layer's window in ``cache["conv"]`` is ``[taps - 1, slots, channels]``,
+tap-major, as ``solar.carried_conv`` takes it and hands it back.
 
 Scope names (``jax.named_scope``; metadata only): ``ssm.proj`` (the
 input projection), ``ssm.conv`` (the convolution, and what turns its
@@ -330,16 +332,14 @@ def mamba(u, ssm_state, conv_state, p, cfg: GraniteConfig, layer, b: int,
                                  preferred_element_type=f32),
                       u.dtype).astype(f32)
     with jax.named_scope("ssm.conv"):
-        # the layers' windows lie [layer, tap, slot, channel] (a slot
-        # along sublanes: 3 taps there would be padded to 8)
-        window = jnp.swapaxes(jax.lax.dynamic_index_in_dim(
-            conv_state, layer, 0, keepdims=False), 0, 1)
-        conv, window = carried_conv(xbc, window, p["conv_k"].astype(f32), b,
-                                    valid, chunk_at,
-                                    bias=p["conv_b"].astype(f32))
-        conv_state = jax.lax.dynamic_update_index_in_dim(
-            conv_state, jnp.swapaxes(window, 0, 1).astype(conv_state.dtype),
-            layer, 0)
+        # a layer's window as it lies in the cache: [tap, slot, channel]
+        conv, window = carried_conv(
+            xbc, jax.lax.dynamic_index_in_dim(conv_state, layer, 0,
+                                              keepdims=False),
+            p["conv_k"].astype(f32), b, valid, chunk_at,
+            bias=p["conv_b"].astype(f32))
+        conv_state = jax.lax.dynamic_update_index_in_dim(conv_state, window,
+                                                         layer, 0)
         xbc = jax.nn.silu(conv)
         x = xbc[:, :di].reshape(r, h, cfg.ssm_head_dim)
         bc = xbc[:, di:].reshape(r, 2, n)

@@ -45,8 +45,9 @@ What it offers the engine (``models/serving.py``): one step over a cache
 of three kinds side by side — ``cache["kv"]``, the page pool of the GQA
 layers only; ``cache["kda"]``, ONE ``[kda layers, slots, heads, d_k,
 d_v]`` float32 array that ``ops/delta_rule.py`` updates in place; and
-``cache["conv"]``, one ``[slots, taps - 1, 3 x heads x d_k]`` array a KDA
-layer — and a :class:`serving.SlotState` for the last two. The step is
+``cache["conv"]``, one ``[taps - 1, slots, 3 x heads x d_k]`` array a KDA
+layer (tap-major: :func:`carried_conv` says why) — and a
+:class:`serving.SlotState` for the last two. The step is
 ``models/lfm2.py``'s shape: B decode rows and one prompt chunk through
 the same products, layers unrolled, ``one_program`` (a router amplifies an
 ulp), parked rows and a chunk's tail write no page and no state, an empty
@@ -264,14 +265,14 @@ def attach_slot_state(cfg: SolarConfig, cache, num_slots: int):
     return dict(
         cache,
         kda=jnp.zeros((n, num_slots, cfg.kda_heads, hd, hd), jnp.float32),
-        conv=[jnp.zeros((num_slots, cfg.conv_kernel - 1, 3 * cfg.kda_width),
+        conv=[jnp.zeros((cfg.conv_kernel - 1, num_slots, 3 * cfg.kda_width),
                         cfg.dtype) for _ in range(n)])
 
 
 def reset_slot_state(cache, slots):
     """Those slots' state zeroed (jit with the cache donated)."""
     return dict(cache, kda=cache["kda"].at[:, slots].set(0),
-                conv=[c.at[slots].set(0) for c in cache["conv"]])
+                conv=[c.at[:, slots].set(0) for c in cache["conv"]])
 
 
 def cache_axes(cfg: SolarConfig) -> Dict:
@@ -297,30 +298,48 @@ def carried_conv(z, state, k, b: int, valid, chunk_at, bias=None):
     is added to every row's result (``models/granite.py``).
 
     z [N, ch]: rows ``[:b]`` one token of slot i each, rows ``[b:]`` (if
-    any) one slot's prompt chunk in order. state [slots, L - 1, ch]: each
-    slot's last L - 1 inputs. k [L, ch] float32. valid [b] bool; chunk_at
-    None or (slot, n_valid). Returns (conv [N, ch] float32, new state): a
+    any) one slot's prompt chunk in order. state [L - 1, slots, ch]: each
+    slot's last L - 1 inputs, TAP-MAJOR, so that a tap is a whole [slots,
+    channels] tile (slots along sublanes, channels along lanes) and the
+    decode rows' result is L multiply-adds of such tiles: a tap beside
+    the slot would be L - 1 = 3 rows of a sublane tile of 8, and a
+    contraction over it a product whose result lies channels x slots.
+    k [L, ch] float32. valid [b] bool; chunk_at None or (slot, n_valid).
+    Returns (conv [N, ch] float32, new state in the state's dtype): a
     parked row's state, an empty chunk's and every slot's not in the step
     are left as they were."""
-    taps = k.shape[0]
-    window = jnp.concatenate([state, z[:b, None]], axis=1)   # [b, L, ch]
-    conv = jnp.einsum("bjd,jd->bd", window.astype(jnp.float32), k)
-    new_state = jnp.where(valid[:, None, None], window[:, 1:], state)
+    taps, f32 = k.shape[0], jnp.float32
+    # (z is converted where it is used, a piece at a time: converted
+    # whole, the conversion moves into the product that made z, which
+    # then writes float32, twice the bytes, and need not round)
+    zb = z[:b]
+    # a decode row's sum in the order of a chunk token's below
+    conv = sum(k[j] * state[j].astype(f32) for j in range(taps - 1)) \
+        + k[taps - 1] * zb.astype(f32)
+    new_state = jnp.where(
+        valid[None, :, None],
+        jnp.concatenate([state[1:], zb[None].astype(state.dtype)], axis=0),
+        state)
     if chunk_at is not None:
         slot, n_valid = chunk_at
-        zc = z[b:]
-        c = zc.shape[0]
-        before = jax.lax.dynamic_index_in_dim(state, slot, 0, keepdims=False)
-        zz = jnp.concatenate([before, zc], axis=0)           # [L - 1 + C, ch]
-        conv_c = sum(k[j] * zz[j:j + c].astype(jnp.float32)
-                     for j in range(taps))
+        c = z.shape[0] - b
+        # the slot's window a tap (one [1, ch] row) at a time: cut whole,
+        # a [L - 1, ch] piece of the layers' cache, it makes the compiler
+        # lay the cache out tap beside channel, and every layer's window
+        # again on the way in and out
+        before = [jax.lax.dynamic_slice(state, (j, slot, 0),
+                                        (1, 1, z.shape[1]))[0].astype(f32)
+                  for j in range(taps - 1)]
+        zz = jnp.concatenate(before + [z[b:].astype(f32)], axis=0)
+        conv_c = sum(k[j] * zz[j:j + c] for j in range(taps))
         conv = jnp.concatenate([conv, conv_c], axis=0)
-        # rows n_valid .. n_valid + L - 2 of zz are the last L - 1 inputs
+        # rows n_valid .. n_valid + L - 2 of zz [L - 1 + C, ch] are the
+        # last L - 1 inputs; they go in by the select that writes the
+        # decode rows' windows
         after = jax.lax.dynamic_slice_in_dim(zz, n_valid, taps - 1, 0)
-        kept = jax.lax.dynamic_index_in_dim(new_state, slot, 0,
-                                            keepdims=False)
-        new_state = jax.lax.dynamic_update_slice_in_dim(
-            new_state, jnp.where(n_valid > 0, after, kept)[None], slot, 0)
+        here = (jnp.arange(state.shape[1]) == slot) & (n_valid > 0)
+        new_state = jnp.where(here[None, :, None],
+                              after[:, None].astype(state.dtype), new_state)
     if bias is not None:
         conv = conv + bias
     return conv, new_state

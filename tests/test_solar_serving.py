@@ -279,7 +279,7 @@ def test_reused_slot_gives_the_tokens_a_fresh_engine_gives(params):
     # the parked slot's state was never written, in either engine
     for eng in (used, fresh):
         assert not np.asarray(eng._cache["kda"][:, 1]).any()
-        assert all(not np.asarray(c[1]).any() for c in eng._cache["conv"])
+        assert all(not np.asarray(c[:, 1]).any() for c in eng._cache["conv"])
     assert np.asarray(used._cache["kda"][:, 0]).any()
 
 

@@ -450,6 +450,10 @@ def test_solar_step_updates_the_matrix_state_in_place(v5e):
                                    line)]
                 if m and "128" in m.group(1).split(",")[-2:]]
     assert not laid_out, laid_out
+    # the convolutions a tap at a time on [slots, channels] tiles: no
+    # result channels x slots, none with a tap beside the channels
+    assert not _shaped(text.splitlines(), "kda.conv/", (
+        "f32[24576,128]", "f32[128,3,24576]", "bf16[128,3,24576]"))
     state = cache["kda"]
     assert state.shape == (3, SOLAR_SLOTS, 64, 128, 128)
     assert state.dtype == jnp.float32
@@ -537,6 +541,31 @@ def test_granite_step_updates_the_state_in_place(v5e):
     assert sum(" sort(" in line for line in scan) == 1
     assert not any(" sort(" in line and "/while/body/" in line
                    for line in scan)
+    # the carried convolution a tap at a time on [slots, channels] tiles:
+    # in a layer, under ``ssm.conv``, no array of the convolution's 4352
+    # channels is copied into another layout, no result lies channels x
+    # slots (the contraction over the taps as a dot did) and none has a
+    # tap beside the channels (3 rows of a sublane tile). (The copies
+    # that stay lay a head's dt and decay out over the kernel's [32, 128]
+    # blocks: PERF.md Findings PR 45.) The layers' windows stay in the
+    # layout they arrive and leave in (the chunk's slot cut out as ONE
+    # [taps - 1, channels] piece made the compiler lay the whole cache out
+    # tap beside channel: two copies of all of it a step) and are written
+    # by a dynamic-update-slice in place, nothing else
+    conv = [line for line in _unfused_lines(text)
+            if "ssm.conv/" in line and "/while/body/" in line]
+    assert len(conv) > 20
+    copied = [line.strip()[:160] for line in conv
+              if re.match(r"\s*\S+ = \w+\[[\d,]*4352\]\S* copy\(", line)]
+    assert not copied, copied
+    assert not _shaped(text.splitlines(), "ssm.conv/", (
+        "f32[4352,64]", "f32[64,3,4352]", "bf16[64,3,4352]"))
+    windows = _shaped(_unfused_lines(text), "", ("bf16[9,3,64,4352]",))
+    assert windows and all(
+        "{3,2,1,0:" in line
+        and re.search(r"ssm\.conv/dynamic_update_slice\"", line)
+        and re.search(r" (fusion|dynamic-update-slice)\(", line)
+        for line in windows), [line[:200] for line in windows]
     state = cache["ssm"]
     assert state.shape == (9, GRANITE_SLOTS, 32, 128, 128)
     assert state.dtype == jnp.float32
@@ -580,6 +609,17 @@ def test_granite_step_updates_the_state_in_place(v5e):
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < 0.2e9
     assert state.dtype.itemsize * math.prod(state.shape) > 1.2e9
+
+
+def _shaped(lines, scope, shapes):
+    """The instructions among a compiled program's ``lines`` under
+    ``scope`` that make a result of one of ``shapes``, given as
+    ``dtype[dims]``: not parameters, tuple elements or bitcasts."""
+    return [line.strip() for line in lines
+            for m in [re.match(r"\s*(?:ROOT )?\S+ = \(?(\w+\[[\d,]*\])", line)]
+            if m and m.group(1) in shapes and scope in line
+            and not re.search(r" (parameter|get-tuple-element|bitcast|"
+                              r"tuple)\(", line)]
 
 
 def _unfused_lines(text):
