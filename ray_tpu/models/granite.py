@@ -36,10 +36,8 @@ attention layers only; ``cache["ssm"]``, ONE ``[mamba layers, slots, G,
 N, W]`` float32 array (``ops/ssm_scan.py`` says why the channels lie in G
 blocks of W along lanes, N along sublanes) that the kernel updates in
 place; ``cache["conv"]``, ``[mamba layers, taps - 1, slots, d_inner + 2
-N]`` — and a :class:`serving.SlotState` for the last two. The step is
-``models/solar.py``'s shape (B decode rows and one prompt chunk through
-the same products; parked rows and a chunk's tail write no page and no
-state, an empty chunk reads and writes nothing) with two differences: the
+N]`` — and a :class:`serving.SlotState` for the last two. The step's
+rows and pages are ``models/step.py``'s; what is this family's alone: the
 layers are STACKED BY KIND and the step loops over them (a loop over the
 whole periods of ``layer_types``, inside it one loop a run of mamba
 layers: 40 layers compile as two mamba bodies and one attention body),
@@ -47,7 +45,7 @@ indexing a layer of the stacked weights where it lies. ``one_program``,
 as the two sparse families: no router amplifies an ulp here, but hundreds
 of greedy tokens do (the record below says what the chip showed). A
 layer's window in ``cache["conv"]`` is ``[taps - 1, slots, channels]``,
-tap-major, as ``solar.carried_conv`` takes it and hands it back.
+tap-major, as ``step.carried_conv`` takes it and hands it back.
 
 Scope names (``jax.named_scope``; metadata only): ``ssm.proj`` (the
 input projection), ``ssm.conv`` (the convolution, and what turns its
@@ -66,13 +64,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import paged_attention as paged_attention_op
 from ..ops.slot_stream import step_plan
 from ..ops.ssm_scan import blocks_of, ssm_scan
-from . import lfm2, llama, serving
-from .common import rms_norm
-from .llama import PAGED_KV_AXES, _write_and_attend
-from .solar import _draw, carried_conv
+from . import serving, step
+from .common import bulk_key, draw, rms_norm
 
 ATTENTION, MAMBA = "attention", "mamba"
 PERIOD = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
@@ -213,8 +208,8 @@ def param_axes(cfg: GraniteConfig = None) -> Dict:
 def _init_stack(key, cfg: GraniteConfig, kind: str):
     n = cfg.layer_types.count(kind)
     shapes = _layer_shapes(cfg, kind)
-    keys = jax.random.split(lfm2._bulk_key(key), len(shapes))
-    return {name: _draw(k, (n,) + shape, init,
+    keys = jax.random.split(bulk_key(key), len(shapes))
+    return {name: draw(k, (n,) + shape, init,
                         jnp.float32 if name in _FLOAT32 else cfg.dtype)
             for k, (name, (shape, init)) in zip(keys, shapes.items())}
 
@@ -226,7 +221,7 @@ def _init_table(key, cfg: GraniteConfig):
     ``multiplier x |E[t]|^2`` for the token just fed, several deviations
     above every other logit, and a greedy request would repeat its last
     token whatever the layers compute."""
-    return _draw(lfm2._bulk_key(key), (cfg.vocab_size, cfg.d_model),
+    return draw(bulk_key(key), (cfg.vocab_size, cfg.d_model),
                  0.02 / cfg.embedding_multiplier, cfg.dtype)
 
 
@@ -245,12 +240,8 @@ def init_params(key, cfg: GraniteConfig) -> Tuple[Dict, Dict]:
 # -- the cache: pages for the attention layers, state a slot for the rest ----
 
 def init_cache(cfg: GraniteConfig, num_pages: int, page_size: int):
-    if cfg.max_seq % page_size != 0:
-        raise ValueError(
-            f"page_size ({page_size}) must divide max_seq ({cfg.max_seq})")
-    shape = (cfg.layer_types.count(ATTENTION), 2, num_pages, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
-    return {"kv": jnp.zeros(shape, cfg.dtype)}
+    return step.init_pool(cfg.layer_types.count(ATTENTION), cfg, num_pages,
+                          page_size)
 
 
 def attach_slot_state(cfg: GraniteConfig, cache, num_slots: int):
@@ -273,7 +264,7 @@ def reset_slot_state(cache, slots):
                 conv=cache["conv"].at[:, :, slots].set(0))
 
 
-CACHE_AXES = {"kv": PAGED_KV_AXES, "ssm": (None,) * 5, "conv": (None,) * 4}
+CACHE_AXES = {"kv": step.PAGED_KV_AXES, "ssm": (None,) * 5, "conv": (None,) * 4}
 
 
 def check_shardable(cfg: GraniteConfig, tp: int) -> None:
@@ -314,13 +305,13 @@ def _gated_norm(y, z, scale, eps):
     return rms_norm(y * jax.nn.silu(z), scale, eps)
 
 
-def mamba(u, ssm_state, conv_state, p, cfg: GraniteConfig, layer, b: int,
-          valid, chunk_at, plan, live):
+def mamba(u, ssm_state, conv_state, p, cfg: GraniteConfig, layer, rows,
+          plan):
     """One mamba layer's mixer on a step's rows u [R, d] -> (out [R, d],
     the layers' states, the layers' conv states). ``layer`` indexes both
-    states' leading axis; ``plan`` is the step's ``step_plan(valid,
-    chunk_at)`` and ``live [R]`` says which rows are in the step, the same
-    for every layer."""
+    states' leading axis; ``rows`` is the step's ``step.StepRows`` and
+    ``plan`` its ``step_plan(rows.valid, rows.chunk_at)``, the same for
+    every layer."""
     f32, r = jnp.float32, u.shape[0]
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     with jax.named_scope("ssm.proj"):
@@ -333,10 +324,10 @@ def mamba(u, ssm_state, conv_state, p, cfg: GraniteConfig, layer, b: int,
                       u.dtype).astype(f32)
     with jax.named_scope("ssm.conv"):
         # a layer's window as it lies in the cache: [tap, slot, channel]
-        conv, window = carried_conv(
+        conv, window = step.carried_conv(
             xbc, jax.lax.dynamic_index_in_dim(conv_state, layer, 0,
                                               keepdims=False),
-            p["conv_k"].astype(f32), b, valid, chunk_at,
+            p["conv_k"].astype(f32), rows.b, rows.valid, rows.chunk_at,
             bias=p["conv_b"].astype(f32))
         conv_state = jax.lax.dynamic_update_index_in_dim(conv_state, window,
                                                          layer, 0)
@@ -360,7 +351,7 @@ def mamba(u, ssm_state, conv_state, p, cfg: GraniteConfig, layer, b: int,
         # into their pages by a 0/1 product over ALL the chunk's tokens,
         # and 0 x NaN of a tail token is NaN in every live one (on the
         # chip, at 64 slots, every request but a few read NaN logits).
-        y = jnp.where(live[:, None, None], y, 0.0)
+        y = jnp.where(rows.live[:, None, None], y, 0.0)
         y = y.reshape(x.shape) + p["d_skip"][:, None] * x
         y = _gated_norm(y.reshape(r, di), z, p["y_norm"], cfg.norm_eps)
         out = _product(_rounded(y, u.dtype), p["w_out"])
@@ -396,25 +387,11 @@ def _layer_of(stack, i):
 def paged_step(params, cache, tables, tokens, pos, chunk, cfg: GraniteConfig,
                page_size: int, rules=None):
     """One continuous-batching step: the contract of
-    ``models/serving.py``'s ``step``, with a fourth result: the counts
-    :data:`STEP_COUNTERS` names.
-
-    The rows of a step, all through the same weight products: the B
-    decode rows, then the chunk's C tokens if there is a chunk. A chunk
-    with ``pre_n_valid`` 0 is empty: it writes no page and no state, and
-    its logits mean nothing."""
-    b, s_max = tokens.shape[0], cfg.max_seq
-    h, hd = cfg.num_heads, cfg.head_dim
+    ``models/serving.py``'s ``step`` over the rows of
+    ``step.step_rows``, with a fourth result: the counts
+    :data:`STEP_COUNTERS` names."""
+    rows = step.step_rows(tables, tokens, pos, chunk, cfg.max_seq)
     f32 = jnp.float32
-    valid = pos < s_max
-    packed, live, chunk_at, c = [tokens], valid, None, 0
-    if chunk is not None:
-        pre_tokens, pre_slot, pre_p0, pre_n_valid = chunk
-        c = pre_tokens.shape[0]
-        n_valid = jnp.clip(jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c)
-        packed.append(pre_tokens)
-        live = jnp.concatenate([valid, jnp.arange(c) < n_valid])
-        chunk_at = (pre_slot, n_valid)
     # The residual stream is float32, as ``models/solar.py``'s is and for
     # its reason: a step's rows are few, and a bfloat16 stream rounds
     # every layer's sum by 2^-9 of the STREAM. Every product takes its
@@ -422,43 +399,27 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: GraniteConfig,
     # float32 and hands on float32: each rounding is written out
     # (``_rounded``), none is left to where the compiler fuses.
     with jax.named_scope("embed"):
-        x = params["wte"][jnp.concatenate(packed)].astype(f32) \
+        x = params["wte"][rows.packed()].astype(f32) \
             * cfg.embedding_multiplier
-    # the paged kernel's row metadata: once a step
-    rows_d = paged_attention_op.row_meta(
-        tables, pos, jnp.where(valid, pos + 1, 0))
-    if chunk is not None:
-        rows_c = paged_attention_op.row_meta(
-            jax.lax.dynamic_slice(tables, (pre_slot, 0),
-                                  (1, tables.shape[1])),
-            jnp.reshape(pre_p0, (1,)), jnp.reshape(pre_p0 + n_valid, (1,)))
     # The kernel scales scores by head_dim^-1/2; the model's scale is
     # ``attention_multiplier``, so q carries the ratio (2^-3 for the
     # published 1/64 at a head of 64: exact in any float dtype).
-    q_scale = cfg.attention_multiplier * math.sqrt(hd)
+    q_scale = cfg.attention_multiplier * math.sqrt(cfg.head_dim)
 
     def attention(u, kv, p, layer):
-        """u [R, d] -> (out [R, d], pool): decode rows, then the chunk;
-        each writes its own tokens before it attends. No positional term:
-        the causal order of the pages is all the order there is."""
-        q = _rounded(_product(u, p["wq"]) * q_scale, u.dtype)
-        k_new = _rounded(_product(u, p["wk"]), u.dtype)
-        v_new = _rounded(_product(u, p["wv"]), u.dtype)
-        o, kv = _write_and_attend(q[:b].reshape(b, 1, h, hd), k_new[:b, None],
-                                  v_new[:b, None], kv, layer, rows_d, cfg,
-                                  page_size, rules)
-        outs = [o[:, 0]]
-        if chunk is not None:
-            oc, kv = _write_and_attend(q[b:].reshape(1, c, h, hd),
-                                       k_new[None, b:], v_new[None, b:], kv,
-                                       layer, rows_c, cfg, page_size, rules)
-            outs.append(oc[0])
-        return _product(jnp.concatenate(outs).astype(u.dtype), p["wo"]), kv
+        """u [R, d] -> (out [R, d], pool). No positional term: the causal
+        order of the pages is all the order there is."""
+        parts = rows.parts(
+            _rounded(_product(u, p["wq"]) * q_scale, u.dtype),
+            _rounded(_product(u, p["wk"]), u.dtype),
+            _rounded(_product(u, p["wv"]), u.dtype), cfg.num_heads)
+        o, kv = step.attend(rows, parts, kv, layer, cfg, page_size, rules)
+        return _product(o.astype(u.dtype), p["wo"]), kv
 
     # which rows' states the step reads and writes: once a step, for
     # every mamba layer
     with jax.named_scope("ssm.scan"):
-        plan = step_plan(valid, chunk_at)
+        plan = step_plan(rows.valid, rows.chunk_at)
 
     def layer_step(carry, kind, i):
         """Layer i of ``kind`` (counted among the kind's layers)."""
@@ -466,8 +427,7 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: GraniteConfig,
         p = _layer_of(params[kind], i)
         u = _rounded(rms_norm(x, p["op_norm"], cfg.norm_eps), cfg.dtype)
         if kind == MAMBA:
-            out, ssm, conv = mamba(u, ssm, conv, p, cfg, i, b, valid,
-                                   chunk_at, plan, live)
+            out, ssm, conv = mamba(u, ssm, conv, p, cfg, i, rows, plan)
         else:
             with jax.named_scope("attn"):
                 out, kv = attention(u, kv, p, i)
@@ -500,17 +460,10 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: GraniteConfig,
                 lambda carry, j: (period_step(carry, j), None), carry,
                 jnp.arange(periods, dtype=jnp.int32))
     x, kv, ssm, conv = carry
-    ssm_rows = valid.sum().astype(jnp.int32)
-    if chunk is not None:
-        ssm_rows = ssm_rows + (n_valid > 0)
-    counts = jnp.reshape(ssm_rows * cfg.layer_types.count(MAMBA), (1,))
-    cache = {"kv": kv, "ssm": ssm, "conv": conv}
-    if chunk is None:
-        return _lm_head(x[:b], params, cfg), None, cache, counts
-    last = jnp.maximum(pre_n_valid, 1) - 1
-    logits = _lm_head(jnp.concatenate([x[:b], x[b + last][None]], axis=0),
-                      params, cfg)
-    return logits[:b], logits[b], cache, counts
+    counts = jnp.reshape(
+        rows.state_rows() * cfg.layer_types.count(MAMBA), (1,))
+    head = partial(_lm_head, params=params, cfg=cfg)
+    return *step.logits_of(rows, x, head), {"kv": kv, "ssm": ssm, "conv": conv}, counts
 
 
 # ``param_axes()`` and ``CACHE_AXES`` are read only under a mesh, which
@@ -519,9 +472,7 @@ serving.register(serving.ServingModel(
     config_type=GraniteConfig, configs=CONFIGS, init_params=init_params,
     param_axes=param_axes, check_shardable=check_shardable,
     init_cache=init_cache, cache_axes=CACHE_AXES, step=paged_step,
-    copy_pages=lfm2.copy_pages,
-    write_pages=lfm2.write_pages,  # the pool's; the slots' state is no page
-    read_pages=llama.read_pages, check_frames=llama.check_frames,
+    **step.PAGE_FUNCTIONS,
     slot_state=serving.SlotState(attach=attach_slot_state,
                                  reset=reset_slot_state),
     step_counters=STEP_COUNTERS,

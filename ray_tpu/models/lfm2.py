@@ -27,7 +27,7 @@ The layer, ``u`` the normed input (RMSNorm with the config's ``norm_eps``):
 What it offers the engine (``models/serving.py``): one step over a cache
 of two kinds side by side — ``cache["kv"]``, the page pool of the
 ATTENTION layers only (its leading axis counts attention layers, not
-layers; layout and kernel are ``models/llama.py``'s and
+layers; layout and kernel are ``models/step.py``'s and
 ``ops/paged_attention.py``'s), and ``cache["conv"]``, one ``[slots, L - 1,
 d]`` array a conv layer — and a :class:`serving.SlotState` for the second.
 The layers are unrolled, not scanned: a period holds two kinds of
@@ -65,13 +65,9 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import paged_attention as paged_attention_op
-from . import llama, serving
-from .common import rms_norm
-# the attention layers are the Llama family's paged attention: same pool
-# layout, same kernel, same rotary step on the projections' flat lanes
-from .llama import (PAGED_KV_AXES, _write_and_attend, rope_lane_tables,
-                    rope_lanes)
+from . import serving, step
+from .common import (bulk_key, draw, rms_norm, rope_lane_tables,
+                     rope_lanes)
 # the expert layer is the one every sparse serving family calls; this
 # family holds all of its experts (``held=None``)
 from .moe import EXPERT_COUNTERS, experts_ffn, route  # noqa: F401
@@ -183,39 +179,17 @@ def param_axes(cfg: Lfm2Config = None) -> Dict:
                        for op, ffn in _layer_kinds(cfg)]}
 
 
-def _draw(key, shape, init, dtype):
-    if init == "ones":
-        return jnp.ones(shape, dtype)
-    # "bias": not zero, so selection by s + b differs from selection by
-    # s ("the bias picks, the score weighs"), and small beside the scores'
-    # own spread, as a bias trained to even the load out is: at 0.1 a few
-    # experts took most rows and a quarter of them none (PERF.md, PR 31)
-    std = 0.01 if init == "bias" else init
-    return (std * jax.random.truncated_normal(
-        key, -2.0, 2.0, shape, jnp.float32)).astype(dtype)
-
-
-def _bulk_key(key):
-    """The caller's (threefry) key as a key of the ``rbg`` generator: the
-    device's own random-bit instruction instead of a few hundred integer
-    operations a word, for the 0.6 G draws of an expert layer."""
-    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
-        key = jax.random.key_data(key)
-    return jax.random.wrap_key_data(
-        jnp.concatenate([key, key]).astype(jnp.uint32), impl="rbg")
-
-
 @partial(jax.jit, static_argnums=(1, 2, 3))
 def _init_layer(key, cfg: Lfm2Config, op: str, ffn: str):
     shapes = _layer_shapes(cfg, op, ffn)
-    keys = jax.random.split(_bulk_key(key), len(shapes))
-    return {name: _draw(k, shape, init, cfg.dtype)
+    keys = jax.random.split(bulk_key(key), len(shapes))
+    return {name: draw(k, shape, init, cfg.dtype)
             for k, (name, (shape, _, init)) in zip(keys, shapes.items())}
 
 
 @partial(jax.jit, static_argnums=(1,))
 def _init_embedding(key, cfg: Lfm2Config):
-    return _draw(_bulk_key(key), (cfg.vocab_size, cfg.d_model), 0.02,
+    return draw(bulk_key(key), (cfg.vocab_size, cfg.d_model), 0.02,
                  cfg.dtype)
 
 
@@ -237,12 +211,8 @@ def init_params(key, cfg: Lfm2Config) -> Tuple[Dict, Dict]:
 # -- the cache: pages for the attention layers, state a slot for the conv ----
 
 def init_cache(cfg: Lfm2Config, num_pages: int, page_size: int):
-    if cfg.max_seq % page_size != 0:
-        raise ValueError(
-            f"page_size ({page_size}) must divide max_seq ({cfg.max_seq})")
-    shape = (cfg.layer_types.count(ATTN), 2, num_pages, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
-    return {"kv": jnp.zeros(shape, cfg.dtype)}
+    return step.init_pool(cfg.layer_types.count(ATTN), cfg, num_pages,
+                          page_size)
 
 
 def attach_slot_state(cfg: Lfm2Config, cache, num_slots: int):
@@ -260,17 +230,8 @@ def reset_slot_state(cache, slots):
 
 
 def cache_axes(cfg: Lfm2Config) -> Dict:
-    return {"kv": PAGED_KV_AXES,
+    return {"kv": step.PAGED_KV_AXES,
             "conv": [(None, None, None)] * cfg.layer_types.count(CONV)}
-
-
-def copy_pages(cache, src, dst):
-    """``llama.copy_pages`` on the pool; the slots' state is no page."""
-    return dict(cache, **llama.copy_pages(cache, src, dst))
-
-
-def write_pages(cache, dst, values):
-    return dict(cache, **llama.write_pages(cache, dst, values))
 
 
 def check_shardable(cfg: Lfm2Config, tp: int) -> None:
@@ -347,64 +308,38 @@ def _lm_head(x, params, cfg: Lfm2Config):
 def paged_step(params, cache, tables, tokens, pos, chunk, cfg: Lfm2Config,
                page_size: int, rules=None):
     """One continuous-batching step: the contract of
-    ``models/serving.py``'s ``step`` (and of ``llama.paged_step``, whose
-    page tables, row metadata and kernel the attention layers share),
-    with a fourth result: the expert layers' counts, summed over the
-    layers, as :data:`STEP_COUNTERS` names them.
-
-    The rows of a step, all through the same weight products: the B
-    decode rows, then the chunk's C tokens if there is a chunk. A chunk
-    with ``pre_n_valid`` 0 is empty: it writes no page and no state, and
-    its logits mean nothing."""
-    b, s_max = tokens.shape[0], cfg.max_seq
+    ``models/serving.py``'s ``step`` over the rows of
+    ``step.step_rows``, with a fourth result: the expert layers' counts,
+    summed over the layers, as :data:`STEP_COUNTERS` names them."""
+    rows = step.step_rows(tables, tokens, pos, chunk, cfg.max_seq)
     h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
-    valid = pos < s_max
-    packed, valid_rows, chunk_at, c = [tokens], [valid], None, 0
-    if chunk is not None:
-        pre_tokens, pre_slot, pre_p0, pre_n_valid = chunk
-        c = pre_tokens.shape[0]
-        n_valid = jnp.clip(jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c)
-        packed.append(pre_tokens)
-        valid_rows.append(jnp.arange(c) < n_valid)
-        chunk_at = (pre_slot, n_valid)
-    valid_rows = jnp.concatenate(valid_rows)
     with jax.named_scope("embed"):
-        x = params["wte"][jnp.concatenate(packed)].astype(cfg.dtype)
-    # rotary tables and the kernel's row metadata: once a step
-    angles_d = [rope_lane_tables(pos[:, None], n, hd, cfg.rope_theta)
-                for n in (h, hkv)]
-    rows_d = paged_attention_op.row_meta(
-        tables, pos, jnp.where(valid, pos + 1, 0))
+        x = params["wte"][rows.packed()].astype(cfg.dtype)
+    # how the decode rows [B, 1, ..] and the chunk [1, C, ..] are cut out
+    # of a step's rows, and the rotary tables of each: once a step
+    b = rows.b
+    cuts, positions = [lambda a: a[:b, None]], [pos[:, None]]
     if chunk is not None:
-        angles_c = [rope_lane_tables((pre_p0 + jnp.arange(c))[None], n, hd,
-                                     cfg.rope_theta) for n in (h, hkv)]
-        rows_c = paged_attention_op.row_meta(
-            jax.lax.dynamic_slice(tables, (pre_slot, 0),
-                                  (1, tables.shape[1])),
-            jnp.reshape(pre_p0, (1,)), jnp.reshape(pre_p0 + n_valid, (1,)))
+        _, _, pre_p0, _ = chunk
+        cuts.append(lambda a: a[None, b:])
+        positions.append((pre_p0 + jnp.arange(rows.c))[None])
+    angles = [[rope_lane_tables(at, n, hd, cfg.rope_theta) for n in (h, hkv)]
+              for at in positions]
 
     def attention(u, kv, p, layer):
-        """u [N, d] -> (out [N, d], pool): decode rows, then the chunk;
-        each writes its own tokens before it attends."""
+        """u [N, d] -> (out [N, d], pool)."""
         q = _head_norm(u @ p["wq"].astype(u.dtype), p["q_norm"], h,
                        cfg.norm_eps)
         k_new = _head_norm(u @ p["wk"].astype(u.dtype), p["k_norm"], hkv,
                            cfg.norm_eps)
         v_new = u @ p["wv"].astype(u.dtype)
-        qd, kd = (rope_lanes(a[:b, None], t)
-                  for a, t in zip((q, k_new), angles_d))
-        o, kv = _write_and_attend(qd.reshape(b, 1, h, hd), kd,
-                                  v_new[:b, None], kv, layer, rows_d, cfg,
-                                  page_size, rules)
-        outs = [o[:, 0]]
-        if chunk is not None:
-            qc, kc = (rope_lanes(a[None, b:b + c], t)
-                      for a, t in zip((q, k_new), angles_c))
-            oc, kv = _write_and_attend(qc.reshape(1, c, h, hd), kc,
-                                       v_new[None, b:b + c], kv, layer,
-                                       rows_c, cfg, page_size, rules)
-            outs.append(oc[0])
-        return jnp.concatenate(outs) @ p["wo"].astype(u.dtype), kv
+        parts = []
+        for cut, (at_q, at_k) in zip(cuts, angles):
+            qp = rope_lanes(cut(q), at_q)
+            parts.append((qp.reshape(qp.shape[:2] + (h, hd)),
+                          rope_lanes(cut(k_new), at_k), cut(v_new)))
+        o, kv = step.attend(rows, parts, kv, layer, cfg, page_size, rules)
+        return o @ p["wo"].astype(u.dtype), kv
 
     kv, conv = cache["kv"], list(cache["conv"])
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
@@ -414,7 +349,7 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: Lfm2Config,
             with jax.named_scope("conv"):
                 out, conv[n_conv] = short_conv(
                     rms_norm(x, p["op_norm"], cfg.norm_eps), conv[n_conv],
-                    p, cfg, b, valid, chunk_at)
+                    p, cfg, rows.b, rows.valid, rows.chunk_at)
             n_conv += 1
         else:
             with jax.named_scope("attn"):
@@ -433,16 +368,11 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: Lfm2Config,
             experts, weights = route(u, p, cfg)
         with jax.named_scope("moe.experts"):
             out, layer_counts = experts_ffn(u.astype(x.dtype), experts,
-                                            weights, valid_rows, p, cfg)
+                                            weights, rows.live, p, cfg)
         x = x + out
         counts = counts + layer_counts
-    cache = {"kv": kv, "conv": conv}
-    if chunk is None:
-        return _lm_head(x[:b], params, cfg), None, cache, counts
-    last = jnp.maximum(pre_n_valid, 1) - 1
-    logits = _lm_head(jnp.concatenate([x[:b], x[b + last][None]], axis=0),
-                      params, cfg)
-    return logits[:b], logits[b], cache, counts
+    head = partial(_lm_head, params=params, cfg=cfg)
+    return *step.logits_of(rows, x, head), {"kv": kv, "conv": conv}, counts
 
 
 # ``param_axes()`` and ``cache_axes`` are read only under a mesh, which
@@ -451,8 +381,7 @@ serving.register(serving.ServingModel(
     config_type=Lfm2Config, configs=CONFIGS, init_params=init_params,
     param_axes=param_axes, check_shardable=check_shardable,
     init_cache=init_cache, cache_axes=cache_axes(CONFIGS["lfm2-24b-a2b"]),
-    step=paged_step, copy_pages=copy_pages, write_pages=write_pages,
-    read_pages=llama.read_pages, check_frames=llama.check_frames,
+    step=paged_step, **step.PAGE_FUNCTIONS,
     slot_state=serving.SlotState(attach=attach_slot_state,
                                  reset=reset_slot_state),
     step_counters=STEP_COUNTERS, one_program=True))

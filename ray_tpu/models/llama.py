@@ -14,15 +14,13 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from ..ops import paged_attention as paged_attention_op
 from ..ops.attention import attention as attention_op
 from ..parallel.sharding import (constrain, current_mesh, mesh_axes_for,
                                  spec_for)
-from . import serving
-from .common import cross_entropy_loss, rms_norm, truncated_normal
+from . import serving, step
+from .common import (cross_entropy_loss, rms_norm, rope_lane_tables,
+                     rope_lanes, truncated_normal)
 
 
 @dataclass(frozen=True)
@@ -119,68 +117,6 @@ def rope(x, positions, theta: float):
     out2 = xf2 * cos + xf1 * sin
     out = jnp.stack([out1, out2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
-
-
-def rope_lane_tables(positions, heads: int, d: int, theta: float):
-    """The rotary tables of ``positions`` [B, T] for :func:`rope_lanes`:
-    (cos, signed sin), each [B, T, heads * d] — a token's heads side by
-    side, every head the same d lanes: pair i's angle at lanes 2i and
-    2i + 1 of a head and the sine negative at 2i. They depend on the
-    positions alone: a loop over layers computes them once, outside."""
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[:, :, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(angles), jnp.sin(angles)           # [B, T, d/2]
-    shape = angles.shape[:-1] + (d,)
-    return tuple(
-        jnp.tile(jnp.stack(pair, axis=-1).reshape(shape), (1, 1, heads))
-        for pair in ((cos, cos), (-sin, sin)))
-
-
-def _swap_pairs(x):
-    """Lanes 2i and 2i + 1 of the last axis exchanged: each lane takes
-    its left or its right neighbour, by its parity (two shifts and a
-    select, no lane leaves its pair, so the zeros shifted in are never
-    taken)."""
-    edge = [(0, 0, 0)] * (x.ndim - 1)
-    zero = jnp.zeros((), x.dtype)
-    return jnp.where(jnp.arange(x.shape[-1]) % 2 == 0,
-                     jax.lax.pad(x, zero, edge + [(-1, 1, 0)]),
-                     jax.lax.pad(x, zero, edge + [(1, -1, 0)]))
-
-
-def rope_lanes(x, tables, mesh=None, lanes_axis=None):
-    """:func:`rope` of a projection's output AS THE MATMUL LEAVES IT, x
-    [B, T, heads * D] with a token's heads side by side: ``x * cos +
-    swap(x) * signed_sin``, where swap exchanges the two lanes of every
-    pair — the same products and the same sum, bit for bit, as rope's
-    ``x1 * cos - x2 * sin`` and ``x2 * cos + x1 * sin``.
-
-    Why on the flat lanes and not on [.., heads, D]: a reshape to heads
-    between the projection and the rotary step is folded by the TPU
-    compiler INTO the projection, which becomes a product batched over
-    the heads and wants its weight as [heads, D, d_in], the transpose of
-    what is stored — so every layer of every step it slices the layer's
-    [d_in, heads * D] weight out of the stacked array and writes it again
-    transposed before the matmul reads it (40 MiB of traffic for an 8 MiB
-    weight; tests/test_tpu_compile.py holds both engine programs to no
-    such copy). Kept flat, the matmul reads the layer where it lies. The
-    strided halves of :func:`rope` and their re-interleaving are gathers
-    and copies on the TPU, a dozen operations a layer; here q and k share
-    one elementwise fusion.
-
-    The shifts cross the lanes axis: where that axis is sharded (whole
-    heads a shard, so no pair is cut) they run per shard in a shard_map,
-    or GSPMD would exchange a halo between chips for lanes never taken.
-    """
-    def rotate(x, cos, sin):
-        return (x.astype(jnp.float32) * cos
-                + _swap_pairs(x).astype(jnp.float32) * sin).astype(x.dtype)
-
-    if mesh is None or lanes_axis is None:
-        return rotate(x, *tables)
-    spec = P(None, None, lanes_axis)
-    return jax.shard_map(rotate, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(x, *tables)
 
 
 def _repeat_kv(x, n_rep: int):
@@ -351,11 +287,11 @@ def generate(params, prompt_tokens, cfg: LlamaConfig, max_new: int = 32,
         raise ValueError("temperature > 0 requires a PRNG key")
     b, s = prompt_tokens.shape
     cache = init_kv_cache(cfg, b)
-    step = jax.jit(partial(decode_step, cfg=cfg))
+    decode = jax.jit(partial(decode_step, cfg=cfg))
     tokens = prompt_tokens
     logits = None
     for i in range(s):
-        logits, cache = step(params, cache, tokens[:, i], jnp.asarray(i))
+        logits, cache = decode(params, cache, tokens[:, i], jnp.asarray(i))
     out = [tokens]
     cur = None
     for j in range(max_new):
@@ -365,48 +301,19 @@ def generate(params, prompt_tokens, cfg: LlamaConfig, max_new: int = 32,
         else:
             cur = jnp.argmax(logits, axis=-1)
         out.append(cur[:, None])
-        logits, cache = step(params, cache, cur, jnp.asarray(s + j))
+        logits, cache = decode(params, cache, cur, jnp.asarray(s + j))
     return jnp.concatenate(out, axis=1)
 
 
 # ---------------------------------------------------------------------------
-# Paged KV cache — what this family offers the serving engine through
-# ``models/serving.py``: fixed-size pages + slot->page-table indirection,
+# What this family offers the serving engine through ``models/serving.py``:
+# one step over the page pool of ``models/step.py`` (its layout, its scratch
+# page, its kernel, its sharding and the rows of a step are that module's),
 # so prompt-prefix pages can be SHARED between slots (radix/prefix cache,
 # refcounted by the engine) and freed pages return to a pool instead of
-# dying with a slot. PagedAttention (vLLM) / RadixAttention (SGLang).
-#
-# Layout: cache["kv"] is ONE fused array [L, 2, num_pages, page_size,
-# Hkv * hd] (index 0 = K, 1 = V): a token's KV heads lie side by side in
-# the minor axis, so a physical page is a contiguous [page_size, Hkv * hd]
-# block whose rows fill whole 128-lane rows (hd = 64 alone is half of one:
-# with [.., Hkv, hd] minor axes the TPU pads hd to 128 or makes the PAGE
-# index the lane axis and scatters a page over the whole pool). A page
-# table row [P] (P = max_seq // page_size) maps a slot's logical page l to
-# a physical page id. Physical page 0 is the RESERVED SCRATCH page: every
-# invalid write (parked slots, chunk tail padding, position overshoot) is
-# routed there explicitly, so garbage can never land in a real — possibly
-# shared — page. Unallocated page-table entries are 0 for the same reason.
-# Positions in unallocated logical pages are always > the slot's current
-# pos, so attention masks them before they are ever read.
-#
-# The pool inside a step is touched only IN PLACE. The loop over the
-# layers carries the whole pool and scans over the layers' weights and a
-# layer index: a layer sliced out of the pool or stacked back as the
-# loop's xs/ys makes XLA copy the pool into the loop's layout and back
-# every step. On the TPU the pool's only reader and writer is
-# ``ops/paged_attention.py``: a Pallas kernel that takes the whole pool,
-# aliased to its output, and the layer index, puts the rows' new K/V into
-# their pages and DMAs only the pages a row has. Off the TPU it is the
-# reference below: a scatter at [layer, :, page, offset] on the carried
-# pool, a gather of every table entry, and the masked einsum.
-#
-# Sharding: ``rules`` is a table logical axis -> mesh axis. Under a tp
-# mesh the serving engine maps the "kv" logical axis to tp, so the pool's
-# Hkv * hd axis — whole heads a shard — and the q/k/v lanes of every
-# intermediate shard across chips while the page/seq axes stay
-# replicated; the kernel and the rotary step's lane shifts run per shard
-# in a shard_map. With no mesh the constraints no-op.
+# dying with a slot. PagedAttention (vLLM) / RadixAttention (SGLang). Under
+# a tp mesh the q/k/v lanes of every intermediate shard like the pool's,
+# and the rotary step's lane shifts run per shard in a shard_map.
 #
 # Scope names: the step carries ``jax.named_scope`` names — metadata on
 # the HLO (``op_name``), no operation added, moved or changed — so a
@@ -421,64 +328,8 @@ def generate(params, prompt_tokens, cfg: LlamaConfig, max_new: int = 32,
 # half of the step. benchmark/trace/program.py reads them.
 # ---------------------------------------------------------------------------
 
-def init_paged_kv_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
-    if cfg.max_seq % page_size != 0:
-        raise ValueError(
-            f"page_size ({page_size}) must divide max_seq ({cfg.max_seq})")
-    shape = (cfg.num_layers, 2, num_pages, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
-    return {"kv": jnp.zeros(shape, cfg.dtype)}
-
-
-# Logical axes of cache["kv"] — the heads-and-head_dim axis shards under
-# the "kv" rule (the serving engine maps it to tp), whole heads a shard.
-PAGED_KV_AXES = (None, None, None, None, "kv")
-
-
-def _write_and_attend(q, kn, vn, kv, layer, rows, cfg: LlamaConfig,
-                      page_size: int, rules):
-    """The rows' new K/V into the carried pool, then attention of q over
-    each row's pages of ``layer`` -> (o [R, T, D], pool).
-
-    q [R, T, H, hd]; kn / vn [R, T, Hkv * hd]; rows: ``row_meta`` of the
-    page tables [R, P], q_start [R] and lengths [R]; token t of row r is
-    position q_start[r] + t, written if it is under lengths[r]
-    (a parked row has length 0, a chunk's tail lies past it) and reading
-    positions <= its own. On the TPU one Pallas kernel does both, in
-    place, reading only the row's live pages. Off the TPU: a scatter with
-    the invalid tokens routed to the scratch page, a gather of every
-    table entry, and the masked einsum."""
-    r, t, h, hd = q.shape
-    if paged_attention_op.use_kernel():
-        kv_spec = spec_for(("kv",), rules)
-        with jax.named_scope("attn"):
-            o, kv = paged_attention_op.paged_attention(
-                q, kn, vn, kv, layer, rows, mesh=current_mesh(),
-                heads_axis=kv_spec[0] if len(kv_spec) else None)
-        return o.reshape(r, t, h * hd), kv
-    tables, q_start, lengths = rows[:, :-2], rows[:, -2], rows[:, -1]
-    pos = q_start[:, None] + jnp.arange(t)[None, :]              # [R, T]
-    with jax.named_scope("kv_write"):
-        phys, off = paged_attention_op.page_slots(
-            tables, jnp.arange(r)[:, None], pos, pos < lengths[:, None],
-            page_size)
-        # Pin the written pool to the kv sharding: the scatter must never
-        # trigger a resharding of the (multi-GB) pool, and the loop's
-        # carry must match the donated input's sharding so donation stays
-        # in place.
-        kv = constrain(paged_attention_op.write_token_kv(
-            kv, layer, kn.reshape(r * t, -1), vn.reshape(r * t, -1),
-            phys.reshape(-1), off.reshape(-1)), PAGED_KV_AXES, rules)
-    with jax.named_scope("kv_gather"):
-        kv_l = jax.lax.dynamic_index_in_dim(kv, layer, 0, keepdims=False)
-        kv_att = constrain(
-            paged_attention_op.gather_pages(kv_l, tables, cfg.num_kv_heads),
-            (None, None, None, "kv", None), rules)
-    with jax.named_scope("attn"):
-        mask = (jnp.arange(cfg.max_seq)[None, None, None, None, :]
-                <= pos[:, None, None, :, None])
-        return paged_attention_op.gqa_attention(
-            q.transpose(0, 2, 1, 3), kv_att, mask, cfg.num_kv_heads), kv
+def init_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
+    return step.init_pool(cfg.num_layers, cfg, num_pages, page_size)
 
 
 def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
@@ -489,14 +340,13 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
     attention and the K/V landing sites split.
 
     tables [B, P] int32, tokens [B] int32, pos [B] int32 (the position
-    the new token is written at; a row at pos >= max_seq is parked and
-    writes nothing). chunk: None, or (pre_tokens [C], pre_slot, pre_p0,
-    pre_n_valid): the chunk goes into ``pre_slot``'s pages from position
-    pre_p0, straddling page boundaries freely, and its tokens at index >=
-    pre_n_valid are tail padding that lands nowhere. The caller
-    guarantees pre_slot is not a live decode row this step, so the two
-    groups of rows touch disjoint pages. A chunk alone is a chunk with
-    every decode row parked.
+    the new token is written at). chunk: None, or (pre_tokens [C],
+    pre_slot, pre_p0, pre_n_valid): the chunk goes into ``pre_slot``'s
+    pages from position pre_p0, straddling page boundaries freely.
+    ``step.step_rows`` says which of these rows are in the step. The
+    caller guarantees pre_slot is not a live decode row this step, so the
+    two groups of rows touch disjoint pages. A chunk alone is a chunk
+    with every decode row parked.
 
     The q / k / v projections read a layer of the stacked weights where
     it lies, as ``wo`` and the MLP's do: their outputs stay [.., T,
@@ -508,32 +358,20 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
 
     Returns (logits [B, vocab] fp32, the logits [vocab] of chunk index
     pre_n_valid - 1 or None, new cache)."""
-    b, s_max = tokens.shape[0], cfg.max_seq
-    if chunk is None:
-        packed, lay = tokens, lambda a: a[:, None]     # rows [B, 1, ..]
-    else:
-        pre_tokens, pre_slot, pre_p0, pre_n_valid = chunk
-        c = pre_tokens.shape[0]
-        packed = jnp.concatenate([tokens, pre_tokens])
-        lay = lambda a: a[None]              # one sequence [1, B + C, ..]
+    rows = step.step_rows(tables, tokens, pos, chunk, cfg.max_seq)
+    b, c, packed = rows.b, rows.c, rows.packed()
+    # the rows alone [B, 1, ..]; with a chunk one sequence [1, B + C, ..]
+    lay = (lambda a: a[:, None]) if chunk is None else (lambda a: a[None])
     with jax.named_scope("embed"):
         x = lay(params["wte"][packed].astype(cfg.dtype))
-    positions = (pos if chunk is None
-                 else jnp.concatenate([pos, pre_p0 + jnp.arange(c)]))
+    positions = pos
+    if chunk is not None:
+        _, _, pre_p0, pre_n_valid = chunk
+        positions = jnp.concatenate([pos, pre_p0 + jnp.arange(c)])
     h, hd, hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
     angles_q, angles_k = (
         rope_lane_tables(lay(positions), n, hd, cfg.rope_theta)
         for n in (h, hkv))
-    if chunk is not None:
-        slot_table = jax.lax.dynamic_slice(tables, (pre_slot, 0),
-                                           (1, tables.shape[1]))
-    rows_d = paged_attention_op.row_meta(
-        tables, pos, jnp.where(pos < s_max, pos + 1, 0))
-    if chunk is not None:
-        rows_c = paged_attention_op.row_meta(
-            slot_table, jnp.reshape(pre_p0, (1,)),
-            jnp.reshape(pre_p0 + jnp.clip(
-                jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c), (1,)))
 
     q_axes, kv_axes = (None, None, "qkv"), (None, None, "kv")
     mesh = current_mesh()
@@ -557,12 +395,13 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
             q = q.reshape(b, 1, h, hd)
         # Decode rows, then the chunk: each writes its own tokens before
         # it attends, so in-chunk causality holds.
-        o, kv = _write_and_attend(q, k_new, v_new, kv, layer, rows_d, cfg,
-                                  page_size, rules)
+        o, kv = step.write_and_attend(q, k_new, v_new, kv, layer,
+                                      rows.decode, cfg, page_size, rules)
         if chunk is not None:
             with jax.named_scope("prefill_lane"):
-                op, kv = _write_and_attend(qp, kp, vp, kv, layer, rows_c,
-                                           cfg, page_size, rules)
+                op, kv = step.write_and_attend(qp, kp, vp, kv, layer,
+                                               rows.chunk, cfg, page_size,
+                                               rules)
         with jax.named_scope("attn"):
             if chunk is not None:
                 o = jnp.concatenate([o[:, 0][None], op], axis=1)
@@ -581,44 +420,11 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: LlamaConfig,
             (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     if chunk is None:
         return _lm_head(x[:, 0], params, cfg), None, {"kv": kv}
+    # (this family's engine never sends an empty chunk: the index is
+    # ``pre_n_valid - 1`` as it stands, not ``rows.last``)
     logits = _lm_head(jnp.concatenate(
         [x[0, :b], x[0, b + pre_n_valid - 1][None]], axis=0), params, cfg)
     return logits[:b], logits[b], {"kv": kv}
-
-
-def copy_pages(cache, src, dst):
-    """Device-side page copy (the COW in copy-on-write): physical pages
-    ``src[i]`` -> ``dst[i]`` across every layer in one program. src/dst
-    [N] int32; jit with the cache donated so the copy is in-place."""
-    kv = cache["kv"]
-    return {"kv": kv.at[:, :, dst].set(kv[:, :, src])}
-
-
-def write_pages(cache, dst, values):
-    """Host->device page import (session migration): physical pages
-    ``dst[i]`` <- ``values[:, :, i]`` across every layer in one program.
-    dst [N] int32; values [L, 2, N, page_size, Hkv * hd] host frames of
-    a peer engine's :func:`read_pages`. Jit with the cache donated so the
-    import is an in-place scatter; callers pad N to a few bucket sizes
-    (padding rows aimed at scratch page 0) so imports rarely recompile."""
-    kv = cache["kv"]
-    return {"kv": kv.at[:, :, dst].set(values.astype(kv.dtype))}
-
-
-def read_pages(cache, idx):
-    """Device->host page export: physical pages ``idx`` [N] of every
-    layer as one contiguous host frame [L, 2, N, page_size, Hkv * hd]."""
-    return np.ascontiguousarray(np.asarray(cache["kv"][:, :, idx]))
-
-
-def check_frames(cache, frames) -> None:
-    """ValueError unless ``frames`` are pages of a pool like this one."""
-    kv_shape = cache["kv"].shape
-    if (tuple(frames.shape[:2]) != tuple(kv_shape[:2])
-            or tuple(frames.shape[3:]) != tuple(kv_shape[3:])):
-        raise ValueError(
-            f"KV frame shape {frames.shape} does not match "
-            f"cache {kv_shape}")
 
 
 def check_shardable(cfg: LlamaConfig, tp: int) -> None:
@@ -634,6 +440,5 @@ def check_shardable(cfg: LlamaConfig, tp: int) -> None:
 serving.register(serving.ServingModel(
     config_type=LlamaConfig, configs=CONFIGS, init_params=init_params,
     param_axes=param_axes, check_shardable=check_shardable,
-    init_cache=init_paged_kv_cache, cache_axes={"kv": PAGED_KV_AXES},
-    step=paged_step, copy_pages=copy_pages, write_pages=write_pages,
-    read_pages=read_pages, check_frames=check_frames))
+    init_cache=init_cache, cache_axes={"kv": step.PAGED_KV_AXES},
+    step=paged_step, **step.PAGE_FUNCTIONS))

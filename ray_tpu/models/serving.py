@@ -7,8 +7,22 @@ computes. It finds all of that here, from the config it is handed
 (:func:`model_for`), and ``llm/serve.py`` finds config and family from a
 name (:func:`named`). A family's module builds a :class:`ServingModel`
 and registers it when imported; nothing under ``models/`` imports
-``llm/``. Adding a serving architecture is a model file and its name in
-``FAMILIES``.
+``llm/``, and no family imports another.
+
+Adding a serving architecture is a model file and its name in
+``FAMILIES``. The file TAKES from ``models/step.py`` the page pool
+(``init_pool`` with its count of attention layers, ``PAGED_KV_AXES``,
+``PAGE_FUNCTIONS`` to register as they are), the rows of a step
+(``step_rows``: who is parked, how much of the chunk is real, the
+kernel's row metadata, which row is the chunk's logits), ``attend`` for
+its paged layers, ``logits_of`` round its own head, ``state_rows`` for a
+count, ``carried_conv`` if it carries a window; from ``models/common.py``
+the seeded ``draw`` / ``bulk_key``, the norms and the rotary step on flat
+lanes; from ``models/moe.py`` the expert layer. It WRITES its config, its
+parameters' shapes, its mixer(s) on a step's rows (with ``ops/
+slot_stream.py step_plan`` inside its own scan scope, if a kernel streams
+its state), its head, ``attach`` / ``reset`` of its state a slot, and the
+loop over its layers under its own scope names.
 
 The cache contract. A cache is a pytree of device arrays that only the
 family's own functions look into. The engine owns which PHYSICAL PAGE

@@ -46,12 +46,10 @@ of three kinds side by side — ``cache["kv"]``, the page pool of the GQA
 layers only; ``cache["kda"]``, ONE ``[kda layers, slots, heads, d_k,
 d_v]`` float32 array that ``ops/delta_rule.py`` updates in place; and
 ``cache["conv"]``, one ``[taps - 1, slots, 3 x heads x d_k]`` array a KDA
-layer (tap-major: :func:`carried_conv` says why) — and a
-:class:`serving.SlotState` for the last two. The step is
-``models/lfm2.py``'s shape: B decode rows and one prompt chunk through
-the same products, layers unrolled, ``one_program`` (a router amplifies an
-ulp), parked rows and a chunk's tail write no page and no state, an empty
-chunk reads and writes nothing.
+layer (tap-major: ``step.carried_conv`` says why) — and a
+:class:`serving.SlotState` for the last two. The step's rows and pages
+are ``models/step.py``'s; the layers are unrolled, and the family is
+``one_program`` (a router amplifies an ulp).
 
 Scope names (``jax.named_scope``; metadata only): ``kda.proj`` (every
 projection of u), ``kda.conv``, ``kda.scan`` (the recurrence),
@@ -69,11 +67,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import paged_attention as paged_attention_op
-from ..ops.delta_rule import delta_rule, step_plan
-from . import lfm2, llama, serving
-from .common import rms_norm
-from .llama import PAGED_KV_AXES, _write_and_attend
+from ..ops.delta_rule import delta_rule
+from ..ops.slot_stream import step_plan
+from . import serving, step
+from .common import bulk_key, draw, rms_norm
 from .moe import EXPERT_COUNTERS, experts_ffn, route, shared_ffn
 
 GQA, KDA = "gqa", "kda"
@@ -201,32 +198,17 @@ def param_axes(cfg: SolarConfig = None) -> Dict:
                        for op in cfg.layer_types]}
 
 
-def _draw(key, shape, init, dtype):
-    if init == "a_log":
-        # the decay's rate a head: log of U(1, 16), in float32 as the
-        # family keeps it
-        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
-                                          16.0))
-    if init == "dt_bias":
-        # inverse softplus of a log-uniform dt in [0.001, 0.1], so that
-        # exp(g) is neither 0 nor 1 and the state is worth carrying
-        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
-                                        math.log(1e-3), math.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))
-    return lfm2._draw(key, shape, init, dtype)  # "ones", "bias", a std
-
-
 @partial(jax.jit, static_argnums=(1, 2))
 def _init_layer(key, cfg: SolarConfig, op: str):
     shapes = _layer_shapes(cfg, op)
-    keys = jax.random.split(lfm2._bulk_key(key), len(shapes))
-    return {name: _draw(k, shape, init, cfg.dtype)
+    keys = jax.random.split(bulk_key(key), len(shapes))
+    return {name: draw(k, shape, init, cfg.dtype)
             for k, (name, (shape, init)) in zip(keys, shapes.items())}
 
 
 @partial(jax.jit, static_argnums=(1,))
 def _init_table(key, cfg: SolarConfig):
-    return _draw(lfm2._bulk_key(key), (cfg.vocab_here, cfg.d_model), 0.02,
+    return draw(bulk_key(key), (cfg.vocab_here, cfg.d_model), 0.02,
                  cfg.dtype)
 
 
@@ -248,12 +230,8 @@ def init_params(key, cfg: SolarConfig) -> Tuple[Dict, Dict]:
 # -- the cache: pages for the GQA layers, state a slot for the KDA layers ----
 
 def init_cache(cfg: SolarConfig, num_pages: int, page_size: int):
-    if cfg.max_seq % page_size != 0:
-        raise ValueError(
-            f"page_size ({page_size}) must divide max_seq ({cfg.max_seq})")
-    shape = (cfg.layer_types.count(GQA), 2, num_pages, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
-    return {"kv": jnp.zeros(shape, cfg.dtype)}
+    return step.init_pool(cfg.layer_types.count(GQA), cfg, num_pages,
+                          page_size)
 
 
 def attach_slot_state(cfg: SolarConfig, cache, num_slots: int):
@@ -277,7 +255,7 @@ def reset_slot_state(cache, slots):
 
 def cache_axes(cfg: SolarConfig) -> Dict:
     n = cfg.layer_types.count(KDA)
-    return {"kv": PAGED_KV_AXES, "kda": (None,) * 5,
+    return {"kv": step.PAGED_KV_AXES, "kda": (None,) * 5,
             "conv": [(None, None, None)] * n}
 
 
@@ -290,60 +268,6 @@ def check_shardable(cfg: SolarConfig, tp: int) -> None:
 
 
 # -- the operators ---------------------------------------------------------------
-
-def carried_conv(z, state, k, b: int, valid, chunk_at, bias=None):
-    """A causal depthwise convolution on a step's rows, its window carried
-    across decode rows, chunk and chunk boundary the way
-    ``lfm2.short_conv`` carries its own; ``bias [ch]`` float32, if given,
-    is added to every row's result (``models/granite.py``).
-
-    z [N, ch]: rows ``[:b]`` one token of slot i each, rows ``[b:]`` (if
-    any) one slot's prompt chunk in order. state [L - 1, slots, ch]: each
-    slot's last L - 1 inputs, TAP-MAJOR, so that a tap is a whole [slots,
-    channels] tile (slots along sublanes, channels along lanes) and the
-    decode rows' result is L multiply-adds of such tiles: a tap beside
-    the slot would be L - 1 = 3 rows of a sublane tile of 8, and a
-    contraction over it a product whose result lies channels x slots.
-    k [L, ch] float32. valid [b] bool; chunk_at None or (slot, n_valid).
-    Returns (conv [N, ch] float32, new state in the state's dtype): a
-    parked row's state, an empty chunk's and every slot's not in the step
-    are left as they were."""
-    taps, f32 = k.shape[0], jnp.float32
-    # (z is converted where it is used, a piece at a time: converted
-    # whole, the conversion moves into the product that made z, which
-    # then writes float32, twice the bytes, and need not round)
-    zb = z[:b]
-    # a decode row's sum in the order of a chunk token's below
-    conv = sum(k[j] * state[j].astype(f32) for j in range(taps - 1)) \
-        + k[taps - 1] * zb.astype(f32)
-    new_state = jnp.where(
-        valid[None, :, None],
-        jnp.concatenate([state[1:], zb[None].astype(state.dtype)], axis=0),
-        state)
-    if chunk_at is not None:
-        slot, n_valid = chunk_at
-        c = z.shape[0] - b
-        # the slot's window a tap (one [1, ch] row) at a time: cut whole,
-        # a [L - 1, ch] piece of the layers' cache, it makes the compiler
-        # lay the cache out tap beside channel, and every layer's window
-        # again on the way in and out
-        before = [jax.lax.dynamic_slice(state, (j, slot, 0),
-                                        (1, 1, z.shape[1]))[0].astype(f32)
-                  for j in range(taps - 1)]
-        zz = jnp.concatenate(before + [z[b:].astype(f32)], axis=0)
-        conv_c = sum(k[j] * zz[j:j + c] for j in range(taps))
-        conv = jnp.concatenate([conv, conv_c], axis=0)
-        # rows n_valid .. n_valid + L - 2 of zz [L - 1 + C, ch] are the
-        # last L - 1 inputs; they go in by the select that writes the
-        # decode rows' windows
-        after = jax.lax.dynamic_slice_in_dim(zz, n_valid, taps - 1, 0)
-        here = (jnp.arange(state.shape[1]) == slot) & (n_valid > 0)
-        new_state = jnp.where(here[None, :, None],
-                              after[:, None].astype(state.dtype), new_state)
-    if bias is not None:
-        conv = conv + bias
-    return conv, new_state
-
 
 def _l2_norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
@@ -382,18 +306,18 @@ def _kda_output(o, gate, p, cfg: SolarConfig, dtype):
     return o.astype(dtype) @ p["wo"].astype(dtype)
 
 
-def kda(u, scan_state, conv_state, p, cfg: SolarConfig, layer, b: int,
-        valid, chunk_at, plan):
+def kda(u, scan_state, conv_state, p, cfg: SolarConfig, layer, rows, plan):
     """One KDA layer on a step's rows u [N, d] -> (out [N, d], the
     layers' matrix states, this layer's conv state). ``layer`` indexes
-    ``scan_state``'s leading axis; ``plan`` is the step's
-    ``delta_rule.step_plan(valid, chunk_at)``, the same for every layer."""
+    ``scan_state``'s leading axis; ``rows`` is the step's
+    ``step.StepRows`` and ``plan`` its ``step_plan(rows.valid,
+    rows.chunk_at)``, the same for every layer."""
     with jax.named_scope("kda.proj"):
         qkv, g, beta, gate = _kda_project(u, p, cfg)
     with jax.named_scope("kda.conv"):
-        conv, conv_state = carried_conv(
-            qkv, conv_state, p["conv_k"].astype(jnp.float32), b, valid,
-            chunk_at)
+        conv, conv_state = step.carried_conv(
+            qkv, conv_state, p["conv_k"].astype(jnp.float32), rows.b,
+            rows.valid, rows.chunk_at)
         q, k, v = _kda_heads(conv, cfg)
     with jax.named_scope("kda.scan"):
         # the step's rows as they are: the decode rows, one token of slot
@@ -425,63 +349,31 @@ def _lm_head(x, params, cfg: SolarConfig):
 def paged_step(params, cache, tables, tokens, pos, chunk, cfg: SolarConfig,
                page_size: int, rules=None):
     """One continuous-batching step: the contract of
-    ``models/serving.py``'s ``step``, with a fourth result: the counts
-    :data:`STEP_COUNTERS` names.
-
-    The rows of a step, all through the same weight products: the B
-    decode rows, then the chunk's C tokens if there is a chunk. A chunk
-    with ``pre_n_valid`` 0 is empty: it writes no page and no state, and
-    its logits mean nothing."""
-    b, s_max = tokens.shape[0], cfg.max_seq
-    h, hd = cfg.num_heads, cfg.head_dim
-    valid = pos < s_max
-    packed, valid_rows, chunk_at, c = [tokens], [valid], None, 0
-    if chunk is not None:
-        pre_tokens, pre_slot, pre_p0, pre_n_valid = chunk
-        c = pre_tokens.shape[0]
-        n_valid = jnp.clip(jnp.minimum(pre_n_valid, s_max - pre_p0), 0, c)
-        packed.append(pre_tokens)
-        valid_rows.append(jnp.arange(c) < n_valid)
-        chunk_at = (pre_slot, n_valid)
-    valid_rows = jnp.concatenate(valid_rows)
+    ``models/serving.py``'s ``step`` over the rows of
+    ``step.step_rows``, with a fourth result: the counts
+    :data:`STEP_COUNTERS` names."""
+    rows = step.step_rows(tables, tokens, pos, chunk, cfg.max_seq)
     # The residual stream is float32: a step's rows are few (B + C), so
     # it costs nothing beside the weights' read, and a bfloat16 stream
     # rounds every layer's sum by 2^-9 of the STREAM, more than the
     # products' own rounding adds (PERF.md Findings PR 39). Every product
     # takes its input rounded to the model's dtype, as the weights are.
     with jax.named_scope("embed"):
-        x = params["wte"][jnp.concatenate(packed)].astype(jnp.float32)
-    # the paged kernel's row metadata: once a step
-    rows_d = paged_attention_op.row_meta(
-        tables, pos, jnp.where(valid, pos + 1, 0))
-    if chunk is not None:
-        rows_c = paged_attention_op.row_meta(
-            jax.lax.dynamic_slice(tables, (pre_slot, 0),
-                                  (1, tables.shape[1])),
-            jnp.reshape(pre_p0, (1,)), jnp.reshape(pre_p0 + n_valid, (1,)))
+        x = params["wte"][rows.packed()].astype(jnp.float32)
 
     def attention(u, kv, p, layer):
-        """u [N, d] -> (out [N, d], pool): decode rows, then the chunk;
-        each writes its own tokens before it attends. No positional term:
-        the causal order of the pages is all the order there is."""
-        q = u @ p["wq"].astype(u.dtype)
-        k_new = u @ p["wk"].astype(u.dtype)
-        v_new = u @ p["wv"].astype(u.dtype)
-        o, kv = _write_and_attend(q[:b].reshape(b, 1, h, hd), k_new[:b, None],
-                                  v_new[:b, None], kv, layer, rows_d, cfg,
-                                  page_size, rules)
-        outs = [o[:, 0]]
-        if chunk is not None:
-            oc, kv = _write_and_attend(q[b:].reshape(1, c, h, hd),
-                                       k_new[None, b:], v_new[None, b:], kv,
-                                       layer, rows_c, cfg, page_size, rules)
-            outs.append(oc[0])
-        return _gqa_gated(jnp.concatenate(outs), u, p), kv
+        """u [N, d] -> (out [N, d], pool). No positional term: the causal
+        order of the pages is all the order there is."""
+        parts = rows.parts(u @ p["wq"].astype(u.dtype),
+                           u @ p["wk"].astype(u.dtype),
+                           u @ p["wv"].astype(u.dtype), cfg.num_heads)
+        o, kv = step.attend(rows, parts, kv, layer, cfg, page_size, rules)
+        return _gqa_gated(o, u, p), kv
 
     # which rows' matrix states the step reads and writes: once a step,
     # for every KDA layer
     with jax.named_scope("kda.scan"):
-        plan = step_plan(valid, chunk_at)
+        plan = step_plan(rows.valid, rows.chunk_at)
     kv, scan, conv = cache["kv"], cache["kda"], list(cache["conv"])
     counts = jnp.zeros((len(EXPERT_COUNTERS),), jnp.int32)
     n_gqa = n_kda = 0
@@ -489,8 +381,7 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: SolarConfig,
         u = rms_norm(x, p["op_norm"], cfg.norm_eps).astype(cfg.dtype)
         if op == KDA:
             out, scan, conv[n_kda] = kda(u, scan, conv[n_kda], p, cfg,
-                                         jnp.int32(n_kda), b, valid,
-                                         chunk_at, plan)
+                                         jnp.int32(n_kda), rows, plan)
             n_kda += 1
         else:
             with jax.named_scope("attn"):
@@ -502,26 +393,18 @@ def paged_step(params, cache, tables, tokens, pos, chunk, cfg: SolarConfig,
             experts, weights = route(u, p, cfg)
         u = u.astype(cfg.dtype)
         with jax.named_scope("moe.experts"):
-            out, layer_counts = experts_ffn(u, experts, weights, valid_rows,
+            out, layer_counts = experts_ffn(u, experts, weights, rows.live,
                                             p, cfg, held=cfg.experts_held)
         with jax.named_scope("moe.shared"):
             out = out + shared_ffn(u, p)
         x = x + out.astype(jnp.float32)
         counts = counts + layer_counts
-    n_rows = valid_rows.sum().astype(jnp.int32)
-    kda_rows = valid.sum().astype(jnp.int32)
-    if chunk is not None:
-        kda_rows = kda_rows + (n_valid > 0)
+    n_rows = rows.live.sum().astype(jnp.int32)
     counts = jnp.concatenate([counts, jnp.stack([
         n_rows * cfg.num_experts_per_tok * cfg.num_layers,
-        kda_rows * n_kda])])
-    cache = {"kv": kv, "kda": scan, "conv": conv}
-    if chunk is None:
-        return _lm_head(x[:b], params, cfg), None, cache, counts
-    last = jnp.maximum(pre_n_valid, 1) - 1
-    logits = _lm_head(jnp.concatenate([x[:b], x[b + last][None]], axis=0),
-                      params, cfg)
-    return logits[:b], logits[b], cache, counts
+        rows.state_rows() * n_kda])])
+    head = partial(_lm_head, params=params, cfg=cfg)
+    return *step.logits_of(rows, x, head), {"kv": kv, "kda": scan, "conv": conv}, counts
 
 
 # ``param_axes()`` and ``cache_axes`` are read only under a mesh, which
@@ -531,9 +414,7 @@ serving.register(serving.ServingModel(
     param_axes=param_axes, check_shardable=check_shardable,
     init_cache=init_cache,
     cache_axes=cache_axes(CONFIGS["solar-open2-250b-whole"]),
-    step=paged_step, copy_pages=lfm2.copy_pages,
-    write_pages=lfm2.write_pages,  # the pool's; the slots' state is no page
-    read_pages=llama.read_pages, check_frames=llama.check_frames,
+    step=paged_step, **step.PAGE_FUNCTIONS,
     slot_state=serving.SlotState(attach=attach_slot_state,
                                  reset=reset_slot_state),
     step_counters=STEP_COUNTERS, one_program=True))

@@ -1,4 +1,4 @@
-"""``models/solar.py carried_conv`` (the convolution ``solar``'s KDA layers
+"""``models/step.py carried_conv`` (the convolution ``solar``'s KDA layers
 and ``granite``'s mamba layers carry from step to step, its window
 tap-major) against a plain NumPy causal convolution that carries each
 slot's last inputs: the sums to float32 rounding, the new window bit for
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.solar import carried_conv
+from ray_tpu.models.step import carried_conv
 
 TAPS, SLOTS, CH, CHUNK = 4, 6, 256, 8
 

@@ -14,6 +14,7 @@ import pytest
 from ray_tpu.llm.engine import SlotEngine
 from ray_tpu.llm.paged import OverloadedError, PagePool, RadixIndex
 from ray_tpu.models import llama
+from ray_tpu.models.step import PAGED_KV_AXES, init_pool
 
 CFG = llama.CONFIGS["llama-tiny"]
 PS = 8  # page size under test: 16 pages per 128-token sequence
@@ -104,13 +105,13 @@ def test_paged_cache_layout_heads_minor():
     reshapes to the seq-major attention view without a materializing
     transpose, and axis 4 carries the 'kv' logical axis for tp sharding
     (whole heads a shard)."""
-    cache = llama.init_paged_kv_cache(CFG, 7, PS)
+    cache = init_pool(CFG.num_layers, CFG, 7, PS)
     assert set(cache) == {"kv"}
     assert cache["kv"].shape == (CFG.num_layers, 2, 7, PS,
                                  CFG.num_kv_heads * CFG.head_dim)
     # The logical-axis annotation must line up with that shape: exactly
     # one 'kv' entry, on the heads axis.
-    assert llama.PAGED_KV_AXES == (None, None, None, None, "kv")
+    assert PAGED_KV_AXES == (None, None, None, None, "kv")
 
 
 def test_paged_sampled_parity_vs_dense_reference(engine, params):
@@ -188,7 +189,7 @@ def test_paged_kernels_match_dense(params, case, heads):
 
     want_prompt, dense_cache = dense(prompt)
     want_other, _ = dense(other)
-    paged = llama.init_paged_kv_cache(cfg, nrows * pps + 1, PS)
+    paged = init_pool(cfg.num_layers, cfg, nrows * pps + 1, PS)
     toks = np.zeros((nrows,), np.int32)
     pos = np.full((nrows,), CFG.max_seq, np.int32)  # every row parked
     if case == "decode_only":

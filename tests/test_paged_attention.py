@@ -16,6 +16,7 @@ import pytest
 
 from ray_tpu.llm.engine import SlotEngine
 from ray_tpu.models import llama
+from ray_tpu.models.step import init_pool
 from ray_tpu.ops import paged_attention as PA
 
 # name: (H, Hkv, hd, page_size, dtype) — llama-tiny's own widths, then MHA
@@ -168,7 +169,7 @@ def _greedy_run(cfg, ps, params, prompt, chunk, steps):
     at a time while another decodes beside it, then steps with no chunk;
     every step's logits, greedy-chained."""
     b, pps = 3, cfg.max_seq // ps
-    cache = llama.init_paged_kv_cache(cfg, b * pps + 1, ps)
+    cache = init_pool(cfg.num_layers, cfg, b * pps + 1, ps)
     tables = jnp.asarray(
         np.arange(1, b * pps + 1)[::-1].reshape(b, pps).astype(np.int32))
     step = jax.jit(lambda cache, toks, pos, chunk: llama.paged_step(

@@ -223,18 +223,23 @@ def test_a_config_of_no_family_is_refused():
 
 # -- who imports whom ------------------------------------------------------------
 
+def _imports_of(path, node):
+    """Dotted names one import statement of the module at ``path`` names,
+    relative ones resolved."""
+    if isinstance(node, ast.Import):
+        yield from (a.name for a in node.names)
+    elif isinstance(node, ast.ImportFrom):
+        pkg = ".".join(path.relative_to(PKG.parent).parts[:-1])
+        base = pkg.rsplit(".", node.level - 1)[0] if node.level else None
+        module = ".".join(x for x in (base, node.module) if x)
+        yield module
+        yield from (f"{module}.{a.name}" for a in node.names)
+
+
 def _imports(path):
     """Dotted names a module imports, relative ones resolved."""
-    pkg = ".".join(path.relative_to(PKG.parent).parts[:-1])
     for node in ast.walk(ast.parse(path.read_text())):
-        if isinstance(node, ast.Import):
-            yield from (a.name for a in node.names)
-        elif isinstance(node, ast.ImportFrom):
-            base = (pkg.rsplit(".", node.level - 1)[0] if node.level
-                    else None)
-            module = ".".join(x for x in (base, node.module) if x)
-            yield module
-            yield from (f"{module}.{a.name}" for a in node.names)
+        yield from _imports_of(path, node)
 
 
 def test_the_engine_names_no_model_and_models_no_engine():
@@ -251,6 +256,52 @@ def test_the_engine_names_no_model_and_models_no_engine():
     for p in (PKG / "models").glob("*.py"):
         upward = [n for n in _imports(p) if n.startswith("ray_tpu.llm")]
         assert not upward, (p.name, upward)
+
+
+def _sibling_underscores(path):
+    """(sibling, name) for every underscore name of another module of
+    ``ray_tpu/models`` that the module at ``path`` imports or reads as an
+    attribute of the imported module."""
+    siblings = {f"ray_tpu.models.{p.stem}" for p in path.parent.glob("*.py")
+                if p != path}
+    tree = ast.parse(path.read_text())
+    bound = {}                       # local name -> sibling module
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.ImportFrom):
+            continue
+        module, *named = _imports_of(path, node)
+        for a, full in zip(node.names, named):
+            if full in siblings:
+                bound[a.asname or a.name] = full
+            elif module in siblings and a.name.startswith("_"):
+                yield module, a.name
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Attribute) and node.attr.startswith("_")
+                and isinstance(node.value, ast.Name)
+                and node.value.id in bound):
+            yield bound[node.value.id], node.attr
+
+
+def test_no_serving_family_imports_another():
+    """A family is a file: deleting or editing one breaks no other. What
+    they share (the page pool, a step's rows, the carried window, the
+    seeded draws, the expert layer) lies in ``models/step.py``,
+    ``common.py`` and ``moe.py``."""
+    families = {f"ray_tpu.models.{name}" for name in serving.FAMILIES}
+    for name in serving.FAMILIES:
+        path = PKG / "models" / f"{name}.py"
+        others = families - {f"ray_tpu.models.{name}"}
+        crossed = {n for n in _imports(path)
+                   if any(n == o or n.startswith(o + ".") for o in others)}
+        assert not crossed, (name, sorted(crossed))
+
+
+def test_no_model_file_reaches_for_a_siblings_private_name():
+    """No module under ``models/`` takes a sibling's underscore name, by
+    import or as an attribute of the imported module."""
+    for path in (PKG / "models").glob("*.py"):
+        private = sorted(set(_sibling_underscores(path)))
+        assert not private, (path.name, private)
 
 
 # -- a config registered after import -------------------------------------------
