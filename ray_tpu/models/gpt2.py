@@ -25,8 +25,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention as attention_op
-from ..parallel.sharding import constrain
+from ..ops.attention import attention as attention_op, packed_heads_for
+from ..parallel.sharding import constrain, mesh_axes_for
 from .common import cross_entropy_sums, layer_norm, truncated_normal
 
 
@@ -166,7 +166,9 @@ def init_params(key, cfg: GPT2Config) -> Tuple[Dict, Dict]:
     return params, axes
 
 
-def _attend(q, k, v, cfg: GPT2Config, rules):
+def _attend(q, k, v, cfg: GPT2Config, rules, packed: bool = False):
+    """q, k, v [B, H, S, hd] -> o in the same layout; ``packed``: all four
+    in the kernel's packed layout (``_packed_heads``)."""
     from ..parallel.sharding import current_mesh, smap, spec_for
 
     impl = cfg.attention_impl
@@ -175,7 +177,8 @@ def _attend(q, k, v, cfg: GPT2Config, rules):
 
         o = attention_op(
             q, k, v, causal=True, impl=impl, mesh=current_mesh(),
-            spec=spec_for(("batch", "heads", None, None), rules))
+            spec=spec_for(("batch", "heads", None, None), rules),
+            head_dim=cfg.head_dim if packed else None)
         # Named for the "dots_attn" remat policy: saving attention outputs
         # skips re-running the flash kernel in the backward pass (the
         # single biggest recompute in the block at ~400MB saved for 355M).
@@ -251,6 +254,60 @@ def _moe_ffn(y, p, cfg: GPT2Config, rules):
     return fn(y, p["router_w"], p["moe_in_w"], p["moe_out_w"])
 
 
+def _packed_heads(cfg: GPT2Config, seq: int, rules) -> int:
+    """Heads a 128-lane row in the layout the block's projections write
+    and read (``ops/attention.py flash_attention``); 1: heads whole,
+    through [B, S, 3D] and a transpose, as the sequence-parallel impls, the
+    reference and a mesh that shards the heads take them."""
+    from ..parallel.sharding import current_mesh
+
+    mesh = current_mesh() or jax.sharding.get_abstract_mesh()
+    split = [a for name in ("qkv", "heads")
+             for a in mesh_axes_for(name, rules)
+             if dict(mesh.shape).get(a, 1) > 1]
+    if cfg.attention_impl not in ("auto", "flash") or split:
+        return 1
+    return packed_heads_for(cfg.head_dim, cfg.attention_impl, seq)
+
+
+def _packed_attention(y, p, cfg: GPT2Config, rules, n: int):
+    """The attention half of a block from the normed input to the output
+    projection with q, k, v and o never in another layout than the flash
+    kernel's: ``qkv_w`` [D, 3D] is read as [D, 3, rows, n * hd] and
+    ``proj_w`` [D, D] as [rows, n * hd, D] (bitcasts of the stored
+    parameters), so each projection's matmul writes, or reads, [B, rows,
+    S, n * hd] and no split, reshape or transpose of an activation stands
+    between a matmul and a kernel. Heads that do not fill the last row
+    (GPT-2 XL's 25) are made up with zero heads in the weights' columns:
+    exact, because a zero head adds zero to ``o @ proj_w`` and its
+    gradient is cut off again. Three products and not one stacked: a
+    stacked result is cut into thirds again, a copy a pass; the price is
+    ``dy`` summed from three bfloat16 results (0.24 % rms from float32
+    where one ``K = 3D`` matmul reads 0.17 %, PERF.md Findings PR 50)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    d, h, hd = cfg.d_model, cfg.num_heads, cfg.head_dim
+    rows = -(-h // n)
+    fill = ((0, rows * n - h), (0, 0))
+
+    def columns(w, lead):  # [.., H * hd] -> [.., rows, n * hd]
+        w = w.astype(y.dtype).reshape(lead + (h, hd))
+        w = jnp.pad(w, ((0, 0),) * len(lead) + fill)
+        return w.reshape(lead + (rows, n * hd))
+
+    w = columns(p["qkv_w"], (d, 3))
+    bias = columns(p["qkv_b"], (3,))
+    q, k, v = (
+        checkpoint_name(
+            jnp.einsum("bsd,drl->brsl", y, w[:, c])
+            + bias[c][None, :, None, :], "qkv")
+        for c in range(3))
+    o = _attend(q, k, v, cfg, rules, packed=True)
+    proj = jnp.pad(p["proj_w"].astype(o.dtype).reshape(h, hd, d),
+                   fill + ((0, 0),)).reshape(rows, n * hd, d)
+    return jnp.einsum("brsl,rld->bsd", o, proj)
+
+
 def _block(x, p, cfg: GPT2Config, rules):
     """One transformer block. x: [B, S, D]; p: this layer's param slice.
     Returns (x, aux_loss) — aux is 0 for dense blocks, the router
@@ -262,17 +319,23 @@ def _block(x, p, cfg: GPT2Config, rules):
 
     with jax.named_scope("attn"):
         y = layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-        qkv = (y @ p["qkv_w"].astype(y.dtype)) + p["qkv_b"].astype(y.dtype)
-        qkv = constrain(qkv, ("batch", "seq", "qkv"), rules)
-        qkv = checkpoint_name(qkv, "qkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        n = _packed_heads(cfg, s, rules)
+        if n > 1:
+            o = _packed_attention(y, p, cfg, rules, n)
+        else:
+            qkv = (y @ p["qkv_w"].astype(y.dtype)) \
+                + p["qkv_b"].astype(y.dtype)
+            qkv = constrain(qkv, ("batch", "seq", "qkv"), rules)
+            qkv = checkpoint_name(qkv, "qkv")
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(t):  # [B,S,D] -> [B,H,S,hd]
-            return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+            def heads(t):  # [B,S,D] -> [B,H,S,hd]
+                return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
 
-        o = _attend(heads(q), heads(k), heads(v), cfg, rules)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-        o = (o @ p["proj_w"].astype(o.dtype)) + p["proj_b"].astype(o.dtype)
+            o = _attend(heads(q), heads(k), heads(v), cfg, rules)
+            o = o.transpose(0, 2, 1, 3).reshape(b, s, d) \
+                @ p["proj_w"].astype(o.dtype)
+        o = o + p["proj_b"].astype(o.dtype)
         x = x + constrain(o, ("batch", "seq", None), rules)
 
     with jax.named_scope("mlp"):

@@ -28,6 +28,32 @@ kernel alone at ``[8, 20, 1024, 64]`` bfloat16, causal):
     (0.12 ms each, outside the kernel).
   - bf16 matmul operands, fp32 accumulation and softmax statistics; a
     power-of-two scale is folded into the query, which is exact.
+  - PACKED ROWS (PR 50). A ``[.., seq, 64]`` bfloat16 array is tiled
+    (8, 128) in HBM: its 64 lanes are padded to 128, so it takes twice its
+    size and every DMA of it moves twice its bytes; and a caller whose
+    matmul wrote ``[b, s, heads * 64]`` pays a split, a reshape and a
+    transpose to get there. So the kernels also take rows of
+    ``128 // head_dim`` heads side by side, ``[b, heads / 2, seq, 128]`` at
+    head dim 64 (head ``r * n + j`` in lanes ``[j * 64, (j + 1) * 64)`` of
+    row ``r``), which a projection's matmul can write and read directly
+    (``models/gpt2.py _packed_attention``). One body for both: a row's
+    heads are taken one after another, each over the row's full 128 lanes
+    with NO lane shuffle and NO added MXU pass, because a 64-wide head
+    half-fills the MXU anyway. For the head in lanes 0-63:
+    ``S = (q_row * keep) @ k_row^T`` contracts all 128 lanes with the other
+    head's zeroed (``keep`` is a [1, 128] row of ``scale`` and 0: the ONE
+    multiply a folded scale cost already); ``p @ v_row`` yields 128 output
+    lanes of which the head's 64 are kept by a lane select when the row is
+    stored. In the backward ``dO`` is zeroed the same way, so
+    ``dV += P^T dO`` and ``dK += dS^T Q``
+    add exact zeros to the other head's lanes of accumulators the row's
+    heads share, ``dP = V dO^T`` contracts the head's lanes alone, and
+    ``dQ = dS K`` is selected like ``o``. What a head costs beyond the
+    unpacked kernel: one [block_q, 128] multiply (dO) and two selects a
+    query block. lse and delta stay a head's (``[b, heads, ..]``). On a
+    row of one head (head dim 128, or the ``[b, h, s, d]`` entry at any
+    head dim) the body traces to the kernel it was before, instruction
+    for instruction.
 
 ``flash_attention`` is differentiable end-to-end in Pallas: forward kernel
 plus a fused dq/dk/dv backward kernel (blockwise recompute from the saved
@@ -46,6 +72,7 @@ import jax
 import jax.numpy as jnp
 
 _NEG_INF = -1e30
+_LANES = 128  # of a vector register and of an HBM tile's minor dimension
 
 
 def _on_tpu() -> bool:
@@ -173,24 +200,65 @@ def _walk_tiles(tile, carry, q0, block_q: int, block_k: int, seq_k: int,
         0, first, lambda kb, c: tile(c, kb * block_k, block_k, False), carry)
 
 
+def _lane_head(width: int, heads_a_row: int):
+    """``j -> [1, width] bool``, the lanes of head ``j`` of a packed row;
+    None where a row is one head."""
+    if heads_a_row == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    per = width // heads_a_row
+    return lambda j: (lane >= j * per) & (lane < (j + 1) * per)
+
+
+def _own_lanes(x, lane_head, j: int, scale: Optional[float] = None):
+    """``x`` [rows, D] with every lane outside head ``j`` zeroed, times
+    ``scale``: ONE multiply by a [1, D] row, which a folded scale cost an
+    unpacked row already. Contracted over all D lanes against a packed
+    operand, the zeros drop the other heads' terms; as the [.., D] factor
+    of a product whose result is accumulated, they leave the other heads'
+    lanes of the accumulator alone."""
+    if lane_head is None:
+        return x if scale is None else x * scale
+    keep = jnp.where(lane_head(j), 1.0 if scale is None else scale, 0.0)
+    return x * keep.astype(x.dtype)
+
+
+def _join_lanes(per_head, lane_head):
+    """One [rows, D] row from each head's [rows, D] result, of which only
+    that head's lanes mean anything (the others hold another head's
+    operand times this head's weights)."""
+    out = per_head[0]
+    for j in range(1, len(per_head)):
+        out = jnp.where(lane_head(j), per_head[j], out)
+    return out
+
+
+def _head_of(row, j: int, heads_a_row: int):
+    """Index of head ``j`` of ``row`` among the heads (lse, delta)."""
+    return row if heads_a_row == 1 else row * heads_a_row + j
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       *, block_q: int, block_k: int, seq_q: int, seq_k: int,
                       scale: float, causal: bool, static: bool,
-                      num_heads: int):
+                      num_heads: int, heads_a_row: int):
+    """``num_heads`` rows of ``heads_a_row`` heads each: see the module's
+    docstring for how a head of a packed row is computed."""
     from jax.experimental import pallas as pl
 
     fold = _folds_exactly(scale)
     rel = (_rows_minus_cols(block_q, block_q if static else block_k)
            if causal else None)
+    lane_head = _lane_head(q_ref.shape[-1], heads_a_row)
 
     def head_body(hh, _):
-        def q_block(qb):
-            q0, rows = _block_rows(qb, block_q, static)
+        def one_head(j, q0, rows):
+            """(m, l, acc) of head ``j`` of the row; of acc [block_q, D]
+            only that head's lanes mean anything."""
             # Matmul operands stay in the input dtype (bf16 on the MXU's
             # fast path); accumulators and softmax statistics are float32.
-            q = q_ref[0, hh, rows, :]  # [block_q, D]
-            if fold:
-                q = q * scale
+            q = _own_lanes(q_ref[0, hh, rows, :], lane_head, j,
+                           scale if fold else None)
 
             def tile(carry, k0, width, masked):
                 if not static:
@@ -219,11 +287,19 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                     acc_new = acc * alpha + acc_new
                 return m_new, l_new, acc_new
 
-            m, l, acc = _walk_tiles(tile, None, q0, block_q, block_k, seq_k,
-                                    causal, static)
+            return _walk_tiles(tile, None, q0, block_q, block_k, seq_k,
+                               causal, static)
+
+        def q_block(qb):
+            q0, rows = _block_rows(qb, block_q, static)
+            heads = [one_head(j, q0, rows) for j in range(heads_a_row)]
             # l >= 1: the row's largest visible score contributes exp(0)
-            o_ref[0, hh, rows, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
-            lse_ref[0, hh, rows, :] = m + jnp.log(l)  # [block_q, 1]
+            o = _join_lanes([acc * (1.0 / l) for _, l, acc in heads],
+                            lane_head)
+            o_ref[0, hh, rows, :] = o.astype(o_ref.dtype)
+            for j, (m, l, _) in enumerate(heads):  # [block_q, 1] a head
+                lse_ref[0, _head_of(hh, j, heads_a_row), rows, :] = \
+                    m + jnp.log(l)
 
         _for_each_block(seq_q // block_q, q_block, static)
         return 0
@@ -264,9 +340,11 @@ def _one_diagonal_tile(causal, static, block_q, block_k):
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
-                      block_q: int, block_k: int, interpret: bool):
-    """ONE pallas call, (q, k, v) -> (o, lse[b, h, sq, 1]); the blocks
-    must divide the sequences."""
+                      block_q: int, block_k: int, interpret: bool,
+                      heads_a_row: int = 1):
+    """ONE pallas call, (q, k, v) -> (o, lse[b, heads, sq, 1]); the blocks
+    must divide the sequences. q, k, v and o are ``[b, rows, s, D]``,
+    ``heads_a_row`` heads side by side in a row's D lanes."""
     from jax.experimental import pallas as pl
 
     b, h, sq, d = q.shape
@@ -274,9 +352,10 @@ def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
     static = _is_static(causal, sq, sk, block_q)
     block_q = _one_diagonal_tile(causal, static, block_q, block_k)
     esize = q.dtype.itemsize
-    # whole-sequence q, o, k, v and the lse column (a [sq, 1] float32
+    # whole-sequence q, o, k, v and a head's lse column (a [sq, 1] float32
     # block is padded to 128 lanes in VMEM); x2 for double-buffering.
-    per_head = 2 * ((2 * sq + 2 * sk) * d * esize + sq * 128 * 4)
+    per_head = 2 * ((2 * sq + 2 * sk) * d * esize
+                    + heads_a_row * sq * 128 * 4)
     hb = _pick_head_block(h, per_head)
 
     full_q = pl.BlockSpec((1, hb, sq, d), lambda i, g: (i, g, 0, 0))
@@ -285,24 +364,26 @@ def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
         functools.partial(
             _flash_fwd_kernel, block_q=block_q, block_k=block_k, seq_q=sq,
             seq_k=sk, scale=scale, causal=causal, static=static,
-            num_heads=hb),
+            num_heads=hb, heads_a_row=heads_a_row),
         grid=(b, h // hb),
         in_specs=[full_q, full_k, full_k],
         out_specs=[full_q,
-                   pl.BlockSpec((1, hb, sq, 1), lambda i, g: (i, g, 0, 0))],
+                   pl.BlockSpec((1, hb * heads_a_row, sq, 1),
+                                lambda i, g: (i, g, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, h * heads_a_row, sq, 1),
+                                        jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
     )(q, k, v)
-    return o, lse.reshape(b, h, sq)
+    return o, lse.reshape(b, h * heads_a_row, sq)
 
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                             block_q: int, block_k: int, seq_q: int,
                             seq_k: int, scale: float, causal: bool,
-                            static: bool, num_heads: int):
+                            static: bool, num_heads: int, heads_a_row: int):
     """dq + dk + dv in ONE pallas program (per (batch, head-group)): it
     walks Q blocks, recomputes P per (Q, K) tile from the saved LSE — no
     S x S array anywhere — and accumulates dk/dv into fp32 VMEM scratch
@@ -314,7 +395,10 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     it into that layout before every call), and dv = P^T dO and
     dk = dS^T Q are plain products; only dq = dS K contracts the tile's
     leading axis. With a folded scale the scaled query gives S and carries
-    the scale into dk; dq takes it once a block."""
+    the scale into dk; dq takes it once a block.
+
+    A packed row's heads share dk_acc and dv_acc: head j's q and dO are
+    zero outside its lanes, so its products add nothing to the others'."""
     from jax.experimental import pallas as pl
 
     d = q_ref.shape[-1]
@@ -322,19 +406,20 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # [keys, queries]: query position minus key position inside a tile
     rel = (-_rows_minus_cols(block_q if static else block_k, block_q)
            if causal else None)
+    lane_head = _lane_head(d, heads_a_row)
 
     def head_body(hh, _):
         dk_acc[...] = jnp.zeros((seq_k, d), jnp.float32)
         dv_acc[...] = jnp.zeros((seq_k, d), jnp.float32)
 
-        def q_block(qb):
-            q0, rows = _block_rows(qb, block_q, static)
-            q = q_ref[0, hh, rows, :]
-            if fold:
-                q = q * scale
-            do = do_ref[0, hh, rows, :]
-            lse = lse_ref[0, hh, pl.ds(qb, 1), :]  # [1, block_q]
-            delta = delta_ref[0, hh, pl.ds(qb, 1), :]
+        def one_head(j, qb, q0, rows):
+            """dq of head ``j`` of the row, right in that head's lanes."""
+            q = _own_lanes(q_ref[0, hh, rows, :], lane_head, j,
+                           scale if fold else None)
+            do = _own_lanes(do_ref[0, hh, rows, :], lane_head, j)
+            head = _head_of(hh, j, heads_a_row)
+            lse = lse_ref[0, head, pl.ds(qb, 1), :]  # [1, block_q]
+            delta = delta_ref[0, head, pl.ds(qb, 1), :]
 
             def tile(dq_part, k0, width, masked):
                 if not static:
@@ -369,8 +454,12 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
             dq = _walk_tiles(tile, jnp.zeros((block_q, d), jnp.float32),
                              q0, block_q, block_k, seq_k, causal, static)
-            if fold:
-                dq = dq * scale
+            return dq * scale if fold else dq
+
+        def q_block(qb):
+            q0, rows = _block_rows(qb, block_q, static)
+            dq = _join_lanes([one_head(j, qb, q0, rows)
+                              for j in range(heads_a_row)], lane_head)
             dq_ref[0, hh, rows, :] = dq.astype(dq_ref.dtype)
 
         _for_each_block(seq_q // block_q, q_block, static)
@@ -382,9 +471,10 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
-                      block_q, block_k, interpret):
+                      block_q, block_k, interpret, heads_a_row: int = 1):
     """ONE pallas call, (q, k, v, do, lse, delta) -> (dq, dk, dv); the
-    blocks must divide the sequences."""
+    blocks must divide the sequences. Rows as in ``_flash_fwd_pallas``;
+    lse is ``[b, heads, sq]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -392,9 +482,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
     sk = k.shape[2]
     static = _is_static(causal, sq, sk, block_q)
     block_q = _one_diagonal_tile(causal, static, block_q, block_k)
-    # a row of block_q lanes a query block: see the kernel
-    per_block = (b, h, sq // block_q, block_q)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # a row of block_q lanes a query block and head: see the kernel
+    per_block = (b, h * heads_a_row, sq // block_q, block_q)
+    delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if heads_a_row == 1:
+        delta = jnp.sum(delta, axis=-1)
+    else:  # [b, rows, heads a row, sq]: each head's lanes summed apart
+        hd = d // heads_a_row
+        delta = jnp.stack([jnp.sum(delta[..., j * hd:(j + 1) * hd], axis=-1)
+                           for j in range(heads_a_row)], axis=2)
     esize = q.dtype.itemsize
     # Full-seq q/k/v/do in, dq/dk/dv out, double-buffered, plus fp32
     # compiler temps for the tile chain — empirically ~5.5MB/head at
@@ -404,11 +500,13 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
 
     full_q = pl.BlockSpec((1, hb, sq, d), lambda i, g: (i, g, 0, 0))
     full_k = pl.BlockSpec((1, hb, sk, d), lambda i, g: (i, g, 0, 0))
-    q_rows = pl.BlockSpec((1, hb) + per_block[2:], lambda i, g: (i, g, 0, 0))
+    q_rows = pl.BlockSpec((1, hb * heads_a_row) + per_block[2:],
+                          lambda i, g: (i, g, 0, 0))
     return pl.pallas_call(
         functools.partial(_flash_bwd_fused_kernel, block_q=block_q,
                           block_k=block_k, seq_q=sq, seq_k=sk, scale=scale,
-                          causal=causal, static=static, num_heads=hb),
+                          causal=causal, static=static, num_heads=hb,
+                          heads_a_row=heads_a_row),
         grid=(b, h // hb),
         in_specs=[full_q, full_k, full_k, full_q, q_rows, q_rows],
         out_specs=[full_q, full_k, full_k],
@@ -426,11 +524,12 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
 # Differentiable wrapper: pallas forward, blockwise-recompute backward.
 # ---------------------------------------------------------------------------
 
-def _fwd(q, k, v, causal, scale, block_q, block_k):
+def _fwd(q, k, v, causal, scale, block_q, block_k, heads_a_row=1):
     """The forward at the callers' blocks: 512-row query blocks read
     fastest on the chip in both schedules (PERF.md, Findings PR 32)."""
     return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                             interpret=not _on_tpu())
+                             interpret=not _on_tpu(),
+                             heads_a_row=heads_a_row)
 
 
 # The backward's query block where the static schedule can take it: four
@@ -439,24 +538,26 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
 _BWD_STATIC_BLOCK_Q = 256
 
 
-def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
+def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+         heads_a_row):
     want = _BWD_STATIC_BLOCK_Q
     if block_q % want == 0 and _is_static(causal, q.shape[2], k.shape[2],
                                           want):
         block_q = want
     return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q,
-                             block_k, interpret=not _on_tpu())
+                             block_k, interpret=not _on_tpu(),
+                             heads_a_row=heads_a_row)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, block_k):
-    return _fwd(q, k, v, causal, scale, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, block_q, block_k, heads_a_row):
+    return _fwd(q, k, v, causal, scale, block_q, block_k, heads_a_row)[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, heads_a_row):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k)
+    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k, heads_a_row)
     # Named so remat policies (gpt2 "dots_attn") can save BOTH outputs:
     # with o and lse saved, the rematerialized forward's kernel call is
     # dead code and the backward never re-runs flash.
@@ -465,21 +566,20 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
+def _flash_bwd_rule(causal, scale, block_q, block_k, heads_a_row, res, do):
     """Backward: the fused pallas kernel, recomputing P per block from
     the saved LSE (no S×S materialization across blocks) with bf16 matmul
     operands and fp32 accumulation. ``flash_attention`` admits only
     shapes the blocks divide, so there is no other path."""
-    return _bwd(*res, do, causal, scale, block_q, block_k)
+    return _bwd(*res, do, causal, scale, block_q, block_k, heads_a_row)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _tileable(q, k, causal: bool, block_q: int, block_k: int):
-    """Clamp block sizes to the sequence and decide whether the pallas
-    kernels can tile this shape; (bq, bk, ok)."""
-    sq, sk = q.shape[2], k.shape[2]
+def _tileable(sq: int, sk: int, causal: bool, block_q: int, block_k: int):
+    """Clamp block sizes to the sequences and decide whether the pallas
+    kernels can tile them; (bq, bk, ok)."""
     bq = min(block_q, sq)
     bk = min(block_k, sk)
     ok = not (sq % bq != 0 or sk % bk != 0
@@ -488,7 +588,7 @@ def _tileable(q, k, causal: bool, block_q: int, block_k: int):
 
 
 def _blocks_or_raise(q, k, causal: bool, block_q: int, block_k: int):
-    bq, bk, ok = _tileable(q, k, causal, block_q, block_k)
+    bq, bk, ok = _tileable(q.shape[2], k.shape[2], causal, block_q, block_k)
     if not ok:
         raise ValueError(
             f"flash attention cannot tile q seq {q.shape[2]} / k seq "
@@ -497,34 +597,55 @@ def _blocks_or_raise(q, k, causal: bool, block_q: int, block_k: int):
     return bq, bk
 
 
-def _use_reference(impl: str, q, k, causal: bool,
-                   block_q: int, block_k: int) -> bool:
+def _use_reference(impl: str, sq: int, sk: int, causal: bool,
+                   block_q: int = 512, block_k: int = 512) -> bool:
     """'reference' always; 'auto' wherever the kernel would not run
-    compiled (off TPU, or a shape it cannot tile); 'flash' never."""
+    compiled (off TPU, or sequences it cannot tile); 'flash' never."""
     if impl == "auto":
         return not (_on_tpu()
-                    and _tileable(q, k, causal, block_q, block_k)[2])
+                    and _tileable(sq, sk, causal, block_q, block_k)[2])
     return impl == "reference"
+
+
+def packed_heads_for(head_dim: int, impl: str = "flash", seq: int = 0,
+                     causal: bool = True) -> int:
+    """How many heads :func:`attention` under ``impl`` takes side by side
+    in one row of 128 lanes (2 at head dim 64); 1 where the kernels do not
+    pack (a row is a head) or would not run on ``seq`` tokens: only they
+    gain by the packed layout."""
+    if _LANES % head_dim or _use_reference(impl, seq, seq, causal):
+        return 1
+    return _LANES // head_dim
 
 
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 512):
-    """Flash attention. q/k/v: [batch, heads, seq, head_dim].
+                    block_q: int = 512, block_k: int = 512,
+                    head_dim: Optional[int] = None):
+    """Flash attention. q/k/v: [batch, heads, seq, head_dim], or with
+    ``head_dim`` given the packed layout, [batch, rows, seq, D]: head
+    ``r * n + j`` in lanes ``[j * head_dim, (j + 1) * head_dim)`` of row
+    ``r``, ``n = D // head_dim``; the result in the same layout.
 
     The Pallas kernel: compiled on TPU, interpreted (same code path) in
     CPU tests. Raises ``ValueError`` for a shape the kernel cannot tile.
     """
+    head_dim = head_dim or q.shape[-1]
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(head_dim)
     bq, bk = _blocks_or_raise(q, k, causal, block_q, block_k)
-    return _flash(q, k, v, causal, scale, bq, bk)
+    return _flash(q, k, v, causal, scale, bq, bk, q.shape[-1] // head_dim)
 
 
 def attention(q, k, v, causal: bool = True, impl: str = "auto",
-              scale: Optional[float] = None, mesh=None, spec=None):
+              scale: Optional[float] = None, mesh=None, spec=None,
+              head_dim: Optional[int] = None):
     """Dispatch: 'flash' | 'reference' | 'auto' (flash on TPU for shapes
-    it tiles, the reference elsewhere).
+    it tiles, the reference elsewhere). ``head_dim``: q, k, v and the
+    result are in the packed layout, as in :func:`flash_attention`; the
+    kernels' alone, so ``ValueError`` where the reference would run
+    (:func:`packed_heads_for` says 1 there, and a caller keeps its heads
+    whole).
 
     ``mesh`` / ``spec``: GSPMD cannot partition a Mosaic kernel, so in a
     program over several devices the kernel runs per shard inside a
@@ -533,9 +654,15 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto",
     batch and heads, so ``spec`` may shard those two dims and no other.
     The reference is plain XLA and needs neither.
     """
-    if _use_reference(impl, q, k, causal, 512, 512):
+    if _use_reference(impl, q.shape[2], k.shape[2], causal):
+        if head_dim is not None:
+            raise ValueError(
+                f"packed rows (head_dim={head_dim}) are the flash kernels' "
+                f"layout; impl={impl!r} would run the reference on seq "
+                f"{q.shape[2]} / {k.shape[2]}")
         return mha_reference(q, k, v, causal=causal, scale=scale)
-    fn = functools.partial(flash_attention, causal=causal, scale=scale)
+    fn = functools.partial(flash_attention, causal=causal, scale=scale,
+                           head_dim=head_dim)
     # Not where the caller is itself a shard_map body (the pp pipeline's
     # stages): there the mesh axes are manual already.
     if (mesh is not None and mesh.size > 1
@@ -555,7 +682,8 @@ def attention_with_lse(q, k, v, causal: bool = True,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if _use_reference(impl, q, k, causal, block_q, block_k):
+    if _use_reference(impl, q.shape[2], k.shape[2], causal, block_q,
+                      block_k):
         return mha_reference_with_lse(q, k, v, causal=causal, scale=scale)
     bq, bk = _blocks_or_raise(q, k, causal, block_q, block_k)
     return _fwd(q, k, v, causal, scale, bq, bk)
