@@ -156,16 +156,17 @@ def rope_lanes(x, tables, mesh=None, lanes_axis=None):
                          out_specs=spec, check_vma=False)(x, *tables)
 
 
-def cross_entropy_sums(logits, targets, ignore_id: int = -1,
-                       vocab_axis=None):
-    """Masked token CE in fp32 as (nll_sum, token_count) — the composable
-    form, summable across sequence/loss chunks.
+def cross_entropy_terms(logits, targets, ignore_id: int = -1,
+                        vocab_axis=None):
+    """What masked token CE in fp32 is summed from, a token: ``(logz,
+    gold, mask, local)``, the log-sum-exp, the target's logit, 1.0 where
+    the target counts, and the target's index into ``logits``' last axis.
 
     Inside a ``shard_map`` whose ``vocab_axis`` mesh axis (or axes) shards
     the vocab, ``logits`` is this device's slice of it: the log-sum-exp's
     max and sum and the gold logit cross that axis as ``[tokens]``
-    vectors, the ``[tokens, vocab]`` matrix never does. The sums are of
-    this device's tokens either way.
+    vectors, the ``[tokens, vocab]`` matrix never does, and ``local`` lies
+    outside the slice where another device holds the target.
     """
     logits = logits.astype(jnp.float32)
     mask = (targets != ignore_id).astype(jnp.float32)
@@ -174,6 +175,7 @@ def cross_entropy_sums(logits, targets, ignore_id: int = -1,
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, targets[..., None], axis=-1)[..., 0]
+        local = targets
     else:
         v = logits.shape[-1]
         top = jax.lax.pmax(
@@ -185,6 +187,18 @@ def cross_entropy_sums(logits, targets, ignore_id: int = -1,
             logits, jnp.clip(local, 0, v - 1)[..., None], axis=-1)[..., 0]
         gold = jax.lax.psum(
             jnp.where((local >= 0) & (local < v), here, 0.0), vocab_axis)
+    return logz, gold, mask, local
+
+
+def cross_entropy_sums(logits, targets, ignore_id: int = -1,
+                       vocab_axis=None):
+    """Masked token CE in fp32 as (nll_sum, token_count) — the composable
+    form, summable across sequence/loss chunks. The sums are of this
+    device's tokens, whether or not ``vocab_axis`` shards the vocab
+    (``cross_entropy_terms``).
+    """
+    logz, gold, mask, _ = cross_entropy_terms(logits, targets, ignore_id,
+                                              vocab_axis)
     return jnp.sum((logz - gold) * mask), mask.sum()
 
 

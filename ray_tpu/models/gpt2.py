@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import attention as attention_op, packed_heads_for
 from ..parallel.sharding import constrain, mesh_axes_for
-from .common import cross_entropy_sums, layer_norm, truncated_normal
+from .common import cross_entropy_terms, layer_norm, truncated_normal
 
 
 @dataclass(frozen=True)
@@ -544,6 +544,68 @@ def _pp_forward_features(params, tokens, cfg: GPT2Config, rules):
     return x, jnp.zeros((), jnp.float32)
 
 
+def _chunk_sums_and_grads(xc, tc, wte, vocab_axes):
+    """The head's chunk loop: ``(nll_sum, count)`` of one device's tokens
+    ``xc [chunks, c, d]`` / ``tc [chunks, c]`` against ``wte [V, d]``, and
+    ``nll_sum``'s gradient ``(dx [chunks, c, d], d wte [V, d])``, float32
+    logits a chunk at a time and each chunk's ONCE.
+
+    Soft-max cross-entropy's gradient is known where the logits are: ``g =
+    (softmax - onehot) * mask``, float32, handed to the MXU in x's dtype
+    (what its default pass makes of autodiff's float32 ``d logits``
+    too). So a chunk is three vocab-wide products, each accumulating in
+    float32: the logits, ``dx_i = g @ wte`` and ``d wte += g.T @ x_i``,
+    whose sum over the chunks is carried in the table's dtype. A chunk of
+    x is read once, so ``dx_i`` is written where ``x_i`` was and ``dx``
+    needs no buffer of its own."""
+    def contract(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def chunk(i, carry):
+        nll_sum, count, dwte, held = carry
+        xi, ti = held[i], tc[i]
+        logits = contract(xi, wte, ((1,), (1,)))
+        logz, gold, mask, local = cross_entropy_terms(
+            logits, ti, vocab_axis=vocab_axes)
+        onehot = local[:, None] == jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, 1)
+        g = ((jnp.exp(logits - logz[:, None]) - onehot)
+             * mask[:, None]).astype(xi.dtype)
+        dwte = (dwte + contract(g, xi, ((0,), (0,)))).astype(wte.dtype)
+        dxi = contract(g, wte, ((1,), (0,))).astype(xi.dtype)
+        return (nll_sum + jnp.sum((logz - gold) * mask), count + mask.sum(),
+                dwte, jax.lax.dynamic_update_index_in_dim(held, dxi, i, 0))
+
+    zero = jnp.zeros((), jnp.float32)
+    nll_sum, count, dwte, dx = jax.lax.fori_loop(
+        0, xc.shape[0], chunk, (zero, zero, jnp.zeros_like(wte), xc))
+    return (nll_sum, count), (dx, dwte)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunk_sums(xc, tc, wte, vocab_axes):
+    """``(nll_sum, count)`` of ``_chunk_sums_and_grads``. Differentiated,
+    the forward pass keeps that loop's ``dx`` and ``d wte`` and the
+    backward pass only scales them by ``nll_sum``'s cotangent: no logits
+    are saved and none made again, three vocab-wide products a chunk
+    (``jax.checkpoint`` of the chunk would run four). Undifferentiated,
+    nothing reads the two gradients and the compiler drops their
+    products."""
+    return _chunk_sums_and_grads(xc, tc, wte, vocab_axes)[0]
+
+
+def _chunk_sums_bwd(vocab_axes, grads, cts):
+    dx, dwte = grads
+    ct, _ = cts  # the count has no gradient
+    if vocab_axes:  # what the forward's psums over them transpose to
+        ct = jax.lax.psum(ct, vocab_axes)
+    return ((dx * ct).astype(dx.dtype), None, (dwte * ct).astype(dwte.dtype))
+
+
+_chunk_sums.defvjp(_chunk_sums_and_grads, _chunk_sums_bwd)
+
+
 def _ce_sums_local(x, targets, wte, loss_chunk, token_axes, vocab_axes,
                    embed_axes):
     """(nll_sum, count) over ALL tokens from one device's share of them.
@@ -555,8 +617,8 @@ def _ce_sums_local(x, targets, wte, loss_chunk, token_axes, vocab_axes,
     one reduce-scatter of ``d wte``, which accumulates over the chunks on
     the device (the TPU compiler makes it an all-reduce and a slice where
     the shard is off the 128-lane tiling, as gpt2-xl's 400 is). The head
-    matmul + CE run in token chunks under ``jax.checkpoint``, so the
-    float32 logits live a chunk at a time.
+    matmul + CE run in token chunks (``_chunk_sums``), so the float32
+    logits live a chunk at a time.
     """
     if embed_axes:
         wte = jax.lax.all_gather(wte, embed_axes, axis=1, tiled=True)
@@ -574,23 +636,8 @@ def _ce_sums_local(x, targets, wte, loss_chunk, token_axes, vocab_axes,
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
         tf = jnp.pad(tf, (0, pad), constant_values=-1)  # ignore_id
     n_chunks = xf.shape[0] // chunk
-    xc = xf.reshape(n_chunks, chunk, d)
-    tc = tf.reshape(n_chunks, chunk)
-
-    @jax.checkpoint
-    def chunk_loss(carry, xt):
-        xi, ti = xt
-        logits = jax.lax.dot_general(
-            xi, wte, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        nll, count = cross_entropy_sums(logits, ti, vocab_axis=vocab_axes)
-        nll_sum, denom = carry
-        return (nll_sum + nll, denom + count), None
-
-    sums, _ = jax.lax.scan(
-        chunk_loss,
-        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xc, tc))
+    sums = _chunk_sums(xf.reshape(n_chunks, chunk, d),
+                       tf.reshape(n_chunks, chunk), wte, vocab_axes)
     if token_axes:  # the two scalars are all that crosses chips
         sums = jax.lax.psum(sums, token_axes)
     return sums
@@ -650,13 +697,15 @@ def loss_fn(params, batch, cfg: GPT2Config, rules=None,
             loss_chunk: int = 4096):
     """batch: {"tokens": [B, S+1]} → next-token CE loss.
 
-    The LM head + CE run in token chunks under ``jax.checkpoint``: fp32
-    logits for the full batch are B*S*vocab*4 bytes (1.65GB at 774M batch
-    8) and the CE backward doubles that — chunking caps the live logits
-    footprint at chunk*vocab*4*2 A DEVICE (chunks are cut from a device's
-    own tokens, ``_head_ce_sums``) and recomputes the chunk's head matmul
-    in backward (~2.5% extra FLOPs), which is what lets the large-batch
-    configs fit one chip.
+    The LM head + CE run in token chunks: fp32 logits for the full batch
+    are B*S*vocab*4 bytes (1.65GB at 774M batch 8), so a DEVICE holds
+    ``loss_chunk`` tokens' at a time (chunks are cut from a device's own
+    tokens, ``_head_ce_sums``), which is what lets the large-batch configs
+    fit one chip. Differentiated, a chunk forms the loss's gradient in the
+    pass that forms its logits (``_chunk_sums``), and between the forward
+    and the backward pass the head holds ``dx [tokens, d]`` and ``d wte
+    [V, d]`` in the activations' dtype (21 MB and 129 MB at 774M batch 8).
+    Reverse mode only: a ``custom_vjp`` has no forward-mode rule.
     """
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]

@@ -348,3 +348,116 @@ def test_gpt2_head_on_a_mesh_equals_one_device(name):
         np.testing.assert_allclose(
             np.asarray(g), w, atol=2e-5 * np.abs(w).max(), rtol=2e-5,
             err_msg=jax.tree_util.keystr(path))
+
+
+def _assert_leaves_close(want_grads, got_grads, scale=1.0):
+    """Every leaf of ``got_grads`` is ``scale`` x its twin in
+    ``want_grads``, to 2e-5 of the leaf's largest element (leaves' scales
+    differ 1000-fold)."""
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want_grads),
+                            jax.tree.leaves(got_grads), strict=True):
+        w = scale * np.asarray(w)
+        assert np.abs(w).max() > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(
+            np.asarray(g), w, atol=2e-5 * np.abs(w).max(), rtol=2e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("name", ["fsdp2_tp2", "one_device"])
+def test_gpt2_head_applies_the_cotangent_it_is_handed(name):
+    """The head's backward rule is written by hand and only SCALES what the
+    forward pass kept (``gpt2._chunk_sums``): the gradient of 3 x the loss
+    is 3 x the gradient of the loss, leaf by leaf (under tp the cotangent
+    also crosses the vocab axis, as the transposed psums did)."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.sharding import (prune_rules_for_mesh,
+                                           shardings_for, under_mesh)
+
+    spec, attention = HEAD_MESHES[name]
+    cfg = gpt2.GPT2Config(
+        vocab_size=256, max_seq=64, num_layers=1, num_heads=2, d_model=32,
+        dtype=jnp.float32, remat=False, attention_impl=attention)
+    params, axes = gpt2.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = np.array(jax.random.randint(
+        jax.random.PRNGKey(1), (8, 65), 0, 256))
+    tokens[2, -9:] = -1
+    batch = {"tokens": jnp.asarray(tokens)}
+    mesh = spec.build(jax.devices()[:spec.num_devices])
+    rules = prune_rules_for_mesh(mesh)
+
+    def grads(scale):
+        return under_mesh(mesh, jax.jit(
+            jax.grad(lambda p, b: scale * gpt2.loss_fn(
+                p, b, cfg, rules, loss_chunk=100)),
+            in_shardings=(shardings_for(mesh, axes, rules), None)))(
+                params, batch)
+
+    _assert_leaves_close(grads(1.0), grads(3.0), scale=3.0)
+
+
+def test_gpt2_head_in_bfloat16_is_as_close_as_autodiff_was():
+    """``dx`` and ``d wte`` of the bfloat16 head at two chunks against the
+    float32 head's, by the rms of the difference over the rms of the
+    value. The parent (``jax.checkpoint`` autodiff of the chunk, PR 50's
+    tree, these inputs, this CPU) read 0.001656 and 0.002380. There
+    autodiff's float32 ``d logits`` entered the CPU's products unrounded,
+    where the MXU's default pass rounds it to bfloat16 as the head now
+    does itself before both products: ``dx`` reads 0.001979, and is given
+    1.25 x the parent's; ``d wte`` (0.002265: the chunks' products are
+    summed in float32 and rounded once) is held to the parent's. The loss
+    is the float32 head's to the bit: these inputs are bfloat16's own."""
+    from ray_tpu.models import gpt2
+
+    tokens, chunk, d, vocab = 1024, 512, 64, 512
+    kx, kw, kt = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(kx, (4, tokens // 4, d), jnp.bfloat16)
+    wte = (0.3 * jax.random.normal(kw, (vocab, d))).astype(jnp.bfloat16)
+    targets = jax.random.randint(kt, (4, tokens // 4), -1, vocab)
+
+    def head(x, wte):
+        nll, count = gpt2._ce_sums_local(x, targets, wte, chunk, (), (), ())
+        return nll / count
+
+    def grads(dtype):
+        loss, g = jax.jit(jax.value_and_grad(head, argnums=(0, 1)))(
+            x.astype(dtype), wte.astype(dtype))
+        assert all(a.dtype == dtype for a in g)
+        return float(loss), [np.asarray(a, np.float32) for a in g]
+
+    want_loss, want = grads(jnp.float32)
+    loss, got = grads(jnp.bfloat16)
+    assert loss == want_loss
+    limits = {"dx": 1.25 * 0.001656, "d wte": 0.002380}
+    for (name, limit), w, g in zip(limits.items(), want, got):
+        rms = float(np.sqrt(np.mean((g - w) ** 2) / np.mean(w ** 2)))
+        assert 0 < rms <= limit, (name, rms)
+
+
+def test_gpt2_head_under_a_mixture_of_experts():
+    """``jax.grad`` through ``loss_fn`` with ``num_experts > 0`` (the aux
+    loss joins after the head, and its gradient does not pass the head's
+    hand-written rule): loss and every leaf equal plain autodiff of the
+    same features through whole float32 logits and one un-chunked CE."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.models.common import cross_entropy_sums
+
+    cfg = gpt2.GPT2Config(
+        vocab_size=128, max_seq=32, num_layers=2, num_heads=2, d_model=32,
+        num_experts=4, dtype=jnp.float32, remat=False,
+        attention_impl="reference")
+    params, _ = gpt2.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = np.array(jax.random.randint(
+        jax.random.PRNGKey(1), (4, 33), 0, 128))
+    tokens[1, -5:] = -1
+    batch = {"tokens": jnp.asarray(tokens)}
+
+    def plain_loss(p):
+        x, aux = gpt2.forward_features(p, batch["tokens"][:, :-1], cfg)
+        nll, count = cross_entropy_sums(x @ p["wte"].T, batch["tokens"][:, 1:])
+        return nll / count + cfg.moe_aux_weight * aux / cfg.num_layers
+
+    want, want_grads = jax.jit(jax.value_and_grad(plain_loss))(params)
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda p: gpt2.loss_fn(p, batch, cfg, loss_chunk=50)))(params)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
+    _assert_leaves_close(want_grads, got_grads)

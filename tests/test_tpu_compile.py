@@ -808,7 +808,25 @@ def _vocab_collectives(text, vocab_dims):
 _STEPS: dict = {}
 
 
-def _gpt2_step(v5e, case, layers=2, packed=True):
+def _checkpointed_head(xc, tc, wte, vocab_axes):
+    """``gpt2._chunk_sums`` as it was before the head wrote its own
+    gradient (PR 52's parent): the chunk under ``jax.checkpoint``, its
+    gradient autodiff's."""
+    from ray_tpu.models.common import cross_entropy_sums
+
+    @jax.checkpoint
+    def chunk(carry, xt):
+        logits = jax.lax.dot_general(
+            xt[0], wte, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        nll, count = cross_entropy_sums(logits, xt[1], vocab_axis=vocab_axes)
+        return (carry[0] + nll, carry[1] + count), None
+
+    zero = jnp.zeros((), jnp.float32)
+    return jax.lax.scan(chunk, (zero, zero), (xc, tc))[0]
+
+
+def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True):
     """The gpt2 train step of ``HEAD_CASES[case]`` compiled for the
     described chips, as the training cells build it (``mem2``, the flash
     kernel, a float32 master); each compiled once a session. At two layers
@@ -816,8 +834,10 @@ def _gpt2_step(v5e, case, layers=2, packed=True):
     optimizer. ``packed=False``: the block as it was before its
     projections wrote the kernel's packed rows (PR 50's parent: a split, a
     reshape and a transpose of [b, s, 3d], still the code of every mesh
-    that shards the heads), for the same compiler to be asked about both."""
-    from contextlib import nullcontext
+    that shards the heads), for the same compiler to be asked about both;
+    ``own_gradient=False``: likewise the loss head of PR 52's parent
+    (``_checkpointed_head``)."""
+    from contextlib import ExitStack
     from unittest import mock
 
     import optax
@@ -826,8 +846,8 @@ def _gpt2_step(v5e, case, layers=2, packed=True):
     from ray_tpu.train.optim import adamw_lowmem
     from ray_tpu.train.step import build_sharded_train
 
-    if (case, layers, packed) in _STEPS:
-        return _STEPS[case, layers, packed]
+    if (case, layers, packed, own_gradient) in _STEPS:
+        return _STEPS[case, layers, packed, own_gradient]
     c = HEAD_CASES[case]
     mesh = MeshSpec(**c["mesh"]).build(v5e)
     cfg = gpt2.GPT2Config(
@@ -852,11 +872,16 @@ def _gpt2_step(v5e, case, layers=2, packed=True):
         init.out_info, init.compile().output_shardings)
     tokens = jax.ShapeDtypeStruct((c["batch"], 1025), jnp.int32,
                                   sharding=whole)
-    with nullcontext() if packed else mock.patch.object(
-            gpt2, "_packed_heads", lambda *a: 1):
+    with ExitStack() as patched:
+        if not packed:
+            patched.enter_context(mock.patch.object(
+                gpt2, "_packed_heads", lambda *a: 1))
+        if not own_gradient:
+            patched.enter_context(mock.patch.object(
+                gpt2, "_chunk_sums", _checkpointed_head))
         lowered = sstep.lower(*state, {"tokens": tokens})
-    _STEPS[case, layers, packed] = lowered.compile()
-    return _STEPS[case, layers, packed]
+    _STEPS[case, layers, packed, own_gradient] = lowered.compile()
+    return _STEPS[case, layers, packed, own_gradient]
 
 
 @pytest.mark.parametrize("case", ["fsdp4", "fsdp2_tp2"])
@@ -885,6 +910,62 @@ def test_lm_head_moves_no_logits_between_chips(v5e, case):
     # (under tp the step reads 0.16 MB over the parent's)
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= c["parent_temp"] + 2**20)
+
+
+def _head_products(text):
+    """(instruction, op_name) of every matrix product of a compiled step
+    that the benchmark's scope reader (``trace/program.py scope_of``)
+    gives to ``ce`` and that has a vocab-sized dimension, in an operand
+    or in its result. The text names an operand without its shape, so
+    shapes are looked up by the operand's name."""
+    from benchmark.trace.program import scope_of
+
+    shapes, found = {}, []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)"
+                     r"\(([^)]*)\)", line)
+        if not m:
+            continue
+        name, result, opcode, operands = m.groups()
+        shapes[name] = re.findall(r"\w+\[([\d,]*)\]", result)
+        if opcode not in ("convolution", "dot"):
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        dims = shapes[name] + [d for o in re.findall(r"%([\w.\-]+)", operands)
+                               for d in shapes.get(o, [])]
+        if scope_of(op_name)[0] == "ce" and any(
+                str(VOCAB) in d.split(",") for d in dims):
+            found.append((name, op_name))
+    return found
+
+
+@pytest.mark.parametrize("case", ["one_chip", "fsdp4"])
+def test_lm_head_multiplies_by_the_vocab_three_times_a_chunk(v5e, case):
+    """``gpt2-large`` on one chip and ``gpt2-xl`` under fsdp=4: the loss
+    head forms its gradient where it has the logits, so under ``ce`` the
+    chunk loop holds THREE products with a ``[chunk, vocab]`` operand or
+    result (the logits, ``dx = g @ wte``, ``d wte += g.T @ x``) and none
+    is a recomputation. The parent's head (the chunk under
+    ``jax.checkpoint``), compiled beside it at the cell's depth, holds
+    four, the logits twice; and the whole step needs no more temporaries
+    than that one plus what the head now keeps from its forward to its
+    backward pass, ``dx`` and ``d wte`` in the activations' dtype."""
+    c = HEAD_CASES[case]
+    products = _head_products(_gpt2_step(v5e, case).as_text())
+    assert len(products) == 3, products
+    assert not [p for p in products if "rematted_computation" in p[1]]
+
+    parent = _gpt2_step(v5e, case, c["depth"], own_gradient=False)
+    was = _head_products(parent.as_text())
+    assert len(was) == 4, was
+    assert len([p for p in was if "rematted_computation" in p[1]]) == 1
+    tokens = c["batch"] // c["mesh"].get("fsdp", 1) * 1024
+    kept = 2 * c["d"] * (tokens + VOCAB)  # bfloat16
+    temp, parent_temp = (
+        step.memory_analysis().temp_size_in_bytes
+        for step in (_gpt2_step(v5e, case, c["depth"]), parent))
+    assert temp <= parent_temp + kept, (temp, parent_temp, kept)
 
 
 # -- the train step's layer bodies: q, k, v and o between matmul and kernel ---
