@@ -1526,6 +1526,7 @@ def bench_llm_sessions(on_tpu: bool, smoke: bool = False) -> dict:
         ttfts_ms, toks = [], 0
         hits0, total0 = engine.prefix_hits, (engine.prefix_hits
                                              + engine.prefix_misses)
+        saved0 = engine.prefix_tokens_saved
         t_pass = _t.perf_counter()
         for turn in range(m_turns):
             for sess in range(n_sessions):
@@ -1549,6 +1550,7 @@ def bench_llm_sessions(on_tpu: bool, smoke: bool = False) -> dict:
             "tokens_per_s": round(toks / dt, 1),
             "hit_rate": round((engine.prefix_hits - hits0)
                               / max(total, 1), 3),
+            "tokens_saved": engine.prefix_tokens_saved - saved0,
         }
 
     try:
@@ -1569,6 +1571,8 @@ def bench_llm_sessions(on_tpu: bool, smoke: bool = False) -> dict:
             2),
         "prefix_hit_rate": warm["hit_rate"],
         "prefix_tokens_saved": engine.prefix_tokens_saved,
+        "prefix_tokens_saved_cold": cold["tokens_saved"],
+        "prefix_tokens_saved_warm": warm["tokens_saved"],
         "tokens_per_s_cold": cold["tokens_per_s"],
         "tokens_per_s_warm": warm["tokens_per_s"],
         "pages_total": engine.pages_total,

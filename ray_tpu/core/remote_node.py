@@ -50,6 +50,7 @@ class DaemonConn:
         self._on_disconnect = on_disconnect
         self._req_ids = itertools.count(1)
         self._pending: Dict[int, _Pending] = {}
+        self._lost = False  # under _lock: the reader has seen the end
         self._assembler = ChunkAssembler()
         self._lock = threading.Lock()
         # Events (worker messages etc.) dispatch on a separate thread so a
@@ -74,6 +75,10 @@ class DaemonConn:
         req_id = next(self._req_ids)
         p = _Pending()
         with self._lock:
+            # No reader is left to answer, and the first send to a peer
+            # that closed still succeeds: it would wait out its timeout.
+            if self._lost:
+                raise ConnectionError("node daemon connection lost")
             self._pending[req_id] = p
         for frame in build_msg(req_id):
             if not self._conn.send(frame):
@@ -119,6 +124,7 @@ class DaemonConn:
         # Fail outstanding RPCs, then run the node-death path (after any
         # queued events drain, so a final "done" isn't lost behind death).
         with self._lock:
+            self._lost = True
             pending = list(self._pending.values())
             self._pending.clear()
         for p in pending:

@@ -438,7 +438,9 @@ class Runtime:
                     if node is None:
                         raise ObjectLostError(oid, "holding node gone")
                     return self._store_read_bytes(node.store, oid)
-                except ObjectLostError:
+                except (ObjectLostError, ConnectionError):
+                    # ConnectionError: the holder's daemon died and its
+                    # disconnect is not processed yet; the copy is lost.
                     with self._lock:
                         entry.status = _ObjStatus.LOST
                         entry.location = None

@@ -343,6 +343,12 @@ class TrialRunner:
 
     def _poll_trial(self, trial: Trial) -> None:
         try:
+            if trial.done_ref is not None:
+                # What start() itself raised (a trainable the worker
+                # cannot import) ends the trial: its drain() would answer
+                # "nothing yet, not done" for good.
+                get(trial.done_ref, timeout=30)
+                trial.done_ref = None
             reports, done, error = get(trial.actor.drain.remote(), timeout=30)
         except Exception as e:  # actor died
             self._handle_failure(trial, str(e))

@@ -29,6 +29,11 @@ import re
 import sys
 import time
 
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
+
+import pytest  # noqa: E402
+
 PAGE = 16
 # name -> (configuration, slots, lane, 0 block | 1 decode_only)
 CASES = {
@@ -43,8 +48,6 @@ CASES = {
 
 
 def dump(root, out, only=None):
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    os.environ["JAX_PLATFORMS"] = "cpu"
     sys.path.insert(0, root)
     import jax
     import jax.numpy as jnp
@@ -56,11 +59,8 @@ def dump(root, out, only=None):
         os.path.realpath(root) + os.sep), ray_tpu.__file__
     from ray_tpu.llm.engine import HostInputs, build_step_programs
     from ray_tpu.models import granite, lfm2, llama, serving, solar
-    from ray_tpu.ops import (attention, delta_rule, grouped_matmul,
-                             paged_attention, ssm_scan)
 
-    for ops in (attention, paged_attention, grouped_matmul, delta_rule,
-                ssm_scan):
+    for ops in _kernel_modules():
         ops._on_tpu = lambda: True     # the kernels, not their references
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
@@ -116,6 +116,93 @@ def dump(root, out, only=None):
             f.write(text)
         print(f"{name}: {len(text)} bytes, {time.time() - started:.1f} s",
               flush=True)
+
+
+# -- what tests/test_tpu_compile*.py share: the described chip, the kernels'
+# compiled branch, and readers of a compiled program's text ------------------
+
+def _kernel_modules():
+    from ray_tpu.ops import (attention, delta_rule, grouped_matmul,
+                             paged_attention, ssm_scan)
+
+    return attention, paged_attention, grouped_matmul, delta_rule, ssm_scan
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def compiled_for_tpu(monkeypatch):
+    """Kernels take their compiled (not interpreted) branch, and the
+    persistent cache stays out of it: a program compiled for a described
+    chip is written there but cannot be read back without one."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for ops in _kernel_modules():
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled(fn, *specs) -> str:
+    """``fn`` compiled for the specs' (described) devices, as HLO text."""
+    import jax
+
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _kernels(fn, *specs) -> int:
+    """How many Mosaic kernels the compiled ``fn`` holds."""
+    return _compiled(fn, *specs).count("tpu_custom_call")
+
+
+def _kernel_shapes(text):
+    """Each Mosaic kernel of a compiled program as the benchmark's trace
+    reduction hands it on (``parse_op``: outputs and operands with their
+    shapes). A trace event spells the operands' shapes inside the call;
+    compiled text has them in ``operand_layout_constraints``."""
+    from benchmark.trace.reduce import parse_op
+
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        head, _, rest = line.strip().partition(" custom-call(")
+        operands = rest.partition("operand_layout_constraints={")[2]
+        out.append(parse_op(
+            f"{head} custom-call({operands.partition('}}')[0]}}})"))
+    return out
+
+
+_COLLECTIVE = re.compile(
+    r"= (.*?) (all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start)?\(")
+
+
+def _by_computation(text):
+    """(computation, line) for every instruction line of a compiled
+    program's text."""
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+        else:
+            yield name, line
 
 
 _TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")

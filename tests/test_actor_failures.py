@@ -17,7 +17,7 @@ def test_kill_actor(rt_init):
             return "pong"
 
     v = Victim.remote()
-    assert rt.get(v.ping.remote()) == "pong"
+    assert rt.get(v.ping.remote(), timeout=60) == "pong"
     rt.kill(v)
     with pytest.raises(rt.ActorError):
         rt.get(v.ping.remote(), timeout=15)
@@ -41,7 +41,7 @@ def test_actor_restart(rt_init):
             os._exit(1)
 
     p = Phoenix.remote()
-    assert rt.get(p.incr.remote()) == 1
+    assert rt.get(p.incr.remote(), timeout=60) == 1
     p.die.remote()
     # After restart state is fresh (recovered via user checkpointing if
     # needed, like the reference).
@@ -71,7 +71,7 @@ def test_actor_no_restart_fails_calls(rt_init):
             return "pong"
 
     m = Mortal.remote()
-    assert rt.get(m.ping.remote()) == "pong"
+    assert rt.get(m.ping.remote(), timeout=60) == "pong"
     m.die.remote()
     with pytest.raises(rt.ActorError):
         rt.get(m.ping.remote(), timeout=15)

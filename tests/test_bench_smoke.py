@@ -21,7 +21,7 @@ def test_bench_smoke_runs_all_stages():
     env["BENCH_SMOKE_FAST"] = "1"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--smoke"],
-        capture_output=True, text=True, timeout=400, env=env, cwd=REPO)
+        capture_output=True, text=True, timeout=280, env=env, cwd=REPO)
     line = next((ln for ln in proc.stdout.splitlines()
                  if ln.startswith("RESULT::")), None)
     assert line is not None, (
@@ -83,16 +83,17 @@ def test_bench_smoke_runs_all_stages():
     assert scrape["rt_serve_request_latency_count"] > 0, scrape
 
     # Paged-KV multi-turn sessions (ISSUE 15): warm turns must hit the
-    # radix prefix cache and beat cold TTFT. The full bench commits the
-    # >= 2x criterion; the smoke gate is deliberately looser (1.5x) so
-    # a loaded CI host can't flake it, while still catching a prefix
-    # cache that stopped caching (speedup ~1x, hit rate 0).
+    # radix prefix cache and prefill fewer tokens than cold ones. The
+    # smoke gate holds the stage to what a count shows, whatever the
+    # host's load; the warm/cold TTFT ratio (>= 2x) is the full bench's
+    # to commit.
     assert "llm_sessions_error" not in result, result
     sess = result["llm_sessions"]
     assert sess["prefix_hit_rate"] > 0, sess
     assert sess["ttft_cold_ms_p50"] > 0 and sess["ttft_warm_ms_p50"] > 0
-    assert sess["warm_ttft_speedup"] >= 1.5, sess
-    assert sess["prefix_tokens_saved"] > 0, sess
+    assert sess["prefix_tokens_saved_warm"] > 0, sess
+    assert sess["prefix_tokens_saved_cold"] == 0, sess
+    assert sess["prefix_tokens_saved"] == sess["prefix_tokens_saved_warm"]
 
     # Stateful-session chaos stage (ISSUE 19): drain mid-traffic AND
     # SIGKILL mid-generation — sessions migrate (KV page export/import)

@@ -51,7 +51,7 @@ def test_ref_passing_between_tasks(client):
     assert client.get(r2) == 84
 
 
-def test_wait(client):
+def test_wait(client, tmp_path):
     import time
 
     @client.remote
@@ -59,14 +59,25 @@ def test_wait(client):
         return 1
 
     @client.remote
-    def slow():
-        time.sleep(5)
+    def slow(release):
+        # Held by a file the test owns, not by a sleep raced against
+        # wait()'s timeout: pending until the test has seen wait() return.
+        deadline = time.monotonic() + 60
+        while not release.exists() and time.monotonic() < deadline:
+            time.sleep(0.02)
         return 2
 
-    refs = [slow.remote(), fast.remote()]
-    ready, pending = client.wait(refs, num_returns=1, timeout=4)
-    assert len(ready) == 1 and len(pending) == 1
-    assert client.get(ready[0]) == 1
+    release = tmp_path / "release"
+    # ``fast`` goes first: submitted behind ``slow`` it can be pipelined
+    # onto slow's worker and wait for it (why ``sleep(5)`` against
+    # ``timeout=4`` failed by turns).
+    fast_ref = fast.remote()
+    refs = [slow.remote(release), fast_ref]
+    ready, pending = client.wait(refs, num_returns=1, timeout=20)
+    release.touch()
+    assert ready == [refs[1]] and pending == [refs[0]]
+    assert client.get(ready[0], timeout=20) == 1
+    assert client.get(pending[0], timeout=20) == 2
 
 
 def test_actor_lifecycle(client):

@@ -23,6 +23,14 @@ from ray_tpu.ops.delta_rule import (delta_rule, delta_rule_reference,
 H, D = 8, 128
 LAYERS, SLOTS = 2, 5
 
+# The interpreted kernel and its references are traced and built once a
+# shape, layer, burst and head block (jit's own cache, the module's for
+# its life): cases that differ in the plan's values share the build.
+delta_rule = jax.jit(delta_rule, static_argnums=1,
+                     static_argnames=("interpret", "head_block", "burst"))
+delta_rule_reference = jax.jit(delta_rule_reference, static_argnums=1)
+recurrence = jax.jit(recurrence)
+
 
 def _inputs(seed, n, beta=None, decay=None, slots=SLOTS):
     """A state and n rows' q, k, v, g [n, H, D] and beta [n, H]."""
@@ -43,17 +51,37 @@ def _inputs(seed, n, beta=None, decay=None, slots=SLOTS):
 
 def _sequential(state, q, k, v, g, b):
     """The recurrence as the layer's equations write it, in float64 on
-    the host: S_t = (I - b k k^T) Diag(a) S_{t-1} + b k v^T; o = S^T q."""
+    the host: S_t = (I - b k k^T) Diag(a) S_{t-1} + b k v^T; o = S^T q.
+    With M = Diag(a) S_{t-1} that is M + b k (v - M^T k)^T, which is how
+    it is computed: a D x D product a token and head was most of the
+    file's seconds beside busy workers."""
     s = np.asarray(state, np.float64)
     q, k, v, g, b = (np.asarray(x, np.float64) for x in (q, k, v, g, b))
     out = np.zeros(v.shape)
     for t in range(q.shape[0]):
         for h in range(q.shape[1]):
             kk = k[t, h][:, None]
+            m = np.exp(g[t, h])[:, None] * s[h]
+            s[h] = m + b[t, h] * kk * (v[t, h] - (kk * m).sum(0))
+            out[t, h] = (s[h] * q[t, h][:, None]).sum(0)
+    return out, s
+
+
+def test_the_reference_is_the_equations_as_they_are_written():
+    """``_sequential``'s rank-one form against the D x D products of the
+    layer's equations, both float64, over a few tokens."""
+    state, q, k, v, g, b = (np.asarray(x, np.float64)
+                            for x in _inputs(11, 4, slots=1))
+    s = state[0, 0].copy()
+    for t in range(4):
+        for h in range(H):
+            kk = k[t, h][:, None]
             s[h] = (np.eye(D) - b[t, h] * kk @ kk.T) @ (
                 np.exp(g[t, h])[:, None] * s[h]) + b[t, h] * kk * v[t, h]
-            out[t, h] = s[h].T @ q[t, h]
-    return out, s
+    out, got = _sequential(state[0, 0], q, k, v, g, b)
+    np.testing.assert_allclose(got, s, rtol=0, atol=1e-12)
+    np.testing.assert_allclose(out[-1], np.einsum("hkv,hk->hv", s, q[-1]),
+                               rtol=0, atol=1e-12)
 
 
 def _plan(valid, chunk_at=None):
@@ -277,20 +305,22 @@ def test_a_state_carried_over_1024_steps_stays_on_the_references():
 
     # 1024 tokens as 15 calls of a 64-token chunk, then one-token calls
     # (a decode row) on the last 64 tokens
-    @jax.jit
-    def chunk(state, i):
-        sl = lambda x: jax.lax.dynamic_slice_in_dim(x, i * 64, 64)  # noqa: E731
-        return delta_rule(state, 0, lane, sl(q), sl(k), sl(v), sl(g), sl(b),
-                          interpret=True)
+    # (each traced and built once: the position is an argument)
+    def call(plan, n):
+        @jax.jit
+        def step(state, at):
+            sl = lambda x: jax.lax.dynamic_slice_in_dim(x, at, n)  # noqa: E731
+            return delta_rule(state, 0, plan, sl(q), sl(k), sl(v), sl(g),
+                              sl(b), interpret=True)
+        return step
 
+    chunk, token = call(lane, 64), call(row, 1)
     outs = []
     for i in range(steps // 64 - 1):
-        o, state = chunk(state, i)
+        o, state = chunk(state, i * 64)
         outs.append(o)
     for t in range(steps - 64, steps):
-        o, state = delta_rule(state, 0, row, q[t:t + 1], k[t:t + 1],
-                              v[t:t + 1], g[t:t + 1], b[t:t + 1],
-                              interpret=True)
+        o, state = token(state, t)
         outs.append(o)
     got = np.concatenate([np.asarray(o) for o in outs])
     want_o, want_s = _sequential(np.zeros((h, D, D)), q, k, v, g, b)

@@ -22,7 +22,7 @@ def test_daemon_event_stats_reach_head():
         def f(x):
             return x * 2
 
-        assert rt.get([f.remote(i) for i in range(8)]) == \
+        assert rt.get([f.remote(i) for i in range(8)], timeout=60) == \
             [2 * i for i in range(8)]
         rows = event_loop_stats(top=0)
         daemon_rows = [r for r in rows

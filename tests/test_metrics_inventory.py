@@ -56,9 +56,10 @@ def test_every_emitted_metric_is_documented(rt_init):
         def ping(self):
             return 1
 
-    assert rt.get([inv_task.remote(i) for i in range(4)]) == [1, 2, 3, 4]
+    assert rt.get([inv_task.remote(i) for i in range(4)],
+                  timeout=60) == [1, 2, 3, 4]
     a = InvActor.remote()
-    assert rt.get(a.ping.remote()) == 1
+    assert rt.get(a.ping.remote(), timeout=60) == 1
     # LLM family: a series only appears in the exposition once touched
     # — publish the page gauges the way an idle engine would.
     from ray_tpu.llm.paged import llm_metrics

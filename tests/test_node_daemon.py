@@ -186,3 +186,59 @@ def test_holder_daemon_killed_mid_pull_recovers_via_lineage():
         assert rt.get(out_ref, timeout=180) == n
     finally:
         cluster.shutdown()
+
+
+def test_a_holder_whose_connection_is_lost_is_a_lost_copy(rt_init,
+                                                          monkeypatch):
+    """The head's relay of a cross-host pull reads the holder's store
+    through its daemon; when that daemon was SIGKILLed and the head has
+    not yet processed the disconnect, the read raises ``ConnectionError``:
+    a lost copy, to be recomputed from lineage like any other, not the
+    consumer task's error (what failed
+    ``test_holder_daemon_killed_mid_pull_recovers_via_lineage`` by turns)."""
+    from ray_tpu.core.api import get_runtime
+
+    @rt_init.remote(max_retries=2)
+    def produce(n):
+        return np.ones(n, dtype=np.int64)
+
+    ref = produce.remote(1 << 18)
+    rt.wait([ref], timeout=60)
+    runtime = get_runtime()
+    real, calls = runtime._store_read_bytes, []
+
+    def dead_once(store, oid):
+        calls.append(oid)
+        if len(calls) == 1:
+            raise ConnectionError("node daemon connection lost")
+        return real(store, oid)
+
+    monkeypatch.setattr(runtime, "_store_read_bytes", dead_once)
+    frame = runtime._fetch_frame_blocking(ref.id, timeout=60)
+    assert len(calls) == 2
+    assert int(runtime.serializer.deserialize(frame).sum()) == 1 << 18
+
+
+def test_a_request_after_the_daemon_died_fails_at_once():
+    """A request sent after the daemon's death was seen (its pending
+    requests failed, the node not yet removed) is refused at once: the
+    first ``sendall`` to a peer that closed succeeds, so the request was
+    registered with no reader left to answer it and waited out its 60 s
+    (the consumer's ``TimeoutError: node daemon RPC timed out``)."""
+    import socket
+    import threading
+
+    from ray_tpu.core.node_protocol import FrameConn
+    from ray_tpu.core.remote_node import DaemonConn
+
+    with socket.create_server(("127.0.0.1", 0)) as server:  # TCP, as daemons
+        ours = socket.create_connection(server.getsockname())
+        theirs, _ = server.accept()
+    gone = threading.Event()
+    conn = DaemonConn(FrameConn(ours), lambda msg: None, gone.set)
+    theirs.close()  # the daemon, SIGKILLed
+    assert gone.wait(10)
+    t0 = time.monotonic()
+    with pytest.raises(ConnectionError):
+        conn.request(lambda req_id: [("store_get", req_id, b"x")], timeout=5)
+    assert time.monotonic() - t0 < 4

@@ -129,7 +129,7 @@ def test_bohb_end_to_end(rt_shared):
 
     scheduler, searcher = create_bohb(
         {"x": uniform(0, 1)}, metric="loss", mode="min", max_t=9,
-        grace_period=3, max_trials=24, seed=0)
+        grace_period=3, max_trials=12, seed=0)
     results = Tuner(
         objective, param_space=None,
         tune_config=TuneConfig(scheduler=scheduler, search_alg=searcher,

@@ -37,7 +37,7 @@ def test_blocked_worker_pipeline_no_starvation():
             if i == 0:
                 # Blocks in rt.get inside the worker until the gate
                 # opens — the head-of-line task of the pipelined lease.
-                assert rt.get(gate.wait.remote())
+                assert rt.get(gate.wait.remote(), timeout=90)
                 return -1
             return i
 
@@ -48,8 +48,8 @@ def test_blocked_worker_pipeline_no_starvation():
         assert len(done) == 3, (
             f"pipelined tasks starved behind blocked worker "
             f"({len(done)}/3 completed)")
-        assert sorted(rt.get(done)) == [1, 2, 3]
-        rt.get(gate.open.remote())
+        assert sorted(rt.get(done, timeout=30)) == [1, 2, 3]
+        rt.get(gate.open.remote(), timeout=30)
         assert rt.get(refs[0], timeout=30) == -1
     finally:
         rt.shutdown()

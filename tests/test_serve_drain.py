@@ -94,7 +94,7 @@ def test_drain_completes_streams_not_severed(serve_instance):
         drain_result.update(
             serve.drain("drainstream", timeout_s=30.0))
 
-    t = threading.Thread(target=do_drain)
+    t = threading.Thread(target=do_drain, daemon=True)
     t.start()
     got = list(it)  # must complete, not raise StreamInterruptedError
     t.join(timeout=60)
@@ -139,7 +139,7 @@ def test_drain_zero_dropped_requests(serve_instance):
             with lock:
                 errors.append(e)
 
-    threads = [threading.Thread(target=call) for _ in range(10)]
+    threads = [threading.Thread(target=call, daemon=True) for _ in range(10)]
     for th in threads:
         th.start()
     time.sleep(0.05)  # let a few land in flight

@@ -50,7 +50,7 @@ def _put_loop_victim(name: str, barrier) -> None:
     from ray_tpu._native import NativeStore
 
     store = NativeStore.attach(name)
-    barrier.wait()
+    barrier.wait(30)
     i = 0
     while True:
         key = b"victim" + i.to_bytes(14, "little")
@@ -119,7 +119,7 @@ def test_sigkill_during_put_loop(arena):
     barrier = _MP.Barrier(2)
     p = _MP.Process(target=_put_loop_victim, args=(name, barrier))
     p.start()
-    barrier.wait()
+    barrier.wait(30)
     import time
 
     for round_i in range(3):

@@ -24,6 +24,14 @@ from ray_tpu.ops.ssm_scan import (heads_view, recurrence, ssm_scan,
 G, N, W = 4, 128, 128          # 8 heads of 64
 LAYERS, SLOTS = 2, 5
 
+# The interpreted kernel and its references are traced and built once a
+# shape, layer, burst and blocks (jit's own cache, the module's for its
+# life): cases that differ in the plan's values share the build.
+ssm_scan = jax.jit(ssm_scan, static_argnums=1,
+                   static_argnames=("interpret", "blocks", "burst"))
+ssm_scan_reference = jax.jit(ssm_scan_reference, static_argnums=1)
+recurrence = jax.jit(recurrence)
+
 
 def _inputs(seed, r, decay=None, slots=SLOTS):
     """A state and r rows' x, a [r, G, W] and bc [r, 2, N]; ``a`` is one

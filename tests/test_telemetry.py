@@ -66,17 +66,21 @@ def test_runtime_metrics_visible_in_cluster_scrape(rt_shared):
 
     start_dashboard(port=18361)
     try:
-        # Worker latency series arrive on the flush interval (1s
-        # default); poll instead of assuming a single scrape is enough.
+        # Each worker ships its series on its own flush interval (1s
+        # default), so the eleven calls arrive in as many shipments as
+        # workers ran them: poll until every one has, not until the first.
         deadline = time.monotonic() + 20
         while True:
             rows = _samples(_scrape(18361))
-            lat = [(labels, v) for name, labels, v in rows
-                   if name == "rt_task_latency_seconds_count" and v > 0]
-            if any("node" in labels for labels, _ in lat):
+            arrived = [sum(v for name, labels, v in rows
+                           if name == series and "node" in labels
+                           and labels.get("state", "DONE") == "DONE")
+                       for series in ("rt_task_latency_seconds_count",
+                                      "rt_tasks_finished")]
+            if min(arrived) >= 11:
                 break
             assert time.monotonic() < deadline, \
-                f"no node-tagged latency series arrived; rows={rows[:40]}"
+                f"node-tagged series of 11 calls: {arrived}; rows={rows[:40]}"
             time.sleep(0.25)
 
         by_name = {}
@@ -87,14 +91,6 @@ def test_runtime_metrics_visible_in_cluster_scrape(rt_shared):
         assert submitted.get("task", 0) >= 8
         assert submitted.get("actor", 0) >= 3
         assert submitted.get("actor_creation", 0) >= 1
-        finished = by_name["rt_tasks_finished"]
-        done = [(labels, v) for labels, v in finished
-                if labels.get("state") == "DONE"]
-        assert done and any("node" in labels for labels, _ in done)
-        assert sum(v for _, v in done) >= 11
-        # Node-tagged worker latency histogram, nonzero and consistent.
-        total = sum(v for labels, v in lat if "node" in labels)
-        assert total >= 11
         # Cluster gauges refreshed at scrape time.
         assert by_name["rt_workers_alive"][0][1] >= 1
         assert by_name["rt_actors_alive"][0][1] >= 1

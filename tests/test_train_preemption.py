@@ -78,7 +78,7 @@ def test_trainer_survives_daemon_sigkill(rt_cluster, tmp_path):
     def run_fit():
         result_box["result"] = trainer.fit()
 
-    t = threading.Thread(target=run_fit)
+    t = threading.Thread(target=run_fit, daemon=True)
     t.start()
 
     # Wait until async checkpoints have landed on disk, then SIGKILL the

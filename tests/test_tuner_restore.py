@@ -76,7 +76,8 @@ def test_tuner_restore_after_driver_kill(tmp_path):
         f"EXP_ROOT = {exp_root!r}\n" + _DRIVER)
 
     env = dict(os.environ)
-    env["PYTHONPATH"] = f"{tmp_path}:/root/repo:" + env.get("PYTHONPATH", "")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = f"{tmp_path}:{repo}:" + env.get("PYTHONPATH", "")
     proc = subprocess.Popen([sys.executable, str(tmp_path / "driver.py")],
                             env=env, cwd=str(tmp_path))
     # load_state unpickles the trainable by module reference — make
@@ -113,7 +114,7 @@ def test_tuner_restore_after_driver_kill(tmp_path):
     finally:
         if proc.poll() is None:
             os.kill(proc.pid, signal.SIGKILL)
-            proc.wait()
+            proc.wait(timeout=30)
 
     finished_before = {t.trial_id for t in pre_state["trials"]
                        if t.status in (TrialStatus.TERMINATED,
@@ -126,8 +127,13 @@ def test_tuner_restore_after_driver_kill(tmp_path):
         from ray_tpu.tune import Tuner
 
         # Explicit CPUs: auto_init sizes to the machine (1 core on the
-        # bench box), which cannot host 2 concurrent trial actors.
-        rt.init(num_cpus=4, ignore_reinit_error=True)
+        # bench box), which cannot host 2 concurrent trial actors. And a
+        # runtime of its own: one left up by an earlier file has warm
+        # workers spawned before ``tmp_path`` joined ``sys.path``, which
+        # cannot import the trainable.
+        if rt.is_initialized():
+            rt.shutdown()
+        rt.init(num_cpus=4)
         assert Tuner.can_restore(exp_path)
         result = Tuner.restore(exp_path).fit()
     finally:
@@ -154,3 +160,38 @@ def test_tuner_restore_after_driver_kill(tmp_path):
         assert (starts_after.count(trial_id)
                 == starts_before.count(trial_id)), (
             f"finished trial {trial_id} was retrained after restore")
+
+
+def test_a_trial_that_cannot_start_ends_as_an_error(rt_init, tmp_path,
+                                                    monkeypatch):
+    """A trainable the warm workers cannot import (its module's directory
+    joined ``sys.path`` after they were spawned: what ``Tuner.restore``
+    met on a runtime an earlier test had left up) fails in the actor's
+    ``start``; the runner reads that and ends the trial. It polled
+    ``drain`` on a session that never began, and ``fit()`` did not return
+    (the driver's tier-1 run of PR 52: 1 472 s, cut)."""
+    import threading
+
+    rt = rt_init
+
+    @rt.remote
+    def warm():
+        time.sleep(0.2)
+
+    rt.get([warm.remote() for _ in range(4)], timeout=60)
+    (tmp_path / "late_module.py").write_text(
+        "def trainable(config):\n    pass\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    import late_module
+
+    from ray_tpu.tune import Tuner, grid_search
+
+    box = {}
+    fit = threading.Thread(daemon=True, target=lambda: box.update(
+        grid=Tuner(late_module.trainable,
+                   param_space={"x": grid_search([1, 2])}).fit()))
+    fit.start()
+    fit.join(timeout=120)
+    assert not fit.is_alive(), "fit() still polls trials that never began"
+    assert len(box["grid"].trials) == 2
+    assert all("late_module" in t.error for t in box["grid"].trials)

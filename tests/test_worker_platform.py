@@ -41,7 +41,7 @@ def _assert_inherited(out):
 
 def test_worker_backend_matches_driver(rt_init):
     probe = rt.remote(_probe_backend)
-    _assert_inherited(rt.get(probe.remote()))
+    _assert_inherited(rt.get(probe.remote(), timeout=60))
 
 
 def test_worker_backend_matches_driver_in_actor(rt_init):
@@ -51,4 +51,4 @@ def test_worker_backend_matches_driver_in_actor(rt_init):
             return _probe_backend()
 
     a = Probe.remote()
-    _assert_inherited(rt.get(a.backend.remote()))
+    _assert_inherited(rt.get(a.backend.remote(), timeout=60))
