@@ -94,7 +94,6 @@ def main():
         remat=True,
         remat_policy=os.environ.get(
             "BENCH_REMAT", "mem2" if on_tpu else "dots_attn"),
-        scan_unroll=int(os.environ.get("BENCH_UNROLL", "1")),
     )
 
     mesh = MeshSpec(dp=n_dev).build()

@@ -9,7 +9,11 @@ TPU design choices:
   - bf16 activations + matmuls with fp32 layernorm/softmax/loss
   - per-layer ``jax.checkpoint`` (remat) so 1.5B trains at seq 1024+
   - layers stacked into one scanned super-layer (single compile of the
-    block; XLA unrolls collectives per iteration)
+    block; XLA unrolls collectives per iteration); where the remat policy
+    saves the flash kernel's operands and results alone (``mem2``) the
+    loop's backward pass is written out too (``_blocks_saving``), so the
+    backward kernel reads what its layer saved where the forward loop
+    stacked it and no operand is copied out of a stack first
   - attention pluggable: flash (pallas), reference, ring (sp), ulysses (sp)
   - every activation/param annotated with logical axes for the
     dp/fsdp/tp/sp rule table (``parallel/sharding.py``)
@@ -25,7 +29,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention as attention_op, packed_heads_for
+from ..ops.attention import (attention as attention_op, attention_of_saved,
+                             attention_saving, packed_heads_for)
 from ..parallel.sharding import constrain, mesh_axes_for
 from .common import cross_entropy_terms, layer_norm, truncated_normal
 
@@ -46,10 +51,6 @@ class GPT2Config:
     # recomputes only cheap elementwise ops — the standard transformer
     # trade (much better MFU, modestly more memory); "none" disables.
     remat_policy: str = "dots"
-    scan_layers: bool = True
-    # Unrolling the layer scan trades compile time for per-iteration
-    # while-loop overhead (XLA sequencing + carry copies per step).
-    scan_unroll: int = 1
     sp_axis: str = "sp"
     # MoE (expert-parallel) FFN: >0 replaces every block's dense MLP with
     # a top-k routed mixture over ``num_experts`` experts sharded on the
@@ -270,7 +271,7 @@ def _packed_heads(cfg: GPT2Config, seq: int, rules) -> int:
     return packed_heads_for(cfg.head_dim, cfg.attention_impl, seq)
 
 
-def _packed_attention(y, p, cfg: GPT2Config, rules, n: int):
+def _packed_attention(y, p, cfg: GPT2Config, rules, n: int, attend=None):
     """The attention half of a block from the normed input to the output
     projection with q, k, v and o never in another layout than the flash
     kernel's: ``qkv_w`` [D, 3D] is read as [D, 3, rows, n * hd] and
@@ -283,7 +284,9 @@ def _packed_attention(y, p, cfg: GPT2Config, rules, n: int):
     gradient is cut off again. Three products and not one stacked: a
     stacked result is cut into thirds again, a copy a pass; the price is
     ``dy`` summed from three bfloat16 results (0.24 % rms from float32
-    where one ``K = 3D`` matmul reads 0.17 %, PERF.md Findings PR 50)."""
+    where one ``K = 3D`` matmul reads 0.17 %, PERF.md Findings PR 50).
+    ``attend``: ``(q, k, v) -> o`` in place of ``_attend``, for a loop
+    that keeps the kernel's operands itself (``_blocks_saving``)."""
     from jax.ad_checkpoint import checkpoint_name
 
     d, h, hd = cfg.d_model, cfg.num_heads, cfg.head_dim
@@ -302,16 +305,18 @@ def _packed_attention(y, p, cfg: GPT2Config, rules, n: int):
             jnp.einsum("bsd,drl->brsl", y, w[:, c])
             + bias[c][None, :, None, :], "qkv")
         for c in range(3))
-    o = _attend(q, k, v, cfg, rules, packed=True)
+    o = attend(q, k, v) if attend else _attend(q, k, v, cfg, rules,
+                                               packed=True)
     proj = jnp.pad(p["proj_w"].astype(o.dtype).reshape(h, hd, d),
                    fill + ((0, 0),)).reshape(rows, n * hd, d)
     return jnp.einsum("brsl,rld->bsd", o, proj)
 
 
-def _block(x, p, cfg: GPT2Config, rules):
+def _block(x, p, cfg: GPT2Config, rules, attend=None):
     """One transformer block. x: [B, S, D]; p: this layer's param slice.
     Returns (x, aux_loss) — aux is 0 for dense blocks, the router
-    load-balance loss for MoE blocks."""
+    load-balance loss for MoE blocks. ``attend``: see
+    ``_packed_attention``, the one layout that takes it."""
     b, s, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
@@ -321,7 +326,7 @@ def _block(x, p, cfg: GPT2Config, rules):
         y = layer_norm(x, p["ln1_scale"], p["ln1_bias"])
         n = _packed_heads(cfg, s, rules)
         if n > 1:
-            o = _packed_attention(y, p, cfg, rules, n)
+            o = _packed_attention(y, p, cfg, rules, n, attend)
         else:
             qkv = (y @ p["qkv_w"].astype(y.dtype)) \
                 + p["qkv_b"].astype(y.dtype)
@@ -376,6 +381,135 @@ def _embed_lookup(wte, tokens, rules):
     return lookup(wte, tokens)
 
 
+# What the flash kernel reads and writes, by the names ``_block`` and
+# ``ops/attention.py`` give them: q, k, v; o; the log-sum-exp.
+_KERNELS_OWN = ("qkv", "attn_out", "attn_lse")
+# The policies that save by name. "mem": the three big matmul outputs the
+# backward pass actually consumes (qkv feeds flash dq/dkv, attn_out feeds
+# proj bwd, pre-gelu mlp_in feeds gelu bwd); residual-branch outputs
+# (proj/mlp_out) are recomputed — one extra d×d matmul per block (~3% step
+# FLOPs) for ~25% less activation HBM. "mem2", the leanest: drop mlp_in too
+# (recomputed by re-running the mlp_in matmul in backward, ~+1/6 fwd matmul
+# FLOPs) — fits 774M at batch 8 / 1.5B at batch 2 on a 16GB chip.
+_SAVED_NAMES = {"mem": _KERNELS_OWN + ("mlp_in",), "mem2": _KERNELS_OWN}
+
+
+def _remat_block(cfg: GPT2Config, rules):
+    """``_block`` of ``cfg`` under ``jax.checkpoint`` with the policy
+    ``cfg.remat_policy`` names: ``(x, layer) -> (x, aux)``."""
+    block = partial(_block, cfg=cfg, rules=rules)
+    if not cfg.remat or cfg.remat_policy == "none":
+        return block
+    names = jax.checkpoint_policies.save_only_these_names
+    dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    policies = {
+        "dots": dots,
+        "dots_attn": jax.checkpoint_policies.save_from_both_policies(
+            dots, names("attn_out", "attn_lse")),
+        **{policy: names(*saved) for policy, saved in _SAVED_NAMES.items()},
+    }
+    # any other name ("full"): everything recomputed
+    return jax.checkpoint(block, policy=policies.get(cfg.remat_policy))
+
+
+def _owns_backward(cfg: GPT2Config, seq: int, rules) -> bool:
+    """Whether the layer loop's backward pass is ``_blocks_saving``'s and
+    not ``lax.scan``'s transpose: where what a layer saves is its input
+    and the flash kernel's operands and results, no more and no less, in
+    the layout the kernel takes them (packed rows: the kernel runs, heads
+    whole on every device). Every other policy saves or recomputes
+    something else, and the reference, ring, ulysses, a mesh that shards
+    the heads and the pipeline have no such kernel to hand a stack to. A
+    head that fills a row (head dim 128) keeps the checkpointed scan and
+    its four copies a layer too: the unpacked branch of ``_block`` takes
+    no ``attend`` (ROADMAP Queue A item 6(a))."""
+    return (cfg.remat and _SAVED_NAMES.get(cfg.remat_policy) == _KERNELS_OWN
+            and _packed_heads(cfg, seq, rules) > 1)
+
+
+def _blocks_saving(cfg: GPT2Config, rules):
+    """``(blocks, x) -> (x, aux)``, every block in turn, for
+    ``_owns_backward``'s configurations: one ``jax.custom_vjp`` of two
+    ``lax.scan``s over the SAME ``_block``, which differ in what stands
+    for attention alone.
+
+    Forward: ``attention_saving``, and the scan stacks each layer's input
+    and the ``(q, k, v, o, lse)`` its flash kernel read and wrote: what
+    ``jax.checkpoint`` under the policy saves. Backward: the layers in
+    reverse, the stacks loop constants and the layer number the scanned
+    value; a layer is ``jax.vjp`` of the block with
+    ``attention_of_saved``, whose o is read from the stack (the q, k, v
+    products it is handed are dead code, as under ``jax.checkpoint``) and
+    whose gradient is the backward kernel reading layer ``i`` of the
+    stacks in place.
+
+    ``lax.scan``'s own transpose hands the backward body SLICES of what
+    the forward stacked, and a Mosaic call takes no slice of a buffer as
+    an operand: XLA copied q, k, v and o out of their stacks in front of
+    every backward kernel, 13 ms of gpt2-large's 360 ms step (PERF.md
+    Findings PR 55). Saved, recomputed and summed are what the
+    checkpointed scan saves, recomputes and sums, in the same order: loss
+    and gradients are equal, not close (``tests/test_models.py``). Still
+    two ``while``s: a TPU program is straight-line code that only a loop
+    shares, and an unrolled one is an executable ``setup_s`` cannot pay
+    (ibid.)."""
+    from ..parallel.sharding import current_mesh, spec_for
+
+    where = dict(mesh=current_mesh(), head_dim=cfg.head_dim,
+                 spec=spec_for(("batch", "heads", None, None), rules))
+    block = partial(_block, cfg=cfg, rules=rules)
+
+    @jax.custom_vjp
+    def run(blocks, x):
+        return _scan_blocks(block, blocks, x)
+
+    def forward(blocks, x):
+        def body(carry, layer):
+            x, aux = carry
+            kept = []
+
+            def attend(q, k, v):
+                o, saved = attention_saving(q, k, v, **where)
+                kept.append(saved)
+                return o
+
+            y, a = block(x, layer, attend=attend)
+            (saved,) = kept
+            return (y, aux + a), (x, saved)
+
+        out, (xs, saved) = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), blocks)
+        return out, (blocks, xs, saved)
+
+    def backward(kept, cts):
+        (blocks, xs, saved), (dx, daux) = kept, cts
+
+        def body(dx, at):
+            layer, x, i = at
+            attend = partial(attention_of_saved, saved=saved, layer=i,
+                             **where)
+            _, pull = jax.vjp(partial(block, attend=attend), x, layer)
+            # every layer's aux was added into the sum: each gets daux
+            return pull((dx, daux))
+
+        layers = jnp.arange(xs.shape[0], dtype=jnp.int32)
+        dx, dblocks = jax.lax.scan(body, dx, (blocks, xs, layers),
+                                   reverse=True)
+        return dblocks, dx
+
+    run.defvjp(forward, backward)
+    return run
+
+
+def _scan_blocks(block, blocks, x):
+    def body(carry, layer):
+        x, aux = carry
+        x, a = block(x, layer)
+        return (x, aux + a), None
+
+    return jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), blocks)[0]
+
+
 def forward_features(params, tokens, cfg: GPT2Config, rules=None):
     """tokens [B, S] -> final hidden states [B, S, D] (pre LM head)."""
     b, s = tokens.shape
@@ -392,52 +526,10 @@ def forward_features(params, tokens, cfg: GPT2Config, rules=None):
     x = x.astype(cfg.dtype) + wpe[:s].astype(cfg.dtype)[None]
     x = constrain(x, ("batch", "seq", None), rules)
 
-    block = partial(_block, cfg=cfg, rules=rules)
-    if cfg.remat and cfg.remat_policy != "none":
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            block = jax.checkpoint(block, policy=policy)
-        elif cfg.remat_policy == "dots_attn":
-            policy = jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                jax.checkpoint_policies.save_only_these_names(
-                    "attn_out", "attn_lse"),
-            )
-            block = jax.checkpoint(block, policy=policy)
-        elif cfg.remat_policy == "mem":
-            # Save only the three big matmul outputs the backward pass
-            # actually consumes (qkv feeds flash dq/dkv, attn_out feeds
-            # proj bwd, pre-gelu mlp_in feeds gelu bwd). Residual-branch
-            # outputs (proj/mlp_out) are recomputed — one extra d×d matmul
-            # per block (~3% step FLOPs) for ~25% less activation HBM,
-            # which is what fits 774M at batch 8 on a 16GB chip.
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "qkv", "attn_out", "attn_lse", "mlp_in")
-            block = jax.checkpoint(block, policy=policy)
-        elif cfg.remat_policy == "mem2":
-            # Leanest: drop mlp_in too (recomputed by re-running the
-            # mlp_in matmul in backward, ~+1/6 fwd matmul FLOPs) —
-            # fits 774M at batch 8 / 1.5B at batch 2 on a 16GB chip.
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "qkv", "attn_out", "attn_lse")
-            block = jax.checkpoint(block, policy=policy)
-        else:
-            block = jax.checkpoint(block)
-
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.scan_layers:
-        def scan_body(carry, layer_params):
-            x, aux = carry
-            x, a = block(x, layer_params)
-            return (x, aux + a), None
-
-        (x, aux), _ = jax.lax.scan(scan_body, (x, aux), params["blocks"],
-                                   unroll=cfg.scan_unroll)
+    if _owns_backward(cfg, s, rules):
+        x, aux = _blocks_saving(cfg, rules)(params["blocks"], x)
     else:
-        for i in range(cfg.num_layers):
-            layer = jax.tree.map(lambda a: a[i], params["blocks"])
-            x, a = block(x, layer)
-            aux = aux + a
+        x, aux = _scan_blocks(_remat_block(cfg, rules), params["blocks"], x)
 
     x = layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     return x, aux

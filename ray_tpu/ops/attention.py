@@ -332,11 +332,14 @@ def _compiler_params(interpret: bool):
     return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _one_diagonal_tile(causal, static, block_q, block_k):
-    """The rolled causal walk wants ``block_q`` to divide ``block_k``."""
+def _schedule(causal, sq, sk, block_q, block_k):
+    """``(static, block_q)`` a kernel asked for ``block_q`` runs at: the
+    unrolled schedule where ``_is_static``, else the rolled causal walk,
+    which wants ``block_q`` to divide ``block_k``."""
+    static = _is_static(causal, sq, sk, block_q)
     if causal and not static and block_k % block_q != 0:
-        return block_k
-    return block_q
+        block_q = block_k
+    return static, block_q
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
@@ -349,8 +352,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    static = _is_static(causal, sq, sk, block_q)
-    block_q = _one_diagonal_tile(causal, static, block_q, block_k)
+    static, block_q = _schedule(causal, sq, sk, block_q, block_k)
     esize = q.dtype.itemsize
     # whole-sequence q, o, k, v and a head's lse column (a [sq, 1] float32
     # block is padded to 128 lanes in VMEM); x2 for double-buffering.
@@ -470,20 +472,48 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     jax.lax.fori_loop(0, num_heads, head_body, 0)
 
 
+def _layer_of(stack, layer):
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def _flash_bwd_of_layer_kernel(layer_ref, *refs, **static):
+    """``_flash_bwd_fused_kernel`` behind a prefetched layer number: the
+    block index maps have read it, the body has no use for it."""
+    del layer_ref
+    _flash_bwd_fused_kernel(*refs, **static)
+
+
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
-                      block_q, block_k, interpret, heads_a_row: int = 1):
+                      block_q, block_k, interpret, heads_a_row: int = 1,
+                      layer=None):
     """ONE pallas call, (q, k, v, do, lse, delta) -> (dq, dk, dv); the
     blocks must divide the sequences. Rows as in ``_flash_fwd_pallas``;
-    lse is ``[b, heads, sq]``."""
+    lse is ``[b, heads, sq]``.
+
+    ``layer`` (an int32 scalar): q, k, v, o and lse are what a loop over
+    layers saved, stacked ``[layers, ...]`` (lse as ``_lse_rows`` lays it
+    out), and the kernel reads layer ``layer`` of q, k, v and lse where it
+    lies in its stack, the layer a prefetched scalar that the block index
+    maps read. A Mosaic call takes no slice of a buffer as an operand, so
+    a slice in front of it is a copy of each a layer (67-69 us each at the
+    train cells' shapes, PERF.md Findings PR 55). The kernel is the same
+    kernel on the same blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    static = _is_static(causal, sq, sk, block_q)
-    block_q = _one_diagonal_tile(causal, static, block_q, block_k)
+    stacked = layer is not None
+    b, h, sq, d = q.shape[-4:]
+    sk = k.shape[-2]
+    static, block_q = _schedule(causal, sq, sk, block_q, block_k)
     # a row of block_q lanes a query block and head: see the kernel
     per_block = (b, h * heads_a_row, sq // block_q, block_q)
+    if stacked:
+        if lse.shape[1:] != per_block:  # a caller's own stack, mis-tiled
+            raise ValueError(f"a stacked lse wants ``_lse_rows``' layout "
+                             f"{per_block} a layer, not {lse.shape[1:]}")
+        o = _layer_of(o, layer)
+    else:
+        lse = lse.reshape(per_block)
     delta = do.astype(jnp.float32) * o.astype(jnp.float32)
     if heads_a_row == 1:
         delta = jnp.sum(delta, axis=-1)
@@ -498,26 +528,39 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
     per_head = 5 * (7 * sq * d * esize + 8 * sq) + 8 * sk * d
     hb = _pick_head_block(h, per_head)
 
-    full_q = pl.BlockSpec((1, hb, sq, d), lambda i, g: (i, g, 0, 0))
-    full_k = pl.BlockSpec((1, hb, sk, d), lambda i, g: (i, g, 0, 0))
-    q_rows = pl.BlockSpec((1, hb * heads_a_row) + per_block[2:],
-                          lambda i, g: (i, g, 0, 0))
+    def spec(*block, saved=False):
+        """A block of the call's own operand, or (``saved``) of layer
+        ``layer`` of a stack."""
+        if stacked and saved:
+            return pl.BlockSpec((None,) + block,
+                                lambda i, g, at: (at[0], i, g, 0, 0))
+        return pl.BlockSpec(block, lambda i, g, *_: (i, g, 0, 0))
+
+    q_rows = (1, hb * heads_a_row) + per_block[2:]
+    full_q, full_k = spec(1, hb, sq, d), spec(1, hb, sk, d)
+    saved_q = spec(1, hb, sq, d, saved=True)
+    saved_k = spec(1, hb, sk, d, saved=True)
     return pl.pallas_call(
-        functools.partial(_flash_bwd_fused_kernel, block_q=block_q,
-                          block_k=block_k, seq_q=sq, seq_k=sk, scale=scale,
-                          causal=causal, static=static, num_heads=hb,
-                          heads_a_row=heads_a_row),
-        grid=(b, h // hb),
-        in_specs=[full_q, full_k, full_k, full_q, q_rows, q_rows],
-        out_specs=[full_q, full_k, full_k],
+        functools.partial(
+            _flash_bwd_of_layer_kernel if stacked
+            else _flash_bwd_fused_kernel, block_q=block_q, block_k=block_k,
+            seq_q=sq, seq_k=sk, scale=scale, causal=causal, static=static,
+            num_heads=hb, heads_a_row=heads_a_row),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(stacked),
+            grid=(b, h // hb),
+            in_specs=[saved_q, saved_k, saved_k, full_q,
+                      spec(*q_rows, saved=True), spec(*q_rows)],
+            out_specs=[full_q, full_k, full_k],
+            scratch_shapes=[pltpu.VMEM((sk, d), jnp.float32),
+                            pltpu.VMEM((sk, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((sk, d), jnp.float32),
-                        pltpu.VMEM((sk, d), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
-    )(q, k, v, do, lse.reshape(per_block), delta.reshape(per_block))
+    )(*([jnp.reshape(layer, (1,)).astype(jnp.int32)] if stacked else []),
+      q, k, v, do, lse, delta.reshape(per_block))
 
 
 # ---------------------------------------------------------------------------
@@ -538,15 +581,19 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, heads_a_row=1):
 _BWD_STATIC_BLOCK_Q = 256
 
 
-def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-         heads_a_row):
+def _bwd_block_q(causal, sq: int, sk: int, block_q: int) -> int:
     want = _BWD_STATIC_BLOCK_Q
-    if block_q % want == 0 and _is_static(causal, q.shape[2], k.shape[2],
-                                          want):
-        block_q = want
+    if block_q % want == 0 and _is_static(causal, sq, sk, want):
+        return want
+    return block_q
+
+
+def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+         heads_a_row, layer=None):
+    block_q = _bwd_block_q(causal, q.shape[-2], k.shape[-2], block_q)
     return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q,
                              block_k, interpret=not _on_tpu(),
-                             heads_a_row=heads_a_row)
+                             heads_a_row=heads_a_row, layer=layer)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -575,6 +622,39 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, heads_a_row, res, do):
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _lse_rows(lse, causal, sq, sk, block_q, block_k):
+    """lse ``[b, heads, sq]`` as ``_bwd``'s kernel takes it, a row of
+    lanes a query block: ``_bwd``'s block, at ``_flash_bwd_pallas``'s
+    ``_schedule``."""
+    _, block_q = _schedule(causal, sq, sk,
+                           _bwd_block_q(causal, sq, sk, block_q), block_k)
+    return lse.reshape(lse.shape[:2] + (sq // block_q, block_q))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_of_saved(q, k, v, saved, layer, causal, scale, block_q, block_k,
+                    heads_a_row):
+    """``_flash`` of layer ``layer`` of a loop whose forward pass kept
+    ``saved``, each layer's ``(q, k, v, o, lse rows)`` stacked: o as saved,
+    so q, k and v are not read and whatever made them is dead, and the
+    backward kernel reads its layer of the stacks by index."""
+    return _layer_of(saved[3], layer)
+
+
+def _flash_of_saved_fwd(q, k, v, saved, layer, *static):
+    return _layer_of(saved[3], layer), (saved, layer)
+
+
+def _flash_of_saved_bwd(causal, scale, block_q, block_k, heads_a_row, res,
+                        do):
+    saved, layer = res
+    return (*_bwd(*saved, do, causal, scale, block_q, block_k, heads_a_row,
+                  layer=layer), None, None)
+
+
+_flash_of_saved.defvjp(_flash_of_saved_fwd, _flash_of_saved_bwd)
 
 
 def _tileable(sq: int, sk: int, causal: bool, block_q: int, block_k: int):
@@ -630,11 +710,20 @@ def flash_attention(q, k, v, causal: bool = True,
     The Pallas kernel: compiled on TPU, interpreted (same code path) in
     CPU tests. Raises ``ValueError`` for a shape the kernel cannot tile.
     """
+    return _flash(q, k, v, *_static_arguments(q, k, causal, scale, head_dim,
+                                              block_q, block_k))
+
+
+def _static_arguments(q, k, causal, scale, head_dim, block_q=512,
+                      block_k=512):
+    """``(causal, scale, block_q, block_k, heads a row)`` as ``_flash``
+    and ``_flash_of_saved`` take them, or ``ValueError`` for sequences the
+    kernels cannot tile."""
     head_dim = head_dim or q.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(head_dim)
     bq, bk = _blocks_or_raise(q, k, causal, block_q, block_k)
-    return _flash(q, k, v, causal, scale, bq, bk, q.shape[-1] // head_dim)
+    return causal, scale, bq, bk, q.shape[-1] // head_dim
 
 
 def attention(q, k, v, causal: bool = True, impl: str = "auto",
@@ -663,13 +752,55 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto",
         return mha_reference(q, k, v, causal=causal, scale=scale)
     fn = functools.partial(flash_attention, causal=causal, scale=scale,
                            head_dim=head_dim)
+    return _per_shard(fn, mesh, (spec, spec, spec), spec)(q, k, v)
+
+
+def _per_shard(fn, mesh, in_specs, out_specs):
+    """``fn`` on each device's shard where a program spans several."""
     # Not where the caller is itself a shard_map body (the pp pipeline's
     # stages): there the mesh axes are manual already.
     if (mesh is not None and mesh.size > 1
             and not jax.sharding.get_abstract_mesh().manual_axes):
-        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-    return fn(q, k, v)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+    return fn
+
+
+def attention_saving(q, k, v, causal: bool = True,
+                     scale: Optional[float] = None, mesh=None, spec=None,
+                     head_dim: Optional[int] = None):
+    """The flash kernel's forward pass for a loop over layers that owns
+    its backward pass: ``(o, saved)``, arguments as :func:`attention`
+    under ``impl="flash"``. The loop stacks each layer's ``saved``
+    ``[layers, ...]`` and hands the stacks to :func:`attention_of_saved`
+    where it differentiates the layer."""
+    static = _static_arguments(q, k, causal, scale, head_dim)
+    _, _, bq, bk, _ = static
+
+    def fn(q, k, v):
+        o, lse = _fwd(q, k, v, *static)
+        return o, _lse_rows(lse, causal, q.shape[2], k.shape[2], bq, bk)
+
+    o, lse = _per_shard(fn, mesh, (spec,) * 3, (spec, spec))(q, k, v)
+    return o, (q, k, v, o, lse)
+
+
+def attention_of_saved(q, k, v, saved, layer, causal: bool = True,
+                       scale: Optional[float] = None, mesh=None, spec=None,
+                       head_dim: Optional[int] = None):
+    """Attention of layer ``layer`` (an int32 scalar) where the forward
+    pass has run already and ``saved`` holds every layer's
+    :func:`attention_saving` ``saved``, stacked: o is read from there, and
+    the gradient reaches q, k and v from the backward kernel, which reads
+    its operands in the stacks by index. q, k and v give their shape
+    alone."""
+    from jax.sharding import PartitionSpec as P
+
+    static = _static_arguments(q, k, causal, scale, head_dim)
+    stack = None if spec is None else P(None, *spec)
+    return _per_shard(
+        lambda *traced: _flash_of_saved(*traced, *static), mesh,
+        (spec,) * 3 + ((stack,) * 5, P()), spec)(q, k, v, saved, layer)
 
 
 def attention_with_lse(q, k, v, causal: bool = True,

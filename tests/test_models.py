@@ -1,5 +1,7 @@
 """Model tests: shapes, loss decrease, llama decode-vs-forward parity."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,3 +158,189 @@ def test_llama_rope_lanes_is_rope_bit_for_bit(heads, t, dtype):
         np.asarray(jax.jit(lanes)(x, positions).astype(jnp.float32)),
         np.asarray(jax.jit(strided)(x, positions).astype(jnp.float32)),
         rtol=ulp, atol=ulp)
+
+
+# -- gpt2's layer loop where it owns its backward pass --------------------------
+
+def _checkpointed_scan(cfg, rules):
+    """``gpt2._blocks_saving``'s twin as the layer loop was before it:
+    ``jax.checkpoint`` of the block under the ``mem2`` policy's names and
+    ``lax.scan``, differentiated by JAX."""
+    from functools import partial
+
+    from ray_tpu.models import gpt2
+
+    block = jax.checkpoint(
+        partial(gpt2._block, cfg=cfg, rules=rules),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            "qkv", "attn_out", "attn_lse"))
+
+    def run(blocks, x):
+        def body(carry, layer):
+            x, a = block(carry[0], layer)
+            return (x, carry[1] + a), None
+
+        return jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                            blocks)[0]
+
+    return run
+
+
+def _gpt2_loss_and_grads(cfg, params, axes, batch, mesh):
+    """Loss and gradients of ``gpt2.loss_fn`` on one device (``mesh`` None)
+    or with the parameters placed on ``mesh`` by the model's rules."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.sharding import (prune_rules_for_mesh,
+                                           shardings_for, under_mesh)
+
+    if mesh is None:
+        return jax.jit(jax.value_and_grad(
+            lambda p, b: gpt2.loss_fn(p, b, cfg)))(params, batch)
+    rules = prune_rules_for_mesh(mesh)
+    return under_mesh(mesh, jax.jit(
+        jax.value_and_grad(lambda p, b: gpt2.loss_fn(p, b, cfg, rules)),
+        in_shardings=(shardings_for(mesh, axes, rules), None)))(params, batch)
+
+
+@pytest.mark.parametrize("mesh", ["one_device", "fsdp2"])
+@pytest.mark.parametrize("kind", ["dense", "experts2", "dense_5_heads"])
+@pytest.mark.parametrize("layers", [2, 3])
+def test_gpt2_owned_backward_is_the_checkpointed_scans(layers, kind, mesh):
+    """Loss and every gradient leaf of ``gpt2.loss_fn`` under ``mem2``
+    with the flash kernel (``_blocks_saving``: the forward scan stacks each
+    layer's input and kernel operands, the backward scan hands the kernel
+    the stacks and a layer number) against ``jax.checkpoint`` and
+    ``lax.scan`` over the same block differentiated by JAX, float32. With
+    two experts the router's ``aux`` is summed through both scans and its
+    cotangent reaches every layer; 5 heads: a zero head fills the last
+    packed row; under fsdp=2 the kernels run a shard each and the stacks
+    are split by batch. The same sums in the same order: not round-off
+    apart but equal."""
+    from unittest import mock
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.mesh import MeshSpec
+
+    heads = 5 if kind == "dense_5_heads" else 4
+    cfg = gpt2.GPT2Config(
+        vocab_size=128, max_seq=128, num_layers=layers, num_heads=heads,
+        d_model=64 * heads, dtype=jnp.float32, attention_impl="flash",
+        remat=True, remat_policy="mem2",
+        num_experts=2 if kind == "experts2" else 0)
+    params, axes = gpt2.init_params(jax.random.PRNGKey(layers), cfg)
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(7), (2, 129), 0, cfg.vocab_size)}
+    mesh = None if mesh == "one_device" \
+        else MeshSpec(fsdp=2).build(jax.devices()[:2])
+    with mock.patch.object(gpt2, "_blocks_saving",
+                           wraps=gpt2._blocks_saving) as owned:
+        loss, grads = _gpt2_loss_and_grads(cfg, params, axes, batch, mesh)
+    assert owned.call_count == 1
+    with mock.patch.object(gpt2, "_blocks_saving", _checkpointed_scan):
+        want, want_grads = _gpt2_loss_and_grads(cfg, params, axes, batch,
+                                                mesh)
+    assert float(loss) == float(want)
+    assert jax.tree.structure(grads) == jax.tree.structure(params)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want_grads), strict=True):
+        assert g.shape == w.shape and g.dtype == w.dtype, path
+        assert float(jnp.abs(w).max()) > 0, path
+        np.testing.assert_array_equal(
+            g, w, err_msg=jax.tree_util.keystr(path))
+
+
+# what keeps ``jax.checkpoint`` under ``lax.scan``: (configuration, mesh)
+NOT_OWNED = {
+    **{policy: (dict(remat_policy=policy), {})
+       for policy in ("dots", "dots_attn", "mem", "full")},
+    "none": (dict(remat_policy="none"), {}),
+    "no_remat": (dict(remat=False), {}),
+    "reference": (dict(attention_impl="reference"), {}),
+    "auto_off_the_tpu": (dict(attention_impl="auto"), {}),
+    "ring": (dict(attention_impl="ring"), dict(sp=2)),
+    "ulysses": (dict(attention_impl="ulysses"), dict(sp=2)),
+    "tp": ({}, dict(tp=2)),
+    "fsdp_tp": ({}, dict(fsdp=2, tp=2)),
+}
+
+
+@pytest.mark.parametrize("setting", list(NOT_OWNED))
+def test_gpt2_owned_backward_is_mem2s_over_packed_rows_alone(setting):
+    """Every other policy saves or recomputes something else than the
+    kernel's operands, and the reference, ring, ulysses and a mesh that
+    shards the heads hand no kernel packed rows: each keeps
+    ``jax.checkpoint`` under ``lax.scan``, and differentiates."""
+    from unittest import mock
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.parallel.sharding import prune_rules_for_mesh, under_mesh
+
+    changed, axes = NOT_OWNED[setting]
+    cfg = dataclasses.replace(gpt2.GPT2Config(
+        vocab_size=128, max_seq=128, num_layers=2, num_heads=4, d_model=256,
+        dtype=jnp.float32, attention_impl="flash", remat=True,
+        remat_policy="mem2"), **changed)
+    spec = MeshSpec(**axes)
+    mesh = spec.build(jax.devices()[:spec.num_devices])
+    rules = prune_rules_for_mesh(mesh)
+    params, _ = gpt2.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0, 128)
+    with mock.patch.object(gpt2, "_blocks_saving") as owned:
+        grads = under_mesh(mesh, jax.eval_shape)(
+            jax.grad(lambda p: gpt2.forward_features(
+                p, tokens, cfg, rules)[0].sum()), params)
+    assert owned.call_count == 0
+    assert jax.tree.structure(grads) == jax.tree.structure(params)
+    # ... and the configuration these were changed from does take it
+    owned_cfg = dataclasses.replace(cfg, **{k: getattr(
+        gpt2.GPT2Config(attention_impl="flash", remat_policy="mem2"), k)
+        for k in changed})
+    assert gpt2._owns_backward(owned_cfg, 128, None)
+    assert not under_mesh(mesh, gpt2._owns_backward)(cfg, 128, rules)
+
+
+def test_gpt2_keeps_its_stacked_parameters_shardings_and_checkpoint(
+        tmp_path):
+    """The loop that owns its backward pass, over two devices (the kernels
+    a shard each, the saved stacks split by batch): what is stored,
+    sharded, updated and saved is ``[layers, ...]`` a leaf, under the
+    ``layers`` logical axis, as before."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.train.checkpoint import restore_arrays, save_arrays
+    from ray_tpu.train.step import build_sharded_train
+
+    cfg = gpt2.GPT2Config(
+        vocab_size=128, max_seq=32, num_layers=3, num_heads=2, d_model=32,
+        dtype=jnp.float32, attention_impl="flash", remat_policy="mem2")
+    mesh = MeshSpec(fsdp=2).build(jax.devices()[:2])
+    sinit, sstep, _ = build_sharded_train(
+        lambda key: gpt2.init_params(key, cfg),
+        lambda p, b: gpt2.loss_fn(p, b, cfg), mesh)
+    params, opt_state, step = sinit(jax.random.PRNGKey(0))
+    shapes = {k: v.shape for k, v in params["blocks"].items()}
+    assert shapes == {
+        "ln1_scale": (3, 32), "ln1_bias": (3, 32), "qkv_w": (3, 32, 96),
+        "qkv_b": (3, 96), "proj_w": (3, 32, 32), "proj_b": (3, 32),
+        "ln2_scale": (3, 32), "ln2_bias": (3, 32), "mlp_in_w": (3, 32, 128),
+        "mlp_in_b": (3, 128), "mlp_out_w": (3, 128, 32),
+        "mlp_out_b": (3, 32)}
+    specs = {k: tuple(v.sharding.spec) + (None,) * (
+        v.ndim - len(v.sharding.spec)) for k, v in params["blocks"].items()}
+    assert specs["qkv_w"] == (None, "fsdp", None), specs
+    assert specs["mlp_out_w"] == (None, None, "fsdp"), specs
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 128)
+    stepped, opt_state, step, m = sstep(params, opt_state, step,
+                                        {"tokens": tokens})
+    assert np.isfinite(float(m["loss"]))
+    assert jax.tree.structure(stepped) == jax.tree.structure(params)
+    for old, new in zip(jax.tree.leaves(params), jax.tree.leaves(stepped)):
+        assert (new.shape, new.dtype, new.sharding.spec) == (
+            old.shape, old.dtype, old.sharding.spec)
+    save_arrays(str(tmp_path / "ckpt"), stepped)
+    restored = restore_arrays(str(tmp_path / "ckpt"))
+    assert jax.tree.structure(restored) == jax.tree.structure(stepped)
+    for saved, back in zip(jax.tree.leaves(stepped),
+                           jax.tree.leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(saved), np.asarray(back))

@@ -116,6 +116,40 @@ def test_dispatch_takes_packed_rows_where_the_kernel_runs_and_nowhere_else():
     assert A.packed_heads_for(96, "flash", 128) == 1
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", ["even", "odd25", "rolled"])
+def test_backward_by_layer_index_is_the_kernels_on_that_layer(shape, causal):
+    """A loop over layers that owns its backward pass: ``attention_saving``
+    is the forward kernel, and ``attention_of_saved`` hands back layer
+    ``i``'s o and, differentiated, the backward kernel's dq, dk, dv read
+    from the ``[layers, ...]`` stacks at ``i`` (a traced index): for the
+    first, the middle and the last of three layers bit for bit what
+    ``attention`` gives on that layer's q, k, v alone. The q, k, v it is
+    called with are not read."""
+    heads, seq, hd, _ = SHAPES[shape]
+    layers = [[_rows(t, hd) for t in _qkv(heads, seq, hd, seed=s)]
+              for s in (3, 4, 5)]
+    kept = [A.attention_saving(q, k, v, causal=causal, head_dim=hd)
+            for q, k, v, _ in layers]
+    saved = jax.tree.map(lambda *x: jnp.stack(x), *[s for _, s in kept])
+
+    @jax.jit
+    def of_saved(i, do):
+        unread = jnp.zeros_like(layers[0][0])
+        o, pull = jax.vjp(lambda q, k, v: A.attention_of_saved(
+            q, k, v, saved, i, causal=causal, head_dim=hd),
+            unread, unread, unread)
+        return o, pull(do)
+
+    for i, ((q, k, v, do), (o, _)) in enumerate(zip(layers, kept)):
+        want, pull = jax.vjp(lambda q, k, v: A.attention(
+            q, k, v, causal=causal, impl="flash", head_dim=hd), q, k, v)
+        got, grads = of_saved(jnp.int32(i), do)
+        assert _gap(o, want) == 0.0 and _gap(got, want) == 0.0
+        for g, w in zip(grads, pull(do), strict=True):
+            assert _gap(g, w) == 0.0
+
+
 # -- the gpt2 block -----------------------------------------------------------
 
 def _tiny(heads, **kw):

@@ -3,6 +3,7 @@ a mesh and the layer bodies between matmul and kernel, as
 ``tests/test_tpu_compile.py`` says of the kernels (a described ``v5e:2x2``,
 shapes and not arrays; nothing runs, and a pass is not a chip run)."""
 
+import collections
 import math
 import re
 
@@ -84,7 +85,8 @@ def _checkpointed_head(xc, tc, wte, vocab_axes):
     return jax.lax.scan(chunk, (zero, zero), (xc, tc))[0]
 
 
-def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True):
+def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True,
+               owned=True):
     """The gpt2 train step of ``HEAD_CASES[case]`` compiled for the
     described chips, as the training cells build it (``mem2``, the flash
     kernel, a float32 master); each compiled once a session. At two layers
@@ -94,7 +96,9 @@ def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True):
     reshape and a transpose of [b, s, 3d], still the code of every mesh
     that shards the heads), for the same compiler to be asked about both;
     ``own_gradient=False``: likewise the loss head of PR 52's parent
-    (``_checkpointed_head``)."""
+    (``_checkpointed_head``); ``owned=False``: likewise the layer loop of
+    PR 55's parent, ``jax.checkpoint`` under ``lax.scan`` differentiated
+    by JAX (still the loop of every policy but ``mem2``)."""
     from contextlib import ExitStack
     from unittest import mock
 
@@ -104,8 +108,9 @@ def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True):
     from ray_tpu.train.optim import adamw_lowmem
     from ray_tpu.train.step import build_sharded_train
 
-    if (case, layers, packed, own_gradient) in _STEPS:
-        return _STEPS[case, layers, packed, own_gradient]
+    key = (case, layers, packed, own_gradient, owned)
+    if key in _STEPS:
+        return _STEPS[key]
     c = HEAD_CASES[case]
     mesh = MeshSpec(**c["mesh"]).build(v5e)
     cfg = gpt2.GPT2Config(
@@ -137,9 +142,12 @@ def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True):
         if not own_gradient:
             patched.enter_context(mock.patch.object(
                 gpt2, "_chunk_sums", _checkpointed_head))
+        if not owned:
+            patched.enter_context(mock.patch.object(
+                gpt2, "_owns_backward", lambda *a: False))
         lowered = sstep.lower(*state, {"tokens": tokens})
-    _STEPS[case, layers, packed, own_gradient] = lowered.compile()
-    return _STEPS[case, layers, packed, own_gradient]
+    _STEPS[key] = lowered.compile()
+    return _STEPS[key]
 
 
 @pytest.mark.parametrize("case", ["fsdp4", "fsdp2_tp2"])
@@ -282,11 +290,15 @@ def test_train_step_relays_no_activation_between_matmul_and_kernel(v5e,
     # ... which this reading does find in the unpacked block's bodies
     unpacked = _layer_bodies(_gpt2_step(v5e, case, packed=False).as_text())
     assert sum(len(_relayouts(b, local)) for b in unpacked) >= 14
-    assert all(name.endswith("dynamic_slice") for _, name in moved), moved
+    for name, _ in moved:  # o, cut from the stack of every layer's
+        assert re.search(rf"%{re.escape(name)} = \S+ \w+\(%dynamic-slice",
+                         text), moved
     # the kernels take packed rows: two heads to a row, XL's 25 as 13 rows
-    kernels = [k["operands"][0][1] for k in _kernel_shapes(text)]
+    # (the backward kernel after a layer index, in the layers' stacks)
+    kernels = [next(dims for _, dims in k["operands"] if len(dims) > 3)
+               for k in _kernel_shapes(text)]
     rows = -(-c["heads"] // 2)
-    assert kernels and all(tuple(k[1:]) == (rows, 1024, 128)
+    assert kernels and all(tuple(k[-3:]) == (rows, 1024, 128)
                            for k in kernels), kernels
 
 
@@ -321,3 +333,106 @@ def test_packed_projections_add_no_collective(v5e, case):
         for m in filter(None, map(_COLLECTIVE.search, body)):
             dims = re.findall(r"\w+\[([\d,]*)\]", m.group(1))
             assert not [d for d in dims if "1024" in d.split(",")], dims
+
+
+# -- the backward kernel reads what its layer saved where the scan stacked it --
+
+_INSTRUCTION = re.compile(
+    r"\s*(?:ROOT )?%([\w.\-]+) = \(?\w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+# what a fusion that only moves is made of (``dynamic-slice_bitcast_fusion``)
+_FREE = {"parameter", "constant", "bitcast"}
+_MOVES = _FREE | {"copy", "dynamic-slice", "slice", "reshape", "transpose"}
+
+
+def _operand_copies(text, operand):
+    """Every instruction of the layers' bodies that is a ``copy``, a slice
+    or a fusion of nothing but those (inside a fusion that computes, an
+    operand is read where it lies) and whose result is ``operand``, the
+    dims of a flash kernel's."""
+    fused = collections.defaultdict(set)
+    for name, line in _by_computation(text):
+        m = _INSTRUCTION.match(line)
+        if m:
+            fused[name].add(m.group(3))
+    found = []
+    for line in (line for body in _layer_bodies(text) for line in body):
+        m = _INSTRUCTION.match(line)
+        if not m or tuple(int(x) for x in m.group(2).split(",")
+                          if x not in ("", "1")) != operand:
+            continue
+        inner = {m.group(3)}
+        if m.group(3) == "fusion":
+            inner = fused[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        if inner <= _MOVES and inner - _FREE:
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("case", ["one_chip", "fsdp4"])
+def test_backward_kernel_reads_its_layer_of_the_saves_in_place(v5e, case):
+    """``gpt2-large`` on one chip and ``gpt2-xl`` under fsdp=4, two
+    layers (a layer's body is what is asserted). ``lax.scan`` stacks what
+    a layer saves ``[layers, ...]`` and its transpose hands the backward
+    body slices; a Mosaic call takes no slice of a buffer, so q, k, v and
+    o were each copied out of ``bf16[layers, b, rows, 1024, 128]`` a layer
+    (4 x 67-69 us of each of gpt2-large's 36, PERF.md Findings PR 55). The
+    loop that owns its backward pass (``gpt2._blocks_saving``) hands the
+    kernel the stacks and a layer number: no such copy in either body, the
+    kernel's operands ARE the stacks, the projections still write q, k
+    and v into them in place, the step needs no more memory, and the
+    layers are still two ``while``s."""
+    c = HEAD_CASES[case]
+    step = _gpt2_step(v5e, case)
+    text = step.as_text()
+    b, rows = c["batch"] // c["mesh"].get("fsdp", 1), -(-c["heads"] // 2)
+    assert not _operand_copies(text, (b, rows, 1024, 128))
+    backward = [k["operands"] for k in _kernel_shapes(text)
+                if len(k["outputs"]) == 3]
+    assert len(backward) == 1
+    stack, rows_of_lanes = (2, b, rows, 1024, 128), (b, 2 * rows, 4, 256)
+    assert [(t, tuple(d)) for t, d in backward[0]] == [
+        ("s32", (1,)),  # the layer
+        ("bf16", stack), ("bf16", stack), ("bf16", stack),  # q, k, v
+        ("bf16", stack[1:]),  # dO
+        ("f32", (2,) + rows_of_lanes),  # lse, a row of lanes a query block
+        ("f32", rows_of_lanes)], backward  # delta
+    # each projection's matmul has the stack it writes its result into as
+    # an output of its own fusion: q, k and v are not stacked by a copy
+    forward = [body for body in _layer_bodies(text)
+               if not any("transpose(jvp(" in line for line in body)]
+    assert len(forward) == 1
+    stacked = "bf16[" + ",".join(map(str, stack)) + "]"
+    in_place = [line for line in forward[0]
+                if "bsd,drl->brsl/dot_general" in line
+                and " fusion(" in line and "kind=kOutput" in line
+                and stacked in line.partition(" fusion(")[0]]
+    assert len(in_place) == 3, in_place
+    # ... and this reading does find the copies of the scan's own transpose
+    parent = _gpt2_step(v5e, case, owned=False)
+    assert len(_operand_copies(parent.as_text(), (b, rows, 1024, 128))) >= 4
+    assert step.memory_analysis().temp_size_in_bytes \
+        <= parent.memory_analysis().temp_size_in_bytes
+    assert text.count(" while(") == parent.as_text().count(" while(") == 3
+
+
+@pytest.mark.parametrize("owned", [True, False])
+@pytest.mark.parametrize("case", ["one_chip", "fsdp4"])
+def test_backward_kernel_is_what_the_benchmark_looks_for(v5e, case, owned):
+    """``kernel.flash_bwd_roofline`` finds the backward call in a trace by
+    its results and the last four dims of its operands
+    (``benchmark/readers/flash_bwd_roofline.py backward_call``): the call
+    that reads its layer of the stacks and the parent's on a layer sliced
+    out are one call doing one layer's work to it, so the step's and the
+    parent's rooflines are read by one reader. ``kernel.flash_roofline``
+    (three 4-d operands first) sees the parent's alone."""
+    from benchmark.readers.flash_bwd_roofline import backward_call
+    from benchmark.trace.opsbytes import classify_flash
+
+    c = HEAD_CASES[case]
+    b, rows = c["batch"] // c["mesh"].get("fsdp", 1), -(-c["heads"] // 2)
+    kernels = _kernel_shapes(_gpt2_step(v5e, case, owned=owned).as_text())
+    assert len(kernels) == 2
+    assert sorted(map(backward_call, kernels), key=bool) == [
+        None, (b, rows, 1024, 1024, 128)]
+    assert [k[0] for k in map(classify_flash, kernels) if k] == \
+        ([] if owned else ["bwd"])
