@@ -239,6 +239,15 @@ def serve_phase(found: dict, seed: int) -> dict:
             f"{stats['gc_pause_s']:.3f}s, {stats['compiles']} programs "
             f"built in {stats['compile_s']:.1f}s, "
             f"{stats['compile_cache_hits']} of them from the cache")
+        loop = stats["loop"]
+        log(f"serve: the engine loop's account — {loop['holes']} holes "
+            f"({loop['hole_s']:.3f}s over the typical step)"
+            + "".join(f"; {h['stage']} +{h['over_ms']:.0f}ms at step "
+                      f"{h['step']}" for h in loop["last_holes"][-4:])
+            + "; stages (count, mean us, max ms): " + ", ".join(
+                f"{r['handler'][len('rt.llm.'):]} {r['count']} "
+                f"{r['mean_us']:.0f} {r['max_ms']:.1f}"
+                for r in loop["stages"]))
         return stats["device"]
     finally:
         try:

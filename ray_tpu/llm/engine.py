@@ -65,6 +65,7 @@ import numpy as np
 from ..core.exceptions import EngineStoppedError
 from ..models import serving
 from ..observability import tracing
+from ..observability.event_stats import EventStats
 from ..parallel import sharding as shd
 from ..parallel.mesh import DEVICE_PEAKS
 from .paged import OverloadedError, PagePool, RadixIndex, llm_metrics
@@ -391,20 +392,85 @@ def build_step_programs(cfg, page_size: int, decode_block: int, rows: int,
     return block_fn, decode_only_fn
 
 
+# A step that ran this much longer than its kind's typical is a HOLE:
+# the ``--long-ms 20`` of ``benchmark/tools/stall_probe.py`` and the size
+# ROADMAP [runtime-stalls] speaks of. A constant, not an option.
+HOLE_S = 0.020
+HOLES_KEPT = 32  # the last holes ``loop_account()`` remembers
+
+
+class _Typical:
+    """What a step of one kind usually takes: the median of the kind's
+    first ``SETTLE`` steps (a compile or an empty pipeline among them
+    moves no median), then a running mean over about the last 16 steps
+    that a hole never enters, so it follows a change of load within a
+    fraction of a second and ten holes in a row leave it where it was.
+    ``RESEED`` holes in a row are the new load, not holes: the estimate
+    starts again."""
+
+    SETTLE, RESEED = 8, 32
+    __slots__ = ("mean", "_first", "_streak")
+
+    def __init__(self):
+        self.mean: Optional[float] = None  # None until settled
+        self._first: List[float] = []
+        self._streak = 0
+
+    def over(self, wall: float) -> float:
+        """Seconds ``wall`` lies over the typical (0.0 until settled);
+        ``wall`` enters the typical unless that makes it a hole."""
+        if self.mean is None:
+            self._first.append(wall)
+            if len(self._first) == self.SETTLE:
+                self.mean = sorted(self._first)[self.SETTLE // 2]
+                self._first = []
+            return 0.0
+        over = wall - self.mean
+        if over < HOLE_S:
+            self._streak = 0
+            self.mean += over / 16
+        else:
+            self._streak += 1
+            if self._streak == self.RESEED:
+                self.mean, self._streak = None, 0
+        return over
+
+
+def _cpu_clock_of_this_thread() -> Optional[int]:
+    """The calling thread's CPU-time clock, which ANY thread may then read
+    with ``time.clock_gettime_ns`` (the kernel refuses a thread that is
+    gone); None where the platform has none."""
+    try:
+        return time.pthread_getcpuclockid(threading.get_ident())
+    except (AttributeError, OSError):
+        return None
+
+
 class _acquired:
     """``with lock:`` for the engine thread, the wait for the lock an
     ``rt.llm.acquire`` span: between two steps the thread contends with
     ``submit()`` callers for the engine's one lock, and a loop that is
-    never out of work shows that wait nowhere else."""
+    never out of work shows that wait nowhere else. A wait of ``HOLE_S``
+    or more is a hole of its own, its typical zero; the thread's clocks
+    are read for it only where the lock was not free."""
 
-    __slots__ = ("_lock",)
+    __slots__ = ("_engine", "_lock")
 
-    def __init__(self, lock):
+    def __init__(self, engine, lock):
+        self._engine = engine
         self._lock = lock
 
     def __enter__(self):
-        with tracing.step_span("rt.llm.acquire"):
-            self._lock.acquire()
+        engine, clocks = self._engine, None
+        with tracing.step_span("rt.llm.acquire",
+                               into=engine.account) as sp:
+            if not self._lock.acquire(False):
+                clocks = engine._read_clocks()
+                self._lock.acquire()
+        if clocks is not None and sp.seconds >= HOLE_S:
+            engine._keep_hole(sp.seconds, 0.0, clocks,
+                              engine._since(clocks), "none",
+                              {"acquire": sp.seconds})
 
     def __exit__(self, *exc):
         self._lock.release()
@@ -630,6 +696,21 @@ class SlotEngine:
         self.experts_hit = self.expert_rows = self.expert_rows_max = 0
         self.expert_picks = self.kda_rows = self.ssm_rows = 0
         self._callbacks = 0  # on_token calls made delivering tokens
+        # The loop's own time account, always on (no profiler, no ring):
+        # every ``rt.llm.*`` stage's count, seconds and longest run, added
+        # by the stage's own span (``step_span(.., into=)``), and the
+        # steps that ran HOLE_S or more over their kind's typical, each
+        # kept with what the step was made of. ``loop_account()`` reads it.
+        self.account = EventStats()
+        self.holes = 0      # cumulative, like steps_block
+        self.hole_s = 0.0   # seconds over the typical, summed over holes
+        self._holes: deque = deque(maxlen=HOLES_KEPT)
+        self._typical = {"block": _Typical(), "decode_only": _Typical()}
+        self._steps = 0  # rt.llm.step spans opened: a hole's ``step``
+        # the thread that last called submit() (a replica's event loop)
+        # and its CPU-time clock, which this loop reads at a step's ends
+        self._caller: Optional[int] = None
+        self._caller_clock: Optional[int] = None
         # The last finished requests' timing: a streamed response
         # carries tokens only, so this is where its stages are read.
         self._timings: deque = deque(maxlen=self.TIMINGS_KEPT)
@@ -666,7 +747,14 @@ class SlotEngine:
                      temperature=float(temperature), eos_id=eos_id,
                      on_token=on_token, submit_t=time.monotonic(),
                      session_id=session_id, trace_ctx=trace_ctx)
-        with self._work:
+        ident = threading.get_ident()
+        if ident != self._caller:
+            self._caller_clock = _cpu_clock_of_this_thread()
+            self._caller = ident
+        # The counterpart of rt.llm.acquire, on the CALLER's thread: its
+        # wait for the engine's lock and what it does holding it.
+        with tracing.step_span("rt.llm.submit", into=self.account), \
+                self._work:
             if (self.max_pending is not None
                     and len(self._pending) >= self.max_pending):
                 self.requests_shed += 1
@@ -984,9 +1072,10 @@ class SlotEngine:
 
     def _run(self) -> None:
         while True:
-            with _acquired(self._work):
+            with _acquired(self, self._work):
                 while not self._stop and not self._has_work_locked():
-                    with tracing.step_span("rt.llm.wait_work"):
+                    with tracing.step_span("rt.llm.wait_work",
+                                           into=self.account):
                         self._work.wait()
                 if self._stop:
                     self._drain_control_locked()
@@ -1151,17 +1240,123 @@ class SlotEngine:
         decode+prefill block, then fetch the PREVIOUS block's tokens
         (ready by now — lag-1 pipelining). Returns True if any work
         ran."""
-        with _acquired(self._lock):
+        with _acquired(self, self._lock):
             if not self._has_work_locked():
                 return False
-        with tracing.step_span("rt.llm.step", cpu=True,
+        with tracing.step_span("rt.llm.step", into=self.account,
                                slots=self.num_slots,
                                block=self.decode_block) as sp:
-            return self._step(sp)
+            self._steps += 1
+            stages: Dict[str, float] = {}
+            mark = self._read_clocks()
+            ran, program, active, admitted = self._step(sp, stages)
+            ends = wall, off_cpu, caller_cpu = self._since(mark)
+            hole, under = 0.0, None
+            if program != "none":
+                typical = self._typical[program]
+                usual = typical.mean or 0.0
+                over = typical.over(wall / 1e9)
+                if over >= HOLE_S:
+                    hole = over
+                    # what no stage covers (the fetched array's release,
+                    # a collection between two stages) is the step's own
+                    stages["step"] = wall / 1e9 - sum(stages.values())
+                    under = self._keep_hole(over, usual, mark, ends, program,
+                                            stages, active, admitted)
+            if sp.recording:
+                sp.set(wall_us=wall / 1e3, off_cpu_us=off_cpu / 1e3,
+                       caller_cpu_us=caller_cpu / 1e3, hole_ms=hole * 1e3)
+                if under:
+                    sp.set(hole_stage=under)
+            return ran
 
-    def _step(self, sp) -> bool:
+    # -- the loop's time account -------------------------------------------
+
+    def _caller_cpu_ns(self, clock: Optional[int]) -> int:
+        if clock is None:
+            return 0
+        try:
+            return time.clock_gettime_ns(clock)
+        except OSError:  # the thread is gone
+            if clock == self._caller_clock:
+                self._caller_clock = None
+            return 0
+
+    def _read_clocks(self) -> tuple:
+        """A mark to measure from: the wall, this thread's CPU and the
+        CPU of the thread that last called ``submit()`` (NOT the
+        process's: summing a JAX process's hundreds of threads costs
+        microseconds a read; 0 where there is no such thread or clock; the
+        caller's own where the caller steps the engine itself), in ns,
+        and the process's collections, compiles and this engine's
+        callbacks so far."""
+        events = tracing.process_events()
+        clock = self._caller_clock
+        return (time.perf_counter_ns(), time.thread_time_ns(),
+                self._caller_cpu_ns(clock), clock, events.gc_pause_s,
+                events.compiles, self._callbacks)
+
+    def _since(self, mark: tuple) -> tuple:
+        """``(wall, off_cpu, caller_cpu)`` in ns since ``mark``. Off the
+        CPU while the caller's thread burned about as much is the
+        interpreter lock; off the CPU with the caller asleep too is the
+        device, the runtime or a lock a sleeper holds. The thread's clock
+        is read inside the wall clock's interval, and neither difference
+        is clamped: where the kernel charges CPU time a tick at a time,
+        one reading is all of the wall or less than none (sums stay
+        true)."""
+        wall0, cpu0, caller0, clock = mark[:4]
+        caller_cpu = 0
+        if clock is not None and clock == self._caller_clock:
+            caller_cpu = max(0, self._caller_cpu_ns(clock) - caller0)
+        on_cpu = time.thread_time_ns() - cpu0
+        wall = time.perf_counter_ns() - wall0
+        return wall, wall - on_cpu, caller_cpu
+
+    def _keep_hole(self, over_s: float, typical_s: float, mark: tuple,
+                   ends: tuple, program: str, stages: Dict[str, float],
+                   active: int = 0, admitted: int = 0) -> str:
+        """Count a hole and keep it with what lay beside it from ``mark``
+        to ``ends`` (``_read_clocks``, ``_since``): the stage it lay under
+        (the longest of the step's own, ``step`` where that is what none
+        of them covers; returned), what this thread and
+        the caller's did meanwhile, the collections and compiles of the
+        process. Takes no lock: an ``acquire`` hole is kept holding the
+        engine's."""
+        wall, off_cpu, caller_cpu = ends
+        events = tracing.process_events()
+        stage = max(stages, key=stages.get)
+        self.holes += 1
+        self.hole_s += over_s
+        self._holes.append({
+            "t_unix": time.time(), "step": self._steps, "program": program,
+            "wall_ms": round(wall / 1e6, 3),
+            "typical_ms": round(typical_s * 1e3, 3),
+            "over_ms": round(over_s * 1e3, 3),
+            "stages_ms": {k: round(v * 1e3, 3) for k, v in stages.items()},
+            "stage": stage,
+            "off_cpu_ms": round(off_cpu / 1e6, 3),
+            "caller_cpu_ms": round(caller_cpu / 1e6, 3),
+            "gc_ms": round((events.gc_pause_s - mark[4]) * 1e3, 3),
+            "compiled": events.compiles - mark[5],
+            "active": active, "admitted": admitted,
+            "callbacks": self._callbacks - mark[6]})
+        return stage
+
+    def loop_account(self) -> dict:
+        """The engine loop's time account since the engine was built:
+        every stage's count, total and longest run, the holes counted and
+        the last ``HOLES_KEPT`` of them. Read from any thread."""
+        return {"stages": self.account.snapshot(), "holes": self.holes,
+                "hole_s": round(self.hole_s, 6),
+                "last_holes": list(self._holes)}
+
+    def _step(self, sp, stages: Dict[str, float]) -> tuple:
+        """``(ran, program, active, admitted)``; each stage's seconds
+        into ``stages`` under its name."""
         ran_control = False
-        with tracing.step_span("rt.llm.schedule") as sched, \
+        with tracing.step_span("rt.llm.schedule",
+                               into=self.account) as sched, \
                 self._lock:
             # Session export/import and friends run HERE, between
             # decode steps: the previous block's cache assignment is
@@ -1186,6 +1381,7 @@ class SlotEngine:
             live = [s for s in self._slots if s is not None]
             pending = len(self._pending)
             sched.set(admitted=admitted)
+        stages["schedule"] = sched.seconds
         ran = ran_control
         had_fetch = self._inflight is not None
         program, pre_tokens, waiting = "none", 0, len(live) - len(active)
@@ -1224,15 +1420,17 @@ class SlotEngine:
                        for s in live))
         new_block = None
         if program != "none":
-            with tracing.step_span("rt.llm.dispatch", cpu=True):
-                new_block = self._dispatch_block(active, prefill_idx)
+            with tracing.step_span("rt.llm.dispatch", cpu=True,
+                                   into=self.account):
+                new_block = self._dispatch_block(active, prefill_idx,
+                                                 stages)
         if had_fetch:
-            self._process_fetch(sp)
+            self._process_fetch(sp, stages)
             ran = True
         if new_block is not None:
             self._inflight = new_block
             ran = True
-        return ran
+        return ran, program, len(active), admitted
 
     def _pages_read(self, active, prefill_idx) -> int:
         """Pages the rows about to be dispatched attend over, a layer:
@@ -1249,7 +1447,7 @@ class SlotEngine:
                             s.prefill_offset + self.chunk) // ps)
         return pages
 
-    def _dispatch_block(self, active, prefill_idx):
+    def _dispatch_block(self, active, prefill_idx, stages):
         """Dispatch one K-step block: every active slot decodes K
         tokens and (when a slot is mid-prompt) ONE prefill chunk rides
         the first step's fused program: up to ``self.chunk`` tokens of
@@ -1265,7 +1463,8 @@ class SlotEngine:
         # reads.
         fused = prefill_idx is not None or self._model.one_program
         layout, idle = self._host_in[fused]
-        with tracing.step_span("rt.llm.dispatch.pack"):
+        with tracing.step_span("rt.llm.dispatch.pack",
+                               into=self.account) as pack:
             host_in = idle.copy()
             f = layout.views(host_in)
             # the table as it stands NOW: the live one is written again
@@ -1301,12 +1500,14 @@ class SlotEngine:
                 f["lane_slot"][0], f["p0"][0] = prefill_idx, p0
                 f["n_valid"][0], f["lane_seed"][0] = n_valid, s.seed
                 f["lane_temp"][0] = s.temperature
-        with tracing.step_span("rt.llm.dispatch.upload", cpu=True) as sp:
+        with tracing.step_span("rt.llm.dispatch.upload", cpu=True,
+                               into=self.account) as upload:
             # ONE transfer a dispatch; _last_dev never left the device
             host_dev = jnp.asarray(host_in)
-            if sp.recording:
-                sp.set(arrays=1, bytes=host_in.nbytes)
-        with tracing.step_span("rt.llm.dispatch.launch") as sp:
+            if upload.recording:
+                upload.set(arrays=1, bytes=host_in.nbytes)
+        with tracing.step_span("rt.llm.dispatch.launch",
+                               into=self.account) as sp:
             built = tracing.process_events().compiles
             pre_tok = None
             step = self._block if fused else self._decode_only
@@ -1320,18 +1521,21 @@ class SlotEngine:
                 # backend meanwhile: 0 in a loop that was warmed up
                 sp.set(program="block" if fused else "decode_only",
                        compiled=tracing.process_events().compiles - built)
+        stages["pack"], stages["upload"] = pack.seconds, upload.seconds
+        stages["launch"] = sp.seconds
         for i, s in active:
             s.pos += self.decode_block
             s.on_device_chain = True
         return (list(active), pre_info, toks_k, pre_tok)
 
-    def _process_fetch(self, step_sp) -> None:
+    def _process_fetch(self, step_sp, stages) -> None:
         snapshot, pre_info, toks_k, pre_tok = self._inflight
         self._inflight = None
-        with tracing.step_span("rt.llm.fetch"):
+        with tracing.step_span("rt.llm.fetch", into=self.account) as sp:
             # the lag-1 wait for the device: the block dispatched one
             # step ago is usually ready, so this is a fast fetch
             arr = np.asarray(toks_k)  # [K, rows (+ the family's counts)]
+        stages["fetch"] = sp.seconds
         names = self._model.step_counters
         if names:
             # the counts of the block dispatched one step ago, on the
@@ -1342,19 +1546,28 @@ class SlotEngine:
                 setattr(self, name, getattr(self, name) + int(n))
             if step_sp.recording:
                 step_sp.set(**{k: int(n) for k, n in zip(names, counts)})
-        with tracing.step_span("rt.llm.deliver", cpu=True) as sp:
+        with tracing.step_span("rt.llm.deliver", cpu=True,
+                               into=self.account) as sp:
             tokens0, done0 = self.tokens_generated, self.requests_completed
             calls0 = self._callbacks
+            # the metric family once a fetch, its token counter once a
+            # deliver: nobody reads either a token at a time
+            metrics = llm_metrics()
             overshoot = self._deliver_block(snapshot, pre_info, arr,
-                                            pre_tok)
+                                            pre_tok, metrics)
             self.overshoot_tokens += overshoot
-            sp.set(delivered=self.tokens_generated - tokens0,
+            delivered = self.tokens_generated - tokens0
+            if metrics is not None and delivered:
+                metrics["tokens"].inc(float(delivered))
+            sp.set(delivered=delivered,
                    finished=self.requests_completed - done0,
                    overshoot=overshoot,
                    # on_token calls: each wakes the caller's thread
                    callbacks=self._callbacks - calls0)
+        stages["deliver"] = sp.seconds
 
-    def _deliver_block(self, snapshot, pre_info, arr, pre_tok) -> int:
+    def _deliver_block(self, snapshot, pre_info, arr, pre_tok,
+                       metrics) -> int:
         """Hand a fetched block's tokens to their requests. Returns the
         tokens the device computed and nobody gets: the rest of a block
         after EOS / length, and the whole block of a slot that finished
@@ -1366,7 +1579,7 @@ class SlotEngine:
                 overshoot += k_block  # finished in an earlier block
                 continue
             for k in range(k_block):
-                self._deliver(idx, s, int(arr[k, idx]))
+                self._deliver(idx, s, int(arr[k, idx]), metrics)
                 if self._slots[idx] is not s:
                     overshoot += k_block - 1 - k  # eos / length mid-block
                     break
@@ -1388,7 +1601,7 @@ class SlotEngine:
                 s.first_tok_pending = False
                 s.pos = len(s.prompt)
                 s.on_device_chain = False
-                self._deliver(idx, s, int(pre_tok))
+                self._deliver(idx, s, int(pre_tok), metrics)
         return overshoot
 
     def _request_timing(self, s: _Slot) -> dict:
@@ -1479,17 +1692,14 @@ class SlotEngine:
             kept = list(self._timings)
         return [t for t in kept if t["submit_unix_s"] >= since_unix_s]
 
-    def _deliver(self, idx: int, s: _Slot, tok: int) -> None:
+    def _deliver(self, idx: int, s: _Slot, tok: int, metrics) -> None:
         s.last_token = tok
         s.produced += 1
         self.tokens_generated += 1
-        m = llm_metrics()
-        if m is not None:
-            m["tokens"].inc(1.0)
         if s.produced == 1:
             s.first_tok_t = time.monotonic()
-            if m is not None:
-                m["ttft"].observe(s.first_tok_t - s.submit_t)
+            if metrics is not None:
+                metrics["ttft"].observe(s.first_tok_t - s.submit_t)
         s.handle._emit(tok)
         if s.on_token:
             self._callbacks += 1

@@ -239,6 +239,8 @@ def llm_metrics() -> Optional[Dict[str, Any]]:
 
     if not config().telemetry_enabled:
         return None
+    if _llm_metrics_cache is not None:  # built: no lock to read it
+        return _llm_metrics_cache
     with _llm_metrics_lock:
         if _llm_metrics_cache is None:
             _llm_metrics_cache = {

@@ -243,6 +243,10 @@ class LLMServer:
             # garbage collections, and programs built for the backend
             # (after warm-up there should be none)
             **tracing.process_events().counters(),
+            # the engine loop's own time account, always on: every
+            # stage's count, total and longest run, and the steps that ran
+            # 20 ms or more over their kind's typical (engine.HOLE_S)
+            "loop": self.engine.loop_account(),
         }
 
     def request_timings(self, since_unix_s: float = 0.0) -> list:

@@ -15,7 +15,8 @@ attribution on the HOST's wall clock (``rt trace <id>``). ``step_span``
 is for the hot paths that drive the device (an engine step, a stream
 pull): it always opens a ``jax.profiler.TraceAnnotation``, which lands in
 a profiler trace's ``/host:CPU`` plane on the clock the device events
-carry, and records a ring span too only when the tracer is on. The
+carry, records a ring span too only when the tracer is on, and, where
+the caller names an account (``into=``), always adds its duration to it. The
 device's own time is never in a span: it is in the profiler trace, under
 the ``jax.named_scope`` names the programs carry.
 
@@ -326,15 +327,22 @@ class _StepSpan:
     """What :func:`step_span` returns: the profiler annotation and, when
     the tracer is on, a ring span, entered and left together."""
 
-    __slots__ = ("_ann", "_ctx", "_attributes", "_clocks")
+    __slots__ = ("_ann", "_ctx", "_attributes", "_clocks", "_into",
+                 "_name", "seconds")
 
-    def __init__(self, ann, ctx, attributes, cpu=False):
+    def __init__(self, ann, ctx, attributes, cpu=False, into=None,
+                 name=""):
         self._ann = ann
         self._ctx = ctx  # ring side: _SpanCtx, _BoundsSpanCtx or None
         self._attributes = attributes  # the ring span's dict too
         # cpu: True until entered, then the two clocks read at entry, or
         # None where nobody records the span (nothing is read then)
         self._clocks = cpu
+        # into: the caller's account. ``seconds`` is the clock read at
+        # entry, then, once the span is left, how long it ran.
+        self._into = into
+        self._name = name
+        self.seconds = 0.0
 
     def __enter__(self) -> "_StepSpan":
         if self._ann is not None:
@@ -344,9 +352,14 @@ class _StepSpan:
         if self._clocks:
             self._clocks = (time.perf_counter_ns(), time.thread_time_ns()
                             ) if self.recording else None
+        if self._into is not None:
+            self.seconds = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        if self._into is not None:
+            self.seconds = time.perf_counter() - self.seconds
+            self._into.record(self._name, self.seconds)
         if self._clocks:
             # the thread's clock inside the wall clock's interval, so a
             # clock that is exact never reads more CPU than wall
@@ -376,7 +389,7 @@ class _StepSpan:
 
 
 def step_span(name: str, interleaved: bool = False, cpu: bool = False,
-              **attributes) -> _StepSpan:
+              into=None, **attributes) -> _StepSpan:
     """A span round one step of a hot path (names start with ``rt.``).
 
     Always a ``jax.profiler.TraceAnnotation``: while a profiler trace is
@@ -398,7 +411,15 @@ def step_span(name: str, interleaved: bool = False, cpu: bool = False,
     a SUM over many spans: where the kernel charges a thread its CPU
     time a scheduler tick at a time (10 ms on the machines the TPU is
     measured on), one span reads all of its wall time or less than none
-    (``off_cpu_us`` is not clamped at 0, so that the sums stay true)."""
+    (``off_cpu_us`` is not clamped at 0, so that the sums stay true).
+
+    ``into`` is a third sink, an ``event_stats.EventStats`` the caller
+    owns, and the one that is always on: the span then reads
+    ``time.perf_counter()`` at its two ends whether or not anyone records
+    it, adds its duration to that account under its name (count, total,
+    longest), and keeps it in ``.seconds`` for the caller. A loop that
+    drives the chip has its time account that way with no profiler and no
+    ring running (``llm/engine.py``: ``LLMServer.stats()["loop"]``)."""
     annotation = _trace_annotation()
     ctx = None
     if _tracer.enabled:
@@ -406,7 +427,7 @@ def step_span(name: str, interleaved: bool = False, cpu: bool = False,
                                                             attributes)
     return _StepSpan(
         None if annotation is None else annotation(name, **attributes),
-        ctx, attributes, cpu)
+        ctx, attributes, cpu, into, name)
 
 
 # -- process-wide events that stop a loop -----------------------------------

@@ -396,7 +396,12 @@ def test_the_manifest_resolves_the_cell_its_traffic_and_its_metrics():
     e2e = [m["name"] for m in cell["metrics"]["end_to_end"]]
     assert sorted(e2e) == ["out_tokens_per_s", "setup_s"]
     per_layer = {m["name"] for m in cell["metrics"]["per_layer"]}
-    assert {"step.decode_ms.granite", "step.ssm_share",
+    assert {
+            # PR 56: the engine loop's own account, one file a metric for
+            # the three cells (tests/test_loop_account.py)
+            "engine.hole_ms", "engine.caller_cpu_share",
+            "engine.submit_p90_ms",
+            "step.decode_ms.granite", "step.ssm_share",
             "step.attn_share.granite", "kernel.ssm_roofline",
             "engine.slot_occupancy"} | {
                 f"engine.{stem}.granite" for stem in (

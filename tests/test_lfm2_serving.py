@@ -275,7 +275,12 @@ def test_the_manifest_resolves_the_new_cell_and_its_configuration():
     e2e = [m["name"] for m in cell["metrics"]["end_to_end"]]
     assert sorted(e2e) == ["out_tokens_per_s", "setup_s"]
     per_layer = {m["name"] for m in cell["metrics"]["per_layer"]}
-    assert {"step.decode_ms.lfm2", "step.moe_share", "step.conv_share",
+    assert {
+            # PR 56: the engine loop's own account, one file a metric for
+            # the three cells (tests/test_loop_account.py)
+            "engine.hole_ms", "engine.caller_cpu_share",
+            "engine.submit_p90_ms",
+            "step.decode_ms.lfm2", "step.moe_share", "step.conv_share",
             "kernel.moe_roofline", "moe.experts_hit_share",
             "moe.load_max_share", "engine.slot_occupancy",
             # PR 37: the engine's own spans (tests/test_host_metrics.py
