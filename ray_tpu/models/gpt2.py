@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (attention as attention_op, attention_of_saved,
-                             attention_saving, packed_heads_for)
+                             attention_saving, packed_heads_for, saved_stacks)
 from ..parallel.sharding import constrain, mesh_axes_for
 from .common import cross_entropy_terms, layer_norm, truncated_normal
 
@@ -433,9 +433,17 @@ def _blocks_saving(cfg: GPT2Config, rules):
     ``lax.scan``s over the SAME ``_block``, which differ in what stands
     for attention alone.
 
-    Forward: ``attention_saving``, and the scan stacks each layer's input
+    Forward: ``attention_saving``, and the scan keeps each layer's input
     and the ``(q, k, v, o, lse)`` its flash kernel read and wrote: what
-    ``jax.checkpoint`` under the policy saves. Backward: the layers in
+    ``jax.checkpoint`` under the policy saves. The input, q, k and v are
+    the scan's stacked outputs (XLA fusions make them, and write them into
+    their stacks in place); the o and lse stacks are CARRIED, because a
+    Mosaic call's result cannot be pointed into a slice of a larger
+    buffer: the kernel takes them with the layer number and writes its
+    layer itself, where stacking o after the call was a copy of it a layer
+    and lse a ``reduce`` (5.7 ms of gpt2-large's 346 ms step, PERF.md
+    Findings PR 57), and the block's projection reads o at ``[layer]`` of
+    the stack inside its own fusion. Backward: the layers in
     reverse, the stacks loop constants and the layer number the scanned
     value; a layer is ``jax.vjp`` of the block with
     ``attention_of_saved``, whose o is read from the stack (the q, k, v
@@ -464,22 +472,29 @@ def _blocks_saving(cfg: GPT2Config, rules):
         return _scan_blocks(block, blocks, x)
 
     def forward(blocks, x):
-        def body(carry, layer):
-            x, aux = carry
+        def body(carry, at):
+            (x, aux, stacks), (layer, i) = carry, at
             kept = []
 
             def attend(q, k, v):
-                o, saved = attention_saving(q, k, v, **where)
+                o, saved = attention_saving(q, k, v, stacks=stacks, layer=i,
+                                            **where)
                 kept.append(saved)
                 return o
 
             y, a = block(x, layer, attend=attend)
-            (saved,) = kept
-            return (y, aux + a), (x, saved)
+            ((q, k, v, *stacks),) = kept
+            return (y, aux + a, tuple(stacks)), (x, (q, k, v))
 
-        out, (xs, saved) = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), blocks)
-        return out, (blocks, xs, saved)
+        (b, s, _), n = x.shape, _packed_heads(cfg, x.shape[1], rules)
+        layers = jax.tree.leaves(blocks)[0].shape[0]
+        stacks = saved_stacks(
+            layers, (b, -(-cfg.num_heads // n), s, n * cfg.head_dim),
+            x.dtype, head_dim=cfg.head_dim)
+        (y, aux, stacks), (xs, qkv) = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), stacks),
+            (blocks, jnp.arange(layers, dtype=jnp.int32)))
+        return (y, aux), (blocks, xs, (*qkv, *stacks))
 
     def backward(kept, cts):
         (blocks, xs, saved), (dx, daux) = kept, cts

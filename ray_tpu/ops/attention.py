@@ -55,6 +55,17 @@ kernel alone at ``[8, 20, 1024, 64]`` bfloat16, causal):
     head dim) the body traces to the kernel it was before, instruction
     for instruction.
 
+  - SAVED IN PLACE (PRs 55 and 57). A loop over layers that owns its
+    backward pass keeps each layer's q, k, v, o and lse stacked
+    ``[layers, ...]``, and a Mosaic call takes no slice of a buffer as an
+    operand and gives none as a result: XLA copies. So both kernels take an
+    optional layer number, a prefetched scalar that their block index maps
+    read: the backward reads its layer of the stacks where it lies, the
+    forward writes its layer of the o and lse stacks (operands aliased to
+    its results), lse as the rows of lanes the backward reads
+    (``attention_saving`` / ``attention_of_saved``). Without a layer each
+    is the call it was, instruction for instruction.
+
 ``flash_attention`` is differentiable end-to-end in Pallas: forward kernel
 plus a fused dq/dk/dv backward kernel (blockwise recompute from the saved
 LSE — no S×S materialization anywhere). An explicit request for the
@@ -233,6 +244,22 @@ def _join_lanes(per_head, lane_head):
     return out
 
 
+def _column_as_lanes(col):
+    """``[n, 1] -> [1, n]`` on the VPU: each run of 128 rows is spread
+    over the lanes, all but its diagonal zeroed, and summed over the rows
+    (``x + 0.0`` is ``x``: the same bits). At the train cells' shapes the
+    forward reads 8.8 us a call FASTER with it than with the one-lane
+    columns it replaces, where a transpose through the XLU reads 15 us
+    slower and a one-hot product on the MXU 59 (PERF.md Findings PR 57)."""
+    n = col.shape[0]
+    run = math.gcd(n, _LANES)
+    diagonal = _rows_minus_cols(run, run) == 0
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(diagonal, jnp.broadcast_to(
+            col[r:r + run], (run, run)), 0.0), axis=0, keepdims=True)
+         for r in range(0, n, run)], axis=1)
+
+
 def _head_of(row, j: int, heads_a_row: int):
     """Index of head ``j`` of ``row`` among the heads (lse, delta)."""
     return row if heads_a_row == 1 else row * heads_a_row + j
@@ -241,9 +268,12 @@ def _head_of(row, j: int, heads_a_row: int):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       *, block_q: int, block_k: int, seq_q: int, seq_k: int,
                       scale: float, causal: bool, static: bool,
-                      num_heads: int, heads_a_row: int):
+                      num_heads: int, heads_a_row: int, lse_lanes: int = 0):
     """``num_heads`` rows of ``heads_a_row`` heads each: see the module's
-    docstring for how a head of a packed row is computed."""
+    docstring for how a head of a packed row is computed. ``lse_lanes``:
+    ``lse_ref`` is ``[1, heads, seq_q // lse_lanes, lse_lanes]``, rows of
+    lanes as the backward kernel reads them (``_lse_rows``), and not
+    ``[1, heads, seq_q, 1]`` columns."""
     from jax.experimental import pallas as pl
 
     fold = _folds_exactly(scale)
@@ -298,8 +328,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                             lane_head)
             o_ref[0, hh, rows, :] = o.astype(o_ref.dtype)
             for j, (m, l, _) in enumerate(heads):  # [block_q, 1] a head
-                lse_ref[0, _head_of(hh, j, heads_a_row), rows, :] = \
-                    m + jnp.log(l)
+                lse = m + jnp.log(l)
+                head = _head_of(hh, j, heads_a_row)
+                if not lse_lanes:
+                    lse_ref[0, head, rows, :] = lse
+                    continue
+                lse = _column_as_lanes(lse)  # [1, block_q]
+                for r in range(block_q // lse_lanes):
+                    lse_ref[0, head, pl.ds(qb * (block_q // lse_lanes) + r,
+                                           1), :] = \
+                        lse[:, r * lse_lanes:(r + 1) * lse_lanes]
 
         _for_each_block(seq_q // block_q, q_block, static)
         return 0
@@ -342,43 +380,102 @@ def _schedule(causal, sq, sk, block_q, block_k):
     return static, block_q
 
 
-def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
-                      block_q: int, block_k: int, interpret: bool,
-                      heads_a_row: int = 1):
-    """ONE pallas call, (q, k, v) -> (o, lse[b, heads, sq, 1]); the blocks
-    must divide the sequences. q, k, v and o are ``[b, rows, s, D]``,
-    ``heads_a_row`` heads side by side in a row's D lanes."""
+def _block_spec(*block):
+    """A ``[1, rows, ..]`` block at (batch, head group) of a kernel's own
+    operand or result; with a leading None, the same block of layer
+    ``layer`` of a ``[layers, ...]`` stack, the layer the prefetched scalar
+    that follows the grid's indices."""
     from jax.experimental import pallas as pl
 
+    if block[0] is None:
+        return pl.BlockSpec(block, lambda i, g, at: (at[0], i, g, 0, 0))
+    return pl.BlockSpec(block, lambda i, g, *_: (i, g, 0, 0))
+
+
+def _flash_fwd_into_layer_kernel(layer_ref, q_ref, k_ref, v_ref, o_stack,
+                                 lse_stack, o_ref, lse_ref, **static):
+    """``_flash_fwd_kernel`` behind a prefetched layer number, writing its
+    layer of two stacks: the block index maps have read the layer, and the
+    stacks are the results' own buffers, of which the body sees its blocks
+    (``o_ref``, ``lse_ref``) alone."""
+    del layer_ref, o_stack, lse_stack
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, **static)
+
+
+def _flash_fwd_pallas(q, k, v, causal: bool, scale: float,
+                      block_q: int, block_k: int, interpret: bool,
+                      heads_a_row: int = 1, layer=None, stacks=None):
+    """ONE pallas call, (q, k, v) -> (o, lse[b, heads, sq]); the blocks
+    must divide the sequences. q, k, v and o are ``[b, rows, s, D]``,
+    ``heads_a_row`` heads side by side in a row's D lanes.
+
+    ``layer`` (an int32 scalar) and ``stacks``, what a loop over layers
+    saves of o and lse, ``[layers, b, rows, sq, D]`` and ``[layers, b,
+    heads, sq // n, n]`` (``_lse_rows``' layout): the call writes layer
+    ``layer`` of both where it lies and returns the stacks, every other
+    layer as it was. The stacks are operands aliased to the results and
+    the layer a prefetched scalar that the out blocks' index maps read, so
+    the kernel's own DMAs put o and lse where the backward kernel reads
+    them (``_flash_bwd_pallas(layer=)``): XLA cannot point a Mosaic call's
+    result into a slice of a larger buffer, so stacking o after the call
+    was a copy of it a layer and lse, written as ``[sq, 1]`` columns that
+    HBM and VMEM pad to 128 lanes, a ``reduce`` to make it dense (109 + 62
+    us a layer at the train cells' shapes, PERF.md Findings PR 57). The
+    same kernel on the same blocks: the same numbers in another place."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    stacked = layer is not None
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    lse_lanes = _lse_lanes(causal, sq, sk, block_q, block_k) if stacked else 0
     static, block_q = _schedule(causal, sq, sk, block_q, block_k)
     esize = q.dtype.itemsize
     # whole-sequence q, o, k, v and a head's lse column (a [sq, 1] float32
-    # block is padded to 128 lanes in VMEM); x2 for double-buffering.
+    # block is padded to 128 lanes in VMEM; rows of lanes are held to the
+    # same count, so that stacked or not a program takes the same heads);
+    # x2 for double-buffering.
     per_head = 2 * ((2 * sq + 2 * sk) * d * esize
                     + heads_a_row * sq * 128 * 4)
     hb = _pick_head_block(h, per_head)
+    heads = h * heads_a_row
+    if stacked:
+        per_block = (b, heads, sq // lse_lanes, lse_lanes)
+        # (a query block here is a whole number of the backward's)
+        if (block_q % lse_lanes
+                or [s.shape[1:] for s in stacks] != [q.shape, per_block]):
+            raise ValueError(
+                f"stacks of o {q.shape} and of lse in ``_lse_rows``' layout "
+                f"{per_block} a layer, not "
+                f"{' and '.join(str(s.shape[1:]) for s in stacks)}")
 
-    full_q = pl.BlockSpec((1, hb, sq, d), lambda i, g: (i, g, 0, 0))
-    full_k = pl.BlockSpec((1, hb, sk, d), lambda i, g: (i, g, 0, 0))
+    spec, at = _block_spec, (None,) if stacked else ()
+    lse_block = ((1, hb * heads_a_row, sq // lse_lanes, lse_lanes) if stacked
+                 else (1, hb * heads_a_row, sq, 1))
+    full_q, full_k = spec(1, hb, sq, d), spec(1, hb, sk, d)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
     o, lse = pl.pallas_call(
         functools.partial(
-            _flash_fwd_kernel, block_q=block_q, block_k=block_k, seq_q=sq,
+            _flash_fwd_into_layer_kernel if stacked else _flash_fwd_kernel,
+            block_q=block_q, block_k=block_k, seq_q=sq,
             seq_k=sk, scale=scale, causal=causal, static=static,
-            num_heads=hb, heads_a_row=heads_a_row),
-        grid=(b, h // hb),
-        in_specs=[full_q, full_k, full_k],
-        out_specs=[full_q,
-                   pl.BlockSpec((1, hb * heads_a_row, sq, 1),
-                                lambda i, g: (i, g, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h * heads_a_row, sq, 1),
-                                        jnp.float32)],
+            num_heads=hb, heads_a_row=heads_a_row, lse_lanes=lse_lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(stacked),
+            grid=(b, h // hb),
+            in_specs=[full_q, full_k, full_k] + [in_place] * (2 * stacked),
+            out_specs=[spec(*at, 1, hb, sq, d), spec(*at, *lse_block)]),
+        out_shape=[jax.ShapeDtypeStruct(s.shape, s.dtype) for s in stacks]
+        if stacked else
+        [jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+         jax.ShapeDtypeStruct((b, heads, sq, 1), jnp.float32)],
+        # operands count the prefetched scalar: (layer, q, k, v, o, lse)
+        input_output_aliases={4: 0, 5: 1} if stacked else {},
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
-    )(q, k, v)
-    return o, lse.reshape(b, h * heads_a_row, sq)
+    )(*([jnp.reshape(layer, (1,)).astype(jnp.int32)] if stacked else []),
+      q, k, v, *(stacks if stacked else ()))
+    return (o, lse) if stacked else (o, lse.reshape(b, heads, sq))
 
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -531,10 +628,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
     def spec(*block, saved=False):
         """A block of the call's own operand, or (``saved``) of layer
         ``layer`` of a stack."""
-        if stacked and saved:
-            return pl.BlockSpec((None,) + block,
-                                lambda i, g, at: (at[0], i, g, 0, 0))
-        return pl.BlockSpec(block, lambda i, g, *_: (i, g, 0, 0))
+        return _block_spec(*((None,) if stacked and saved else ()), *block)
 
     q_rows = (1, hb * heads_a_row) + per_block[2:]
     full_q, full_k = spec(1, hb, sq, d), spec(1, hb, sk, d)
@@ -567,12 +661,12 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
 # Differentiable wrapper: pallas forward, blockwise-recompute backward.
 # ---------------------------------------------------------------------------
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, heads_a_row=1):
+def _fwd(q, k, v, causal, scale, block_q, block_k, heads_a_row=1, **into):
     """The forward at the callers' blocks: 512-row query blocks read
     fastest on the chip in both schedules (PERF.md, Findings PR 32)."""
     return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                              interpret=not _on_tpu(),
-                             heads_a_row=heads_a_row)
+                             heads_a_row=heads_a_row, **into)
 
 
 # The backward's query block where the static schedule can take it: four
@@ -624,13 +718,18 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, heads_a_row, res, do):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _lse_rows(lse, causal, sq, sk, block_q, block_k):
-    """lse ``[b, heads, sq]`` as ``_bwd``'s kernel takes it, a row of
-    lanes a query block: ``_bwd``'s block, at ``_flash_bwd_pallas``'s
+def _lse_lanes(causal, sq, sk, block_q, block_k) -> int:
+    """The lanes of a row of lse as ``_bwd``'s kernel takes it, a row a
+    query block: ``_bwd``'s block, at ``_flash_bwd_pallas``'s
     ``_schedule``."""
-    _, block_q = _schedule(causal, sq, sk,
-                           _bwd_block_q(causal, sq, sk, block_q), block_k)
-    return lse.reshape(lse.shape[:2] + (sq // block_q, block_q))
+    return _schedule(causal, sq, sk, _bwd_block_q(causal, sq, sk, block_q),
+                     block_k)[1]
+
+
+def _lse_rows(lse, causal, sq, sk, block_q, block_k):
+    """lse ``[b, heads, sq]`` as rows of ``_lse_lanes`` lanes."""
+    lanes = _lse_lanes(causal, sq, sk, block_q, block_k)
+    return lse.reshape(lse.shape[:2] + (sq // lanes, lanes))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -768,21 +867,55 @@ def _per_shard(fn, mesh, in_specs, out_specs):
 
 def attention_saving(q, k, v, causal: bool = True,
                      scale: Optional[float] = None, mesh=None, spec=None,
-                     head_dim: Optional[int] = None):
+                     head_dim: Optional[int] = None, stacks=None,
+                     layer=None):
     """The flash kernel's forward pass for a loop over layers that owns
     its backward pass: ``(o, saved)``, arguments as :func:`attention`
     under ``impl="flash"``. The loop stacks each layer's ``saved``
     ``[layers, ...]`` and hands the stacks to :func:`attention_of_saved`
-    where it differentiates the layer."""
+    where it differentiates the layer.
+
+    ``stacks`` and ``layer`` (an int32 scalar): the loop carries o's and
+    lse's stacks itself, :func:`saved_stacks` to begin with, and the kernel
+    writes layer ``layer`` of them in place; ``saved`` then holds the two
+    stacks whole, to be carried on, and o is read from its stack."""
+    from jax.sharding import PartitionSpec as P
+
     static = _static_arguments(q, k, causal, scale, head_dim)
     _, _, bq, bk, _ = static
+    if stacks is None:
+        def fn(q, k, v):
+            o, lse = _fwd(q, k, v, *static)
+            return o, _lse_rows(lse, causal, q.shape[2], k.shape[2], bq, bk)
 
-    def fn(q, k, v):
-        o, lse = _fwd(q, k, v, *static)
-        return o, _lse_rows(lse, causal, q.shape[2], k.shape[2], bq, bk)
+        o, lse = _per_shard(fn, mesh, (spec,) * 3, (spec, spec))(q, k, v)
+        return o, (q, k, v, o, lse)
+    stack = None if spec is None else P(None, *spec)
+    o, lse = _per_shard(
+        lambda q, k, v, stacks, layer: _fwd(q, k, v, *static, layer=layer,
+                                            stacks=stacks),
+        mesh, (spec,) * 3 + ((stack,) * 2, P()), (stack,) * 2)(
+            q, k, v, tuple(stacks), layer)
+    return _layer_of(o, layer), (q, k, v, o, lse)
 
-    o, lse = _per_shard(fn, mesh, (spec,) * 3, (spec, spec))(q, k, v)
-    return o, (q, k, v, o, lse)
+
+def saved_stacks(layers: int, shape, dtype, causal: bool = True,
+                 head_dim: Optional[int] = None):
+    """``(o, lse)`` stacks for :func:`attention_saving` to write ``layers``
+    layers of q's ``shape`` and ``dtype`` into, ``causal`` and ``head_dim``
+    as there. UNINITIALISED (``jax.lax.empty``: a buffer and no pass over
+    it): a layer holds nothing until the forward kernel has written it,
+    which a loop's forward pass has done for every layer before its
+    backward pass reads one. On a mesh they take the sharding of the
+    ``shard_map`` that writes them."""
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    _, _, bq, bk, heads_a_row = _static_arguments(q, q, causal, None,
+                                                  head_dim)
+    b, rows, sq, _ = shape
+    lanes = _lse_lanes(causal, sq, sq, bq, bk)
+    return (jax.lax.empty((layers,) + tuple(shape), dtype),
+            jax.lax.empty((layers, b, rows * heads_a_row, sq // lanes, lanes),
+                          jnp.float32))
 
 
 def attention_of_saved(q, k, v, saved, layer, causal: bool = True,

@@ -150,6 +150,65 @@ def test_backward_by_layer_index_is_the_kernels_on_that_layer(shape, causal):
             assert _gap(g, w) == 0.0
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", ["even", "odd25", "rolled"])
+def test_forward_into_layer_index_is_the_kernels_on_that_layer(shape, causal):
+    """The other side of the same loop: handed the o and lse stacks and a
+    layer number (a traced index), ``attention_saving`` is the forward
+    kernel writing layer ``i`` of both where it lies, lse as the rows of
+    lanes the backward kernel reads. For the first, the middle and the last
+    of three layers o and lse are bit for bit what the kernel gives alone
+    (lse through ``_lse_rows``), the o handed back is that layer of the
+    stack, and every other layer keeps what it held."""
+    heads, seq, hd, _ = SHAPES[shape]
+    q, k, v, _ = [_rows(t, hd) for t in _qkv(heads, seq, hd, seed=6)]
+    want, (_, _, _, _, want_lse) = jax.jit(lambda: A.attention_saving(
+        q, k, v, causal=causal, head_dim=hd))()
+    empty = A.saved_stacks(3, q.shape, q.dtype, causal, head_dim=hd)
+    assert [s.shape for s in empty] == [(3,) + want.shape,
+                                        (3,) + want_lse.shape]
+    assert [s.dtype for s in empty] == [want.dtype, want_lse.dtype]
+    held = tuple(jnp.full_like(s, 7) for s in empty)
+
+    @jax.jit
+    def into(i):
+        o, saved = A.attention_saving(q, k, v, causal=causal, head_dim=hd,
+                                      stacks=held, layer=i)
+        return o, saved[3:]
+
+    for i in range(3):
+        o, (os, lses) = into(jnp.int32(i))
+        assert _gap(o, want) == 0.0
+        for stack, layer in ((os, want), (lses, want_lse)):
+            assert stack.dtype == layer.dtype
+            assert _gap(stack[i], layer) == 0.0
+            others = jnp.delete(stack, i, axis=0)
+            assert _gap(others, jnp.full_like(others, 7)) == 0.0
+
+
+@pytest.mark.parametrize("n", [64, 128, 192, 512])
+def test_column_as_lanes_is_the_column_turned(n):
+    """What the forward kernel lays its lse out with: ``[n, 1] -> [1, n]``
+    with every bit kept, runs of 128 rows or of whatever divides ``n``."""
+    col = jax.random.normal(jax.random.PRNGKey(n), (n, 1), jnp.float32) * 30
+    row = A._column_as_lanes(col)
+    assert row.shape == (1, n) and row.dtype == col.dtype
+    assert (row == col.T).all()
+
+
+def test_forward_into_layer_wants_the_stacks_the_backward_reads():
+    """A caller's own stack in another layout (lse a column a head, or o
+    of another batch) is an error where the call is built, not a kernel
+    that writes past its rows."""
+    q, k, v, _ = [_rows(t, 64) for t in _qkv(4, 256, 64, seed=7)]
+    o, lse = A.saved_stacks(2, q.shape, q.dtype, head_dim=64)
+    for stacks in ((o, jnp.zeros((2, 1, 4, 256, 1), jnp.float32)),
+                   (jnp.zeros((2, 2) + q.shape[1:], q.dtype), lse)):
+        with pytest.raises(ValueError, match="stacks of o"):
+            A.attention_saving(q, k, v, head_dim=64, stacks=stacks,
+                               layer=jnp.int32(0))
+
+
 # -- the gpt2 block -----------------------------------------------------------
 
 def _tiny(heads, **kw):

@@ -85,8 +85,21 @@ def _checkpointed_head(xc, tc, wte, vocab_axes):
     return jax.lax.scan(chunk, (zero, zero), (xc, tc))[0]
 
 
+def _stacked_after_the_call(q, k, v, stacks, layer, **where):
+    """``attention_saving`` for the loop of PR 57's parent: the kernel's
+    results are buffers of its own, lse a column a head, and o and lse are
+    put into their stacks after the call, as ``lax.scan`` stacks its
+    ``ys``."""
+    from ray_tpu.ops.attention import attention_saving
+
+    o, (q, k, v, o, lse) = attention_saving(q, k, v, **where)
+    stacks = [jax.lax.dynamic_update_index_in_dim(stack, x, layer, 0)
+              for stack, x in zip(stacks, (o, lse))]
+    return o, (q, k, v, *stacks)
+
+
 def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True,
-               owned=True):
+               owned=True, in_place=True):
     """The gpt2 train step of ``HEAD_CASES[case]`` compiled for the
     described chips, as the training cells build it (``mem2``, the flash
     kernel, a float32 master); each compiled once a session. At two layers
@@ -98,7 +111,9 @@ def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True,
     ``own_gradient=False``: likewise the loss head of PR 52's parent
     (``_checkpointed_head``); ``owned=False``: likewise the layer loop of
     PR 55's parent, ``jax.checkpoint`` under ``lax.scan`` differentiated
-    by JAX (still the loop of every policy but ``mem2``)."""
+    by JAX (still the loop of every policy but ``mem2``);
+    ``in_place=False``: likewise the owned loop of PR 57's parent, whose
+    forward kernel wrote buffers of its own (``_stacked_after_the_call``)."""
     from contextlib import ExitStack
     from unittest import mock
 
@@ -108,7 +123,7 @@ def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True,
     from ray_tpu.train.optim import adamw_lowmem
     from ray_tpu.train.step import build_sharded_train
 
-    key = (case, layers, packed, own_gradient, owned)
+    key = (case, layers, packed, own_gradient, owned, in_place)
     if key in _STEPS:
         return _STEPS[key]
     c = HEAD_CASES[case]
@@ -145,6 +160,9 @@ def _gpt2_step(v5e, case, layers=2, packed=True, own_gradient=True,
         if not owned:
             patched.enter_context(mock.patch.object(
                 gpt2, "_owns_backward", lambda *a: False))
+        if not in_place:
+            patched.enter_context(mock.patch.object(
+                gpt2, "attention_saving", _stacked_after_the_call))
         lowered = sstep.lower(*state, {"tokens": tokens})
     _STEPS[key] = lowered.compile()
     return _STEPS[key]
@@ -341,31 +359,56 @@ _INSTRUCTION = re.compile(
     r"\s*(?:ROOT )?%([\w.\-]+) = \(?\w+\[([\d,]*)\]\S* ([\w\-]+)\(")
 # what a fusion that only moves is made of (``dynamic-slice_bitcast_fusion``)
 _FREE = {"parameter", "constant", "bitcast"}
-_MOVES = _FREE | {"copy", "dynamic-slice", "slice", "reshape", "transpose"}
+_MOVES = _FREE | {"copy", "dynamic-slice", "slice", "reshape", "transpose",
+                  "dynamic-update-slice"}
 
 
-def _operand_copies(text, operand):
-    """Every instruction of the layers' bodies that is a ``copy``, a slice
-    or a fusion of nothing but those (inside a fusion that computes, an
-    operand is read where it lies) and whose result is ``operand``, the
-    dims of a flash kernel's."""
+def _fused_opcodes(text):
+    """computation -> the opcodes of its instructions: what a fusion that
+    calls it is made of."""
     fused = collections.defaultdict(set)
     for name, line in _by_computation(text):
         m = _INSTRUCTION.match(line)
         if m:
             fused[name].add(m.group(3))
+    return fused
+
+
+def _results(lines, opcodes, dims, fused):
+    """The instructions of ``lines`` with one of ``opcodes`` (a fusion
+    counts by what it is made of, ``fused``, less parameters, constants
+    and bitcasts: inside a fusion that computes, an operand is read, or a
+    result written, where it lies) whose result's dims, ones dropped, are
+    among ``dims``."""
     found = []
-    for line in (line for body in _layer_bodies(text) for line in body):
+    for line in lines:
         m = _INSTRUCTION.match(line)
         if not m or tuple(int(x) for x in m.group(2).split(",")
-                          if x not in ("", "1")) != operand:
+                          if x not in ("", "1")) not in dims:
             continue
         inner = {m.group(3)}
         if m.group(3) == "fusion":
             inner = fused[re.search(r"calls=%([\w.\-]+)", line).group(1)]
-        if inner <= _MOVES and inner - _FREE:
+            inner = inner - _FREE
+        if inner and inner <= set(opcodes):
             found.append(m.group(1))
     return found
+
+
+def _operand_copies(text, operand):
+    """Every instruction of the layers' bodies that is a ``copy``, a
+    slice, a ``dynamic-update-slice`` or a fusion of nothing but those and
+    whose result is ``operand``, the dims of a flash kernel's or of a stack
+    of them."""
+    lines = [line for body in _layer_bodies(text) for line in body]
+    return _results(lines, _MOVES - _FREE, [operand], _fused_opcodes(text))
+
+
+def _forward_body(text):
+    """The lines of the layers' forward loop body."""
+    body, = [body for body in _layer_bodies(text)
+             if not any("transpose(jvp(" in line for line in body)]
+    return body
 
 
 @pytest.mark.parametrize("case", ["one_chip", "fsdp4"])
@@ -436,3 +479,86 @@ def test_backward_kernel_is_what_the_benchmark_looks_for(v5e, case, owned):
         None, (b, rows, 1024, 1024, 128)]
     assert [k[0] for k in map(classify_flash, kernels) if k] == \
         ([] if owned else ["bwd"])
+
+
+# -- the forward kernel writes what its layer saves where the backward reads it
+
+def _fills(text, dims):
+    """The instructions of the ENTRY computation that fill an array of
+    ``dims`` with one value: a ``broadcast``, bare or all a fusion does."""
+    entry = re.search(r"^ENTRY %([\w.\-]+)", text, re.M).group(1)
+    lines = [line for name, line in _by_computation(text) if name == entry]
+    return _results(lines, ["broadcast"], [dims], _fused_opcodes(text))
+
+
+@pytest.mark.parametrize("case", ["one_chip", "fsdp4"])
+def test_forward_kernel_writes_its_layer_of_the_saves_in_place(v5e, case):
+    """``gpt2-large`` on one chip and ``gpt2-xl`` under fsdp=4, two layers
+    (a layer's body is what is asserted). XLA cannot point a Mosaic call's
+    result into a slice of a larger buffer: the forward kernel's o was
+    copied into its ``[layers, ...]`` stack after the call, and its lse,
+    ``[b, heads, 1024, 1]`` columns, made dense by a ``reduce`` and then
+    stacked (109 + 62 us of each of gpt2-large's 36 layers, PERF.md Findings
+    PR 57). The forward scan of ``gpt2._blocks_saving`` CARRIES the two
+    stacks and hands them to the kernel with a layer number: they are the
+    call's operands and its results, lse as the rows of lanes the backward
+    kernel reads; nothing in the forward body only moves o, a stack or
+    lse; the stacks begin as buffers nothing fills; the step needs no more
+    memory, and the layers are still two ``while``s."""
+    c = HEAD_CASES[case]
+    step, parent = _gpt2_step(v5e, case), _gpt2_step(v5e, case,
+                                                     in_place=False)
+    text = step.as_text()
+    b, rows = c["batch"] // c["mesh"].get("fsdp", 1), -(-c["heads"] // 2)
+    o, lse = (b, rows, 1024, 128), (b, 2 * rows, 4, 256)
+    forward = [k for k in _kernel_shapes(text) if len(k["outputs"]) == 2]
+    assert len(forward) == 1
+    stacks = [("bf16", (2,) + o), ("f32", (2,) + lse)]
+    assert [(t, tuple(d)) for t, d in forward[0]["operands"]] == [
+        ("s32", (1,)),  # the layer
+        ("bf16", o), ("bf16", o), ("bf16", o),  # q, k, v
+        *stacks], forward
+    assert [(t, tuple(d)) for t, d in forward[0]["outputs"]] == stacks
+    # neither body copies or slices a layer's o or a whole stack of them
+    assert not _operand_copies(text, o)
+    assert not _operand_copies(text, (2,) + o)
+    # ... and the forward body makes no lse dense and moves none
+    layouts = [(b, 2 * rows, 1024), lse, (2,) + lse]
+    assert not _results(_forward_body(text), _MOVES - _FREE | {"reduce"},
+                        layouts, _fused_opcodes(text))
+    # (this reading does find the parent's ``reduce`` of the columns, and
+    # the copy of o into its stack)
+    was = parent.as_text()
+    assert _results(_forward_body(was), ["reduce"], layouts,
+                    _fused_opcodes(was))
+    assert _operand_copies(was, (2,) + o)
+    # the stacks begin uninitialised (``jax.lax.empty``): no pass over
+    # 2 x 755 MB that the parent's scan did not make either
+    for dims in ((2,) + o, (2,) + lse):
+        assert not _fills(text, dims) and not _fills(was, dims)
+    for other in (parent, _gpt2_step(v5e, case, owned=False)):
+        assert step.memory_analysis().temp_size_in_bytes \
+            <= other.memory_analysis().temp_size_in_bytes
+        assert text.count(" while(") == other.as_text().count(" while(") == 3
+
+
+@pytest.mark.parametrize("owned", [True, False])
+@pytest.mark.parametrize("case", ["one_chip", "fsdp4"])
+def test_forward_kernel_is_what_the_benchmark_looks_for(v5e, case, owned):
+    """``kernel.flash_fwd_roofline`` finds the forward call in a trace by
+    its two results and the last four dims of its first three operands
+    (``benchmark/readers/flash_fwd_roofline.py forward_call``): the call
+    that writes its layer of the stacks and the checkpointed scan's, whose
+    results are its own buffers and whose lse is a column a head, are one
+    call doing one layer's work to it. ``kernel.flash_roofline`` sees
+    neither (packed rows: lse has twice the operands' rows)."""
+    from benchmark.readers.flash_fwd_roofline import forward_call
+    from benchmark.trace.opsbytes import classify_flash
+
+    c = HEAD_CASES[case]
+    b, rows = c["batch"] // c["mesh"].get("fsdp", 1), -(-c["heads"] // 2)
+    kernels = _kernel_shapes(_gpt2_step(v5e, case, owned=owned).as_text())
+    assert len(kernels) == 2
+    assert sorted(map(forward_call, kernels), key=bool) == [
+        None, (b, rows, 1024, 1024, 128)]
+    assert "fwd" not in [k[0] for k in map(classify_flash, kernels) if k]
